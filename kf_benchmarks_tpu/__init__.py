@@ -20,7 +20,3 @@ Layer map (mirrors reference SURVEY layer map):
 """
 
 __version__ = "0.1.0"
-
-# API-version bridging (jax.shard_map availability); must run before any
-# submodule builds a sharded program. No-op on current jax.
-from kf_benchmarks_tpu import compat as _compat  # noqa: E402,F401

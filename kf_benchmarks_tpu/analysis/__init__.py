@@ -22,11 +22,8 @@ Two layers:
   ``tests/golden_contracts/*.json``).
 
 * ``lint`` -- the **hazard lint**: an AST pass over the repo encoding
-  CLAUDE.md's hard-won environment rules (``jax.block_until_ready``
-  banned outside ``utils/sync.py``, version gates need a comment
-  naming the missing API, kill-based timeouts around TPU subprocesses
-  banned in tests and experiments, step-line format literals
-  single-sourced, flags must be cross-validated or carry an explicit
+  the repo's conventions (version gates need a comment naming the
+  missing API, step-line format literals single-sourced, flags must be cross-validated or carry an explicit
   no-validation marker, reference citations per module). Pure stdlib:
   importing ``lint`` never imports jax.
 

@@ -78,6 +78,7 @@ PROGRAM_SHAPE_EXCLUDE = frozenset({
     "benchmark_test_id", "trace_file", "trace_events_file",
     "tfprof_file", "graph_file", "partitioned_graph_file_prefix",
     "aot_save_path", "aot_load_path", "backbone_model_path",
+    "compilation_cache_dir",
     "use_chrome_trace_format", "display_every", "save_model_secs",
     "save_model_steps", "save_summaries_steps", "summary_verbosity",
     "max_ckpts_to_keep", "eval_interval_secs",

@@ -32,7 +32,22 @@ from typing import Any, Dict, List, Optional
 
 # -- shared HLO-scraping helpers (the tests import these) ---------------------
 
-ALL_REDUCE_DEF = re.compile(r"=\s+\S+\s+all-reduce(-start)?\(")
+# The result type is one array type or, for a variadic all-reduce, a
+# parenthesized tuple of them.
+ALL_REDUCE_DEF = re.compile(
+    r"=\s+(?:\([^)]*\)|\S+)\s+all-reduce(-start)?\(")
+
+
+def compile_for_audit(lowered):
+  """Compile a lowered program for collective-structure inspection.
+
+  XLA:CPU's all-reduce combiner is switched off: the dump then shows
+  the collectives the PROGRAM places (one per bucket, inside or outside
+  a scan body), not what one backend's combiner merges them into --
+  the property every count/placement rule and HLO pin checks."""
+  return lowered.compile(compiler_options={
+      "xla_disable_hlo_passes": "cpu-all-reduce-combiner"})
+
 
 # A non-scalar all-reduce below this element count is a packed
 # metric/health vector (telemetry packs ~10 floats onto the loss pmean),
@@ -307,7 +322,7 @@ def trace_contract(overrides: Dict[str, Any],
   in_dtypes = bench.model.get_input_data_types("train")
   n = bench.num_devices
   n_data = int(getattr(bench, "num_data_replicas", n))
-  compiled = lowered.compile()
+  compiled = compile_for_audit(lowered)
 
   aux: Dict[str, Any] = {
       "model": bench.model.get_name(),
@@ -639,8 +654,8 @@ def trace_serving_contract(overrides: Dict[str, Any],
     fn, args, donate = decode_lib.verify_lowering_args(spec, bucket)
   else:
     fn, args, donate = decode_lib.decode_lowering_args(spec, bucket)
-  compiled = decode_lib.aot_jit(spec, fn, program, bucket,
-                                donate).lower(*args).compile()
+  compiled = compile_for_audit(
+      decode_lib.aot_jit(spec, fn, program, bucket, donate).lower(*args))
   itemsize = jnp.dtype(spec.dtype).itemsize
   aux: Dict[str, Any] = {
       "bucket_ladder": list(engine_lib.DEFAULT_BUCKET_LADDER),

@@ -39,7 +39,7 @@ Semantics (all enforced by the injector, pinned in tests/test_faults.py):
   post-mortem, telemetry.py) end to end.
 * ``heartbeat_delay`` sleeps on the host between dispatches, starving
   the stall watchdog's heartbeat -- the watchdog must diagnose and
-  NEVER kill (CLAUDE.md wedge hazard).
+  NEVER kill (telemetry.py StallWatchdog).
 * ``drop_msg`` suppresses the NEXT coordination-service poll (sticky
   across boundaries when the fault step is not itself a poll step):
   the elastic dedup must re-see a pending RESIZE on the following poll

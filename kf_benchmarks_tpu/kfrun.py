@@ -28,8 +28,17 @@ Usage:
 
 On real multi-host TPU pods the TPU runtime launches one process per
 host and JAX's distributed init handles the device mesh; kfrun covers
-the single-host-many-process and CPU-test topologies, and the
-coordinator serves as the DCN control plane in both cases.
+the CPU many-process topology (the tests) and the one-process-per-host
+launch, and the coordinator serves as the DCN control plane in both.
+
+On ONE TPU host, multi-chip means ONE process with ``--num_devices=N``
+(measured on a four-chip v5e host, PR 21: sync_sgd / async_sgd / sma
+all run that way). A chip belongs to the process that first touches
+JAX, so ``kfrun -np N`` with N > 1 on one TPU host would hand every
+chip to the first worker; kfrun does not pin chips per worker. For the
+same reason this launcher parent stays OFF JAX: it imports only
+``coordination`` and ``tracing`` (neither initialises a backend), so
+the chips are free for the worker it starts. Keep it so.
 """
 
 from __future__ import annotations

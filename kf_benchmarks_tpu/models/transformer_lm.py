@@ -481,6 +481,12 @@ class TransformerLMModel(model_lib.Model):
       raise ValueError(
           f"KF_TRANSFORMER_LM_ATTN must be 'tiled' or 'flash', got "
           f"{impl!r}")
+    # The library flash kernel's pallas_call gives its outputs no vma
+    # type, which the step's shard_map checker refuses ("`vma` on
+    # `jax.ShapeDtypeStruct` must not be `None`", TPU v5e, PR 21): the
+    # flash arm opts out of the checker like the other models built on
+    # untyped library internals (train_step.py relax_shard_map_vma).
+    self.relax_shard_map_vma = impl == "flash"
     head = os.environ.get("KF_TRANSFORMER_LM_HEAD", "fused")
     if head not in ("fused", "dense"):
       raise ValueError(
@@ -549,7 +555,7 @@ class TransformerLMModel(model_lib.Model):
           lambda s: jax.ShapeDtypeStruct(tuple(s.shape)[1:], s.dtype),
           variables["params"]["blocks"])
       # --partitioner=gspmd traces the step under double vmap, which
-      # has no tuple-axis all_gather batching rule (jax 0.4.x): the
+      # has no tuple-axis all_gather batching rule (jax 0.9.0): the
       # hook's forward gather decomposes per axis there (element-
       # identical; ops/sharded.combined_all_gather).
       fsdp_block_hook = overlap_lib.fsdp_block_gatherer(

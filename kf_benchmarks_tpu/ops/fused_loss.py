@@ -111,7 +111,7 @@ def fused_softmax_xent(hidden, kernel, labels, chunk_size: int = 256,
     return carry + jnp.sum(ll), None
 
   # Inside a shard_map body the hidden states are device-varying, so the
-  # carry must be pcast to match (no-op on pre-vma jax; sequence.py).
+  # carry must be pcast to match (sequence.py vary_like).
   (zero,) = sequence_lib.vary_like(hidden,
                                    (jnp.zeros((), jnp.float32),))
   total, _ = jax.lax.scan(body, zero, (hc, yc, wc))

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from kf_benchmarks_tpu.ops import allreduce
+from kf_benchmarks_tpu.parallel import sequence as sequence_lib
 
 # Default bucket bound. The reference's --gradient_repacking=8 on a
 # ~100 MB ResNet-50 gradient vector works out to ~12 MB chunks; 4 MB
@@ -118,7 +119,13 @@ def _reduce_identity_fwd(reduce_fn, tree):
 
 
 def _reduce_identity_bwd(reduce_fn, _, cotangent):
-  return (reduce_fn(cotangent),)
+  # The reduced cotangent is replicated over the reduction axis, but a
+  # bwd rule must return the primal input's type, which varies over it
+  # (per-replica stacked params): pcast each leaf back up to its
+  # cotangent's varying axes -- a type-level cast, no data movement.
+  return (jax.tree.map(
+      lambda ct, x: sequence_lib.vary_like(ct, (x,))[0],
+      cotangent, reduce_fn(cotangent)),)
 
 
 reduce_identity.defvjp(_reduce_identity_fwd, _reduce_identity_bwd)
@@ -288,7 +295,7 @@ def packed_gather_rows(axes, shapes, dtypes, shards, nested=False):
   axes tuple matches the flat shard index). ``nested`` decomposes the
   tuple-axis gather into per-axis gathers (innermost first -- same
   row-major order) for the gspmd twin, whose double-vmap trace has no
-  tuple-axis all_gather batching rule in jax 0.4.x."""
+  tuple-axis all_gather batching rule (jax 0.9.0)."""
   n = math.prod(lax.axis_size(a) for a in axes)
   ks = tuple(int(s.shape[0]) for s in shards)
   vec = jnp.concatenate(list(shards)) if len(shards) > 1 else shards[0]

@@ -113,7 +113,7 @@ def combined_all_gather(x, batch_axis: str = BATCH_AXIS,
   tiled gathers -- element-identical (inner gather tiles the model
   peers, outer gather tiles the batch groups, reproducing the
   row-major ``b * M + m`` concatenation order exactly) but required on
-  the --partitioner=gspmd path: jax 0.4.x has no vmap batching rule
+  the --partitioner=gspmd path: jax (0.9.0) has no vmap batching rule
   for a tuple-axis all_gather, and the gspmd twin traces the step body
   under double ``jax.vmap`` (train_step.py)."""
   if not nested:

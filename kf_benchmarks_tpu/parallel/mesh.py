@@ -55,8 +55,13 @@ def get_devices(device_kind: str = "tpu", num_devices: Optional[int] = None):
   every process's devices, so the resolved list is global."""
   devices = jax.devices()
   if device_kind == "cpu":
-    cpus = [d for d in devices if d.platform == "cpu"]
-    devices = cpus or devices
+    found = sorted({d.platform for d in devices})
+    devices = [d for d in devices if d.platform == "cpu"]
+    if not devices:
+      raise ValueError(
+          "--device=cpu but JAX exposes no CPU device (platforms: "
+          f"{found}); benchmark.setup() selects the CPU platform "
+          "before the backend initializes")
   if num_devices is not None:
     # Take the first num_devices of EACH process's devices (a global
     # prefix could exclude some processes entirely, leaving them with no

@@ -94,11 +94,6 @@ def vary_like(ref, arrays, default_axes=(), extra_axes=()):
   the axes each array is MISSING are pcast -- pcast rejects
   already-varying axes.
   """
-  if not hasattr(lax, "pcast"):
-    # Pre-vma jax (e.g. 0.4.x): avals carry no varying-manual-axes type
-    # information and shard_map's check_rep accepts untyped carries, so
-    # there is nothing to cast.
-    return arrays
   want = (set(getattr(ref.aval, "vma", ()) or default_axes)
           | set(extra_axes))
   if not want:
@@ -785,6 +780,11 @@ def pallas_flash_attention(q, k, v, causal: bool = False,
   ``True`` forces the reference path on any backend. Differentiable on
   both paths -- the library ships fused dq/dkv backward kernels via
   custom_vjp.
+
+  This is a CPU path for the CPU suites, not a fallback that can hide
+  the device: under ``--device=tpu`` benchmark.setup() has already
+  refused anything but a TPU, so the default cannot take the reference
+  path there, and no caller on the TPU path passes ``cpu_fallback=True``.
   """
   if cpu_fallback is None:
     cpu_fallback = jax.default_backend() != "tpu"

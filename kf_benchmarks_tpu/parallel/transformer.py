@@ -214,9 +214,7 @@ def _fsdp_block_hook(block_template, axes):
   split_shard_row) so the row addressing cannot drift from the
   benchmark leg's gather_params; only the reduction differs: SUM over
   the combined axes (one shard row per device) instead of
-  gather_params' batch-mean + model sub-slice. Works on vma and
-  pre-vma jax alike: the collectives are explicit, like
-  reduce_identity's pre-vma arm in _scan_grad_hook."""
+  gather_params' batch-mean + model sub-slice."""
   from kf_benchmarks_tpu.ops import overlap as overlap_lib
   t_leaves = jax.tree_util.tree_flatten(block_template)[0]
   shapes = tuple(tuple(t.shape) for t in t_leaves)
@@ -286,30 +284,15 @@ def _scan_grad_hook(data_axes):
   iteration's backward compute -- instead of trailing the whole
   backward.
 
-  Two implementations, gated on the vma API (``lax.pcast`` is the
-  missing API pre-vma, the same gate as compat.py/sequence.vary_like):
-
-  * vma jax: pcast the slice to varying on the data axes. Downstream
-    ops then need no implicit pbroadcast, and pcast's TRANSPOSE is the
-    psum -- placed exactly here, in the scan body. Total reduction
-    semantics are unchanged (the implicit machinery inserted the same
-    psum); only its schedule position moves.
-  * pre-vma jax: an identity-with-custom_vjp whose backward psums the
-    slice cotangent over the data axes explicitly (pre-vma shard_map
-    autodiff inserts no implicit psums).
+  The hook pcasts the slice to varying on the data axes. Downstream
+  ops then need no implicit pbroadcast, and pcast's TRANSPOSE is the
+  psum -- placed exactly here, in the scan body. Total reduction
+  semantics are unchanged (the implicit machinery inserted the same
+  psum); only its schedule position moves.
   """
-  if hasattr(lax, "pcast"):
-    def hook(lp):
-      return jax.tree.map(
-          lambda t: lax.pcast(t, data_axes, to="varying"), lp)
-    return hook
-  from kf_benchmarks_tpu.ops import overlap as overlap_lib
-  reduce_fn = lambda g: jax.tree.map(
-      lambda t: lax.psum(t, data_axes), g)
-
   def hook(lp):
-    return overlap_lib.reduce_identity(reduce_fn, lp)
-
+    return jax.tree.map(
+        lambda t: lax.pcast(t, data_axes, to="varying"), lp)
   return hook
 
 
@@ -627,11 +610,8 @@ def make_train_step(mesh: Mesh, params_template, learning_rate: float,
   param slice in the scan body (_scan_grad_hook) so the layer's
   data-axis gradient reduction is issued inside the backward scan
   iteration, overlapped with the preceding layer's backward, instead
-  of trailing the whole backward. Reduction semantics are unchanged on
-  vma jax (the hook only moves the psum's schedule position); on
-  pre-vma jax (no lax.pcast) the hook's explicit psums cover the
-  hooked block leaves only -- the same limitation that gates the
-  composed-trainer oracle tests there."""
+  of trailing the whole backward. Reduction semantics are unchanged
+  (the hook only moves the psum's schedule position)."""
   if sp_layout not in ("contiguous", "zigzag"):
     raise ValueError(f"unknown sp_layout {sp_layout!r}")
   if overlap_grad_reduce and not scan_layers:

@@ -38,8 +38,7 @@ turns request ARRIVALS into device throughput:
   the standard percentile machinery; counters/gauges go through the
   registered ``serving/*`` schema keys (metrics.py). Decode-step
   device time is attributed from completion-to-completion intervals
-  (the token fetch is a value dependency) -- never
-  ``jax.block_until_ready`` (utils/sync.py).
+  (the token fetch is a value dependency).
 """
 
 from __future__ import annotations

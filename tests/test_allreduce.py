@@ -174,10 +174,6 @@ def test_hier_unequal_groups_fall_back_to_pmean():
 
 
 @pytest.mark.distributed
-@pytest.mark.skipif(not hasattr(jax.lax, "pcast"),
-                    reason="jax 0.4.x: multiprocess computations are "
-                           "not implemented on the CPU backend (the "
-                           "gloo cross-host path landed later)")
 def test_two_process_hierarchical_copy_groups_and_numerics(tmp_path):
   """2-process virtual cluster: build_reducer's hierarchical_copy groups
   must align with process boundaries and the grouped reduction must

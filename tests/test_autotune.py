@@ -428,7 +428,16 @@ def test_warm_precompiles_and_follow_up_run_reads_cache_hit(tmp_path):
   cfg = dict(model="trivial", batch_size=4, device="cpu",
              num_devices=8, steps_per_dispatch=2, num_batches=6,
              num_warmup_batches=2)
-  summary = autotune.warm(td, configs=[cfg], log=lambda s: None)
+  # The cache goes where the one rule says (benchmark.
+  # configure_compile_cache): off by default on the CPU, so the warm
+  # pass and the follow-up run both name it -- never <train_dir>/
+  # xla_cache.
+  cache = str(tmp_path / "cache")
+  with pytest.raises(ValueError, match="no persistent XLA cache"):
+    autotune.warm(td, configs=[cfg], log=lambda s: None)
+  summary = autotune.warm(td, configs=[cfg], cache_dir=cache,
+                          log=lambda s: None)
+  assert summary["cache_dir"] == cache
   # steps_per_dispatch=2 predicts both the chunk and the single-step
   # program; both land in the ledger and the cache dir is populated.
   assert {prog for _, prog in summary["warmed"]} == \
@@ -438,11 +447,16 @@ def test_warm_precompiles_and_follow_up_run_reads_cache_hit(tmp_path):
   assert tracing_lib.ledger_programs(ledger) == \
       {"train_step", "train_chunk"}
   # Warming twice is idempotent: everything reads already-warm.
-  again = autotune.warm(td, configs=[cfg], log=lambda s: None)
+  again = autotune.warm(td, configs=[cfg], cache_dir=cache,
+                        log=lambda s: None)
   assert not again["warmed"] and len(again["skipped"]) == 2
 
-  p = params_lib.make_params(**cfg, train_dir=td)
-  benchmark.BenchmarkCNN(p).run()
+  p = params_lib.make_params(**cfg, train_dir=td,
+                             compilation_cache_dir=cache)
+  try:
+    benchmark.BenchmarkCNN(p).run()
+  finally:
+    benchmark.configure_compile_cache("cpu")  # process-global: off again
   after = tracing_lib.read_ledger(td)
   recompiled = {key: row for key, row in after["entries"].items()
                 if "cache_hit" in row}

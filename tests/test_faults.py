@@ -185,7 +185,7 @@ def test_drop_msg_delays_but_never_loses_a_resize():
 def test_heartbeat_delay_starves_watchdog_which_never_kills(tmp_path):
   """A 6 s injected heartbeat gap (past the 5 s min-stall floor) makes
   the watchdog emit its diagnostic and count a stall; the run finishes
-  -- the watchdog NEVER kills (CLAUDE.md wedge hazard)."""
+  -- the watchdog NEVER kills (telemetry.py StallWatchdog)."""
   tmp = str(tmp_path / "train")
   logs, stats = _run(train_dir=tmp, stall_watchdog_factor=0.1,
                      fault_schedule="heartbeat_delay@4:secs=6")

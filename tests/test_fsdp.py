@@ -155,7 +155,6 @@ def test_fsdp_stacked_shards_layout():
 
 
 def _shard_map_2d(fn, mesh, in_specs, out_specs):
-  import kf_benchmarks_tpu.compat  # noqa: F401 (shard_map bridge)
   return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False))
 

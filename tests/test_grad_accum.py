@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from kf_benchmarks_tpu import benchmark, params as params_lib, validation
+from kf_benchmarks_tpu.analysis import contracts
 from kf_benchmarks_tpu.utils import log as log_util
 
 STEP_RE = re.compile(
@@ -324,7 +325,8 @@ def test_accum_emits_one_reduction_collective_per_step():
     x = jnp.zeros((8 * 4, 8), jnp.float32)
     y = jnp.zeros((8 * 4,), jnp.int32)
     state = jax.jit(init_state)(jax.random.PRNGKey(0), x[:1])
-    return train_step.lower(state, x, y).compile().as_text()
+    return contracts.compile_for_audit(
+        train_step.lower(state, x, y)).as_text()
 
   # Shared HLO conventions (analysis/contracts.py): gradient traffic is
   # the non-scalar all-reduce; f32[] reductions are the metric pmeans.

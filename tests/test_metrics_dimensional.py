@@ -12,9 +12,9 @@ Reference-style layering (SURVEY 7.1):
     the engine's own RequestResults over a seeded multi-tenant
     workload.
   * e2e: seeded budget exhaustion fires exactly ONE alert episode
-    (flight-recorder rows included) and recovery clears it; the
-    committed BENCH_r0*.json history backfills into a non-empty
-    report; bench.py --serving --check-regression prints one
+    (flight-recorder rows included) and recovery clears it; a seeded
+    run store renders a non-empty report through the CLI; bench.py
+    --serving --check-regression prints one
     direction-aware verdict line per gated serving key.
 """
 
@@ -249,8 +249,7 @@ def test_metric_direction_reads_schema_then_heuristics():
   # composite metric names).
   assert metrics.metric_direction("serving_tokens_per_sec") is True
   assert metrics.metric_direction(
-      "resnet50_synthetic_images_per_sec_CPU_FALLBACK_tpu_unreachable"
-  ) is True
+      "resnet50_synthetic_images_per_sec") is True
 
 
 def _rows(values, metric, fingerprint="fp-d"):
@@ -290,7 +289,7 @@ def test_record_and_check_gates_serving_snapshot_keys(tmp_path, capsys):
            "unit": "tokens/sec", "platform": "tpu",
            "serving/ttft_p99": 0.05, "serving/shed_fraction": 0.0}
     assert bench.record_and_check(
-        rec, True, store_dir, False, run_id=f"seed{i}",
+        rec, store_dir, False, run_id=f"seed{i}",
         fingerprint="fp-s") == 0
   # Fresh run: throughput fine, TTFT p99 10x worse -- only the
   # snapshot gate can catch it, and only with the LOWER-is-better
@@ -299,7 +298,7 @@ def test_record_and_check_gates_serving_snapshot_keys(tmp_path, capsys):
          "unit": "tokens/sec", "platform": "tpu",
          "serving/ttft_p99": 0.5, "serving/shed_fraction": 0.0}
   rc = bench.record_and_check(
-      rec, True, store_dir, True, run_id="fresh", fingerprint="fp-s",
+      rec, store_dir, True, run_id="fresh", fingerprint="fp-s",
       extra_keys=("serving/ttft_p99", "serving/shed_fraction"))
   err = capsys.readouterr().err
   assert rc == 1
@@ -345,6 +344,8 @@ def _registry():
   metrics.deactivate()
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_engine_per_tenant_percentiles_match_hand_rolled(_registry):
   from kf_benchmarks_tpu.serving import engine as engine_lib
   eng, spec = _small_engine(ttft_slo_s=30.0)
@@ -409,14 +410,14 @@ def test_fleet_rows_group_filter_and_verdict():
                 fingerprint="fp-good")
           + _rows([1.0, 1.0], "y_per_sec", fingerprint="fp-thin"))
   for r in recs[5:]:
-    r["fallback"] = True
+    r["platform"] = "cpu"
   rows = metrics.fleet_rows(recs)
   by_fp = {r["fingerprint"]: r for r in rows}
   assert by_fp["fp-good"]["n"] == 5
   assert by_fp["fp-good"]["verdict"] == "regression"  # last = 50
   assert by_fp["fp-thin"]["verdict"] == "no_history"
-  assert by_fp["fp-thin"]["fallback"] is True
-  assert metrics.fleet_rows(recs, fallback="none") == [by_fp["fp-good"]]
+  assert by_fp["fp-thin"]["platform"] == "cpu"
+  assert metrics.fleet_rows(recs, platform="tpu") == [by_fp["fp-good"]]
   assert metrics.fleet_rows(recs, fingerprint="fp-g")[0][
       "fingerprint"] == "fp-good"
   assert metrics.fleet_rows(recs, metric="y_per_sec")[0][
@@ -432,39 +433,37 @@ def test_fleet_report_html_is_self_contained(tmp_path):
   for r in recs:
     r["snapshot"] = {"serving/ttft_p50": 0.01, "serving/ttft_p90": 0.02,
                      "serving/ttft_p99": 0.03}
-  fell = _rows([1.0, 1.1], "x_per_sec", fingerprint="fp-f")
-  for r in fell:
-    r["fallback"] = True
-  html = metrics.fleet_report_html(metrics.fleet_rows(recs + fell))
+  html = metrics.fleet_report_html(metrics.fleet_rows(recs))
   assert html.startswith("<!doctype html>")
   assert "<svg" in html and "polyline" in html
-  assert "_CPU_FALLBACK probes" in html
+  assert "No matching run records" in metrics.fleet_report_html([])
   # Self-contained: no external fetches of any kind.
   assert "http://" not in html and "https://" not in html
   assert "<script" not in html
 
 
-def test_report_cli_on_backfilled_history(tmp_path, capsys):
-  # Acceptance: the committed BENCH history renders a non-empty
-  # trajectory through the actual CLI.
+def test_report_cli_on_a_seeded_store(tmp_path, capsys):
+  # Acceptance: a store renders a non-empty trajectory through the
+  # actual CLI, and the platform filter narrows it.
   store_dir = str(tmp_path)
-  assert metrics.main(["backfill", "--repo", REPO,
-                       "--run_store_dir", store_dir]) == 0
-  capsys.readouterr()
+  store = metrics.RunStore(store_dir)
+  for r in (_rows([2400.0, 2410.0, 2390.0], "x_per_sec",
+                  fingerprint="fp-chip")
+            + _rows([1.0, 1.1], "x_per_sec", fingerprint="fp-cpu")):
+    if r["fingerprint"] == "fp-cpu":
+      r["platform"] = "cpu"
+    store.append(r)
   out_html = str(tmp_path / "fleet.html")
   assert metrics.main(["report", "--repo", REPO,
                        "--run_store_dir", store_dir,
                        "--html", out_html]) == 0
   out = capsys.readouterr().out
-  assert "FINGERPRINT" in out and "trend row(s)" in out
-  assert "_CPU_FALLBACK" in out  # r02-r05 probes, segregated by flag
+  assert "FINGERPRINT" in out and "2 trend row(s)" in out
   with open(out_html) as f:
-    html = f.read()
-  assert "<svg" in html and "_CPU_FALLBACK probes" in html
-  # Filters narrow the table.
+    assert "<svg" in f.read()
   assert metrics.main(["report", "--repo", REPO,
                        "--run_store_dir", store_dir,
-                       "--fallback", "none"]) == 0
+                       "--platform", "tpu"]) == 0
   narrowed = capsys.readouterr().out
-  assert "_CPU_FALLBACK" not in narrowed
+  assert "fp-cpu" not in narrowed
   assert "1 trend row(s)" in narrowed

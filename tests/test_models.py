@@ -114,6 +114,8 @@ def test_model_gradient_step(name, dataset):
   assert any(float(jnp.max(jnp.abs(g))) > 0 for g in leaves)
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_mobilenet_forward():
   """MobileNet v2 builds, classifies, and has the expected scale
   (ref: models/mobilenet_v2.py:188-198)."""

@@ -11,6 +11,8 @@ import pytest
 
 from kf_benchmarks_tpu import benchmark, observability, params as params_lib
 
+V5E = observability.DEVICE_PEAKS["TPU v5 lite"]
+
 
 def _run(tmp_path, **overrides):
   defaults = dict(model="trivial", batch_size=4, num_batches=6,
@@ -38,25 +40,53 @@ def test_cost_analysis_dump(tmp_path):
     assert report["cost_analysis"].get("flops", 0) > 0
 
 
-def test_per_op_profile_table(tmp_path, capsys):
-  """--tfprof_file also emits the operator-facing top-op ranking the
-  reference printed from tfprof (ref: benchmark_cnn.py:1208-1228): a
-  <path>.ops.txt table AND stdout lines, with MXU flops attributed to
-  dot/conv rows (VERDICT r2 #7)."""
+def test_per_op_profile_needs_a_known_device(tmp_path, capsys):
+  """--tfprof_file on a device with no DEVICE_PEAKS entry (the CPU
+  here) prints NO roofline table and no MFU line -- it says why -- and
+  the host-axis line comes from the run's own measured dispatch
+  overhead, not a constant."""
   path = str(tmp_path / "profile.json")
   _run(tmp_path, model="lenet", tfprof_file=path)
-  table = open(path + ".ops.txt").read()
+  out = capsys.readouterr().out
+  assert "device_kind 'cpu' has no entry in observability.DEVICE_PEAKS" \
+      in out
+  assert not os.path.exists(path + ".ops.txt")
+  assert "MFU: " not in out
+  assert observability.PER_OP_TABLE_HEADER not in out
+  measured = [l for l in out.splitlines()
+              if l.startswith("dispatch overhead:")]
+  assert len(measured) == 1 and "measured" in measured[0]
+
+
+def test_device_peaks_table_is_keyed_by_device_kind():
+  assert V5E.flops == 197e12 and V5E.bytes_per_s == 819e9
+  assert "TPU v5e" in V5E.source
+  # The test host's device has no entry: no default peak exists.
+  assert jax.devices()[0].device_kind not in observability.DEVICE_PEAKS
+
+
+def test_per_op_profile_table(tmp_path):
+  """The operator-facing top-op ranking the reference printed from
+  tfprof (ref: benchmark_cnn.py:1208-1228), over a real compiled
+  program and the v5e peaks: a <path>.ops.txt table with MXU flops
+  attributed to dot/conv rows (VERDICT r2 #7)."""
+  bench = benchmark.BenchmarkCNN(params_lib.make_params(
+      model="lenet", device="cpu", batch_size=4, num_devices=1,
+      num_batches=1))
+  from kf_benchmarks_tpu.analysis import contracts
+  _, lowered = contracts.lower_step_program(bench, "train_step")
+  path = str(tmp_path / "profile.json.ops.txt")
+  table = observability.dump_per_op_profile(lowered.compile(), path, V5E)
+  assert open(path).read() == table + "\n"
   lines = table.splitlines()
   assert lines[0].startswith("Top 20 ops by estimated accelerator time")
   assert lines[1] == observability.PER_OP_TABLE_HEADER
-  # The table closes with the three whole-program lines the per-op rows
-  # cannot carry: per-dispatch RTT amortization (--steps_per_dispatch),
-  # the roofline MFU ceiling (round 7), and the comm/compute overlap
-  # fraction (round 8, --overlap_gradient_reduction).
-  assert lines[-3].startswith("dispatch overhead:")
+  # The table closes with the two whole-program lines the per-op rows
+  # cannot carry: the roofline MFU ceiling and the comm/compute overlap
+  # fraction (--overlap_gradient_reduction).
   assert lines[-2].startswith("MFU: ")
   assert lines[-1].startswith("comm/compute overlap:")
-  ranked = lines[2:-3]
+  ranked = lines[2:-2]
   assert len(ranked) > 1  # actual ranked rows
   # Ranked by estimated time, descending.
   times = [float(l.split()[1]) for l in ranked]
@@ -65,9 +95,6 @@ def test_per_op_profile_table(tmp_path, capsys):
   mxu_rows = [l for l in ranked
               if l.endswith(" convolution") or l.endswith(" dot")]
   assert mxu_rows and all(float(r.split()[3]) > 0 for r in mxu_rows)
-  # The table is also printed to the step log (operator-facing).
-  out = capsys.readouterr().out
-  assert observability.PER_OP_TABLE_HEADER in out
 
 
 def test_per_op_costs_parses_synthetic_hlo():
@@ -90,7 +117,7 @@ ENTRY %main (x: f32[4,8,8,16], k: f32[3,3,16,32], w: f32[32,10]) -> f32[4,10] {
   ROOT %dot = f32[256,10]{1,0} dot(%resh, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
 }
 """
-  rows = {r["name"]: r for r in observability.per_op_costs(hlo)}
+  rows = {r["name"]: r for r in observability.per_op_costs(hlo, V5E)}
   assert "%t" not in rows  # fusion body excluded
   assert rows["%conv"]["flops"] == 2 * (4 * 8 * 8 * 32) * (3 * 3 * 16)
   assert rows["%dot"]["flops"] == 2 * 256 * 10 * 32
@@ -112,7 +139,7 @@ def test_per_op_costs_depthwise_conv_flops():
   txt = jax.jit(dw).lower(
       jnp.ones((4, 8, 8, 32), jnp.float32),
       jnp.ones((3, 3, 1, 32), jnp.float32)).compile().as_text()
-  convs = [r for r in observability.per_op_costs(txt)
+  convs = [r for r in observability.per_op_costs(txt, V5E)
            if r["opcode"] == "convolution"]
   assert convs and convs[0]["flops"] == 2 * (4 * 8 * 8 * 32) * 9
 
@@ -296,7 +323,8 @@ def test_measured_per_op_profile_e2e(tmp_path, capsys):
 
 
 def test_measured_profile_absent_without_trace(tmp_path):
-  """No trace -> no measured table (the static .ops.txt still appears);
+  """No trace -> no measured table (and, on this CPU host, no static
+  roofline table either: no DEVICE_PEAKS entry);
   dump_measured_op_profile returns None rather than writing a header-only
   file -- and an untraced run REMOVES a stale table a previous traced run
   left at the same profile path (it must not masquerade as this run's)."""
@@ -305,7 +333,6 @@ def test_measured_profile_absent_without_trace(tmp_path):
   with open(stale, "w") as f:
     f.write("previous run's table\n")
   _run(tmp_path, model="lenet", tfprof_file=prof)
-  assert os.path.exists(prof + ".ops.txt")
   assert not os.path.exists(stale)
   assert observability.dump_measured_op_profile(
       str(tmp_path / "empty"), str(tmp_path / "out.txt")) is None
@@ -334,14 +361,22 @@ def test_eval_metrics_logged(tmp_path):
 
 def test_mfu_line_math_and_format():
   # 98.5 TFLOP/s over the 197 TFLOP/s peak = 50%.
-  line = observability.mfu_line(98.5e12 * 0.004, 0.004)
+  line = observability.mfu_line(98.5e12 * 0.004, 0.004, V5E)
   assert line.startswith("MFU: 50.0%"), line
   assert "98.50 TFLOP/s" in line
   assert "197 TFLOP/s" in line
-  assert observability.mfu_line(1.0, 0.0) == "MFU: n/a (no step time)"
+  assert observability.mfu_line(1.0, 0.0, V5E) == \
+      "MFU: n/a (no step time)"
   # Measured-rate variant names its source for auditability.
-  assert "measured" in observability.mfu_line(1e12, 1.0,
+  assert "measured" in observability.mfu_line(1e12, 1.0, V5E,
                                               source="measured")
+
+
+def test_dispatch_overhead_line_reads_the_measurement():
+  line = observability.dispatch_overhead_line(0.002, 0.1, 4)
+  assert line.startswith("dispatch overhead: 2.000 ms host time/dispatch "
+                         "measured over 4 step(s)/dispatch")
+  assert "2.0% of dispatch wall" in line
 
 
 def test_per_op_table_ends_with_mfu_line():
@@ -353,12 +388,10 @@ ENTRY e {
   ROOT %d = f32[64,64] dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
 }
 """
-  table = observability.per_op_table(hlo)
+  table = observability.per_op_table(hlo, V5E)
   lines = table.splitlines()
-  # Closing order: dispatch overhead, MFU, comm/compute overlap
-  # (round 8 added the overlap-fraction line).
+  # Closing order: MFU, comm/compute overlap.
   assert lines[-2].startswith("MFU: ")
-  assert lines[-3].startswith("dispatch overhead:")
   assert lines[-1].startswith("comm/compute overlap:")
   # flops of the dot appear in the MFU line's flops/step field.
   assert "5.243e+05" in lines[-2], lines[-2]
@@ -391,8 +424,6 @@ def test_tfprof_run_logs_hbm_line(tmp_path):
     log_util.log_fn = orig
   hbm = [l for l in logs if l.startswith("peak HBM (compiled):")]
   assert len(hbm) == 1, [l for l in logs if "HBM" in l]
-  mfu = [l for l in logs if l.startswith("MFU: ")]
-  assert mfu, "per-op table should close with the MFU line"
 
 
 # -- run_tests.py tiering helpers ---------------------------------------------
@@ -539,23 +570,24 @@ ENTRY %main (a: f32[8]) -> f32[8] {
 
 
 def test_collective_overlap_stats_splits_in_loop_vs_trailing():
-  stats = observability.collective_overlap_stats(_OVERLAP_HLO)
+  stats = observability.collective_overlap_stats(_OVERLAP_HLO, V5E)
   assert stats["num_collectives"] == 2
   # One of the two rides the while body (in-backward, overlappable).
   assert 0.0 < stats["overlap_fraction"] < 1.0
   assert abs(stats["overlap_fraction"] - 0.5) < 1e-6
-  line = observability.overlap_fraction_line(_OVERLAP_HLO)
+  line = observability.overlap_fraction_line(_OVERLAP_HLO, V5E)
   assert "50.0% issued inside loop bodies" in line
   assert "2 collectives" in line
 
 
 def test_overlap_fraction_line_no_collectives():
-  line = observability.overlap_fraction_line("ENTRY %main () -> f32[] {\n}")
+  line = observability.overlap_fraction_line("ENTRY %main () -> f32[] {\n}",
+                                            V5E)
   assert "no collectives" in line
 
 
 def test_per_op_table_includes_overlap_line():
-  table = observability.per_op_table(_OVERLAP_HLO)
+  table = observability.per_op_table(_OVERLAP_HLO, V5E)
   assert "comm/compute overlap:" in table.splitlines()[-1]
 
 
@@ -585,7 +617,8 @@ ENTRY %main (a: f32[64], b: f32[128]) -> f32[128] {
 
 
 def test_collective_overlap_stats_counts_non_allreduce_opcodes():
-  stats = observability.collective_overlap_stats(_MULTI_COLLECTIVE_HLO)
+  stats = observability.collective_overlap_stats(_MULTI_COLLECTIVE_HLO,
+                                                 V5E)
   # collective-permute (in-loop), reduce-scatter, all-gather-start; the
   # -done half of the async pair is not a second collective.
   assert stats["num_collectives"] == 3
@@ -593,15 +626,15 @@ def test_collective_overlap_stats_counts_non_allreduce_opcodes():
   # Only the collective-permute rides the while body.
   permute_bytes = 64 * 4
   assert stats["comm_in_loop_s"] == pytest.approx(
-      permute_bytes / observability.TPU_PEAK_BYTES_PER_S)
+      permute_bytes / V5E.bytes_per_s)
   assert 0.0 < stats["overlap_fraction"] < 1.0
-  line = observability.overlap_fraction_line(_MULTI_COLLECTIVE_HLO)
+  line = observability.overlap_fraction_line(_MULTI_COLLECTIVE_HLO, V5E)
   assert "3 collectives" in line
 
 
 def test_per_op_costs_rows_for_non_allreduce_collectives():
   rows = {r["opcode"]: r for r in observability.per_op_costs(
-      _MULTI_COLLECTIVE_HLO)}
+      _MULTI_COLLECTIVE_HLO, V5E)}
   assert "reduce-scatter" in rows and "collective-permute" in rows
   assert rows["reduce-scatter"]["bytes"] == (16 + 64) * 4
   assert rows["collective-permute"]["bytes"] == (64 + 64) * 4

@@ -44,6 +44,7 @@ N_REPLICAS = 8
 # (the program-contract auditor and these pins share one parser).
 from kf_benchmarks_tpu.analysis.contracts import (  # noqa: E402
     all_reduce_defs as _all_reduce_defs,
+    compile_for_audit as _compile,
     in_backward_loop as _in_backward_loop)
 
 
@@ -268,8 +269,8 @@ def test_bucket_count_shapes_the_program():
   fns_over, model = _mlp_step(True)
   _, _, step_post, args = _run_steps(fns_post, steps=1)
   _, _, step_over, _ = _run_steps(fns_over, steps=1)
-  hlo_post = step_post.lower(*args).compile().as_text()
-  hlo_over = step_over.lower(*args).compile().as_text()
+  hlo_post = _compile(step_post.lower(*args)).as_text()
+  hlo_over = _compile(step_over.lower(*args)).as_text()
   n_post = len(_all_reduce_defs(hlo_post))
   n_over = len(_all_reduce_defs(hlo_over))
   module = model.make_module(4, True)
@@ -291,8 +292,8 @@ def test_accum_keeps_reduction_post_hoc():
   fns_both, _ = _mlp_step(True, num_grad_accum=2, batch_size=2)
   _, _, step_acc, args = _run_steps(fns_acc, steps=1)
   _, _, step_both, _ = _run_steps(fns_both, steps=1)
-  hlo_acc = step_acc.lower(*args).compile().as_text()
-  hlo_both = step_both.lower(*args).compile().as_text()
+  hlo_acc = _compile(step_acc.lower(*args)).as_text()
+  hlo_both = _compile(step_both.lower(*args)).as_text()
   assert not _in_backward_loop(_all_reduce_defs(hlo_both))
   assert len(_all_reduce_defs(hlo_both)) == len(_all_reduce_defs(hlo_acc))
   s_acc, _, _, _ = _run_steps(fns_acc)
@@ -354,8 +355,8 @@ def test_scanned_lm_hook_bit_identical_and_in_loop():
   # The hooked module reduces the scanned 'blocks' stack in-backward.
   _assert_trees_bit_identical(g_hook["blocks"], g_post["blocks"])
 
-  hlo_hook = fn_hook.lower(params, tokens, labels).compile().as_text()
-  hlo_post = fn_post.lower(params, tokens, labels).compile().as_text()
+  hlo_hook = _compile(fn_hook.lower(params, tokens, labels)).as_text()
+  hlo_post = _compile(fn_post.lower(params, tokens, labels)).as_text()
   in_loop = _in_backward_loop(_all_reduce_defs(hlo_hook))
   assert len(in_loop) == 1, (
       "expected the per-block packed collective inside the backward "
@@ -422,9 +423,7 @@ def test_composed_overlap_matches_unhooked_on_degenerate_mesh():
 def test_composed_overlap_reduces_inside_scan_body():
   """Structural HLO check on a real (2,2,1) data mesh: the hooked
   scanned program issues data-axis collectives inside the backward
-  scan's while body (compile-only; the pre-vma oracle-equivalence gap
-  for composed programs is tracked by test_transformer_parallel.py's
-  skip markers)."""
+  scan's while body (compile-only)."""
   key = jax.random.PRNGKey(0)
   params = transformer.init_params(
       key, vocab=64, d_model=16, n_layers=2, n_heads=2, head_dim=8,
@@ -436,7 +435,7 @@ def test_composed_overlap_reduces_inside_scan_body():
   step = transformer.make_train_step(mesh, stacked, 0.1,
                                      scan_layers=True,
                                      overlap_grad_reduce=True)
-  hlo = step.lower(stacked, tokens, labels).compile().as_text()
+  hlo = _compile(step.lower(stacked, tokens, labels)).as_text()
   assert _in_backward_loop(_all_reduce_defs(hlo)), (
       "expected the per-layer data-axis reduction inside the backward "
       "scan body")

@@ -286,6 +286,8 @@ def _tp_decode_all(spec, variables, tokens):
   return jnp.stack(rows, axis=1)
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_tp_decode_bit_identical_to_tp_full_forward(tp_setup):
   """The sharded oracle: under the SAME model sharding, exact-mode
   incremental decode == the full forward bit for bit at every prefix

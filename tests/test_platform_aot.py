@@ -96,6 +96,8 @@ def test_aot_export_roundtrip(tmp_path):
                              rtol=1e-5, atol=1e-5)
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_aot_serving_benchmark_fresh_process(tmp_path):
   """--forward_only --aot_load_path times the frozen artifact in a FRESH
   process (VERDICT r1 next #10: the TRT-serving-benchmark analog,

@@ -57,6 +57,8 @@ def test_flax_depthwise_layout_keeps_per_channel_scales():
   assert q["dw"]["__scale__"].shape == (512,)
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_int8_accuracy_delta_on_depthwise_model():
   """The accuracy-delta check on a depthwise model (mobilenet_v2): the
   quantized forward's top-1 decisions agree with the float forward --
@@ -133,6 +135,8 @@ def trained_lenet(tmp_path_factory):
   return bench.model, variables, bench.dataset.num_classes
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_int8_export_matches_f32_logits_and_shrinks(trained_lenet,
                                                     tmp_path):
   from kf_benchmarks_tpu import aot

@@ -68,7 +68,7 @@ def test_every_registered_rule_has_an_observing_test():
                        "tests/test_*.py quotes it")
   assert not missing, "\n".join(missing)
   # Sanity: the extraction really sees both registries.
-  assert "block-until-ready" in quoted_anywhere  # lint.py
+  assert "version-gate-comment" in quoted_anywhere  # lint.py
   assert "trace-twin" in quoted_anywhere         # audit.py
 
 

@@ -245,6 +245,8 @@ def test_paged_pool_strictly_under_dense_slab():
   assert decode_lib.kv_pool_pages(spec, 1) >= pps + 1
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_paged_engine_matches_dense_and_reference(tiny_vars):
   spec = tiny_spec()
   pspec = tiny_spec(kv_page_size=8)
@@ -328,6 +330,8 @@ def test_truncate_variables_slices_scanned_blocks(tiny_vars):
     assert bool(jnp.all(c == f[:1]))
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_speculative_token_identical_to_plain_greedy(tiny_vars):
   """THE speculative invariant: greedy speculative output is provably
   token-identical to plain greedy decode -- per request, against both
@@ -389,6 +393,8 @@ def test_speculative_oversized_prompt_sheds_not_raises(tiny_vars):
 
 # -- composition + bounded compiles -------------------------------------------
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_all_three_legs_composed_match_int8_arm(tiny_vars):
   cspec = tiny_spec(quantize="int8", kv_page_size=8, speculative_k=3,
                     draft_n_layers=1)

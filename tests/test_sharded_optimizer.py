@@ -207,7 +207,6 @@ def test_health_stats_auto_resolves_off_with_note(tmp_path):
 # -- pure-unit: ops/sharded layout laws on the 8-device mesh ------------------
 
 def _shard_map_2d(fn, mesh, in_specs, out_specs):
-  import kf_benchmarks_tpu.compat  # noqa: F401 (shard_map bridge)
   return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False))
 

@@ -22,6 +22,8 @@ def _small_ds2():
   return model
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_ds2_forward_and_ctc_loss():
   model = _small_ds2()
   rng = jax.random.PRNGKey(0)

@@ -289,7 +289,7 @@ def test_watchdog_midrun_stall_diagnoses_and_never_kills(tmp_path):
   assert wd.stalls == 1
   diag = [l for l in logs if "stall watchdog:" in l]
   assert any("NOT killing the process" in l for l in diag)
-  assert any("tunnel state" in l for l in diag)
+  assert any("platform env" in l for l in diag)
   assert any('"step": 7' in l for l in diag)  # last recorder rows ride along
   # Latched: the same stall episode is counted once...
   t[0] = 2.0
@@ -381,8 +381,9 @@ def test_health_stats_bit_identical_to_stats_off(extra):
 # -- compiled-HLO: no extra collectives ---------------------------------------
 
 # Single-sourced with the program-contract auditor (analysis/contracts.py).
-from kf_benchmarks_tpu.analysis.contracts import ALL_REDUCE_DEF \
-    as _ALL_REDUCE_DEF  # noqa: E402
+from kf_benchmarks_tpu.analysis.contracts import (  # noqa: E402
+    ALL_REDUCE_DEF as _ALL_REDUCE_DEF,
+    compile_for_audit as _compile_for_audit)
 
 
 def test_health_stats_add_no_extra_collectives():
@@ -400,7 +401,8 @@ def test_health_stats_add_no_extra_collectives():
     batch = bench._input_iterator(rng, "train")[0]()
     shape = (bench.batch_size_per_device,) + bench._model_image_shape()
     state = init_state(rng, jnp.zeros(shape, jnp.float32))
-    return train_step.lower(state, *batch).compile().as_text()
+    return _compile_for_audit(
+        train_step.lower(state, *batch)).as_text()
 
   n_on = len([l for l in lowered(True).splitlines()
               if _ALL_REDUCE_DEF.search(l)])

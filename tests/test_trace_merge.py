@@ -7,10 +7,10 @@ all ranks inherit one KF_RUN_ID from the launcher, and rank 0 merges
 the rank files into one coherent Chrome timeline at exit (pid = rank,
 tid = subsystem).
 
-Process-spawning (DISTRIBUTED_TESTS tier) and timeout-free per the
-wedge rule: kfrun.launch blocks on worker exit and the rank-0 merge
-waits on sibling FILES with a bounded host-side poll -- no subprocess
-is ever killed on a timer (CLAUDE.md; analysis/lint.py kill-timeout).
+Process-spawning (DISTRIBUTED_TESTS tier) and timeout-free:
+kfrun.launch blocks on worker exit and the rank-0 merge waits on
+sibling FILES with a bounded host-side poll -- no subprocess is killed
+on a timer.
 """
 
 import json

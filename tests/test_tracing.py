@@ -25,10 +25,12 @@ import pytest
 
 import jax
 
+from kf_benchmarks_tpu import benchmark
 from kf_benchmarks_tpu import params as params_lib
 from kf_benchmarks_tpu import tracing
 from kf_benchmarks_tpu import validation
 from kf_benchmarks_tpu.analysis import baseline
+from kf_benchmarks_tpu.utils import log as log_util
 
 from tests.test_benchmark import STEP_RE, TOTAL_RE, _run_and_scrape
 
@@ -519,20 +521,101 @@ def test_trace_on_bit_identical_to_off(tmp_path, extra):
   _schema_checked(str(tmp_path / "t.json"))
 
 
-def test_compilation_cache_wired_and_ledger_cache_hit(tmp_path):
-  """--compilation_cache_dir (ROADMAP item 3 groundwork): the cache
-  dir defaults to <train_dir>/xla_cache and is configured before the
-  first trace; a SECOND run of the same train_dir ledgers its compile
-  episodes as cache_hit=True (the fingerprint was ledgered by the
-  first run and the persistent cache is live), so the once-per-shape
-  payoff is visible in the ledger rows."""
+@pytest.fixture
+def restore_compile_cache():
+  """The cache config is process-global: put it back to off (the CPU
+  default) after a test placed it somewhere."""
+  yield
+  benchmark.configure_compile_cache("cpu")
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+  """The one resolver (benchmark.resolve_compile_cache_dir): the env
+  wins and is left to jax; else the flag; else <checkout>/.jax_cache
+  for --device=tpu and OFF for CPU -- never a path built from
+  train_dir, a temp name, a pid or the time."""
+  repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+  assert benchmark.resolve_compile_cache_dir("tpu") == (
+      os.path.join(repo, ".jax_cache"), False)
+  assert benchmark.resolve_compile_cache_dir("cpu") == (None, False)
+  for device in ("tpu", "cpu"):
+    assert benchmark.resolve_compile_cache_dir(device, "/x/flag") == (
+        "/x/flag", False)
+  monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x/env")
+  for device in ("tpu", "cpu"):
+    for flag in (None, "/x/flag"):
+      assert benchmark.resolve_compile_cache_dir(device, flag) == (
+          "/x/env", True)
+
+
+@pytest.mark.parametrize("with_train_dir", [False, True])
+def test_env_placed_cache_is_never_touched_in_code(
+    tmp_path, monkeypatch, with_train_dir):
+  """JAX_COMPILATION_CACHE_DIR set: no code path sets
+  jax_compilation_cache_dir -- after setup() + BenchmarkCNN(...).run(),
+  with and without --train_dir (and with a --compilation_cache_dir that
+  is ignored with one log line), the config still equals the env path,
+  which filled during the run."""
+  from jax.experimental.compilation_cache import compilation_cache as cc
+  env_dir = str(tmp_path / "env_cache")
+  monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+  real_update = jax.config.update
+  # What jax itself does with the env var at import time.
+  cc.reset_cache()
+  real_update("jax_compilation_cache_dir", env_dir)
+  touched = []
+
+  def spy(name, value):
+    if name == "jax_compilation_cache_dir":
+      touched.append(value)
+    return real_update(name, value)
+
+  monkeypatch.setattr(jax.config, "update", spy)
+  kw = dict(model="trivial", batch_size=4, num_batches=2,
+            num_warmup_batches=0, device="cpu", num_devices=1,
+            compilation_cache_dir=str(tmp_path / "flag_cache"))
+  if with_train_dir:
+    kw["train_dir"] = str(tmp_path / "train")
+  logs = []
+  orig = log_util.log_fn
+  log_util.log_fn = logs.append
+  try:
+    p = benchmark.setup(params_lib.make_params(**kw))
+    stats = benchmark.BenchmarkCNN(p).run()
+    assert jax.config.jax_compilation_cache_dir == env_dir
+  finally:
+    log_util.log_fn = orig
+    cc.reset_cache()
+    real_update("jax_compilation_cache_dir", None)
+  assert touched == []
+  assert f"XLA compilation cache: {env_dir}" in logs
+  assert any("--compilation_cache_dir" in l and "ignored" in l
+             for l in logs)
+  assert os.listdir(env_dir)
+  assert not os.path.exists(str(tmp_path / "flag_cache"))
+  assert not os.path.exists(str(tmp_path / "train" / "xla_cache"))
+  assert stats["compile_ledger"]["entries"]
+
+
+def test_compilation_cache_flag_and_ledger_cache_hit(
+    tmp_path, monkeypatch, restore_compile_cache):
+  """--compilation_cache_dir on a CPU run (env unset): configured
+  before the first trace; a SECOND run of the same train_dir ledgers
+  its compile episodes as cache_hit=True (the fingerprint was ledgered
+  by the first run and the persistent cache is live). Without the flag
+  a CPU run keeps the cache OFF -- nothing lands under train_dir."""
+  monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
   train_dir = str(tmp_path / "train")
-  logs1, stats1 = _run_and_scrape(num_batches=2, train_dir=train_dir)
-  assert any(l.startswith("XLA compilation cache: ") for l in logs1)
-  assert os.path.isdir(os.path.join(train_dir, "xla_cache"))
+  cache = str(tmp_path / "explicit_cache")
+  logs1, stats1 = _run_and_scrape(num_batches=2, train_dir=train_dir,
+                                  compilation_cache_dir=cache)
+  assert f"XLA compilation cache: {cache}" in logs1
+  assert os.listdir(cache)
   entries1 = stats1["compile_ledger"]["entries"]
   assert entries1 and all(e["cache_hit"] is False for e in entries1)
-  logs2, stats2 = _run_and_scrape(num_batches=2, train_dir=train_dir)
+  logs2, stats2 = _run_and_scrape(num_batches=2, train_dir=train_dir,
+                                  compilation_cache_dir=cache)
   entries2 = stats2["compile_ledger"]["entries"]
   assert entries2 and all(e["cache_hit"] is True for e in entries2)
   # The merged on-disk ledger keeps the LAST cache_hit (a shape's
@@ -541,10 +624,9 @@ def test_compilation_cache_wired_and_ledger_cache_hit(tmp_path):
   data = json.load(open(os.path.join(train_dir, "compile_ledger.json")))
   assert all(row.get("cache_hit") is True
              for row in data["entries"].values())
-  # Explicit path override wins over the train_dir default.
-  other = str(tmp_path / "explicit_cache")
-  logs3, _ = _run_and_scrape(num_batches=2,
-                             train_dir=str(tmp_path / "t2"),
-                             compilation_cache_dir=other)
-  assert any(l == f"XLA compilation cache: {other}" for l in logs3)
-  assert os.path.isdir(other)
+  # No flag, no env, CPU: the cache stays off and train_dir holds none.
+  t2 = str(tmp_path / "t2")
+  logs3, _ = _run_and_scrape(num_batches=2, train_dir=t2)
+  assert not any(l.startswith("XLA compilation cache: ") for l in logs3)
+  assert not os.path.exists(os.path.join(t2, "xla_cache"))
+  assert jax.config.jax_compilation_cache_dir is None

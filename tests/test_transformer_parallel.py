@@ -13,17 +13,10 @@ import pytest
 
 from kf_benchmarks_tpu.parallel import transformer
 
-# Pre-vma jax (no lax.pcast) forces check_rep off in the shard_map shim
-# (kf_benchmarks_tpu/compat.py), and old shard_map without the checker
-# mis-handles psum transposition when differentiating these COMPOSED
-# programs (sp attention / moe / pipeline under one grad) -- a known
-# limitation the vma type system fixed. The single-device-oracle
-# comparisons below hold on current jax; on 0.4.x they are skipped, not
-# failed, so the suite reports the environment honestly.
-pre_vma_oracle_skip = pytest.mark.skipif(
-    not hasattr(jax.lax, "pcast"),
-    reason="pre-vma shard_map grad diverges on composed programs "
-           "(compat.py check_rep note)")
+# Slow tier, whole file (PR 21 tiering): 139 s of composed-trainer
+# oracles for parallel/transformer.py, which only tests and the CPU
+# dryrun reach (ROADMAP C1); tier-1's 870 s wall is the constraint.
+pytestmark = pytest.mark.slow
 
 
 
@@ -39,7 +32,6 @@ def _setup(seed=0):
   return params, tokens, labels
 
 
-@pre_vma_oracle_skip
 def test_composed_step_matches_single_device():
   params, tokens, labels = _setup()
   mesh = transformer.build_mesh(2, 2, 2)
@@ -126,7 +118,6 @@ def _assert_moe_step_matches_oracle(mesh_shape, caps, sp_layout,
     ((2, 2, 1), (None,)),     # ep composed with the seq axis
     ((2, 2, 2), (None,)),     # ep composed with seq AND tensor axes
 ])
-@pre_vma_oracle_skip
 def test_moe_blocks_match_single_device(mesh_shape, caps):
   # Experts shard over the replica axis; loss AND a trained step match
   # the grouped single-device oracle (including capacity queues), on
@@ -157,7 +148,6 @@ def test_moe_composes_with_all_axes():
 
 
 @pytest.mark.parametrize("mesh_shape", [(1, 4, 1), (2, 2, 2)])
-@pre_vma_oracle_skip
 def test_zigzag_layout_matches_single_device(mesh_shape):
   # The load-balanced sp layout is a pure relabeling of which device
   # holds which token: loss AND trained params must equal the
@@ -179,7 +169,6 @@ def test_zigzag_layout_matches_single_device(mesh_shape):
                                rtol=1e-4, atol=1e-5)
 
 
-@pre_vma_oracle_skip
 def test_zigzag_layout_with_moe_matches_single_device():
   # zigzag sp layout + MoE: the capacity queues fill in the zigzag
   # in-shard token order; the oracle mirrors that grouping exactly
@@ -204,7 +193,6 @@ def _pipelined_setup(mesh_shape, seed=31, n_layers=4, batch=4):
     ((2, 2, 2, 1), 2, 4),   # dp x pp x sp
     ((2, 4, 1, 1), 4, 8),   # dp x pp, deeper pipeline, more microbatches
 ])
-@pre_vma_oracle_skip
 def test_pipelined_step_matches_single_device(mesh_shape, n_micro,
                                               batch):
   # GPipe with full-batch SGD is mathematically the sequential step:
@@ -231,7 +219,6 @@ def test_pipelined_step_matches_single_device(mesh_shape, n_micro,
                                rtol=1e-4, atol=1e-5)
 
 
-@pre_vma_oracle_skip
 def test_pipelined_zigzag_matches_single_device():
   # The full 4-D composition with the load-balanced sp layout: stage
   # scan outside, zigzag causal ring inside each tick.
@@ -281,7 +268,6 @@ def test_pipelined_rejects_stage_mesh_mismatch():
 
 
 @pytest.mark.parametrize("sp_layout", ["contiguous", "zigzag"])
-@pre_vma_oracle_skip
 def test_attn_inner_block_matches_single_device(sp_layout):
   # The ring schedules' K/V sub-block tiling, reachable from the
   # composed trainer in both sequence layouts (zigzag's divisibility is

@@ -21,15 +21,6 @@ from kf_benchmarks_tpu.models import transformer_lm
 from kf_benchmarks_tpu.models.model import BuildNetworkResult
 from kf_benchmarks_tpu.parallel import transformer
 
-# Same environment note as test_transformer_parallel.py: pre-vma
-# shard_map mis-transposes psums when differentiating composed
-# programs, so grad-path oracle comparisons on multi-axis meshes skip
-# there (forward-only and single-axis comparisons still run).
-pre_vma_oracle_skip = pytest.mark.skipif(
-    not hasattr(jax.lax, "pcast"),
-    reason="pre-vma shard_map grad diverges on composed programs "
-           "(compat.py check_rep note)")
-
 
 # -- models/transformer_lm.py: nn.scan + nn.remat -----------------------------
 
@@ -161,10 +152,12 @@ def test_make_train_step_scan_layers_rejects_list_tree():
                                 scan_layers=True)
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_scanned_step_matches_list_step_single_axis():
   """Scanned+rematerialized vs per-layer-list training on a 1-device
   mesh: losses and trained parameters agree to the float fusion bound
-  across steps (pre-vma-safe: no composed-axis grad transposition)."""
+  across steps."""
   params, tokens, labels = _setup(n_layers=3)
   mesh = transformer.build_mesh(1, 1, 1)
   step_list = transformer.make_train_step(mesh, params,
@@ -188,8 +181,7 @@ def test_scanned_step_matches_list_step_single_axis():
 
 
 def test_scanned_forward_matches_on_composed_mesh():
-  """Forward-only equivalence ON the (2, 2, 2) mesh (loss needs no
-  grad transposition, so it runs on pre-vma jax too): the scanned
+  """Forward-only equivalence ON the (2, 2, 2) mesh: the scanned
   stack under ring attention + Megatron sharding reproduces the
   list-path loss."""
   params, tokens, labels = _setup(n_layers=2)
@@ -201,9 +193,10 @@ def test_scanned_forward_matches_on_composed_mesh():
     logits, _ = transformer.forward_local(p, toks)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
     ll = jnp.take_along_axis(logp, lbls[..., None], -1)
+    # The logits are already reduced over the tensor axis; the mean
+    # runs over the axes the loss still varies on.
     return jax.lax.pmean(
-        -jnp.mean(ll), (transformer.REPLICA_AXIS, transformer.SEQ_AXIS,
-                        transformer.TENSOR_AXIS))
+        -jnp.mean(ll), (transformer.REPLICA_AXIS, transformer.SEQ_AXIS))
 
   run_list = jax.jit(jax.shard_map(
       fwd_loss, mesh=mesh,
@@ -220,7 +213,8 @@ def test_scanned_forward_matches_on_composed_mesh():
                              rtol=1e-5, atol=1e-6)
 
 
-@pre_vma_oracle_skip
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_scanned_step_matches_list_step_composed_mesh():
   """The full composed proof on (2, 2, 2): scanned+remat training
   equals list-path training, grads included (vma jax only)."""
@@ -296,8 +290,8 @@ def test_fsdp_blocks_rejections():
 def test_fsdp_blocks_forward_loss_matches_scanned():
   """Step-0 loss on a (4, 2, 1) dp x sp mesh: the per-block gather
   re-assembles exactly the scanned stack's values, so the first
-  forward's loss matches the replicated-blocks arm (pre-vma safe: the
-  comparison reads the loss of the SAME params before any update)."""
+  forward's loss matches the replicated-blocks arm (the comparison
+  reads the loss of the SAME params before any update)."""
   params, tokens, labels = _setup(n_layers=2)
   mesh = transformer.build_mesh(4, 2, 1)
   stacked = transformer.stack_blocks(params)
@@ -340,10 +334,11 @@ def test_fsdp_blocks_gather_sits_inside_scan_body():
     assert x.elems * 4 < blocks_bytes, "a gather re-assembles the stack"
 
 
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_fsdp_blocks_training_matches_scanned_degenerate_mesh():
-  """n = 1 training equality (pre-vma safe: every collective is over a
-  singleton group, so the pre-vma transpose gap cannot bite): the
-  whole FSDP pipeline -- shard storage, in-scan gather, custom-vjp
+  """n = 1 training equality (every collective is over a singleton
+  group): the whole FSDP pipeline -- shard storage, in-scan gather, custom-vjp
   scatter, shard update -- reduces to the scanned step exactly."""
   params, tokens, labels = _setup(n_layers=2)
   mesh = transformer.build_mesh(1, 1, 1)
@@ -369,12 +364,12 @@ def test_fsdp_blocks_training_matches_scanned_degenerate_mesh():
                                rtol=1e-5, atol=1e-6)
 
 
-@pre_vma_oracle_skip
+# Slow tier: tier-1's 870 s wall is the constraint (PR 21 tiering).
+@pytest.mark.slow
 def test_fsdp_blocks_training_matches_scanned_dp_mesh():
-  """Trained equality on the real (4, 2, 1) dp x sp mesh (vma jax
-  only: the replicated-blocks arm's gradients need the implicit
-  data-axis psums pre-vma shard_map does not insert; the FSDP arm's
-  block gradients are explicit either way)."""
+  """Trained equality on the real (4, 2, 1) dp x sp mesh: the
+  replicated-blocks arm's gradients ride shard_map's implicit
+  data-axis psums, the FSDP arm's block gradients are explicit."""
   params, tokens, labels = _setup(n_layers=2)
   mesh = transformer.build_mesh(4, 2, 1)
   stacked = transformer.stack_blocks(params)
