@@ -271,31 +271,36 @@ def lower_step_program(bench, program: str = "train_step"):
   """Lower (never execute) a built runtime's step program over abstract
   ``ShapeDtypeStruct`` inputs -- the one build+lower recipe shared by
   :func:`trace_contract` and the autotuner's warm pass (the warm pass
-  compiles the result against the persistent XLA cache). Returns
-  ``(state_sds, lowered)``."""
+  compiles the result against the persistent XLA cache). The abstract
+  state and batch carry the shardings the runtime gives the real ones:
+  the persistent cache keys on them, so this lowering makes the entry a
+  later run's dispatch finds (the cache's own hit events say so,
+  tests/test_autotune.py). Returns ``(state_sds, lowered)``."""
   import jax
+  from kf_benchmarks_tpu.parallel import mesh as mesh_lib
   fns = bench._build()
   init_state, train_step, train_chunk = fns[0], fns[1], fns[4]
   in_shapes = bench.model.get_input_shapes("train")
   in_dtypes = bench.model.get_input_data_types("train")
   sample = jax.ShapeDtypeStruct(tuple(in_shapes[0]), in_dtypes[0])
-  state_sds = jax.eval_shape(init_state, jax.random.PRNGKey(0), sample)
+  state_sds = init_state.eval_shape(jax.random.PRNGKey(0), sample)
+  chunk = program == "train_chunk"
+  if chunk and train_chunk is None:
+    raise ValueError("train_chunk requested but --steps_per_dispatch=1")
   n = bench.num_devices
   # Global batch follows the DATA-parallel width (model-axis peers of a
-  # 2-D mesh re-compute the same shard; == n on 1-D meshes).
+  # 2-D mesh re-compute the same shard; == n on 1-D meshes). A synthetic
+  # resident chunk has a leading staged-steps axis of 1.
   n_data = int(getattr(bench, "num_data_replicas", n))
-  gx = jax.ShapeDtypeStruct(
-      (in_shapes[0][0] * n_data,) + tuple(in_shapes[0][1:]), in_dtypes[0])
-  gy = jax.ShapeDtypeStruct(
-      (in_shapes[1][0] * n_data,) + tuple(in_shapes[1][1:]), in_dtypes[1])
-  if program == "train_chunk":
-    if train_chunk is None:
-      raise ValueError("train_chunk requested but --steps_per_dispatch=1")
-    # Synthetic resident chunk: leading staged-steps axis of 1.
-    gx = jax.ShapeDtypeStruct((1,) + gx.shape, gx.dtype)
-    gy = jax.ShapeDtypeStruct((1,) + gy.shape, gy.dtype)
-    return state_sds, train_chunk.lower(state_sds, gx, gy)
-  return state_sds, train_step.lower(state_sds, gx, gy)
+  lead = (1,) if chunk else ()
+  sharding = (mesh_lib.chunk_batch_sharding(bench.mesh) if chunk
+              else mesh_lib.batch_sharding(bench.mesh))
+  gx, gy = (
+      jax.ShapeDtypeStruct(lead + (shape[0] * n_data,) + tuple(shape[1:]),
+                           dtype, sharding=sharding)
+      for shape, dtype in zip(in_shapes[:2], in_dtypes[:2]))
+  step_fn = train_chunk if chunk else train_step
+  return state_sds, step_fn.lower(state_sds, gx, gy)
 
 
 def trace_contract(overrides: Dict[str, Any],

@@ -36,7 +36,10 @@ Rules (ids):
   percentile/chrome-trace helper anywhere else in the package would
   fork the trace schema the tests validate. READING profiler output
   (``e.get("ph")``, observability.py) is fine -- only construction is
-  emission.
+  emission. The profiler's annotations are the same timeline's second
+  sink: ``TraceAnnotation`` / ``StepTraceAnnotation`` may be named only
+  where they are handed to ``RunTrace(...)`` as a keyword argument, and
+  constructed nowhere -- ``RunTrace.span()`` / ``.step()`` enter them.
 * ``metric-key-literal`` -- metric keys are single-sourced in the
   metric registry schema (``metrics.py``; the same pattern as the
   step-line and trace-event rules): a string literal in one of the
@@ -364,8 +367,17 @@ _TRACE_EVENT_KEYS = {"ph", "traceEvents"}
 _TRACE_HELPER_NAMES = {"percentile", "percentiles", "chrome_events",
                        "chrome_trace_events"}
 _TRACE_HOME = "kf_benchmarks_tpu/tracing.py"
+# The profiler's annotation classes: named outside the home only as a
+# keyword argument of the RunTrace(...) call that injects them.
+_ANNOTATION_NAMES = {"TraceAnnotation", "StepTraceAnnotation"}
 
 TRACE_EMISSION_ALLOWLIST: Dict[str, str] = {}
+
+
+def _names_annotation(node) -> bool:
+  return ((isinstance(node, ast.Attribute)
+           and node.attr in _ANNOTATION_NAMES) or
+          (isinstance(node, ast.Name) and node.id in _ANNOTATION_NAMES))
 
 
 def rule_trace_event_emission(sources: List[_Source]
@@ -378,8 +390,17 @@ def rule_trace_event_emission(sources: List[_Source]
     if src.path == _TRACE_HOME or src.tree is None:
       continue
     findings = []
+    injected = set()  # annotation classes handed to RunTrace(...)
     for node in ast.walk(src.tree):
-      if isinstance(node, ast.Dict):
+      if isinstance(node, ast.Call) and (
+          getattr(node.func, "attr", None) == "RunTrace" or
+          getattr(node.func, "id", None) == "RunTrace"):
+        injected.update(id(k.value) for k in node.keywords)
+    for node in ast.walk(src.tree):
+      if _names_annotation(node) and id(node) not in injected:
+        findings.append((node.lineno,
+                         "profiler annotation named or constructed"))
+      elif isinstance(node, ast.Dict):
         keys = {k.value for k in node.keys
                 if isinstance(k, ast.Constant)
                 and isinstance(k.value, str)}

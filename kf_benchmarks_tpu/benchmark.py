@@ -525,28 +525,33 @@ class BenchmarkCNN:
   def _build(self):
     p = self.params
     nclass = self.dataset.num_classes
-    module = self.model.make_module(
-        nclass=nclass, phase_train=not (p.eval or p.forward_only),
-        data_format=p.data_format, dtype=self.compute_dtype,
-        param_dtype=self.param_dtype)
-    eval_module = self.model.make_module(
-        nclass=nclass, phase_train=False, data_format=p.data_format,
-        dtype=self.compute_dtype, param_dtype=self.param_dtype)
-    lr_fn = learning_rate.make_learning_rate_fn(
-        p, self.model,
-        self.batch_size_per_device * (
-            # Effective batch = per-device x DATA-parallel width (model-
-            # axis peers add no examples); == num_devices on 1-D meshes.
-            self.num_data_replicas if self.strategy.cross_replica else 1),
-        self.dataset.num_examples_per_epoch("train"), self.num_workers)
-    tx = optimizers.get_optimizer(p, lr_fn)
-    self._lr_fn = lr_fn
-    return train_step_lib.make_step_fns(
-        self.model, module, eval_module, self.strategy, tx, lr_fn, p,
-        self.mesh, compute_dtype=self.compute_dtype,
-        # The RESOLVED step count (--num_batches default / --num_epochs
-        # derivation, _get_num_batches) -- params.num_batches may be None.
-        total_train_steps=self.num_batches)
+    with self._trace.span("setup", "build_model"):
+      module = self.model.make_module(
+          nclass=nclass, phase_train=not (p.eval or p.forward_only),
+          data_format=p.data_format, dtype=self.compute_dtype,
+          param_dtype=self.param_dtype)
+      eval_module = self.model.make_module(
+          nclass=nclass, phase_train=False, data_format=p.data_format,
+          dtype=self.compute_dtype, param_dtype=self.param_dtype)
+      lr_fn = learning_rate.make_learning_rate_fn(
+          p, self.model,
+          self.batch_size_per_device * (
+              # Effective batch = per-device x DATA-parallel width
+              # (model-axis peers add no examples); == num_devices on
+              # 1-D meshes.
+              self.num_data_replicas if self.strategy.cross_replica
+              else 1),
+          self.dataset.num_examples_per_epoch("train"), self.num_workers)
+      tx = optimizers.get_optimizer(p, lr_fn)
+      self._lr_fn = lr_fn
+    with self._trace.span("setup", "make_step_fns"):
+      return train_step_lib.make_step_fns(
+          self.model, module, eval_module, self.strategy, tx, lr_fn, p,
+          self.mesh, compute_dtype=self.compute_dtype,
+          # The RESOLVED step count (--num_batches default /
+          # --num_epochs derivation, _get_num_batches) --
+          # params.num_batches may be None.
+          total_train_steps=self.num_batches)
 
   def _synthetic_global_batch(self, rng):
     """Device-resident synthetic inputs, sharded over replicas
@@ -775,12 +780,15 @@ class BenchmarkCNN:
       log_fn(self._health_note)
     # Run-trace session (tracing.py): ONE run id shared with the flight
     # recorder so a post-mortem dump lays over the timeline. Always
-    # created -- the latency percentiles and compile ledger ride the
-    # stats/bench JSON even without --trace_events_file (span retention
-    # and the file export engage only with the flag). Under kfrun the
-    # world size comes from the launcher env (jax.process_count() is 1
-    # per CPU worker there), so rank files and the rank-0 merge cover
-    # every worker of the job.
+    # created -- the span totals, latency percentiles and compile
+    # ledger ride the stats/bench JSON even without --trace_events_file
+    # (span retention and the file export engage only with the flag).
+    # The profiler's annotation factories are handed over HERE and
+    # nowhere else (lint rule trace-event-emission): every live span
+    # then lands in whatever jax.profiler capture is open. Under kfrun
+    # the world size comes from the launcher env (jax.process_count()
+    # is 1 per CPU worker there), so rank files and the rank-0 merge
+    # cover every worker of the job.
     rank = cluster_lib.process_rank()
     world = (int(os.environ.get("KFCOORD_WORLD") or 0) or
              max(self.num_workers, 1))
@@ -788,7 +796,8 @@ class BenchmarkCNN:
     self._trace = tracing_lib.RunTrace(
         path=p.trace_events_file, rank=rank, num_ranks=world,
         run_id=run_id, chrome_format=bool(p.use_chrome_trace_format),
-        log_fn=log_fn)
+        log_fn=log_fn, annotation=jax.profiler.TraceAnnotation,
+        step_annotation=jax.profiler.StepTraceAnnotation)
     tracing_lib.activate(self._trace)
     # Metric-registry session (metrics.py): always created -- the
     # registry is the single render source for run stats and the run
@@ -825,45 +834,24 @@ class BenchmarkCNN:
     self._compile_cache_dir = cache_dir
     if cache_dir:
       log_fn(f"XLA compilation cache: {cache_dir}")
-    # Prior compile-ledger keys (train_dir/compile_ledger.json,
-    # tracing.py write_ledger): a fingerprint seen by an earlier run
-    # of this train_dir AND a live cache dir means this run's compile
-    # episode is served from the persistent cache -- the ledger row's
-    # cache_hit field makes the once-per-shape payoff visible.
-    self._prior_ledger_keys = set()
-    # ... and only when the cache dir actually HOLDS entries: jax
-    # exposes no public per-compile hit signal, so cache_hit is the
-    # conjunction "shape ledgered by an earlier run AND a warm
-    # persistent cache exists" -- a deleted/empty cache dir (or a
-    # prior run whose compiles all fell under jax's
-    # persistent_cache_min_compile_time threshold and were never
-    # serialized) must not read as a hit while the compile is paid in
-    # full again.
-    self._compile_cache_warm = False
-    if cache_dir:
-      try:
-        self._compile_cache_warm = any(os.scandir(cache_dir))
-      except OSError:
-        self._compile_cache_warm = False
-    if self._compile_cache_warm and p.train_dir:
-      # The ledger query API (tracing.py read_ledger) -- the same read
-      # the autotuner's warm pass cross-references, so a warmed
-      # train_dir reads as prior history here and the warmed shapes
-      # report cache_hit below.
-      self._prior_ledger_keys = tracing_lib.ledger_keys(
-          tracing_lib.read_ledger(p.train_dir))
     # Everything from the build on runs under the try: a raise anywhere
     # (compile error, bad data_dir, sink failure) must still deactivate
     # the module-global trace session (a leaked active session would
     # swallow later emitters in this process) and export what was
     # captured.
+    # JAX's own account of tracing, lowering, compiling and the
+    # persistent cache (tracing.py on_*); removed in the finally below.
+    jax.monitoring.register_event_time_span_listener(
+        self._trace.on_time_span)
+    jax.monitoring.register_event_listener(self._trace.on_event)
     try:
       init_state, train_step, eval_step, broadcast_init, train_chunk = \
           self._build()
       rng = jax.random.PRNGKey(p.tf_random_seed or 0)
       data_rng, init_rng = jax.random.split(rng)
       self._data_rng = data_rng
-      next_batch = self._open_input(data_rng, "train")
+      with self._trace.span("setup", "open_input"):
+        next_batch = self._open_input(data_rng, "train")
       # Flight recorder + stall watchdog for the whole build->train span
       # (the watchdog's patient first-compile regime must cover the init
       # and warmup compiles, not just the timed loop). None when the
@@ -893,6 +881,9 @@ class BenchmarkCNN:
         self._metrics_server = None
       metrics_lib.deactivate()
       tracing_lib.deactivate()
+      jax.monitoring.unregister_event_time_span_listener(
+          self._trace.on_time_span)
+      jax.monitoring.unregister_event_listener(self._trace.on_event)
       try:
         self._trace.export()
       except Exception as e:  # an export failure must not eat the run
@@ -1009,15 +1000,15 @@ class BenchmarkCNN:
     (span + p50/p90/p99 sample, tracing.py)."""
     trace = tracing_lib.active()
     t0 = trace.now()
-    checkpoint.save_checkpoint(
-        self.params.train_dir, state, self.params.max_ckpts_to_keep,
-        sharded_opt_state=self._sharded_state,
-        input_incarnation=getattr(self, "_input_incarnation", 0)
-        + incarnation_bump,
-        sharded_params=self._sharded_params)
+    with trace.span("checkpoint", "save",
+                    incarnation_bump=incarnation_bump):
+      checkpoint.save_checkpoint(
+          self.params.train_dir, state, self.params.max_ckpts_to_keep,
+          sharded_opt_state=self._sharded_state,
+          input_incarnation=getattr(self, "_input_incarnation", 0)
+          + incarnation_bump,
+          sharded_params=self._sharded_params)
     dur = trace.now() - t0
-    trace.add_span("checkpoint", "save", t0, dur,
-                   {"incarnation_bump": incarnation_bump})
     trace.add_sample("checkpoint_save", dur)
     metrics_lib.active().observe("checkpoint_save_s", dur)
 
@@ -1048,7 +1039,11 @@ class BenchmarkCNN:
     # DeviceFeeder, so it takes the real-data cursor/chunk paths.
     synthetic = (self.dataset.use_synthetic_gpu_inputs() and
                  not getattr(p, "packed_sequences", False))
-    images, labels = next_batch()
+    trace = self._trace
+    # The first batch: for the resident synthetic feed this is the one
+    # placement of the batch on the devices.
+    with trace.span("setup", "first_batch"):
+      images, labels = next_batch()
 
     def _step_slice(ims, lbs, j: int = 0):
       """One per-step batch out of a staged chunk (identity when
@@ -1067,42 +1062,41 @@ class BenchmarkCNN:
     t0 = time.time()
     # init_state is already jitted with explicit state shardings
     # (train_step.make_step_fns).
-    state = init_state(init_rng, jnp.zeros(sample.shape, sample.dtype))
+    with trace.span("setup", "init_state"):
+      state = init_state(init_rng, jnp.zeros(sample.shape, sample.dtype))
     # Resume from the newest checkpoint if the train_dir has one; the run
     # then executes num_batches MORE steps from the restored global step
     # (ref: Supervisor auto-restore, benchmark_cnn.py:2122-2157).
     resumed = False
     if p.train_dir:
-      t_restore = self._trace.now()
-      try:
-        # Parse-once resolve that skips torn/corrupt files with a
-        # logged warning (checkpoint.load_latest_checkpoint).
-        snapshot, path, ckpt_step = checkpoint.load_latest_checkpoint(
-            p.train_dir)
-        state = checkpoint.restore_state(
-            state, snapshot, sharded_opt_state=self._sharded_state,
-            sharded_params=self._sharded_params)
-        # Cross-topology resumes (a sharded checkpoint written at a
-        # different mesh re-slices in restore_state) re-verify the
-        # structural contract exactly like an in-run rescale.
-        self._verify_resumed_state(state)
-        # Reopen the input stream at the snapshot's incarnation: a
-        # rejoin after an elastic reshape must continue the POST-resize
-        # stream, not silently reset to stream 0.
-        snap_inc = int(snapshot.get("input_incarnation", 0) or 0)
-        if snap_inc != getattr(self, "_input_incarnation", 0):
-          self._input_incarnation = snap_inc
-          next_batch = self._open_input(self._data_rng, "train",
-                                        bump=False)
-          images, labels = next_batch()
-          log_fn(f"Resumed input stream at incarnation {snap_inc}")
-        log_fn(f"Restored checkpoint at global step {ckpt_step}")
-        self._trace.add_span(
-            "checkpoint", "restore", t_restore,
-            self._trace.now() - t_restore, {"global_step": ckpt_step})
-        resumed = True
-      except checkpoint.CheckpointNotFoundException:
-        pass
+      with trace.span("checkpoint", "restore") as restore_args:
+        try:
+          # Parse-once resolve that skips torn/corrupt files with a
+          # logged warning (checkpoint.load_latest_checkpoint).
+          snapshot, path, ckpt_step = checkpoint.load_latest_checkpoint(
+              p.train_dir)
+          state = checkpoint.restore_state(
+              state, snapshot, sharded_opt_state=self._sharded_state,
+              sharded_params=self._sharded_params)
+          # Cross-topology resumes (a sharded checkpoint written at a
+          # different mesh re-slices in restore_state) re-verify the
+          # structural contract exactly like an in-run rescale.
+          self._verify_resumed_state(state)
+          # Reopen the input stream at the snapshot's incarnation: a
+          # rejoin after an elastic reshape must continue the POST-resize
+          # stream, not silently reset to stream 0.
+          snap_inc = int(snapshot.get("input_incarnation", 0) or 0)
+          if snap_inc != getattr(self, "_input_incarnation", 0):
+            self._input_incarnation = snap_inc
+            next_batch = self._open_input(self._data_rng, "train",
+                                          bump=False)
+            images, labels = next_batch()
+            log_fn(f"Resumed input stream at incarnation {snap_inc}")
+          log_fn(f"Restored checkpoint at global step {ckpt_step}")
+          restore_args["global_step"] = ckpt_step
+          resumed = True
+        except checkpoint.CheckpointNotFoundException:
+          restore_args["found"] = False
     # Backbone warm-start before training (ref: benchmark_cnn.py:2204-2205
     # load_backbone_model at session start). Skipped on resume: the
     # resumed checkpoint's backbone is further-trained than the
@@ -1130,10 +1124,11 @@ class BenchmarkCNN:
              f"{p.num_grad_accum}x per step; not numerically "
              "equivalent to the monolithic step (BN-free models are)")
     # Replica-0 broadcast at start (ref: benchmark_cnn.py:2094-2100).
-    state = state.replace(params=broadcast_init(state.params))
-    # Resolve the broadcast so the reported initialization time covers
-    # the real device work.
-    sync.drain(state.params)
+    with trace.span("setup", "broadcast_init"):
+      state = state.replace(params=broadcast_init(state.params))
+      # Resolve the broadcast so the reported initialization time covers
+      # the real device work.
+      sync.drain(state.params)
     log_fn("Initialization: %.1f s" % (time.time() - t0))
 
     def make_run_step(train_step, eval_step):
@@ -1270,29 +1265,22 @@ class BenchmarkCNN:
     # the measurement brackets the async fn call alone -- never the
     # trace drain.
     dispatch_stats = {"compile_s": None, "call_times": []}
-    trace = self._trace
 
-    def _note_compile(label: str, wall_s: float) -> None:
-      """First host call of a jitted program blocks on trace+compile:
-      ledger the episode under the program-shape fingerprint key
-      (analysis/baseline.config_fingerprint_key -- the identity the
-      persistent compile cache of ROADMAP item 5 will share)."""
+    def _note_compile(label: str, wall_s: float, mark) -> None:
+      """A host call of a jitted program that blocked on trace+compile
+      (its first, or a later one that recompiled): ledger the episode
+      under the program-shape fingerprint key
+      (analysis/baseline.config_fingerprint_key). ``mark`` is the
+      session's ``compile_mark`` from before the call: the row's
+      ``cache_hit`` comes from the compilation cache's own events
+      during it (jax.monitoring, tracing.py)."""
       from kf_benchmarks_tpu.analysis import baseline as baseline_lib
       self._compiled_programs.add(label)
       key = baseline_lib.config_fingerprint_key(self.params._asdict(),
                                                 label)
       trace.note_compile(
-          key, label, wall_s, model=self.model.get_name(),
-          num_devices=self.num_devices,
-          # True when the persistent XLA cache is WARM (dir holds
-          # entries) AND an earlier run of this train_dir already
-          # ledgered this shape: the episode deserialized a cached
-          # executable rather than paying the full compile (the
-          # once-per-shape contract; best-effort -- jax exposes no
-          # per-compile hit signal, see _benchmark_train).
-          cache_hit=bool(
-              getattr(self, "_compile_cache_warm", False)
-              and key in getattr(self, "_prior_ledger_keys", ())))
+          key, label, wall_s, since=mark, model=self.model.get_name(),
+          num_devices=self.num_devices)
 
     def _traced(trace_file, idx, trace_at, label, fn, *args):
       """One dispatch under the single-dispatch trace policy: trace it
@@ -1306,25 +1294,26 @@ class BenchmarkCNN:
       dispatch-issue span and the compile ledger: the span brackets the
       ASYNC jit call only (device completion is attributed
       differentially from the pipeline arrival intervals in
-      _handle)."""
+      _handle). A call during which JAX compiled is ledgered, be it
+      the label's first or a recompile of a label already seen."""
       with observability.maybe_trace_step(trace_file, idx, trace_at):
-        t0 = trace.now()
-        t_call = time.monotonic()
-        new_state, out_metrics = fn(*args)
-        dt = time.monotonic() - t_call
         first = label not in self._compiled_programs
-        trace.add_span("dispatch", label, t0, trace.now() - t0,
-                       {"step": idx, "first_call": first})
+        mark = trace.compile_mark()
+        with trace.span("dispatch", label, step=idx, first_call=first):
+          t_call = time.monotonic()
+          new_state, out_metrics = fn(*args)
+          dt = time.monotonic() - t_call
         if dispatch_stats["compile_s"] is None:
           dispatch_stats["compile_s"] = dt
         dispatch_stats["call_times"].append(dt)
-        if first:
-          _note_compile(label, dt)
+        if first or trace.compiles_since(mark):
+          _note_compile(label, dt, mark)
         if trace_file and idx == trace_at:
           sync.drain(out_metrics)
       return new_state, out_metrics
 
     log_fn("Running warm up")
+    trace.begin_phase(tracing_lib.PHASE_WARMUP)
     t0 = time.time()
     t0_warm = trace.now()
     cursor = 0  # consumed slices of the current staged real-data chunk
@@ -1424,6 +1413,12 @@ class BenchmarkCNN:
     issue_walls = []
 
     def _handle(done: "pipeline_lib.CompletedStep"):
+      """The host's bookkeeping for one resolved step, as one span:
+      registry, flight recorder, step line, summaries."""
+      with trace.span("handle", "step", step=start_step + done.index):
+        _handle_step(done)
+
+    def _handle_step(done: "pipeline_lib.CompletedStep"):
       nonlocal loss, last_display_len
       step_train_times.append(done.interval)
       if done.chunk_len > 1 and done.chunk_end:
@@ -1562,6 +1557,7 @@ class BenchmarkCNN:
       return n
 
     loop_start = time.time()
+    trace.begin_phase(tracing_lib.PHASE_TIMED)
     pipe.reset_clock()
     # Warmup dispatches (incl. the compile call) must not skew the
     # timed loop's per-dispatch host-overhead average.
@@ -1589,32 +1585,39 @@ class BenchmarkCNN:
       # (trace fallback: with zero warmup dispatches the trace runs on
       # the FIRST timed dispatch, via _traced's trace_at == i == 0)
       timed_trace = p.trace_file if self.num_warmup_batches == 0 else None
+      # One ``train`` step per dispatch, numbered by the global step it
+      # starts at: issue, next batch, and the fetch + bookkeeping of
+      # the dispatch that left the lag-2 ring. Scheduled events below
+      # (checkpoint, eval, resize) have spans of their own.
       if use_chunk:
-        state, metrics = _traced(timed_trace, i, 0, "train_chunk",
-                                 train_chunk, state, images, labels)
-        issue_walls.append(dispatch_stats["call_times"][-1])
-        images, labels = next_batch()
-        i += K
-        images_processed += K * self.batch_size * max(self.num_workers, 1)
-        for done in pipe.push(i, metrics, count=K):
-          _handle(done)
+        with trace.step("train", start_step + i):
+          state, metrics = _traced(timed_trace, i, 0, "train_chunk",
+                                   train_chunk, state, images, labels)
+          issue_walls.append(dispatch_stats["call_times"][-1])
+          images, labels = next_batch()
+          i += K
+          images_processed += (K * self.batch_size *
+                               max(self.num_workers, 1))
+          for done in pipe.push(i, metrics, count=K):
+            _handle(done)
       else:
         for _ in range(n_dispatch):
-          state, metrics = _traced(timed_trace, i, 0, "train_step",
-                                   run_step, state,
-                                   *_step_slice(images, labels, cursor))
-          issue_walls.append(dispatch_stats["call_times"][-1])
-          if not chunked:
-            images, labels = next_batch()
-          elif not synthetic:
-            cursor += 1
-            if cursor >= images.shape[0]:
+          with trace.step("train", start_step + i):
+            state, metrics = _traced(timed_trace, i, 0, "train_step",
+                                     run_step, state,
+                                     *_step_slice(images, labels, cursor))
+            issue_walls.append(dispatch_stats["call_times"][-1])
+            if not chunked:
               images, labels = next_batch()
-              cursor = 0
-          i += 1
-          images_processed += self.batch_size * max(self.num_workers, 1)
-          for done in pipe.push(i, metrics):
-            _handle(done)
+            elif not synthetic:
+              cursor += 1
+              if cursor >= images.shape[0]:
+                images, labels = next_batch()
+                cursor = 0
+            i += 1
+            images_processed += self.batch_size * max(self.num_workers, 1)
+            for done in pipe.push(i, metrics):
+              _handle(done)
       save_due = _save_steps_due(i) or bool(
           p.train_dir and p.save_model_secs and
           time.time() - last_save_time >= p.save_model_secs)
@@ -1648,18 +1651,19 @@ class BenchmarkCNN:
           last_save_time = time.time()
         if eval_due:
           # Mid-training eval + early stop (ref: benchmark_cnn.py:2310-2324).
-          t_eval = trace.now()
-          acc = eval_step(state, *_step_slice(images, labels, cursor))
-          # The ledger convention brackets the ASYNC first call only
-          # (blocks on trace+compile) -- the device_get below adds
-          # execution + transfer wall, which belongs to the eval span,
-          # not the compile episode.
-          eval_issue = trace.now() - t_eval
-          if "eval_step" not in self._compiled_programs:
-            _note_compile("eval_step", eval_issue)
-          acc = jax.device_get(acc)
-          trace.add_span("eval", "mid_train_eval", t_eval,
-                         trace.now() - t_eval, {"step": i})
+          with trace.span("eval", "mid_train_eval", step=i):
+            t_eval = trace.now()
+            mark = trace.compile_mark()
+            acc = eval_step(state, *_step_slice(images, labels, cursor))
+            # The ledger convention brackets the ASYNC first call only
+            # (blocks on trace+compile) -- the device_get below adds
+            # execution + transfer wall, which belongs to the eval
+            # span, not the compile episode.
+            eval_issue = trace.now() - t_eval
+            if ("eval_step" not in self._compiled_programs or
+                trace.compiles_since(mark)):
+              _note_compile("eval_step", eval_issue, mark)
+            acc = jax.device_get(acc)
           top1 = float(acc["top_1_accuracy"])
           log_fn("Accuracy @ 1 = %.4f Accuracy @ 5 = %.4f [%d examples]" %
                  (top1, float(acc["top_5_accuracy"]), self.batch_size))
@@ -1802,52 +1806,50 @@ class BenchmarkCNN:
                        event["batch_size_per_device"]))
             old_mesh = "x".join(
                 str(int(s)) for s in self.mesh.devices.shape)
-            t_seam = trace.now()
-            if p.train_dir:
-              # Drain happened at the sync point above; snapshot to
-              # disk BEFORE the rebuild, so a crash mid-rescale (or a
-              # preemption racing it) resumes from this exact seam --
-              # and a peer run at the new size can start from the same
-              # snapshot (the bit-identity contract of the rescale
-              # tests). incarnation_bump=1: the seam's resume point is
-              # the POST-resize input stream.
-              self._save_checkpoint(state, incarnation_bump=1)
-              last_save_time = time.time()
-            state, train_step, eval_step, next_batch, train_chunk = \
-                self._reshape_topology(state, event["num_devices"],
-                                       event["batch_size_per_device"],
-                                       init_rng, steps_done=i,
-                                       examples_done=images_processed)
-            run_step = make_run_step(train_step, eval_step)
-            images, labels = next_batch()
-            cursor = 0
-            reshape_events.append(event)
-            # ONE elastic event line (generation, old -> new mesh,
-            # resume step) -- the operator-facing record a preemption
-            # story needs instead of silence -- mirrored into the
-            # flight-recorder window when a telemetry session exists.
-            generation = len(reshape_events)
+            generation = len(reshape_events) + 1
             if controller is not None and hasattr(controller,
                                                   "generation"):
               try:
                 generation = controller.generation()
               except Exception:
                 pass
-            new_mesh = "x".join(
-                str(int(s)) for s in self.mesh.devices.shape)
-            event["mesh"] = f"{old_mesh}->{new_mesh}"
-            log_fn("elastic event: generation %d: mesh %s -> %s, "
-                   "resume step %d" % (generation, old_mesh, new_mesh,
-                                       i))
             # One span per generation on the elastic track: the whole
             # seam (seam snapshot + mesh rebuild + re-jit + restore +
             # contract re-verification), so the timeline shows where a
             # resize's wall went.
-            trace.add_span(
-                "elastic", f"resize_gen{generation}", t_seam,
-                trace.now() - t_seam,
-                {"generation": generation, "mesh": event["mesh"],
-                 "resume_step": i})
+            with trace.span("elastic", f"resize_gen{generation}",
+                            generation=generation,
+                            resume_step=i) as seam_args:
+              if p.train_dir:
+                # Drain happened at the sync point above; snapshot to
+                # disk BEFORE the rebuild, so a crash mid-rescale (or a
+                # preemption racing it) resumes from this exact seam --
+                # and a peer run at the new size can start from the same
+                # snapshot (the bit-identity contract of the rescale
+                # tests). incarnation_bump=1: the seam's resume point is
+                # the POST-resize input stream.
+                self._save_checkpoint(state, incarnation_bump=1)
+                last_save_time = time.time()
+              state, train_step, eval_step, next_batch, train_chunk = \
+                  self._reshape_topology(state, event["num_devices"],
+                                         event["batch_size_per_device"],
+                                         init_rng, steps_done=i,
+                                         examples_done=images_processed)
+              run_step = make_run_step(train_step, eval_step)
+              images, labels = next_batch()
+              cursor = 0
+              reshape_events.append(event)
+              # ONE elastic event line (generation, old -> new mesh,
+              # resume step) -- the operator-facing record a preemption
+              # story needs instead of silence -- mirrored into the
+              # flight-recorder window when a telemetry session exists.
+              new_mesh = "x".join(
+                  str(int(s)) for s in self.mesh.devices.shape)
+              event["mesh"] = f"{old_mesh}->{new_mesh}"
+              log_fn("elastic event: generation %d: mesh %s -> %s, "
+                     "resume step %d" % (generation, old_mesh, new_mesh,
+                                         i))
+              seam_args["mesh"] = event["mesh"]
             if tele is not None:
               tele.elastic_event(generation, old_mesh, new_mesh, i)
         pipe.note_aux_time(time.time() - aux_start)
@@ -2016,6 +2018,15 @@ class BenchmarkCNN:
         # bench.py forwards both into its one-line JSON.
         "latency_percentiles": self._trace.percentile_fields() or None,
         "compile_ledger": self._trace.compile_ledger(),
+        # Always-on totals per span name (n, total_s, max_s) and the
+        # compile-cache counters, for set-up and for the timed loop
+        # (tracing.py span_totals): what the benchmark's set-up and
+        # host-side metrics read, with or without a span file.
+        "span_totals": self._trace.span_totals() or None,
+        # The scopes the step program names: a trace reader holds the
+        # device operations' op_names to them (a warm compile cache can
+        # hand over another version's metadata; benchmarks/spans.py).
+        "step_scopes": list(train_step_lib.STEP_SCOPES),
         # Tuned-config provenance (--autotuned_config,
         # analysis/autotune.py): table path + the matched entry's base
         # fingerprint (entry None when the table had no row for this

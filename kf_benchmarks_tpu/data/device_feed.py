@@ -99,15 +99,12 @@ class DeviceFeeder:
         # device_put -- the producer half of the overlap question
         # stats() answers from the consumer side.
         trace = tracing.active()
-        t0 = trace.now()
-        batch = self._pull(it)
+        with trace.span("feed", "fetch", chunk=self._chunk):
+          batch = self._pull(it)
         if batch is None:
           break
-        trace.add_span("feed", "fetch", t0, trace.now() - t0,
-                       {"chunk": self._chunk})
-        t1 = trace.now()
-        device_batch = mesh_lib.put_batch(batch, self._sharding)
-        trace.add_span("feed", "h2d", t1, trace.now() - t1)
+        with trace.span("feed", "h2d"):
+          device_batch = mesh_lib.put_batch(batch, self._sharding)
         while not self._stop.is_set():
           try:
             self._queue.put(device_batch, timeout=0.5)
@@ -124,25 +121,30 @@ class DeviceFeeder:
 
   def __next__(self):
     t0 = time.monotonic()
-    # The span anchor reads the TRACE clock (injectable; mixing it with
-    # raw monotonic would skew fake-clock tests, tracing.RunTrace.now);
-    # the stats/sample below keep the real monotonic measurement.
     trace = tracing.active()
-    t0_trace = trace.now()
     if self._window_start is None:
       self._window_start = t0
     depth = self._queue.qsize()
-    # Poll with a timeout so a worker error is surfaced even when the
-    # queue is full at error time and the sentinel could not be enqueued.
-    while True:
-      try:
-        item = self._queue.get(timeout=0.5)
-        break
-      except queue.Empty:
-        if self._error is not None:
-          raise self._error
-        if not self._thread.is_alive():
-          raise StopIteration
+    # Consumer-wait lane (tracing.py): the blocking part of every
+    # fetch is a live span, so a profiler capture shows what the loop
+    # was waiting for while the device sat idle. That is EVERY wait the
+    # consumer sat through, the terminal ones too (the end-of-stream
+    # sentinel's drain, a wait that ends in the worker's error): the
+    # host was blocked there like anywhere else, so the span's totals
+    # count one more than the delivered fetches on a finite stream,
+    # while the feed_wait SAMPLE below keeps to delivered batches. Poll
+    # with a timeout so a worker error is surfaced even when the queue
+    # is full at error time and the sentinel could not be enqueued.
+    with trace.span("feed", "wait", queue_depth=depth * self._chunk):
+      while True:
+        try:
+          item = self._queue.get(timeout=0.5)
+          break
+        except queue.Empty:
+          if self._error is not None:
+            raise self._error
+          if not self._thread.is_alive():
+            raise StopIteration
     if item is None:
       # End-of-stream sentinel: not a delivered batch -- counting its
       # (terminal-drain) wait would read a healthy finite stream as
@@ -155,11 +157,8 @@ class DeviceFeeder:
     self._wait_s += waited
     self._window_end = now
     self._fetches += 1
-    # Consumer-wait lane + percentile sample (tracing.py): every fetch
-    # feeds the feed_wait p50/p90/p99, and a traced run shows each wait
-    # as a span (bracketed on the trace clock captured at entry).
-    trace.add_span("feed", "wait", t0_trace, trace.now() - t0_trace,
-                   {"queue_depth": depth * self._chunk})
+    # Percentile sample (tracing.py): every delivered fetch feeds the
+    # feed_wait p50/p90/p99.
     trace.add_sample("feed_wait", waited)
     # Live metric lanes (metrics.py active registry; no-op sink when no
     # endpoint/registry session is active): the /metrics scrape shows

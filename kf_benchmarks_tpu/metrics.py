@@ -422,6 +422,15 @@ NON_METRIC_KEYS = frozenset({
     # the run-store snapshot; the nested form keeps the JSON line
     # readable per tenant.
     "serving_tenants",
+    # PR 23: the run trace's always-on totals ({phase: {spans: {name:
+    # {n, total_s, max_s}}, counters: {...}}}; tracing.span_totals) --
+    # an open-ended table keyed by span name, read by the benchmark's
+    # set-up and host-side metrics (benchmarks/spans.py), not a set of
+    # registry keys.
+    "span_totals",
+    # PR 23: the scopes the step program names (train_step.STEP_SCOPES),
+    # config provenance for the benchmark's trace reader.
+    "step_scopes",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

@@ -10,9 +10,10 @@ TPU-native re-design of the reference's observability stack (SURVEY 5.1,
   --trace_events_file  whole-run HOST-side span timeline (tracing.py;
                   feed/dispatch/compile/checkpoint/elastic spans,
                   Chrome trace-event export, compile ledger, latency
-                  percentiles). maybe_trace_step below drops a marker
-                  span on that timeline so the device-level profiler
-                  capture and the host timeline line up.
+                  percentiles). Its live spans also enter whatever
+                  profiler capture is open as kf/<lane>/<name>
+                  annotations, so maybe_trace_step's capture below
+                  holds the host's spans beside the device planes.
   --tfprof_file   tfprof top-op profile (ref :276-289, :1208-1228) ->
                   compiled-HLO cost analysis (flops / bytes accessed /
                   estimated seconds) plus memory analysis of the jitted
@@ -69,14 +70,8 @@ def maybe_trace_step(trace_file: Optional[str], step: int,
   if trace_file and step == trace_at_step:
     trace_dir = trace_dir_of(trace_file)
     os.makedirs(trace_dir, exist_ok=True)
-    # Marker span on the run-trace timeline (tracing.py; no-op sink
-    # when no session is active): shows WHERE in the host timeline the
-    # device-level profiler capture happened, so the two traces align.
-    from kf_benchmarks_tpu import tracing
-    with tracing.active().span("profiler", "jax_profiler_trace",
-                               step=step, trace_dir=trace_dir):
-      with jax.profiler.trace(trace_dir):
-        yield True
+    with jax.profiler.trace(trace_dir):
+      yield True
     return
   yield False
 

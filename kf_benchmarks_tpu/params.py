@@ -419,8 +419,9 @@ flags.DEFINE_string("compilation_cache_dir", None,
                     "other). Unset = <checkout>/.jax_cache for "
                     "--device=tpu runs, off for CPU runs; never a "
                     "path under --train_dir. The compile ledger's "
-                    "cache_hit column (tracing.py) records which "
-                    "episodes the cache covered.")
+                    "cache_hit column (tracing.py) says which "
+                    "episodes the cache answered, from the cache's "
+                    "own hit events (jax.monitoring).")
 flags.DEFINE_boolean("health_stats", None,
                      "In-step training-health stats (telemetry.py): the "
                      "train step additionally returns a compact f32 "

@@ -1,55 +1,80 @@
-"""Unified run tracing: host-side span timeline, Chrome-trace export,
-compile ledger, streaming latency percentiles.
+"""Run tracing: ONE timeline of what the host did, two sinks, totals
+always.
 
 Re-design of the reference's one-step post-hoc tracing (``--trace_file``
 captures a FULL_TRACE of step -2 and converts it through
 ``timeline.Timeline`` into a Chrome trace, ref: benchmark_cnn.py:270-275,
-:806-817) into a WHOLE-RUN host-side span timeline: every wall-clock
-boundary the run crosses -- DeviceFeeder fetches and consumer waits,
-dispatch issue, device chunk completion, compile episodes, checkpoint
-save/restore, mid-training eval, elastic resize seams, fault
-injections -- is one span/instant event, exported as Chrome trace-event
-JSON (``--trace_events_file``; loads in Perfetto / chrome://tracing)
-with ``pid`` = process rank and ``tid`` = subsystem.  The jax.profiler
-``--trace_file`` device-level capture is untouched; this timeline is the
-host-side picture AROUND it (observability.maybe_trace_step drops a
-marker span so the two line up).
+:806-817) into a WHOLE-RUN account of the host, on the device trace's
+clock whenever a profiler capture is open.
+
+Every wall-clock boundary the run crosses on the host -- set-up pieces
+(build, state init, broadcast, restore), JAX's own trace / lower /
+compile episodes, dispatch issue, the blocking metric fetch, the
+per-step bookkeeping, DeviceFeeder fetches and consumer waits,
+checkpoint saves, mid-training eval, elastic resize seams, fault
+injections, the serving engine's requests -- is one span or instant
+event emitted through the run's ``RunTrace`` (reached everywhere via
+``active()``). From that one emission point the event goes to:
+
+* **The profiler's trace.** A live ``span()`` also enters a profiler
+  annotation named ``kf/<subsystem>/<name>`` with the span's arguments,
+  and ``step()`` a step annotation. Whenever ANY ``jax.profiler``
+  capture is open (the benchmark harness's window, ``--trace_file``, an
+  operator's ``start_server`` session) the span lands in the
+  ``.xplane.pb`` host plane beside the device planes, on the
+  profiler's own clock: one file, one clock, host and device. With no
+  capture open a closed annotation costs under a microsecond. The
+  annotation factories (``jax.profiler.TraceAnnotation`` /
+  ``StepTraceAnnotation``) are INJECTED where the session is created
+  (benchmark.py); this module stays pure stdlib. Retrospective records
+  (``add_span``: durations measured elsewhere, such as the ``device``
+  lane's arrival-interval estimate and JAX's monitoring events) cannot
+  enter the profiler and stay in the sinks below.
+* **The span file** (``--trace_events_file``): the same spans as Chrome
+  trace-event JSON (loads in Perfetto / chrome://tracing), ``pid`` =
+  process rank, ``tid`` = subsystem; spans are retained only with the
+  flag. Measured with ``time.monotonic`` and anchored to the wall
+  clock once at session start so that ranks merge onto one axis.
+* **Totals, always.** O(1) per span name -- ``n``, ``total_s``,
+  ``max_s`` -- kept separately for set-up, warm-up and the timed loop
+  (``begin_phase``), with the compile-cache counters beside them;
+  ``stats["span_totals"]`` carries them in every run, file or no file.
+  A stretched ``max_s`` names the boundary a slow run stalled on.
+
+Compilation is read from JAX itself (``jax.monitoring``; listeners
+registered by benchmark.py): each ``/jax/core/compile/*`` time span
+becomes a ``compile``-lane span carrying ``fun_name``, and the
+``/jax/compilation_cache/*`` events move the counters ``cache_hits``,
+``cache_misses``, ``cache_requests`` and ``backend_compiles``. JAX reports a nested trace (an inner ``jit``
+traced while the outer one is) as an event of its own, so the compile
+lane's totals count OUTERMOST intervals only: they add up to the wall
+time spent, not to a multiple of it.
 
 Hard contract (enforced by the program-contract auditor's twin-trace
 rule, analysis/audit.rule_trace_twin): tracing is HOST-ONLY.  The
 trace-on step program is structurally identical to the trace-off one,
 and per-step losses are bit-identical (tests/test_tracing.py pins it
 through ``--steps_per_dispatch`` / ``--num_grad_accum`` /
-``--shard_optimizer_state``).
-
-Timing discipline: spans are measured with ``time.monotonic`` on the
-host and anchored to the wall clock once at session start (so ranks
-merge onto one comparable axis).  The timed loop never blocks on the
-device: dispatch-issue spans bracket the async jit call alone, and
-per-chunk device spans are attributed DIFFERENTIALLY from the
-metric-pipeline arrival intervals (utils/pipeline.py) with the measured
-host issue overhead carried in the span args.
+``--shard_optimizer_state``). The timed loop never blocks on the device
+for tracing's sake: dispatch spans bracket the async jit call alone.
 
 On top of the same spans:
 
-* **Compile ledger** -- per-program-shape compile wall times keyed on
-  the auditor's contract fingerprint keys
-  (analysis/baseline.config_fingerprint_key), persisted/merged to
-  ``train_dir/compile_ledger.json`` and printed as a table at run end:
-  the groundwork for the persistent compile cache (ROADMAP item 5 --
-  pay the 30-minute first compile once per program shape ever).
+* **Compile ledger** -- per-program-shape first-dispatch wall times keyed
+  on the auditor's contract fingerprint keys
+  (analysis/baseline.config_fingerprint_key), with ``cache_hit`` from the
+  real cache events of that dispatch, persisted/merged to
+  ``train_dir/compile_ledger.json`` and printed as a table at run end.
 * **Streaming latency percentiles** -- p50/p90/p99 of chunk wall, feed
   wait and checkpoint save, printed at run end and carried in the
-  benchmark stats + bench.py JSON: the SLO-telemetry groundwork for the
-  serving path (ROADMAP item 2).
+  benchmark stats + bench.py JSON.
 
-Pure stdlib (no jax): importable from faults.py and loadable standalone
-by the hazard lint.  Span/event EMISSION is single-sourced here -- the
-lint rule ``trace-event-emission`` (analysis/lint.py) bans Chrome
-trace-event construction and percentile helpers outside this module,
-the same single-sourcing pattern as the step-line rule.  The flight
-recorder (telemetry.py) shares this session's run id and cross-links
-rows to span ids, so a post-mortem dump lays over the timeline.
+Span/event EMISSION is single-sourced here -- the lint rule
+``trace-event-emission`` (analysis/lint.py) bans Chrome trace-event
+construction, percentile helpers and profiler-annotation construction
+outside this module's ``RunTrace``.  The flight recorder (telemetry.py)
+shares this session's run id and cross-links rows to span ids, so a
+post-mortem dump lays over the timeline.
 """
 
 from __future__ import annotations
@@ -63,12 +88,37 @@ from typing import Any, Dict, List, Optional
 
 # Subsystem lanes (Chrome tid; one timeline row per subsystem under
 # each rank's pid). Order fixes the tid numbering so merged multi-rank
-# timelines line up row-for-row. "serving" is the request engine's lane
+# timelines line up row-for-row; new lanes go at the end so the old
+# ones keep their rows. "serving" is the request engine's lane
 # (serving/engine.py: enqueue/shed instants, prefill/decode-step spans,
-# whole-request spans).
+# whole-request spans); "setup" the pieces before warm-up; "fetch" the
+# blocking metric read; "handle" the per-step host bookkeeping.
 SUBSYSTEMS = ("run", "compile", "dispatch", "device", "feed",
-              "checkpoint", "eval", "elastic", "faults", "profiler",
-              "serving")
+              "checkpoint", "eval", "elastic", "faults", "serving",
+              "setup", "fetch", "handle")
+
+# Phases the always-on totals are kept for (begin_phase): everything up
+# to the first dispatch, the warm-up (whose first dispatch traces and
+# compiles the step), and the timed loop.
+PHASE_SETUP = "setup"
+PHASE_WARMUP = "warmup"
+PHASE_TIMED = "timed_loop"
+
+# jax.monitoring events the session listens to (the listeners are
+# registered by benchmark.py; this module never imports jax). The time
+# spans become compile-lane spans under the short names on the right.
+COMPILE_SPAN_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+CACHE_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+}
+COUNTER_KEYS = ("cache_hits", "cache_misses", "cache_requests",
+                "backend_compiles")
 
 # Canonical latency-sample keys (the percentile lines / stats fields).
 # The serving/* entries come from the request engine: TTFT per request,
@@ -179,14 +229,18 @@ def validate_chrome_trace(obj) -> List[str]:
 
 
 class RunTrace:
-  """One process's span timeline + latency samples + compile ledger.
+  """One process's span timeline + totals + latency samples + compile
+  ledger.
 
   Host-side only and always cheap: with no ``path`` the span list is
-  not retained (samples and the ledger still are, so percentile lines
-  and bench JSON fields work without ``--trace_events_file``). All
-  methods are thread-safe (the DeviceFeeder worker emits feed spans
-  from its own thread). ``time_fn``/``wall_fn`` are injectable so the
-  unit tests drive a deterministic clock.
+  not retained (the totals, the samples and the ledger still are, so
+  ``stats["span_totals"]``, the percentile lines and the bench JSON
+  fields work without ``--trace_events_file``). All methods are
+  thread-safe (the DeviceFeeder worker emits feed spans from its own
+  thread). ``time_fn``/``wall_fn`` are injectable so the unit tests
+  drive a deterministic clock. ``annotation`` / ``step_annotation`` are
+  the profiler's annotation factories (``name, **kwargs`` -> context
+  manager), None for no profiler sink.
   """
 
   MAX_SPANS = 200_000  # bound memory on very long runs; drops counted
@@ -199,7 +253,8 @@ class RunTrace:
   def __init__(self, path: Optional[str] = None, rank: int = 0,
                num_ranks: int = 1, run_id: Optional[str] = None,
                chrome_format: bool = True, time_fn=time.monotonic,
-               wall_fn=time.time, log_fn=None):
+               wall_fn=time.time, log_fn=None, annotation=None,
+               step_annotation=None):
     self.path = path
     self.rank = int(rank)
     self.num_ranks = max(1, int(num_ranks))
@@ -223,6 +278,18 @@ class RunTrace:
     self._sample_counts: Dict[str, int] = {}
     self._sample_strides: Dict[str, int] = {}
     self._ledger: List[Dict[str, Any]] = []
+    self._annotation = annotation
+    self._step_annotation = step_annotation
+    # Always-on totals: {phase: {"<sub>/<name>": [n, total_s, max_s]}}
+    # and the compile-cache counters, overall (for compile_mark) and
+    # per phase.
+    self._phase = PHASE_SETUP
+    self._totals: Dict[str, Dict[str, List[float]]] = {PHASE_SETUP: {}}
+    self._counters: Dict[str, float] = dict.fromkeys(COUNTER_KEYS, 0)
+    self._phase_counters: Dict[str, Dict[str, float]] = {
+        PHASE_SETUP: dict.fromkeys(COUNTER_KEYS, 0)}
+    # Outermost compile intervals still standing: (t0, t1, totals row).
+    self._compile_cover: List[Any] = []
 
   # -- clock ------------------------------------------------------------------
 
@@ -249,8 +316,24 @@ class RunTrace:
     durations measured elsewhere -- the pipeline's chunk arrival
     intervals, the feeder's consumer wait -- where wrapping a ``with``
     block around the measured region is not possible."""
-    return self._emit("X", subsystem, name, float(t0),
-                      max(0.0, float(dur_s)), dict(args or {}))
+    dur_s = max(0.0, float(dur_s))
+    with self._lock:
+      self._total_row(subsystem, name, dur_s)[1] += dur_s
+    return self._emit("X", subsystem, name, float(t0), dur_s,
+                      dict(args or {}))
+
+  def _total_row(self, subsystem: str, name: str,
+                 dur_s: float) -> List[float]:
+    """The current phase's totals row of a span name, with ``n`` and
+    ``max_s`` already counted; the caller adds what the span is worth
+    to ``total_s`` (all of it, but for the compile lane's nesting).
+    Called under the lock."""
+    row = self._totals[self._phase].setdefault(
+        f"{subsystem}/{name}", [0, 0.0, 0.0])
+    row[0] += 1
+    if dur_s > row[2]:
+      row[2] = dur_s
+    return row
 
   def instant(self, subsystem: str, name: str, **args) -> int:
     """A zero-duration marker event (fault injections, profiler-capture
@@ -275,17 +358,108 @@ class RunTrace:
       })
     return sid
 
-  @contextlib.contextmanager
   def span(self, subsystem: str, name: str, **args):
     """Context manager form; yields the (mutable) args dict so callers
     can attach results discovered inside the span (e.g. the elastic
-    generation number)."""
-    t0 = self._time()
+    generation number). Also enters the profiler annotation
+    ``kf/<subsystem>/<name>`` with the arguments given at entry."""
+    return self._live(subsystem, name, self._annotation,
+                      f"kf/{subsystem}/{name}", args)
+
+  def step(self, name: str, step_num: int):
+    """One iteration of a loop: a ``run``-lane span that enters the
+    profiler's STEP annotation ``name`` (which profile viewers group
+    device and host activity by) instead of a ``kf/`` one."""
+    return self._live("run", name, self._step_annotation, name,
+                      {"step_num": int(step_num)})
+
+  @contextlib.contextmanager
+  def _live(self, subsystem: str, name: str, factory, label: str,
+            args: Dict[str, Any]):
     live_args = dict(args)
+    annotation = factory(label, **args) if factory is not None else None
+    t0 = self._time()
+    if annotation is not None:
+      annotation.__enter__()
     try:
       yield live_args
     finally:
+      if annotation is not None:
+        annotation.__exit__(None, None, None)
       self.add_span(subsystem, name, t0, self._time() - t0, live_args)
+
+  # -- always-on totals -------------------------------------------------------
+
+  def begin_phase(self, phase: str) -> None:
+    """Spans and counters from here on are totalled under ``phase``
+    (PHASE_SETUP from session start, PHASE_WARMUP from the first
+    dispatch, PHASE_TIMED from the first timed one)."""
+    with self._lock:
+      self._phase = phase
+      self._totals.setdefault(phase, {})
+      self._phase_counters.setdefault(phase,
+                                      dict.fromkeys(COUNTER_KEYS, 0))
+
+  def span_totals(self) -> Dict[str, Any]:
+    """``{phase: {"spans": {"<subsystem>/<name>": {n, total_s, max_s}},
+    "counters": {...}}}`` -- the ``stats["span_totals"]`` field."""
+    with self._lock:
+      return {
+          phase: {
+              "spans": {key: {"n": int(n), "total_s": total, "max_s": mx}
+                        for key, (n, total, mx) in sorted(rows.items())},
+              "counters": dict(self._phase_counters[phase]),
+          } for phase, rows in self._totals.items()}
+
+  # -- jax.monitoring listeners (registered by benchmark.py) ------------------
+
+  def on_time_span(self, event: str, start_time: float, end_time: float,
+                   **kwargs) -> None:
+    """``jax.monitoring`` time-span listener: a ``/jax/core/compile/*``
+    span (wall-clock seconds) becomes a compile-lane span carrying
+    ``fun_name``. Events arrive when they END, inner before outer, so
+    an interval that contains standing ones takes their place in the
+    totals (module docstring: outermost intervals only)."""
+    name = COMPILE_SPAN_EVENTS.get(event)
+    if name is None:
+      return
+    dur = max(0.0, float(end_time) - float(start_time))
+    t0 = self._anchor_mono + (float(start_time) - self._anchor_wall)
+    with self._lock:
+      row = self._total_row("compile", name, dur)
+      cover = self._compile_cover
+      while cover and cover[-1][0] >= start_time:
+        inner_t0, inner_t1, inner_row = cover.pop()
+        inner_row[1] -= inner_t1 - inner_t0
+      cover.append((start_time, end_time, row))
+      row[1] += dur
+      if name == "backend_compile":
+        self._count("backend_compiles", 1)
+    self._emit("X", "compile", name, t0, dur,
+               {"fun_name": str(kwargs.get("fun_name", ""))})
+
+  def on_event(self, event: str, **kwargs) -> None:
+    """``jax.monitoring`` event listener: the compilation cache's hit /
+    miss / request events."""
+    key = CACHE_COUNT_EVENTS.get(event)
+    if key is not None:
+      with self._lock:
+        self._count(key, 1)
+
+  def _count(self, key: str, by) -> None:
+    self._counters[key] += by
+    self._phase_counters[self._phase][key] += by
+
+  def compile_mark(self):
+    """The counters now; hand it to ``note_compile(since=...)`` after a
+    dispatch to learn what that dispatch compiled or loaded."""
+    c = self._counters
+    return (c["cache_hits"], c["cache_requests"], c["backend_compiles"])
+
+  def compiles_since(self, mark) -> int:
+    """Backend compilations (loads from the cache included) since
+    ``mark``."""
+    return int(self._counters["backend_compiles"] - mark[2])
 
   # -- latency samples --------------------------------------------------------
 
@@ -343,14 +517,21 @@ class RunTrace:
   # -- compile ledger ---------------------------------------------------------
 
   def note_compile(self, key: str, program: str, wall_s: float,
-                   **meta) -> None:
+                   since=None, **meta) -> None:
     """Record one compile episode. ``key`` is the program-shape
     fingerprint (analysis/baseline.config_fingerprint_key); ``wall_s``
-    the host-observed wall of the first dispatch of that program (which
-    blocks on trace+compile -- the benchmark.py compile_s convention)."""
+    the host-observed wall of the dispatch that compiled (it blocks on
+    trace+compile -- the benchmark.py compile_s convention). With
+    ``since`` (a ``compile_mark`` taken before the dispatch) the row
+    also says ``cache_hit``: every compile request of the episode was
+    answered from the persistent cache, by the cache's own events."""
     entry = {"key": key, "program": program,
              "wall_s": round(float(wall_s), 6)}
     entry.update(meta)
+    if since is not None:
+      hits = self._counters["cache_hits"] - since[0]
+      requests = self._counters["cache_requests"] - since[1]
+      entry["cache_hit"] = bool(hits > 0 and hits == requests)
     with self._lock:
       self._ledger.append(entry)
     self.add_span("compile", program, self._time() - float(wall_s),
@@ -591,8 +772,7 @@ class RunTrace:
 # -- compile-ledger query API -------------------------------------------------
 # Read side of the persisted ledger (write_ledger above): the autotuner's
 # warm pass (analysis/autotune.py) cross-references it to decide which
-# program shapes to precompile, and benchmark.py reads the prior keys
-# for the cache_hit heuristic. Pure stdlib, like everything here.
+# program shapes to precompile. Pure stdlib, like everything here.
 
 def read_ledger(train_dir: str) -> Dict[str, Any]:
   """The persisted compile ledger at ``train_dir/compile_ledger.json``
@@ -677,6 +857,20 @@ class _NullTrace:
   @contextlib.contextmanager
   def span(self, *a, **k):
     yield {}
+
+  step = span
+
+  def begin_phase(self, phase: str) -> None:
+    pass
+
+  def span_totals(self) -> Dict[str, Any]:
+    return {}
+
+  def compile_mark(self):
+    return (0, 0, 0)
+
+  def compiles_since(self, mark) -> int:
+    return 0
 
   def add_sample(self, *a, **k) -> None:
     pass

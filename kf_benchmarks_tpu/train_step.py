@@ -48,6 +48,16 @@ from kf_benchmarks_tpu.parallel import mesh as mesh_lib
 from kf_benchmarks_tpu.parallel.mesh import (BATCH_AXIS, MODEL_AXIS,
                                              REPLICA_AXIS)
 
+# The phases the step program names (``jax.named_scope`` in
+# make_step_fns). Every device operation's ``op_name`` carries the scope
+# it was traced under, and the benchmark's trace reader books the step's
+# time by them (benchmarks/spans.py); run stats carry this list as
+# ``step_scopes`` so that the reader can hold a trace to it. The names
+# are METADATA: they are not in the persistent compile cache's key, so a
+# change to the scopes alone is served the old executable, old names
+# and all, from a warm cache (CLAUDE.md, observability notes).
+STEP_SCOPES = ("forward", "exchange", "metrics", "optimizer_apply")
+
 
 @flax.struct.dataclass
 class TrainState:
@@ -429,9 +439,10 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # FSDP + accumulation: one whole-tree gather up front (the
       # round-11 steady state, rotated to the step top), full-tree
       # microbatch scan, post-hoc scatter below.
-      forward_params = sharded_lib.fsdp_gather_full(
-          model_params, fsdp_template, fsdp_module_prefixes,
-          nested=use_gspmd)
+      with jax.named_scope("exchange"):
+        forward_params = sharded_lib.fsdp_gather_full(
+            model_params, fsdp_template, fsdp_module_prefixes,
+            nested=use_gspmd)
     # Data-replica id: on the 2-D mesh, model-axis peers fold the SAME
     # id (same batch shard, same dropout stream), which is what makes
     # their local gradients identical by construction -- the free
@@ -456,10 +467,11 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         # the SCALED cotangents and the unscale divides by a
         # power-of-two scale afterwards (exponent shift; bit-identical
         # to dividing first, as the post-hoc path does).
-        p = overlap_lib.wrap_tree(
-            p, axis_data, overlap_spec.bucket_bytes,
-            compact_dtype=overlap_spec.compact_dtype,
-            exclude_prefixes=module_reduced_prefixes)
+        with jax.named_scope("exchange"):
+          p = overlap_lib.wrap_tree(
+              p, axis_data, overlap_spec.bucket_bytes,
+              compact_dtype=overlap_spec.compact_dtype,
+              exclude_prefixes=module_reduced_prefixes)
       if fsdp_in_step:
         # FSDP per-bucket gather (ops/overlap.py gather_params): every
         # non-module-gathered leaf of p below is the RE-ASSEMBLED full
@@ -471,38 +483,45 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         # issued where that bucket's backward completes. The unscale-
         # after-scatter ordering is exact for the same power-of-two
         # reason as the overlap hooks above.
-        p = overlap_lib.fsdp_wrap_shards(
-            p, fsdp_template, fsdp_bucket_bytes, BATCH_AXIS, MODEL_AXIS,
-            exclude_prefixes=fsdp_module_prefixes, nested=use_gspmd)
-      variables = {"params": p}
-      if bs:
-        variables["batch_stats"] = bs
-      (logits, aux_logits), updates = module.apply(
-          variables, mb_images, mutable=["batch_stats"],
-          rngs={"dropout": dropout_rng}, **apply_kwargs)
-      new_bs = updates.get("batch_stats", bs)
-      from kf_benchmarks_tpu.models.model import BuildNetworkResult
-      result = BuildNetworkResult(logits=(logits, aux_logits))
-      base_loss = model.loss_function(result, mb_labels)
-      total_loss = base_loss
-      if weight_decay:
-        if fsdp_in_step and fsdp_module_prefixes:
-          # The scanned-stack leaves of p are SHARDS here (their full
-          # values exist only block-at-a-time inside the scan), so
-          # their L2 term reduces shard-locally + one scalar psum over
-          # the mesh -- exact in value (shards tile the stack once,
-          # pad is zero) but reassociated, so total_loss is NOT
-          # bit-identical to the replicated-param L2 for scanned
-          # models with weight decay (the make_step_fns note logs
-          # this; the gathered non-scanned leaves keep the exact
-          # legacy term).
-          total_loss = total_loss + weight_decay * _l2_loss_mixed(
-              p, fsdp_module_prefixes, axis_all,
-              single_op=params.single_l2_loss_op)
-        else:
-          total_loss = total_loss + weight_decay * l2_loss(
-              p, single_op=params.single_l2_loss_op)
-      scaled = total_loss * state.loss_scale
+        with jax.named_scope("exchange"):
+          p = overlap_lib.fsdp_wrap_shards(
+              p, fsdp_template, fsdp_bucket_bytes, BATCH_AXIS, MODEL_AXIS,
+              exclude_prefixes=fsdp_module_prefixes, nested=use_gspmd)
+      # One scope for the model, its loss and the weight decay: under
+      # jax.grad XLA's op_name reads ``jvp(forward)`` going forward and
+      # ``transpose(jvp(forward))`` coming back, which is how the
+      # benchmark's trace reader (benchmarks/spans.py) tells the two
+      # passes apart. Metadata only.
+      with jax.named_scope("forward"):
+        variables = {"params": p}
+        if bs:
+          variables["batch_stats"] = bs
+        (logits, aux_logits), updates = module.apply(
+            variables, mb_images, mutable=["batch_stats"],
+            rngs={"dropout": dropout_rng}, **apply_kwargs)
+        new_bs = updates.get("batch_stats", bs)
+        from kf_benchmarks_tpu.models.model import BuildNetworkResult
+        result = BuildNetworkResult(logits=(logits, aux_logits))
+        base_loss = model.loss_function(result, mb_labels)
+        total_loss = base_loss
+        if weight_decay:
+          if fsdp_in_step and fsdp_module_prefixes:
+            # The scanned-stack leaves of p are SHARDS here (their full
+            # values exist only block-at-a-time inside the scan), so
+            # their L2 term reduces shard-locally + one scalar psum over
+            # the mesh -- exact in value (shards tile the stack once,
+            # pad is zero) but reassociated, so total_loss is NOT
+            # bit-identical to the replicated-param L2 for scanned
+            # models with weight decay (the make_step_fns note logs
+            # this; the gathered non-scanned leaves keep the exact
+            # legacy term).
+            total_loss = total_loss + weight_decay * _l2_loss_mixed(
+                p, fsdp_module_prefixes, axis_all,
+                single_op=params.single_l2_loss_op)
+          else:
+            total_loss = total_loss + weight_decay * l2_loss(
+                p, single_op=params.single_l2_loss_op)
+        scaled = total_loss * state.loss_scale
       return scaled, (base_loss, total_loss, new_bs, result)
 
     accum_acc_metrics = None
@@ -637,8 +656,9 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # elastic.noise_scale_stats. This is the in-collective monitoring
       # KungFu's runtime does (SURVEY 2.9 "monitored gradient noise
       # scale").
-      noise_stats = elastic_lib.noise_scale_stats(
-          grads, axis_data, images.shape[0])
+      with jax.named_scope("metrics"):
+        noise_stats = elastic_lib.noise_scale_stats(
+            grads, axis_data, images.shape[0])
     grad_shards = None
     if fsdp_in_step:
       # Full FSDP: the in-backward gather hooks already reduce-
@@ -651,8 +671,9 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # FSDP + accumulation: post-hoc scatter of the accumulated full
       # tree onto the FSDP layout (per-layer rows for the scanned
       # stacks) -- elementwise the same values as scatter_mean.
-      grad_shards = sharded_lib.fsdp_scatter_mean(grads,
-                                                  fsdp_module_prefixes)
+      with jax.named_scope("exchange"):
+        grad_shards = sharded_lib.fsdp_scatter_mean(grads,
+                                                    fsdp_module_prefixes)
     elif sharded_state:
       # ZeRO gradient pass (ops/sharded.py): reduce-scatter of the
       # batch-axis mean -- each scatter group meets the same B distinct
@@ -660,9 +681,14 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # so the scattered mean is BIT-IDENTICAL to it -- then the free
       # model-axis sub-slice. The full gradient tree dies here; only
       # this device's 1/n flat shard flows on.
-      grad_shards = sharded_lib.scatter_mean(grads)
+      with jax.named_scope("exchange"):
+        grad_shards = sharded_lib.scatter_mean(grads)
     elif not overlap_in_step:
-      grads = strategy.reduce_gradients(grads, axis_data)
+      # "exchange" names the strategy's whole reduction -- the casts,
+      # concatenations and scalings around the collectives too, which a
+      # reader that goes by opcode alone would miss (benchmarks/spans.py).
+      with jax.named_scope("exchange"):
+        grads = strategy.reduce_gradients(grads, axis_data)
     # else: the in-backward hooks already reduced every bucket
     # (module-internal hooks for module_reduced_prefixes, the loss_fn
     # wrap for the rest); everything downstream -- the auto-loss-scale
@@ -706,8 +732,9 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       new_buffers["deferred_grads"] = banked
       grads = buffers["deferred_grads"]
 
-    model_params_pre = strategy.pre_update(model_params, state.step,
-                                           axis_data)
+    with jax.named_scope("exchange"):
+      model_params_pre = strategy.pre_update(model_params, state.step,
+                                             axis_data)
     if sharded_state:
       # The ZeRO apply (the reference's central variable placement
       # rendered SPMD, variable_mgr.py:201-243): run the optimizer on
@@ -726,17 +753,19 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         updates, new_opt_state = tx.update(grad_shards, opt_state,
                                            param_shards)
         new_shards = optax.apply_updates(param_shards, updates)
-      new_params = (new_shards if sharded_params else
-                    sharded_lib.gather_tree(new_shards, model_params_pre,
-                                            nested=use_gspmd))
+      with jax.named_scope("exchange"):
+        new_params = (new_shards if sharded_params else
+                      sharded_lib.gather_tree(new_shards, model_params_pre,
+                                              nested=use_gspmd))
     elif getattr(strategy, "sequential_apply", False):
       # Async PS with a stateful optimizer (strategies.py): serialize
       # every replica's unaveraged gradient through the SHARED optimizer
       # state, in replica-index order -- the deterministic SPMD
       # rendering of the PS's one-at-a-time applications (ref async
       # mode: benchmark_cnn.py:520-522).
-      g_all = jax.tree.map(
-          lambda g: lax.all_gather(g, axis_data, axis=0), grads)
+      with jax.named_scope("exchange"):
+        g_all = jax.tree.map(
+            lambda g: lax.all_gather(g, axis_data, axis=0), grads)
 
       def _apply_one(carry, g):
         prms, ost = carry
@@ -760,8 +789,9 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         updates, new_opt_state = tx.update(grads, opt_state,
                                            model_params_pre)
         new_params = optax.apply_updates(model_params_pre, updates)
-    new_params = strategy.post_update(new_params, state.step, axis_data)
-    new_bs = strategy.sync_batch_stats(new_bs, axis_data)
+    with jax.named_scope("exchange"):
+      new_params = strategy.post_update(new_params, state.step, axis_data)
+      new_bs = strategy.sync_batch_stats(new_bs, axis_data)
 
     if auto_loss_scale:
       # Auto loss-scale state machine (ref: variable_mgr_util.py:51-139):
@@ -792,136 +822,139 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       new_scale = state.loss_scale
       normal_steps = state.loss_scale_normal_steps
 
-    lr = lr_fn(state.step)
-    # Token-weighted metric combine (--packed_sequences): this
-    # replica's real-label count; per-replica losses are already
-    # normalized by it (ops/fused_loss.py), so the global token-mean is
-    # pmean(loss * w) / pmean(w) -- computed from the SAME packed
-    # vector collective that carries the losses.
-    tok_w = None
-    if token_weight_fn is not None:
-      tok_w = (accum_tok_w if accum_tok_w is not None
-               else jnp.sum(token_weight_fn(images)))
-    wm_safe = None
-    if health_stats:
-      # In-step health stats (telemetry.py): grad norm, update/param
-      # ratio, non-finite leaf count, loss scale + skip flag -- all
-      # read from the step's post-reduction values, so they are
-      # replica-identical for the replica-synchronous strategies
-      # validation admits. Each replica reduces a 1/n SLICE of every
-      # tree (telemetry.health_partials) and the pre-scaled partial
-      # sums ride the LOSS pmean: one f32 vector all-reduce replaces
-      # the two scalar loss pmeans, so the health-on program carries
-      # NO extra collective (acceptance-pinned in
-      # tests/test_telemetry.py) and no replicated full-tree passes.
-      # Elementwise, the vector all-reduce computes bit-identical loss
-      # values to the scalar ones (equivalence pinned in the same
-      # tests). ``updates`` exists on every health-admitted path:
-      # sequential_apply (async PS) is rejected/auto-disabled by
-      # validation.py and resolve_health_stats.
-      skipped = (1.0 - fresh_finite.astype(jnp.float32)
-                 if fresh_finite is not None else jnp.float32(0.0))
-      # The fresh-grad overflow skip only suppresses the applied
-      # update on the non-relaxed path (the relaxed bank admits finite
-      # gradients only, so its apply always lands).
-      suppressed = jnp.float32(0.0) if relaxed else skipped
-      # Under --packed_sequences the two loss slots ride token-weighted
-      # (loss * w) and w itself is appended to the SAME vector, so the
-      # weighted combine still costs the one loss pmean.
-      bl32 = base_loss.astype(jnp.float32)
-      tl32 = total_loss.astype(jnp.float32)
-      loss_slots = (jnp.stack([bl32, tl32]) if tok_w is None else
-                    jnp.stack([bl32 * tok_w, tl32 * tok_w]))
-      vec = [loss_slots, telemetry_lib.health_partials(
-          grads, model_params, updates, axis_data)]
-      if tok_w is not None:
-        vec.append(jnp.stack([tok_w]))
-      packed = lax.pmean(jnp.concatenate(vec), axis_data)
-      health_totals = packed[2:] if tok_w is None else packed[2:-1]
-      if tok_w is None:
-        bl_m, tl_m = packed[0], packed[1]
+    # "metrics": the loss / accuracy / health reductions of the step
+    # line, apart from the training arithmetic (benchmarks/spans.py).
+    with jax.named_scope("metrics"):
+      lr = lr_fn(state.step)
+      # Token-weighted metric combine (--packed_sequences): this
+      # replica's real-label count; per-replica losses are already
+      # normalized by it (ops/fused_loss.py), so the global token-mean is
+      # pmean(loss * w) / pmean(w) -- computed from the SAME packed
+      # vector collective that carries the losses.
+      tok_w = None
+      if token_weight_fn is not None:
+        tok_w = (accum_tok_w if accum_tok_w is not None
+                 else jnp.sum(token_weight_fn(images)))
+      wm_safe = None
+      if health_stats:
+        # In-step health stats (telemetry.py): grad norm, update/param
+        # ratio, non-finite leaf count, loss scale + skip flag -- all
+        # read from the step's post-reduction values, so they are
+        # replica-identical for the replica-synchronous strategies
+        # validation admits. Each replica reduces a 1/n SLICE of every
+        # tree (telemetry.health_partials) and the pre-scaled partial
+        # sums ride the LOSS pmean: one f32 vector all-reduce replaces
+        # the two scalar loss pmeans, so the health-on program carries
+        # NO extra collective (acceptance-pinned in
+        # tests/test_telemetry.py) and no replicated full-tree passes.
+        # Elementwise, the vector all-reduce computes bit-identical loss
+        # values to the scalar ones (equivalence pinned in the same
+        # tests). ``updates`` exists on every health-admitted path:
+        # sequential_apply (async PS) is rejected/auto-disabled by
+        # validation.py and resolve_health_stats.
+        skipped = (1.0 - fresh_finite.astype(jnp.float32)
+                   if fresh_finite is not None else jnp.float32(0.0))
+        # The fresh-grad overflow skip only suppresses the applied
+        # update on the non-relaxed path (the relaxed bank admits finite
+        # gradients only, so its apply always lands).
+        suppressed = jnp.float32(0.0) if relaxed else skipped
+        # Under --packed_sequences the two loss slots ride token-weighted
+        # (loss * w) and w itself is appended to the SAME vector, so the
+        # weighted combine still costs the one loss pmean.
+        bl32 = base_loss.astype(jnp.float32)
+        tl32 = total_loss.astype(jnp.float32)
+        loss_slots = (jnp.stack([bl32, tl32]) if tok_w is None else
+                      jnp.stack([bl32 * tok_w, tl32 * tok_w]))
+        vec = [loss_slots, telemetry_lib.health_partials(
+            grads, model_params, updates, axis_data)]
+        if tok_w is not None:
+          vec.append(jnp.stack([tok_w]))
+        packed = lax.pmean(jnp.concatenate(vec), axis_data)
+        health_totals = packed[2:] if tok_w is None else packed[2:-1]
+        if tok_w is None:
+          bl_m, tl_m = packed[0], packed[1]
+        else:
+          wm_safe = jnp.maximum(packed[-1], 1e-30)
+          bl_m, tl_m = packed[0] / wm_safe, packed[1] / wm_safe
+        metrics = {
+            "base_loss": bl_m,
+            "total_loss": tl_m,
+            "learning_rate": lr,
+            "health": telemetry_lib.health_finalize(
+                health_totals, new_scale, skipped, suppressed),
+        }
+      elif tok_w is not None:
+        # One 3-vector pmean replaces the two scalar loss pmeans: the
+        # packed program's collective count stays <= the unpacked one.
+        packed = lax.pmean(
+            jnp.stack([base_loss.astype(jnp.float32) * tok_w,
+                       total_loss.astype(jnp.float32) * tok_w, tok_w]),
+            axis_data)
+        wm_safe = jnp.maximum(packed[2], 1e-30)
+        metrics = {
+            "base_loss": packed[0] / wm_safe,
+            "total_loss": packed[1] / wm_safe,
+            "learning_rate": lr,
+        }
       else:
-        wm_safe = jnp.maximum(packed[-1], 1e-30)
-        bl_m, tl_m = packed[0] / wm_safe, packed[1] / wm_safe
-      metrics = {
-          "base_loss": bl_m,
-          "total_loss": tl_m,
-          "learning_rate": lr,
-          "health": telemetry_lib.health_finalize(
-              health_totals, new_scale, skipped, suppressed),
-      }
-    elif tok_w is not None:
-      # One 3-vector pmean replaces the two scalar loss pmeans: the
-      # packed program's collective count stays <= the unpacked one.
-      packed = lax.pmean(
-          jnp.stack([base_loss.astype(jnp.float32) * tok_w,
-                     total_loss.astype(jnp.float32) * tok_w, tok_w]),
-          axis_data)
-      wm_safe = jnp.maximum(packed[2], 1e-30)
-      metrics = {
-          "base_loss": packed[0] / wm_safe,
-          "total_loss": packed[1] / wm_safe,
-          "learning_rate": lr,
-      }
-    else:
-      # Metric pmeans reduce over the DATA axis only: model-axis peers
-      # compute the identical loss from the identical batch shard, so
-      # the batch-group mean is already the global value -- and it is
-      # bit-identical to the replicated path's B-contribution pmean.
-      metrics = {
-          "base_loss": lax.pmean(base_loss, axis_data),
-          "total_loss": lax.pmean(total_loss, axis_data),
-          "learning_rate": lr,
-      }
-    if tok_w is not None and wm_safe is not None:
-      # Label coverage of the packed batch (real label positions /
-      # slots): the in-step packing-efficiency signal next to the
-      # host-side feed line (observability.packing_feed_line). Post-
-      # collective scalar math, no extra communication.
-      metrics["real_token_fraction"] = wm_safe / jnp.float32(
-          sum(math.prod(l.shape) for l in jax.tree.leaves(labels)) or 1)
-    if steps_per_dispatch > 1:
-      # Replica-mean global norm of the reduced gradients (under relaxed
-      # consistency: of the APPLIED, one-step-stale bank) -- the
-      # per-step training-health scalar the chunked mode stacks
-      # alongside loss and lr, replacing what an operator would
-      # otherwise probe with per-step fetches. K=1 omits it so the
-      # single-step program stays the exact program behind PERF.md's
-      # pinned envelope numbers.
-      if "health" in metrics:
-        # The health vector already carries this exact norm (same grads
-        # tree, sharded reduction): reuse it rather than paying a second,
-        # full-tree replicated square-sum pass -- the replicated pass is
-        # the ~2x-step-time cost _sharded_sumsq exists to avoid.
-        metrics["grad_norm"] = metrics["health"][0]
-      elif sharded_state:
-        # The flat shards tile the reduced gradient exactly once, so
-        # the psum of per-shard square-sums over BOTH axes is the global
-        # square-sum -- no full-tree pass, same cost argument as the
-        # health path's sharded reduction.
-        metrics["grad_norm"] = jnp.sqrt(lax.psum(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grad_shards)), axis_all))
-      else:
-        metrics["grad_norm"] = lax.pmean(
-            jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                         for g in jax.tree.leaves(grads))), axis_data)
-    if params.print_training_accuracy:
-      # Under microbatching the per-microbatch scalar accuracies were
-      # averaged inside the scan (equal microbatch sizes make that the
-      # effective-batch value); monolithic computes them here.
-      acc = (accum_acc_metrics if accum_acc_metrics is not None
-             else model.accuracy_function(net_result, labels))
-      # Scalars only: detection accuracy_functions also return per-box
-      # arrays (decoded predictions), which are not replicated step
-      # metrics. Packed runs weight each replica's (already token-
-      # weighted) accuracy by its real-label count, like the losses.
+        # Metric pmeans reduce over the DATA axis only: model-axis peers
+        # compute the identical loss from the identical batch shard, so
+        # the batch-group mean is already the global value -- and it is
+        # bit-identical to the replicated path's B-contribution pmean.
+        metrics = {
+            "base_loss": lax.pmean(base_loss, axis_data),
+            "total_loss": lax.pmean(total_loss, axis_data),
+            "learning_rate": lr,
+        }
       if tok_w is not None and wm_safe is not None:
-        metrics.update({k: lax.pmean(v * tok_w, axis_data) / wm_safe
-                        for k, v in acc.items() if jnp.ndim(v) == 0})
-      else:
-        metrics.update({k: lax.pmean(v, axis_data)
-                        for k, v in acc.items() if jnp.ndim(v) == 0})
+        # Label coverage of the packed batch (real label positions /
+        # slots): the in-step packing-efficiency signal next to the
+        # host-side feed line (observability.packing_feed_line). Post-
+        # collective scalar math, no extra communication.
+        metrics["real_token_fraction"] = wm_safe / jnp.float32(
+            sum(math.prod(l.shape) for l in jax.tree.leaves(labels)) or 1)
+      if steps_per_dispatch > 1:
+        # Replica-mean global norm of the reduced gradients (under relaxed
+        # consistency: of the APPLIED, one-step-stale bank) -- the
+        # per-step training-health scalar the chunked mode stacks
+        # alongside loss and lr, replacing what an operator would
+        # otherwise probe with per-step fetches. K=1 omits it so the
+        # single-step program stays the exact program behind PERF.md's
+        # pinned envelope numbers.
+        if "health" in metrics:
+          # The health vector already carries this exact norm (same grads
+          # tree, sharded reduction): reuse it rather than paying a second,
+          # full-tree replicated square-sum pass -- the replicated pass is
+          # the ~2x-step-time cost _sharded_sumsq exists to avoid.
+          metrics["grad_norm"] = metrics["health"][0]
+        elif sharded_state:
+          # The flat shards tile the reduced gradient exactly once, so
+          # the psum of per-shard square-sums over BOTH axes is the global
+          # square-sum -- no full-tree pass, same cost argument as the
+          # health path's sharded reduction.
+          metrics["grad_norm"] = jnp.sqrt(lax.psum(
+              sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                  for g in jax.tree.leaves(grad_shards)), axis_all))
+        else:
+          metrics["grad_norm"] = lax.pmean(
+              jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                           for g in jax.tree.leaves(grads))), axis_data)
+      if params.print_training_accuracy:
+        # Under microbatching the per-microbatch scalar accuracies were
+        # averaged inside the scan (equal microbatch sizes make that the
+        # effective-batch value); monolithic computes them here.
+        acc = (accum_acc_metrics if accum_acc_metrics is not None
+               else model.accuracy_function(net_result, labels))
+        # Scalars only: detection accuracy_functions also return per-box
+        # arrays (decoded predictions), which are not replicated step
+        # metrics. Packed runs weight each replica's (already token-
+        # weighted) accuracy by its real-label count, like the losses.
+        if tok_w is not None and wm_safe is not None:
+          metrics.update({k: lax.pmean(v * tok_w, axis_data) / wm_safe
+                          for k, v in acc.items() if jnp.ndim(v) == 0})
+        else:
+          metrics.update({k: lax.pmean(v, axis_data)
+                          for k, v in acc.items() if jnp.ndim(v) == 0})
     if noise_stats is not None:
       metrics["noise_scale_g2"], metrics["noise_scale_s"] = noise_stats
 
@@ -929,10 +962,15 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # Next step's reads see this step's PRE-update weights: the value
       # that was in the staging area at read time (one-step staleness).
       new_buffers["staged_params"] = model_params
+    # Writing the updated state back belongs to the apply: XLA fuses the
+    # stacking reshape with the update and names the fusion after it.
+    with jax.named_scope("optimizer_apply"):
+      stacked_params = _expand(new_params)
+      stacked_opt_state = _expand(new_opt_state)
     new_state = TrainState(
         step=state.step + 1,
-        params=_expand(new_params),
-        opt_state=_expand(new_opt_state),
+        params=stacked_params,
+        opt_state=stacked_opt_state,
         batch_stats=_expand(new_bs),
         loss_scale=new_scale,
         loss_scale_normal_steps=normal_steps,

@@ -40,6 +40,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from kf_benchmarks_tpu import tracing
+
 
 class CompletedStep:
   """A resolved step: its 1-based index, host metrics, and wall interval.
@@ -110,7 +112,11 @@ class MetricsPipeline:
 
   def _resolve(self, index: int, metrics, count: int) -> \
       List[CompletedStep]:
-    host = jax.device_get(metrics)
+    # The one place the timed loop blocks on the device: a live span
+    # (run trace + profiler), so a reader can take it out of the host's
+    # own time per step.
+    with tracing.active().span("fetch", "metrics", step=index):
+      host = jax.device_get(metrics)
     now = time.time()
     if self._last_time is None:
       self._last_time = now

@@ -419,9 +419,12 @@ def test_warm_precompiles_and_follow_up_run_reads_cache_hit(tmp_path):
   """Acceptance: the warm pass compiles every predicted shape into the
   persistent cache under the runtime's own fingerprint keys; a
   follow-up run of the same config reads cache_hit on every shape it
-  re-compiles. Slow-tiered with the measured e2e above (full compile
-  passes + a real training run; the wall budget is the constraint,
-  not the 60 s per-test rule)."""
+  re-compiles -- from the compilation cache's own hit events
+  (tracing.RunTrace.on_event), so the warm pass must have lowered the
+  very program the runtime dispatches (contracts.lower_step_program).
+  Slow-tiered with the measured e2e above (full compile passes + a real
+  training run; the wall budget is the constraint, not the 60 s
+  per-test rule)."""
   from kf_benchmarks_tpu import benchmark
   from kf_benchmarks_tpu import tracing as tracing_lib
   td = str(tmp_path)

@@ -238,6 +238,27 @@ def test_trace_emission_allowed_in_home_and_reads_clean(tmp_path):
   assert not _rules(tmp_path, "trace-event-emission")
 
 
+def test_profiler_annotation_outside_runtrace_seeded(tmp_path):
+  # Constructed directly, or smuggled through an alias: both fork the
+  # one emission point. Handing the class to RunTrace(...) is the way.
+  _seed(tmp_path, "kf_benchmarks_tpu/rogue_annotation.py",
+        "import jax\n\n"
+        "def step(i):\n"
+        "  with jax.profiler.StepTraceAnnotation('train', step_num=i):\n"
+        "    pass\n"
+        "mark = jax.profiler.TraceAnnotation\n")
+  _seed(tmp_path, "kf_benchmarks_tpu/session.py",
+        "import jax\nfrom kf_benchmarks_tpu import tracing\n\n"
+        "trace = tracing.RunTrace(\n"
+        "    annotation=jax.profiler.TraceAnnotation,\n"
+        "    step_annotation=jax.profiler.StepTraceAnnotation)\n")
+  violations = _rules(tmp_path, "trace-event-emission")
+  assert [(v.path, v.line) for v in violations] == [
+      ("kf_benchmarks_tpu/rogue_annotation.py", 4),
+      ("kf_benchmarks_tpu/rogue_annotation.py", 6)]
+  assert "profiler annotation" in violations[0].message
+
+
 def test_trace_emission_allowlist_staleness(tmp_path, monkeypatch):
   _seed(tmp_path, "kf_benchmarks_tpu/clean.py", "x = 1\n")
   monkeypatch.setattr(lint, "TRACE_EMISSION_ALLOWLIST",

@@ -375,11 +375,17 @@ def test_device_feeder_emits_feed_spans_and_wait_samples(tmp_path):
     try:
       for _ in range(3):
         next(f)
+      with pytest.raises(StopIteration):
+        next(f)
     finally:
       f.stop()
   finally:
     tracing.deactivate()
+  # The sample keeps to delivered batches; the span counts every wait
+  # the consumer sat through, the end-of-stream drain included.
   assert tr.percentiles()["feed_wait"]["n"] == 3
+  spans = tr.span_totals()[tracing.PHASE_SETUP]["spans"]
+  assert spans["feed/wait"]["n"] == 4 and spans["feed/h2d"]["n"] == 3
   obj = json.load(open(tr.export()))
   feed = [e for e in obj["traceEvents"]
           if e["ph"] == "X" and e["cat"] == "feed"]
@@ -598,13 +604,21 @@ def test_env_placed_cache_is_never_touched_in_code(
   assert stats["compile_ledger"]["entries"]
 
 
+def _setup_counters(stats):
+  """The compile-cache counters of everything before the timed loop."""
+  blocks = [stats["span_totals"][phase]["counters"]
+            for phase in (tracing.PHASE_SETUP, tracing.PHASE_WARMUP)]
+  return {key: sum(b[key] for b in blocks) for key in tracing.COUNTER_KEYS}
+
+
 def test_compilation_cache_flag_and_ledger_cache_hit(
     tmp_path, monkeypatch, restore_compile_cache):
   """--compilation_cache_dir on a CPU run (env unset): configured
-  before the first trace; a SECOND run of the same train_dir ledgers
-  its compile episodes as cache_hit=True (the fingerprint was ledgered
-  by the first run and the persistent cache is live). Without the flag
-  a CPU run keeps the cache OFF -- nothing lands under train_dir."""
+  before the first trace; a SECOND run of the same program ledgers its
+  compile episodes as cache_hit=True, by the compilation cache's own
+  events (jax.monitoring; tracing.RunTrace.on_event): every request of
+  the episode was a hit and nothing was written. Without the flag a CPU
+  run keeps the cache OFF -- nothing lands under train_dir."""
   monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
   train_dir = str(tmp_path / "train")
   cache = str(tmp_path / "explicit_cache")
@@ -614,10 +628,23 @@ def test_compilation_cache_flag_and_ledger_cache_hit(
   assert os.listdir(cache)
   entries1 = stats1["compile_ledger"]["entries"]
   assert entries1 and all(e["cache_hit"] is False for e in entries1)
+  counters1 = _setup_counters(stats1)
+  assert counters1["cache_misses"] > 0 and counters1["cache_hits"] == 0
+  assert counters1["backend_compiles"] == counters1["cache_requests"]
+  # The same program again: keep the ledger, drop the checkpoint (a
+  # RESUMED run starts from restored arrays, which is another program
+  # -- the real signal says so where the old directory-is-warm guess
+  # called it a hit).
+  for name in os.listdir(train_dir):
+    if name != tracing.LEDGER_FILENAME:
+      os.unlink(os.path.join(train_dir, name))
   logs2, stats2 = _run_and_scrape(num_batches=2, train_dir=train_dir,
                                   compilation_cache_dir=cache)
   entries2 = stats2["compile_ledger"]["entries"]
   assert entries2 and all(e["cache_hit"] is True for e in entries2)
+  counters2 = _setup_counters(stats2)
+  assert counters2["cache_misses"] == 0
+  assert counters2["cache_hits"] == counters2["cache_requests"] > 0
   # The merged on-disk ledger keeps the LAST cache_hit (a shape's
   # first run legitimately misses; later runs read as the hit they
   # were).
@@ -630,3 +657,281 @@ def test_compilation_cache_flag_and_ledger_cache_hit(
   assert not any(l.startswith("XLA compilation cache: ") for l in logs3)
   assert not os.path.exists(os.path.join(t2, "xla_cache"))
   assert jax.config.jax_compilation_cache_dir is None
+
+
+# -- the profiler sink, the always-on totals, JAX's own compile events --------
+
+class FakeAnnotation:
+  """Stands in for jax.profiler.TraceAnnotation: records construction,
+  entry and exit in one shared list."""
+
+  def __init__(self, log, name, **kwargs):
+    self.log, self.name = log, name
+    log.append(("new", name, kwargs))
+
+  def __enter__(self):
+    self.log.append(("enter", self.name))
+    return self
+
+  def __exit__(self, *exc):
+    self.log.append(("exit", self.name))
+    return False
+
+
+def test_span_enters_the_injected_annotation_once_with_name_and_args():
+  log = []
+  factory = lambda name, **kw: FakeAnnotation(log, name, **kw)
+  tr, clock = _trace(annotation=factory, step_annotation=factory)
+  with tr.step("train", 7):
+    with tr.span("dispatch", "train_step", step=3, first_call=False) as a:
+      a["found_inside"] = 1   # results join the span, not the annotation
+      clock.tick(0.5)
+  assert log == [
+      ("new", "train", {"step_num": 7}), ("enter", "train"),
+      ("new", "kf/dispatch/train_step", {"step": 3, "first_call": False}),
+      ("enter", "kf/dispatch/train_step"),
+      ("exit", "kf/dispatch/train_step"), ("exit", "train")]
+  # Retrospective records never reach the profiler.
+  tr.add_span("device", "step", 100.0, 0.1)
+  tr.instant("faults", "kill")
+  assert len(log) == 6
+  # An exception inside the span still leaves the annotation.
+  with pytest.raises(KeyError):
+    with tr.span("handle", "step"):
+      raise KeyError("x")
+  assert log[-2:] == [("enter", "kf/handle/step"),
+                      ("exit", "kf/handle/step")]
+
+
+def test_null_sink_and_default_session_enter_no_annotation(monkeypatch):
+  calls = []
+  monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                      lambda *a, **k: calls.append(a))
+  with tracing.NULL_TRACE.span("dispatch", "train_step", step=1):
+    pass
+  with tracing.NULL_TRACE.step("train", 1):
+    pass
+  tr, _ = _trace()  # no factory injected: no profiler sink
+  with tr.span("dispatch", "train_step"):
+    pass
+  assert calls == []
+  assert tracing.NULL_TRACE.span_totals() == {}
+  assert tracing.NULL_TRACE.compiles_since(
+      tracing.NULL_TRACE.compile_mark()) == 0
+
+
+@pytest.mark.parametrize("with_path", [False, True])
+def test_span_totals_count_n_total_and_max_per_phase(tmp_path, with_path):
+  tr, clock = _trace(tmp_path if with_path else None)
+  for dur in (0.25, 1.0):
+    with tr.span("setup", "init_state"):
+      clock.tick(dur)
+  tr.begin_phase(tracing.PHASE_TIMED)
+  for dur in (0.002, 0.004, 0.003):
+    with tr.span("fetch", "metrics"):
+      clock.tick(dur)
+  tr.add_span("feed", "wait", clock(), 0.5)   # retrospective counts too
+  tr.instant("faults", "kill")                # instants do not
+  totals = tr.span_totals()
+  assert totals[tracing.PHASE_SETUP]["spans"] == {
+      "setup/init_state": {"n": 2, "total_s": 1.25, "max_s": 1.0}}
+  timed = totals[tracing.PHASE_TIMED]["spans"]
+  assert set(timed) == {"fetch/metrics", "feed/wait"}
+  assert timed["fetch/metrics"]["n"] == 3
+  assert timed["fetch/metrics"]["total_s"] == pytest.approx(0.009)
+  assert timed["fetch/metrics"]["max_s"] == pytest.approx(0.004)
+  assert timed["feed/wait"] == {"n": 1, "total_s": 0.5, "max_s": 0.5}
+  # The span list is kept only with a path; the totals either way.
+  assert len(tr.chrome_events()) > 1 if with_path else \
+      len(tr.chrome_events()) == 1
+
+
+def test_monitoring_time_span_becomes_a_compile_lane_span(tmp_path):
+  tr, clock = _trace(tmp_path)   # wall anchor 1000.0 == mono 100.0
+  jax.monitoring.register_event_time_span_listener(tr.on_time_span)
+  try:
+    jax.monitoring.record_event_time_span(
+        "/jax/core/compile/jaxpr_trace_duration", 1001.0, 1001.5,
+        fun_name="relu")
+    jax.monitoring.record_event_time_span(
+        "/jax/core/compile/jaxpr_trace_duration", 1000.5, 1003.0,
+        fun_name="per_replica_train")
+    jax.monitoring.record_event_time_span(
+        "/jax/core/compile/jaxpr_to_mlir_module_duration", 1003.0, 1004.0,
+        fun_name="jit(per_replica_train)")
+    jax.monitoring.record_event_time_span(
+        "/jax/core/compile/backend_compile_duration", 1004.0, 1009.0,
+        fun_name="jit(per_replica_train)")
+    jax.monitoring.record_event_time_span(
+        "/some/other/event", 1.0, 2.0)
+  finally:
+    jax.monitoring.unregister_event_time_span_listener(tr.on_time_span)
+  spans = [e for e in tr.chrome_events() if e["ph"] == "X"]
+  assert [(e["cat"], e["name"], e["args"]["fun_name"]) for e in spans] == [
+      ("compile", "jaxpr_trace", "relu"),
+      ("compile", "jaxpr_trace", "per_replica_train"),
+      ("compile", "jaxpr_to_mlir", "jit(per_replica_train)"),
+      ("compile", "backend_compile", "jit(per_replica_train)")]
+  # On the session's axis: wall 1001.0 is 1 s after the anchor.
+  assert spans[0]["ts"] == pytest.approx(1001.0e6)
+  assert spans[0]["dur"] == pytest.approx(0.5e6)
+  # The nested trace is an event of its own but not time of its own:
+  # totals count outermost intervals, so they add up to the wall spent.
+  setup = tr.span_totals()[tracing.PHASE_SETUP]
+  assert setup["spans"]["compile/jaxpr_trace"] == {
+      "n": 2, "total_s": 2.5, "max_s": 2.5}
+  assert setup["spans"]["compile/jaxpr_to_mlir"]["total_s"] == 1.0
+  assert setup["spans"]["compile/backend_compile"]["total_s"] == 5.0
+  assert setup["counters"]["backend_compiles"] == 1
+
+
+def test_cache_events_move_the_counters_and_the_ledgers_cache_hit():
+  tr, _ = _trace()
+  hit, miss, request = (
+      "/jax/compilation_cache/cache_hits",
+      "/jax/compilation_cache/cache_misses",
+      "/jax/compilation_cache/compile_requests_use_cache")
+  jax.monitoring.register_event_listener(tr.on_event)
+  try:
+    # Episode 1: one request, compiled and written (a miss).
+    mark = tr.compile_mark()
+    jax.monitoring.record_event(request)
+    jax.monitoring.record_event(miss)
+    tr.on_time_span("/jax/core/compile/backend_compile_duration", 1.0, 2.0,
+                    fun_name="jit(step)")
+    assert tr.compiles_since(mark) == 1
+    tr.note_compile("k1", "train_step", 1.0, since=mark)
+    # Episode 2: two requests, both answered from the cache.
+    tr.begin_phase(tracing.PHASE_TIMED)
+    mark = tr.compile_mark()
+    assert tr.compiles_since(mark) == 0
+    for _ in range(2):
+      jax.monitoring.record_event(request)
+      jax.monitoring.record_event(hit)
+    tr.note_compile("k2", "eval_step", 0.5, since=mark)
+    # Episode 3: one of two requests missed -- not a hit. And without a
+    # mark the row says nothing about the cache.
+    mark = tr.compile_mark()
+    jax.monitoring.record_event(request)
+    jax.monitoring.record_event(hit)
+    jax.monitoring.record_event(request)
+    tr.note_compile("k3", "train_chunk", 0.5, since=mark)
+    tr.note_compile("k4", "warm", 0.5)
+  finally:
+    jax.monitoring.unregister_event_listener(tr.on_event)
+  entries = tr.compile_ledger()["entries"]
+  assert [e.get("cache_hit") for e in entries] == [False, True, False, None]
+  totals = tr.span_totals()
+  assert totals[tracing.PHASE_SETUP]["counters"] == {
+      "cache_hits": 0, "cache_misses": 1, "cache_requests": 1,
+      "backend_compiles": 1}
+  assert totals[tracing.PHASE_TIMED]["counters"] == {
+      "cache_hits": 3, "cache_misses": 0, "cache_requests": 4,
+      "backend_compiles": 0}
+
+
+def test_run_stats_carry_span_totals_for_every_phase():
+  """The main path's own boundaries, in any run (no span file): set-up
+  pieces, JAX's trace / lower / compile in warm-up, and per timed
+  iteration one train step with its dispatch, fetch and handling."""
+  _, stats = _run_and_scrape(num_batches=6, num_warmup_batches=2,
+                             display_every=1)
+  totals = stats["span_totals"]
+  setup = totals[tracing.PHASE_SETUP]["spans"]
+  assert {"setup/build_model", "setup/make_step_fns", "setup/open_input",
+          "setup/first_batch", "setup/init_state",
+          "setup/broadcast_init"} <= set(setup)
+  warm = totals[tracing.PHASE_WARMUP]["spans"]
+  assert warm["dispatch/train_step"]["n"] == 2
+  assert warm["compile/backend_compile"]["n"] >= 1
+  # Outermost intervals only: trace + lower + compile fit inside the
+  # first dispatch, whose wall is compile_s.
+  compile_s = sum(warm[k]["total_s"] for k in (
+      "compile/jaxpr_trace", "compile/jaxpr_to_mlir",
+      "compile/backend_compile"))
+  assert 0 < compile_s <= stats["compile_s"]
+  timed = totals[tracing.PHASE_TIMED]
+  for name in ("run/train", "dispatch/train_step", "fetch/metrics",
+               "handle/step"):
+    assert timed["spans"][name]["n"] == 6, name
+  assert timed["counters"]["backend_compiles"] == 0
+  assert timed["spans"]["run/train"]["total_s"] >= sum(
+      timed["spans"][k]["total_s"]
+      for k in ("dispatch/train_step", "handle/step"))
+  # The scopes the step program names ride beside them, for the trace
+  # reader to hold the device operations' op_names to.
+  from kf_benchmarks_tpu import train_step
+  assert stats["step_scopes"] == list(train_step.STEP_SCOPES)
+  # The registry flattening leaves both out.
+  from kf_benchmarks_tpu import metrics as metrics_lib
+  assert not any("span_totals" in k or "step_scopes" in k
+                 for k in metrics_lib.flatten_stats(stats))
+
+
+def test_program_spans_land_in_the_profilers_host_plane(tmp_path):
+  """ONE clock: with a jax.profiler capture open around a tiny run, the
+  program's live spans are events of plane /host:CPU, in order within
+  each train step -- dispatch, then the blocking fetch, then the
+  handling of the resolved step -- and carry their arguments."""
+  from jax.profiler import ProfileData
+  trace_dir = str(tmp_path / "prof")
+  jax.profiler.start_trace(trace_dir)
+  try:
+    _run_and_scrape(num_batches=6, num_warmup_batches=1, display_every=1)
+  finally:
+    jax.profiler.stop_trace()
+  (path,) = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+  host = [p for p in ProfileData.from_file(path).planes
+          if p.name == "/host:CPU"]
+  assert len(host) == 1
+  events = sorted(
+      ((e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+       for line in host[0].lines for e in line.events
+       if e.name == "train" or e.name.startswith("kf/")),
+      key=lambda e: (e[0], -e[1]))
+  names = [e[2] for e in events]
+  assert {"kf/setup/init_state", "kf/setup/make_step_fns",
+          "kf/dispatch/train_step", "kf/fetch/metrics",
+          "kf/handle/step", "train"} <= set(names)
+  steps = [e for e in events if e[2] == "train"]
+  assert [e[3]["step_num"] for e in steps] == [1, 2, 3, 4, 5, 6]
+  for start, end, _, _ in steps[2:]:   # the lag-2 ring is full from here
+    inside = [e[2] for e in events
+              if e[2] != "train" and start <= e[0] and e[1] <= end]
+    assert inside == ["kf/dispatch/train_step", "kf/fetch/metrics",
+                      "kf/handle/step"], inside
+  dispatches = [e for e in events if e[2] == "kf/dispatch/train_step"]
+  assert [e[3]["step"] for e in dispatches[-6:]] == [0, 1, 2, 3, 4, 5]
+  assert dispatches[0][3]["first_call"] == 1
+
+
+@pytest.mark.parametrize("overrides", [
+    dict(variable_update="replicated"),
+    dict(variable_update="kungfu", kungfu_option="sync_sgd"),
+    dict(variable_update="replicated", shard_optimizer_state=True),
+], ids=["replicated", "kungfu_sync_sgd", "sharded_state"])
+def test_step_program_names_its_phases(overrides):
+  """The lowered step's op_names carry the four scopes the benchmark's
+  trace reader keys on: one ``forward`` scope separates the passes
+  (jvp going forward, its transpose coming back)."""
+  from kf_benchmarks_tpu.analysis import contracts
+  p = params_lib.make_params(model="trivial", device="cpu", num_devices=8,
+                             num_batches=2, **overrides)
+  _, lowered = contracts.lower_step_program(benchmark.BenchmarkCNN(p))
+  op_names = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(
+      debug_info=True)))
+  from kf_benchmarks_tpu import train_step
+  for scope in ("jvp(forward)/", "transpose(jvp(forward))/") + tuple(
+      s + "/" for s in train_step.STEP_SCOPES if s != "forward"):
+    assert any(scope in name for name in op_names), scope
+  # ... and they are all it names: every scope in the source is declared
+  # (the reader raises on a scope the program does not declare).
+  source = open(train_step.__file__, encoding="utf-8").read()
+  assert set(re.findall(r'named_scope\("([a-z_]+)"\)', source)) == set(
+      train_step.STEP_SCOPES)
+  # The model lives under the scope in both directions.
+  assert any(n.startswith("jvp(forward)/") and "affine" in n
+             for n in op_names)
+  assert any(n.startswith("transpose(jvp(forward))/") and "affine" in n
+             for n in op_names)
