@@ -242,6 +242,58 @@ def rule_wire_dtype(contract, tracer):
   return []
 
 
+def _plain_mean(contract) -> bool:
+  """The step reduces the whole per-replica gradient tree once, by the
+  plain replica mean (strategies.py ``plain_mean``; the predicate of
+  train_step.make_step_fns, read from the config)."""
+  sync_sgd = (_cfg(contract, "variable_update") == "kungfu"
+              and _cfg(contract, "kungfu_option", "sync_sgd") == "sync_sgd")
+  reducer = any(_cfg(contract, flag) for flag in (
+      "all_reduce_spec", "gradient_repacking", "agg_small_grads_max_bytes",
+      "hierarchical_copy"))
+  return ((sync_sgd or (_replicated_sync(contract) and not reducer))
+          and not _sharded(contract) and not _overlap(contract)
+          and _accum(contract) == 1
+          and not _cfg(contract, "track_grad_noise_scale", False)
+          and int(contract.aux.get("num_devices") or 0) > 1)
+
+
+def rule_gradient_reduced_once(contract, tracer):
+  """ISSUE 25: under the plain replica mean every gradient leaf is
+  reduced EXACTLY once, one of two ways: in the all-reduce of the
+  step's ``exchange`` scope, or -- a dense kernel larger than its batch
+  -- on the factor data plane, formed in the backward pass from two
+  all-gathered factors and left out of the all-reduce
+  (parallel/kungfu.py). So the all-reduced elements and the factor
+  plane's add up to the tree (with the batch statistics, which sync
+  under the same scope), and each factor layer shows its two gathers.
+  A claimed leaf that is all-reduced as well, or one that is neither,
+  breaks the sum."""
+  if (contract.program != "train_step" or not _plain_mean(contract)
+      or "gradient_elems" not in contract.aux):
+    return []
+  aux = contract.aux
+  exchange = [c for c in contract.collectives if c.in_exchange]
+  reduced = sum(c.elems for c in exchange if c.kind == "all-reduce")
+  want = (aux["gradient_elems"] - aux["factor_elems"]
+          + aux["batch_stats_elems"])
+  out = []
+  if reduced != want:
+    out.append(
+        f"{reduced} elements all-reduced under the exchange scope, but "
+        f"the gradient tree has {aux['gradient_elems']}, the factor "
+        f"plane took {aux['factor_elems']} and the batch statistics "
+        f"are {aux['batch_stats_elems']}: want {want} -- a leaf is "
+        "reduced twice or not at all")
+  gathers = sum(c.kind == "all-gather" for c in exchange)
+  if gathers != 2 * aux["factor_layers"]:
+    out.append(
+        f"{gathers} all-gather(s) under the exchange scope for "
+        f"{aux['factor_layers']} dense layer(s) on the factor plane: "
+        "each gathers its two factors, nothing else gathers here")
+  return out
+
+
 def _sharded(contract) -> bool:
   return bool(_cfg(contract, "shard_optimizer_state", False))
 
@@ -937,6 +989,7 @@ RULES: Dict[str, Callable] = {
     "no-btv-buffer": rule_no_btv_buffer,
     "health-no-extra-collective": rule_health_no_extra_collective,
     "wire-dtype": rule_wire_dtype,
+    "gradient-reduced-once": rule_gradient_reduced_once,
     "partitioner-twin": rule_partitioner_twin,
     "sharded-collectives": rule_sharded_collectives,
     "sharded-opt-bytes": rule_sharded_opt_bytes,

@@ -161,6 +161,10 @@ class Collective:
   # schedule the SPMD divergence pass compares; -1 for hand-built
   # Collectives that never went through extract_contract).
   index: int = -1
+  # Issued under the step's ``exchange`` scope (op_name metadata): the
+  # gradient exchange and the batch-statistics sync, apart from the
+  # metric reductions (audit.rule_gradient_reduced_once).
+  in_exchange: bool = False
 
   def is_gradient_traffic(self) -> bool:
     return (self.kind == "all-reduce" and not self.scalar
@@ -238,7 +242,8 @@ def extract_contract(hlo: str, config: Optional[dict] = None,
           kind=m.group("kind"), dtype=dtype, elems=elems,
           scalar=not dims, in_loop="while" in ln,
           replica_groups=groups.group(1).replace(" ", "") if groups
-          else "", index=len(collectives)))
+          else "", index=len(collectives),
+          in_exchange="/exchange/" in ln.partition("op_name=")[2]))
     # Only the instruction text counts (op_name metadata may quote a
     # jax scope containing e.g. 'send' without the op being one).
     head = ln.split("metadata")[0]
@@ -316,13 +321,17 @@ def trace_contract(overrides: Dict[str, Any],
   import jax.numpy as jnp
   from kf_benchmarks_tpu import benchmark
   from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu import tracing
   from kf_benchmarks_tpu.ops import overlap as overlap_lib
 
   kw = dict(device="cpu", num_devices=N_REPLICAS, num_batches=2)
   kw.update(overrides)
   p = params_lib.make_params(**kw)
   bench = benchmark.BenchmarkCNN(p)
-  state_sds, lowered = lower_step_program(bench, program)
+  # The step states its factor-exchange counter to the active run trace
+  # while it is traced; a session of this trace's own catches it.
+  with tracing.session() as session:
+    state_sds, lowered = lower_step_program(bench, program)
   in_shapes = bench.model.get_input_shapes("train")
   in_dtypes = bench.model.get_input_data_types("train")
   n = bench.num_devices
@@ -342,6 +351,18 @@ def trace_contract(overrides: Dict[str, Any],
               lowered.as_text())
           if elems >= GRAD_MIN_ELEMS}),
   }
+  # audit.rule_gradient_reduced_once: the elements of one replica's
+  # gradient tree and batch statistics (the leading dim of the abstract
+  # state is the replica stack), and the dense kernels that took the
+  # factor data plane of the mean gradient (parallel/kungfu.py).
+  per_replica_elems = lambda tree: sum(
+      int(math.prod(l.shape[1:])) for l in jax.tree_util.tree_leaves(tree))
+  factor = session.static("factor_exchange") or {}
+  aux["gradient_elems"] = per_replica_elems(state_sds.params)
+  aux["batch_stats_elems"] = per_replica_elems(state_sds.batch_stats)
+  aux["factor_layers"] = int(factor.get("layers", 0))
+  aux["factor_elems"] = (int(factor.get("bytes_off_allreduce", 0))
+                         // jnp.dtype(bench.param_dtype).itemsize)
   # --shard_optimizer_state contract inputs (audit.rule_sharded_*): the
   # requested reduce-scatter/all-gather wire dtypes, and the per-device
   # optimizer-state bytes read from the ABSTRACT state -- exactly what

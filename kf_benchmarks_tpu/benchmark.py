@@ -2027,6 +2027,12 @@ class BenchmarkCNN:
         # device operations' op_names to them (a warm compile cache can
         # hand over another version's metadata; benchmarks/spans.py).
         "step_scopes": list(train_step_lib.STEP_SCOPES),
+        # The factor data plane of the mean gradient (parallel/kungfu.py
+        # FactorExchange), counted from shapes when the step was traced:
+        # dense layers that took it, gradient bytes they keep off the
+        # all-reduce, bytes gathered instead. All 0 where nothing engages.
+        "factor_exchange": (self._trace.static("factor_exchange")
+                            or dict(kungfu.NO_FACTOR_EXCHANGE)),
         # Tuned-config provenance (--autotuned_config,
         # analysis/autotune.py): table path + the matched entry's base
         # fingerprint (entry None when the table had no row for this

@@ -431,6 +431,11 @@ NON_METRIC_KEYS = frozenset({
     # PR 23: the scopes the step program names (train_step.STEP_SCOPES),
     # config provenance for the benchmark's trace reader.
     "step_scopes",
+    # PR 25: the factor data plane's static counter ({layers,
+    # bytes_off_allreduce, bytes_gathered}; parallel/kungfu.py
+    # FactorExchange) -- computed from shapes when the step is traced:
+    # a description of the program that ran, not a measurement.
+    "factor_exchange",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

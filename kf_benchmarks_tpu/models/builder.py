@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from kf_benchmarks_tpu.parallel import kungfu
+
 
 def _activate(x, activation: Optional[str]):
   if activation in (None, "linear"):
@@ -113,6 +115,33 @@ class BatchNorm(CompactBatchNorm):
   so call sites that relied on nn.BatchNorm's auto-generated
   ``BatchNorm_N`` scope names (mobilenet/nasnet/deepspeech) use this
   subclass and keep their parameter tree layout."""
+
+
+class FactorDense(nn.Module):
+  """nn.Dense's parameters (``kernel``, ``bias``: same names, shapes and
+  dtypes) and forward pass, with the kernel's gradient formed on the
+  factor data plane of the mean gradient (parallel/kungfu.py
+  ``factor_mean_dot``): it leaves the backward pass as the replica mean
+  over ``axis_name`` already. The bias gradient is autodiff's, local.
+  ``affine`` applies it in place of nn.Dense where the step's
+  ``kungfu.FactorExchange`` admits the layer's shape; initialisation
+  never sees it."""
+  features: int
+  axis_name: Any
+  kernel_init: Any
+  bias_init: Any
+  dtype: Any = jnp.float32
+  param_dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x):
+    kernel = self.param("kernel", self.kernel_init,
+                        (x.shape[-1], self.features), self.param_dtype)
+    bias = self.param("bias", self.bias_init, (self.features,),
+                      self.param_dtype)
+    y = kungfu.factor_mean_dot(x.astype(self.dtype), kernel, self.axis_name,
+                               self.dtype)
+    return y + bias.astype(self.dtype)
 
 
 class ConvNetBuilder:
@@ -292,12 +321,22 @@ class ConvNetBuilder:
       init_factor = 2.0 if activation == "relu" else 1.0
       stddev = float(init_factor / int(x.shape[-1])) ** 0.5
     kernel_init = nn.initializers.truncated_normal(stddev=stddev)
-    x = nn.Dense(features=num_out_channels,
-                 kernel_init=kernel_init,
-                 bias_init=nn.initializers.constant(bias),
-                 dtype=self.dtype,
-                 param_dtype=self.param_dtype,
-                 name=name)(x)
+    layer = dict(features=num_out_channels, kernel_init=kernel_init,
+                 bias_init=nn.initializers.constant(bias), dtype=self.dtype,
+                 param_dtype=self.param_dtype, name=name)
+    # Two data planes for the kernel's mean gradient (parallel/kungfu.py):
+    # where the step has opened a FactorExchange and this layer's shape
+    # passes its rule, the backward exchanges the factors x and dy
+    # instead of their product, and the step's all-reduce skips the leaf.
+    shape = (x.shape[0], x.shape[-1], num_out_channels, self.dtype,
+             self.param_dtype)
+    plan = kungfu.active_factor_exchange()
+    if plan is not None and plan.admits(*shape):
+      dense = FactorDense(axis_name=plan.axis_name, **layer)
+      plan.claim(dense.path + ("kernel",), *shape)
+    else:
+      dense = nn.Dense(**layer)
+    x = dense(x)
     x = _activate(x, activation)
     self.top_layer = x
     self.top_size = num_out_channels

@@ -5,7 +5,11 @@ call sites: benchmark_cnn.py:1192-1204 optimizer wrap, :1408-1410 cluster
 size, :2044-2048/:2629-2631 rank, :2097-2100 broadcast-at-init,
 tf_cnn_benchmarks.py:58-60 exit barrier) on JAX collectives:
 
-  allreduce            -> lax.pmean over the 'replica' mesh axis (ICI)
+  allreduce            -> the replica MEAN of the gradients, on one of
+                          two data planes: lax.pmean of the product
+                          (allreduce_mean), or for a dense kernel larger
+                          than its batch the product of the all-gathered
+                          factors (factor_mean_dot; see there)
   pair-averaging gossip-> lax.ppermute of the weights (deterministic
                           synchronous schedule; see PairAveraging below)
   broadcast            -> replica-0 masked psum
@@ -19,6 +23,9 @@ runtime plus the native coordination service in native/ (control plane).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
 import os
 from typing import Optional
 
@@ -80,8 +87,169 @@ def run_barrier() -> None:
 # -- in-SPMD collective ops (used inside shard_map bodies) ------------------
 
 def allreduce_mean(tree, axis_name: str = REPLICA_AXIS):
-  """Gradient averaging: the S-SGD data plane (KungFu allreduce -> psum)."""
+  """Gradient averaging: the S-SGD data plane (KungFu allreduce -> psum).
+
+  Sync SGD's contract is that every replica applies the MEAN of all
+  replicas' gradients every step. This is its first data plane: the
+  per-replica products meet in an all-reduce. The second one
+  (:func:`factor_mean_dot`) never forms the per-replica product."""
   return jax.tree.map(lambda x: lax.pmean(x, axis_name), tree)
+
+
+# -- the mean gradient's second data plane: exchange the factors -------------
+#
+# A dense kernel's gradient is a product of two small factors,
+# dW = x^T dy, and the replica mean of the per-replica products IS the
+# product of the concatenated factors over n:
+#   (1/n) sum_c x_c^T dy_c = (1/n) X^T DY,  X = concat_c x_c.
+# Where the kernel is larger than the batch, all-gathering x and dy and
+# forming the global product on every chip moves a fraction of the bytes
+# the all-reduce of the products would (Krizhevsky, "One weird trick for
+# parallelizing convolutional neural networks", arXiv:1404.5997, in its
+# lightest form: the weights stay replicated, only the weight-gradient
+# matmul sees the global batch). Same mean, summed in another order.
+
+# The shape rule's two numbers, written down once.
+#
+# Wire: engage only when the gathered factors, n*B*(K+N) elements of the
+# compute dtype, are at most a quarter of the product's K*N elements of
+# the parameter dtype. Per chip an all-reduce moves 2(n-1)/n of its
+# bytes and an all-gather (n-1)/n: at least 8x less on the wire.
+FACTOR_WIRE_RATIO = 4
+# Compute: every chip repeats the other chips' share of the matmul,
+# (n-1)*2*B*K*N extra FLOPs, to save the all-reduce of K*N*4 bytes.
+# Measured on four TPU v5e chips (PERF.md section 6, PR 25): the
+# f32[25088,4096] all-reduce runs at 57 MB/ms and a bf16 matmul achieves
+# about 100 TFLOP/s, so extra compute / wire time saved =
+# (n-1)*B * 2/1e14 * 5.7e10/4 = (n-1)*B * 2.85e-4: 5.5% at 4 x 64,
+# break-even at 3,500 rows from the other chips. The bound is where the
+# repeated compute costs at most about half of what the wire saves.
+FACTOR_MAX_GLOBAL_BATCH = 2048
+
+
+def factor_bytes(n: int, batch: int, k: int, n_out: int, compute_dtype,
+                 param_dtype):
+  """``(product, gathered)``: the bytes of a dense kernel's gradient, which
+  the all-reduce would carry, and of its two factors gathered from ``n``
+  replicas, which the factor plane carries instead."""
+  return (k * n_out * jnp.dtype(param_dtype).itemsize,
+          n * batch * (k + n_out) * jnp.dtype(compute_dtype).itemsize)
+
+
+def factors_beat_product(n: int, batch: int, k: int, n_out: int,
+                         compute_dtype, param_dtype) -> bool:
+  """The shape rule: does a dense layer with kernel ``[k, n_out]`` and
+  per-replica batch ``batch`` on ``n`` replicas exchange its factors
+  (True) or its product (False)? Everything it asks is visible at trace
+  time; there is no flag."""
+  if n <= 1 or n * batch > FACTOR_MAX_GLOBAL_BATCH:
+    return False
+  product, gathered = factor_bytes(n, batch, k, n_out, compute_dtype,
+                                   param_dtype)
+  return FACTOR_WIRE_RATIO * gathered <= product
+
+
+class FactorExchange:
+  """One trace of the train step under the factor data plane: the axis
+  the factors are gathered over, and the kernels that took it.
+
+  The step opens one around the model's forward pass
+  (:func:`factor_exchange`) where it reduces gradients by the plain
+  replica mean; ``models/builder.py::affine`` asks it layer by layer
+  (:meth:`admits`) and claims the kernel (:meth:`claim`), whose
+  gradient then leaves the backward pass as the replica mean already;
+  the step takes the claimed leaves out of its all-reduce. Every
+  gradient leaf is reduced exactly once."""
+
+  def __init__(self, axis_name, axis_size: int):
+    self.axis_name = axis_name
+    self.axis_size = int(axis_size)
+    # parameter path (tuple of names) -> (bytes kept off the all-reduce,
+    # bytes gathered instead)
+    self.claimed = {}
+
+  def admits(self, *layer) -> bool:
+    """``layer`` = (batch, k, n_out, compute_dtype, param_dtype)."""
+    return factors_beat_product(self.axis_size, *layer)
+
+  def claim(self, path, *layer) -> None:
+    self.claimed[tuple(path)] = factor_bytes(self.axis_size, *layer)
+
+  def counters(self) -> dict:
+    """The static counter of one step: dense layers on the factor
+    plane, the gradient bytes they keep off the all-reduce, the bytes
+    gathered instead (per chip, as the gathers' results)."""
+    return {
+        "layers": len(self.claimed),
+        "bytes_off_allreduce": sum(p for p, _ in self.claimed.values()),
+        "bytes_gathered": sum(g for _, g in self.claimed.values()),
+    }
+
+
+# What stats["factor_exchange"] reads in a run where nothing engages.
+NO_FACTOR_EXCHANGE = {"layers": 0, "bytes_off_allreduce": 0,
+                      "bytes_gathered": 0}
+
+_FACTOR_EXCHANGE = contextvars.ContextVar("kf_factor_exchange",
+                                          default=None)
+
+
+@contextlib.contextmanager
+def factor_exchange(plan: Optional[FactorExchange]):
+  """Dense layers traced inside may take the factor plane of ``plan``
+  (None: none may, the context is a no-op)."""
+  token = _FACTOR_EXCHANGE.set(plan)
+  try:
+    yield plan
+  finally:
+    _FACTOR_EXCHANGE.reset(token)
+
+
+def active_factor_exchange() -> Optional[FactorExchange]:
+  return _FACTOR_EXCHANGE.get()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def factor_mean_dot(x, kernel, axis_name, compute_dtype):
+  """``x @ kernel`` in ``compute_dtype`` whose KERNEL cotangent is the
+  replica mean over ``axis_name`` already: the backward all-gathers
+  ``x`` and the output cotangent and forms ``X^T DY / n`` on every
+  replica, bit-identical across them. The input cotangent is the local
+  ``dy @ kernel^T`` that autodiff gives.
+
+  Dtype flow: the product accumulates into, and is divided by n in, the
+  wider of the compute and the parameter dtype -- never narrower than
+  autodiff's (the dot's result in the compute dtype, then cast)."""
+  return lax.dot_general(x, kernel.astype(compute_dtype),
+                         (((x.ndim - 1,), (0,)), ((), ())))
+
+
+def _factor_mean_dot_fwd(x, kernel, axis_name, compute_dtype):
+  return factor_mean_dot(x, kernel, axis_name, compute_dtype), (x, kernel)
+
+
+def _factor_mean_dot_bwd(axis_name, compute_dtype, residuals, dy):
+  x, kernel = residuals
+  dx = lax.dot_general(dy, kernel.astype(compute_dtype),
+                       (((1,), (1,)), ((), ())))
+  with jax.named_scope("exchange"):
+    x_all = lax.all_gather(x, axis_name, axis=0, tiled=True)
+    dy_all = lax.all_gather(dy, axis_name, axis=0, tiled=True)
+    # The gathers end where this layer's backward ends: the input
+    # cotangent does not leave before they are done. Left free, the
+    # TPU scheduler starts them here and threads them through every
+    # later backward fusion up to the update that consumes them, which
+    # costs the step program 206 MB of device memory (compiled for
+    # v5e:2x2, PERF.md section 6, PR 25).
+    dx, x_all, dy_all = lax.optimization_barrier((dx, x_all, dy_all))
+  wide = jnp.promote_types(compute_dtype, kernel.dtype)
+  dw = lax.dot_general(x_all, dy_all, (((0,), (0,)), ((), ())),
+                       preferred_element_type=wide)
+  dw = dw / lax.axis_size(axis_name)
+  return dx, dw.astype(kernel.dtype)
+
+
+factor_mean_dot.defvjp(_factor_mean_dot_fwd, _factor_mean_dot_bwd)
 
 
 def broadcast(tree, root: int = 0, axis_name: str = REPLICA_AXIS):

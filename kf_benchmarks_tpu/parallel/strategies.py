@@ -6,6 +6,7 @@ at init. Under SPMD all replicas run one program, so each strategy
 becomes a set of pure hooks called inside the shard_mapped train step:
 
   reduce_gradients  -- gradient aggregation (psum / spec-driven / none)
+  plain_mean        -- whether that aggregation is the plain replica mean
   pre_update        -- weight transform before the optimizer step (SMA)
   post_update       -- weight transform after the step (pair-averaging)
   sync_batch_stats  -- BN running-stat treatment across replicas
@@ -24,6 +25,16 @@ Mapping from --variable_update (ref selection: benchmark_cnn.py:1481-1524):
   horovod                -> per-gradient pmean (ref: benchmark_cnn.py:3122-3130)
   kungfu                 -> optimizer-level hooks per --kungfu_option
                             (ref: benchmark_cnn.py:1192-1204)
+
+The synchronous contract ("pmean grads" above, KungFu sync_sgd) is that
+every replica applies the MEAN of all replicas' gradients every step.
+Where a strategy keeps it by the plain mean (``plain_mean``: no reducer
+built), that mean has two data planes (parallel/kungfu.py): the
+all-reduce of the per-replica products (``allreduce_mean``), and for a
+dense kernel larger than its batch the product of the all-gathered
+factors (``factor_mean_dot``), formed in the backward pass. The train
+step decides once which leaves go which way (train_step.make_step_fns)
+and hands ``reduce_gradients`` the rest.
 """
 
 from __future__ import annotations
@@ -47,6 +58,13 @@ class Strategy:
 
   def __init__(self, params=None):
     self.params = params
+
+  @property
+  def plain_mean(self) -> bool:
+    """True where ``reduce_gradients`` IS ``kungfu.allreduce_mean``, leaf
+    by leaf: the one case in which a leaf that is the replica mean
+    already (the factor data plane) may be left out of it."""
+    return False
 
   def reduce_gradients(self, grads, axis_name=REPLICA_AXIS):
     return grads
@@ -91,6 +109,10 @@ class ReplicatedStrategy(Strategy):
     super().__init__(params)
     self.reducer = reducer
 
+  @property
+  def plain_mean(self) -> bool:
+    return self.reducer is None
+
   def reduce_gradients(self, grads, axis_name=REPLICA_AXIS):
     if self.reducer is not None:
       return self.reducer(grads, axis_name)
@@ -125,6 +147,7 @@ class ShardedOptimizerStrategy(ReplicatedStrategy):
   name = "parameter_server(sharded)"
   cross_replica = True
   sharded_state = True
+  plain_mean = False  # a reduce-scatter onto the state shards
 
   def reduce_gradients(self, grads, axis_name=REPLICA_AXIS):
     raise NotImplementedError(
@@ -165,6 +188,7 @@ class AsyncParameterServerStrategy(ReplicatedStrategy):
   # Unaveraged gradients: the effective step scale follows the
   # per-worker batch, as the reference's async mode behaves.
   cross_replica = False
+  plain_mean = False  # the sum, or every replica's own in turn
 
   def __init__(self, params=None, reducer=None):
     super().__init__(params, reducer=reducer)
@@ -201,7 +225,10 @@ class KungFuStrategy(Strategy):
   """KungFu optimizer-wrapper semantics (ref: benchmark_cnn.py:1192-1204;
   SURVEY 2.9), dispatched on --kungfu_option:
 
-    sync_sgd  -- SynchronousSGDOptimizer: pmean gradients before apply
+    sync_sgd  -- SynchronousSGDOptimizer: every replica applies the
+                 mean gradient (two data planes: the all-reduce of the
+                 products, or the product of all-gathered factors for a
+                 dense kernel larger than its batch; parallel/kungfu.py)
     async_sgd -- PairAveragingOptimizer: local grads + pairwise weight
                  gossip (ppermute), reformulated synchronous (SURVEY 7.4)
     sma       -- SynchronousAveragingOptimizer: average weights, then
@@ -225,6 +252,10 @@ class KungFuStrategy(Strategy):
       raise ValueError(f"Invalid kungfu_option {option!r}")
     self.option = option
     self.cross_replica = option == "sync_sgd"
+
+  @property
+  def plain_mean(self) -> bool:
+    return self.option == "sync_sgd"
 
   def reduce_gradients(self, grads, axis_name=REPLICA_AXIS):
     if self.option == "sync_sgd":

@@ -290,6 +290,8 @@ class RunTrace:
         PHASE_SETUP: dict.fromkeys(COUNTER_KEYS, 0)}
     # Outermost compile intervals still standing: (t0, t1, totals row).
     self._compile_cover: List[Any] = []
+    # What the program states of itself at trace time (set_static).
+    self._statics: Dict[str, Any] = {}
 
   # -- clock ------------------------------------------------------------------
 
@@ -449,6 +451,21 @@ class RunTrace:
   def _count(self, key: str, by) -> None:
     self._counters[key] += by
     self._phase_counters[self._phase][key] += by
+
+  # -- static counters --------------------------------------------------------
+
+  def set_static(self, key: str, value: Dict[str, Any]) -> None:
+    """A counter the program computes from shapes while it is traced
+    (``factor_exchange``: layers, bytes kept off the all-reduce, bytes
+    gathered). SET, not added to: a step traced twice states the same
+    thing twice."""
+    with self._lock:
+      self._statics[key] = dict(value)
+
+  def static(self, key: str) -> Optional[Dict[str, Any]]:
+    with self._lock:
+      value = self._statics.get(key)
+      return dict(value) if value is not None else None
 
   def compile_mark(self):
     """The counters now; hand it to ``note_compile(since=...)`` after a
@@ -863,6 +880,12 @@ class _NullTrace:
   def begin_phase(self, phase: str) -> None:
     pass
 
+  def set_static(self, key: str, value) -> None:
+    pass
+
+  def static(self, key: str):
+    return None
+
   def span_totals(self) -> Dict[str, Any]:
     return {}
 
@@ -918,3 +941,17 @@ def deactivate() -> None:
 def active():
   """The process's active RunTrace, or the no-op sink."""
   return _active if _active is not None else NULL_TRACE
+
+
+@contextlib.contextmanager
+def session(trace: Optional[RunTrace] = None):
+  """``trace`` (a fresh RunTrace by default) is the active one inside the
+  block; what was active before is put back after it. For code that
+  traces a step outside a run and wants what the step states of itself
+  (``set_static``): the contract auditor, tests."""
+  global _active
+  outer, _active = _active, trace if trace is not None else RunTrace()
+  try:
+    yield _active
+  finally:
+    _active = outer

@@ -42,8 +42,10 @@ import optax
 
 from kf_benchmarks_tpu import elastic as elastic_lib
 from kf_benchmarks_tpu import telemetry as telemetry_lib
+from kf_benchmarks_tpu import tracing
 from kf_benchmarks_tpu.ops import overlap as overlap_lib
 from kf_benchmarks_tpu.ops import sharded as sharded_lib
+from kf_benchmarks_tpu.parallel import kungfu
 from kf_benchmarks_tpu.parallel import mesh as mesh_lib
 from kf_benchmarks_tpu.parallel.mesh import (BATCH_AXIS, MODEL_AXIS,
                                              REPLICA_AXIS)
@@ -158,6 +160,25 @@ def _sync_schedule_counts(src_state, dst_state, bump: int = 0):
   flat, treedef = jax.tree_util.tree_flatten_with_path(dst_state)
   return jax.tree_util.tree_unflatten(
       treedef, [fix(p, l) for p, l in flat])
+
+
+def _reduce_unclaimed(grads, claimed, reduce):
+  """``reduce`` over the leaves of ``grads`` whose path (a tuple of key
+  names) is not in ``claimed``; those pass through as they are: the
+  factor data plane made them the replica mean in the backward pass
+  (parallel/kungfu.py FactorExchange)."""
+  flat, treedef = jax.tree_util.tree_flatten_with_path(grads)
+  done = [tuple(k.key for k in path) in claimed for path, _ in flat]
+  if sum(done) != len(claimed):
+    raise ValueError(
+        f"factor exchange: claimed kernels {sorted(claimed)} are not all "
+        "leaves of the gradient tree; a leaf reduced in the backward "
+        "pass AND here would still be the mean, but the step would not "
+        "be the program its counter describes")
+  rest = iter(jax.tree.leaves(reduce(treedef.unflatten(
+      [None if d else g for d, (_, g) in zip(done, flat)]))))
+  return treedef.unflatten(
+      [g if d else next(rest) for d, (_, g) in zip(done, flat)])
 
 
 def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
@@ -316,6 +337,27 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         f"{num_grad_accum} keeps reduction post-hoc on the accumulated "
         "tree (one collective per step is the pinned invariant); "
         "in-backward hooks disengaged")
+  # The factor data plane of the mean gradient (parallel/kungfu.py):
+  # dense kernels larger than their batch leave the backward pass as the
+  # replica mean already and the exchange below skips them. THE one
+  # predicate for it: the step reduces by the plain replica mean over
+  # more than one data replica, once, on the whole per-replica tree.
+  # Every other mode keeps its program: local gradients (independent,
+  # async_sgd, sma, the async parameter server), a built reducer,
+  # in-backward hooks, ZeRO / FSDP's reduce-scatter, accumulation (one
+  # reduction of the ACCUMULATED tree is a pinned invariant), the noise
+  # scale (it reads the per-replica gradients), a model axis. Which
+  # LAYERS take it is the shape rule's, in the layer
+  # (kungfu.factors_beat_product).
+  factor_exchange = (
+      bool(getattr(strategy, "plain_mean", False))
+      and int(mesh.shape[axis_data]) > 1
+      and not (two_d and int(mesh.shape[MODEL_AXIS]) > 1)
+      and overlap_spec is None
+      and num_grad_accum == 1
+      and not params.track_grad_noise_scale)
+  tracing.active().set_static("factor_exchange",
+                              kungfu.NO_FACTOR_EXCHANGE)
   # --health_stats: in-step device health stats (telemetry.py). The
   # step builder takes the CONCRETE boolean benchmark.py resolved
   # (None/auto never reaches here from the runtime); direct callers
@@ -455,6 +497,10 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     if module_takes_progress and total_train_steps > 0:
       apply_kwargs["progress"] = (
           state.step.astype(jnp.float32) / total_train_steps)
+    # This trace's record of the kernels on the factor plane (None:
+    # none may take it, and the context below does nothing).
+    factor_plan = (kungfu.FactorExchange(axis_data, mesh.shape[axis_data])
+                   if factor_exchange else None)
 
     def loss_fn(p, mb_images, mb_labels, bs, dropout_rng):
       if overlap_in_step:
@@ -492,7 +538,7 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # ``transpose(jvp(forward))`` coming back, which is how the
       # benchmark's trace reader (benchmarks/spans.py) tells the two
       # passes apart. Metadata only.
-      with jax.named_scope("forward"):
+      with jax.named_scope("forward"), kungfu.factor_exchange(factor_plan):
         variables = {"params": p}
         if bs:
           variables["batch_stats"] = bs
@@ -688,7 +734,21 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
       # concatenations and scalings around the collectives too, which a
       # reader that goes by opcode alone would miss (benchmarks/spans.py).
       with jax.named_scope("exchange"):
-        grads = strategy.reduce_gradients(grads, axis_data)
+        reduce = lambda g: strategy.reduce_gradients(g, axis_data)
+        if factor_plan is not None and factor_plan.claimed:
+          grads = _reduce_unclaimed(grads, factor_plan.claimed, reduce)
+          counters = factor_plan.counters()
+          tracing.active().set_static("factor_exchange", counters)
+          from kf_benchmarks_tpu.utils import log as log_util
+          log_util.log_fn(
+              "factor exchange: %d dense layer(s) form the mean gradient "
+              "from all-gathered factors: %.1f MB kept off the "
+              "all-reduce, %.1f MB gathered instead" % (
+                  counters["layers"],
+                  counters["bytes_off_allreduce"] / 1e6,
+                  counters["bytes_gathered"] / 1e6))
+        else:
+          grads = reduce(grads)
     # else: the in-backward hooks already reduced every bucket
     # (module-internal hooks for module_reduced_prefixes, the loss_fn
     # wrap for the rest); everything downstream -- the auto-loss-scale

@@ -200,6 +200,27 @@ MUTATIONS = [
     ("host_transfer_in_step", "base",
      lambda c: c.host_transfers.append("outfeed"),
      "no-host-transfer"),
+    # ISSUE 25 seeds: every gradient leaf is reduced exactly once, in
+    # the exchange scope's all-reduce OR on the factor data plane. The
+    # classifier kernel of ``base`` takes the factor plane; all-reducing
+    # it as well is the double reduction the step's one predicate rules
+    # out (an unscoped extra all-reduce trips nothing on ``base``, see
+    # traced_device_side_reduction: the scope is what this rule reads).
+    ("factor_leaf_reduced_twice", "base",
+     lambda c: _add_collective(c, elems=4096 * 1001, in_exchange=True),
+     "gradient-reduced-once"),
+    # ... and a claimed leaf whose gathers are gone is reduced by
+    # neither plane: each replica would apply its local product.
+    ("factor_gathers_lost", "base",
+     lambda c: c.collectives.__setitem__(
+         slice(None), [x for x in c.collectives
+                       if x.kind != "all-gather"]),
+     "gradient-reduced-once"),
+    # The claim lost while the gathers stay: the all-reduced elements
+    # no longer add up to the tree.
+    ("factor_claim_lost", "base",
+     lambda c: c.aux.update(factor_elems=0),
+     "gradient-reduced-once"),
     ("partial_replica_groups", "base",
      lambda c: _add_collective(c, elems=1 << 20,
                                replica_groups="{{0,1,2,3},{4,5,6,7}}"),
