@@ -50,7 +50,9 @@ weight decay and no optimizer state, and is updated inside the step by
 its own rule (DeepSeek-V3, arXiv:2412.19437 section 2.1.2): after each
 step ``bias_e += gamma * sign(mean load - load_e)``, loads counted over
 all the experts from this chip's tokens. The same collection carries
-what the step leaves for its readers: the expert loads, from which
+what the step leaves for its readers: the expert loads, the pairs the
+grouped products computed and whether the layer's pairs fit one round of
+the routed path (``parallel/expert.compact_rows``), from which
 ``moe_counters`` makes the per-step counters that ride the step's
 metrics (``stats["moe"]``), every token's chosen experts, and the
 router's input and scores of the first ``ROUTER_PROBE_TOKENS`` tokens,
@@ -322,6 +324,8 @@ class MoE(_Options):
                          jnp.float32)
     computed = self.variable("batch_stats", "pairs_computed", jnp.zeros, (),
                              jnp.float32)
+    compact = self.variable("batch_stats", "compact", jnp.zeros, (),
+                            jnp.float32)
     # For a check of the timed program: every token's choice, and the
     # router's input and scores of the first few tokens.
     probe = min(ROUTER_PROBE_TOKENS, b * t)
@@ -342,7 +346,8 @@ class MoE(_Options):
       self.sow("intermediates", "topk_idx", idx)
       routed, counts = expert_lib.held_experts_ffn(
           flat, weights, idx, w_gate, w_up, w_down, c.first_expert,
-          impl=self.moe_impl)
+          impl=self.moe_impl, rows=expert_lib.compact_rows(
+              b * t * c.num_experts_per_tok, g, e))
       if self.is_mutable_collection("batch_stats") and \
           not self.is_initializing():
         # The step's loads over ALL the experts from this chip's tokens,
@@ -351,6 +356,7 @@ class MoE(_Options):
                                           dtype=jnp.float32), axis=0)
         load.value = all_load
         computed.value = counts["pairs_computed"].astype(jnp.float32)
+        compact.value = counts["compact"].astype(jnp.float32)
         chosen.value = idx.astype(jnp.float32)
         probe_in.value = flat[:probe].astype(self.dtype)
         probe_scores.value = scores[:probe].astype(jnp.float32)
@@ -442,14 +448,16 @@ def moe_counters(batch_stats, cfg: LMConfig):
                           if path[-1].key == "load"])
   held = load[:, cfg.first_expert:cfg.first_expert + cfg.experts_held]
   pairs_here = jnp.sum(held)
-  pairs_computed = sum(jnp.sum(v) for path, v in leaves
-                       if path[-1].key == "pairs_computed")
+  total = lambda key: sum(jnp.sum(v) for path, v in leaves
+                          if path[-1].key == key)
   return jnp.stack([
-      pairs_here, pairs_here - pairs_computed,
-      jnp.max(jnp.max(held, -1) / jnp.maximum(jnp.mean(held, -1), 1e-9))])
+      pairs_here, pairs_here - total("pairs_computed"),
+      jnp.max(jnp.max(held, -1) / jnp.maximum(jnp.mean(held, -1), 1e-9)),
+      total("compact")])
 
 
-MOE_COUNTERS = ("pairs_routed_here", "pairs_dropped", "load_max_over_mean")
+MOE_COUNTERS = ("pairs_routed_here", "pairs_dropped", "load_max_over_mean",
+                "compact_layers")
 
 
 class MLAMoELMModel(model_lib.Model):
@@ -491,8 +499,11 @@ class MLAMoELMModel(model_lib.Model):
       trace = tracing.active()
       with trace.span("setup", "load_lm_config", config=self._share[0]):
         self._cfg = c = load_lm_config(*self._share)
-      trace.set_static("moe", {"experts_held": c.experts_held,
-                               "vocab_rows": c.vocab_rows})
+      trace.set_static("moe", {
+          "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
+          "buffer_rows": expert_lib.compact_rows(
+              self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
+              c.experts_held, c.n_routed_experts)})
       log_util.log_fn(
           f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
           f"layers ({c.dense_layers} dense, {c.moe_layers} mixture, "
@@ -510,9 +521,12 @@ class MLAMoELMModel(model_lib.Model):
   def counter_stats(self, rows):
     """``rows`` (steps, len(MOE_COUNTERS)) of ``moe_counters`` as the
     run's ``stats["moe"]``."""
+    c = self.cfg
+    layer_steps = rows.shape[0] * (c.moe_layers + c.num_nextn_predict_layers)
     return {"pairs_routed_here": float(rows[:, 0].mean()),
             "pairs_dropped": float(rows[:, 1].sum()),
             "load_max_over_mean": float(rows[:, 2].max()),
+            "compact_share": float(rows[:, 3].sum()) / layer_steps,
             "steps": int(rows.shape[0])}
 
   def make_module(self, nclass, phase_train, data_format="NHWC",

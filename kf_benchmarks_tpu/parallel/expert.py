@@ -22,7 +22,7 @@ by tests/test_expert_parallel.py.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -154,10 +154,32 @@ def reference_switch_moe(x_grouped, gate_w, w1, b1, w2, b2,
 # experts' part of the result. What the absent experts would add is left
 # out here, and on one chip there is no exchange: no code stands in for
 # the absent chips. Routing is dropless: every (token, expert) pair whose
-# expert is held is computed, whatever the imbalance. The pairs are
-# sorted by held expert into a buffer of the worst-case size (all
-# tokens x k), the grouped product visits only the rows in use, and both
-# directions of the shuffle are gathers (``_dispatch`` / ``_collect``).
+# expert is held is computed, whatever the imbalance.
+#
+# The work follows the rows in use. All N x k pairs are sorted by held
+# expert (int32 keys; absent experts' pairs last), so the pairs held here
+# are a prefix of the sorted order, and the path works on that prefix in
+# ROUNDS of ``rows`` sorted rows: ``compact_rows`` sizes a round at twice
+# the share of the pairs these experts get under perfect balance, so one
+# round is the common step, and a step whose pairs do not fit runs
+# further rounds, decided on the chip (ONE ``lax.while_loop`` on a device
+# scalar: no host sync, no recompilation, no pair dropped). No array of
+# N x k rows times a model width exists, forward or backward: a round
+# gathers ``rows`` rows of the tokens, runs the three grouped products
+# on them, weights each row by its pair's score on the sorted side and
+# sums the rows back into their tokens (``_rows_of_tokens`` /
+# ``_rows_to_tokens``, each the other's transpose). Where ``rows`` is
+# all N x k pairs (half of the experts or more held) there is one round
+# and no loop in the program.
+
+
+def compact_rows(pairs: int, held: int, experts: int, tile: int = 512) -> int:
+  """Rows of one round of the routed path's sorted buffer: twice the
+  share of the ``pairs`` (token, expert) pairs of a step that ``held``
+  of ``experts`` experts get under perfect balance, in whole row tiles
+  of the grouped products, and never more than all the pairs."""
+  share = -(-2 * pairs * held // experts)
+  return min(pairs, -(-share // tile) * tile)
 
 
 def gmm_tiling(rows: int, contraction: int, columns: int):
@@ -196,65 +218,111 @@ def route_topk(x, router_w, select_bias, k: int, scale: float,
   return weights * scale, idx.astype(jnp.int32), scores
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(x, token_of, inv, live, k):
-  """Rows of ``x`` (N, D) in sorted-pair order: row r is the token of
-  pair ``order[r]``; rows past the pairs in use are zero. Backward is a
-  gather by the inverse permutation and a sum over each token's k pairs
-  (autodiff would scatter-add)."""
-  del inv
-  return jnp.where(live[:, None], jnp.take(x, token_of, axis=0), 0)
+class SortedPairs(NamedTuple):
+  """A step's N x k (token, expert) pairs sorted by held expert (stable,
+  so by token within an expert; absent experts' pairs last). ``order[r]``
+  is the pair (token x k + choice) in sorted row r and ``key[r]`` the
+  held expert it chose (the number of held experts for an absent one's);
+  ``inv`` is the sorted row of each pair; ``ends`` (G,) the row where
+  each held expert's pairs end, so ``ends[-1]`` pairs are held here."""
+  key: jnp.ndarray
+  order: jnp.ndarray
+  inv: jnp.ndarray
+  ends: jnp.ndarray
 
 
-def _dispatch_fwd(x, token_of, inv, live, k):
-  return _dispatch(x, token_of, inv, live, k), (inv, live)
-
-
-def _dispatch_bwd(k, res, g):
-  inv, live = res
-  g = jnp.where(live[:, None], g, 0)
-  dx = jnp.take(g, inv, axis=0).reshape(-1, k, g.shape[-1]).sum(axis=1)
-  return dx, None, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _sum_rows_by_token(rows, slot):
+  """(N, D) float32: each token's sum of the rows of a round that are
+  its pairs'. ``slot`` (N, k) is the row of each of a token's pairs in
+  the round, or the row count for a pair outside it: k gathers of N rows
+  from the round's rows and one zero row (a segment sum over the rows'
+  tokens, a scatter-add, took 0.8 ms a layer more: PERF.md section 6,
+  PR 28)."""
+  table = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+  return sum(jnp.take(table, slot[:, j], axis=0, mode="clip").astype(
+      jnp.float32) for j in range(slot.shape[1]))
 
 
 @jax.custom_vjp
-def _collect(ys, order, inv):
-  """Sorted rows back in pair order (N*k, D): a permutation, whose
-  backward is the inverse permutation's gather."""
+def _rows_of_tokens(x, tok, slot):
+  """Rows of ``x`` (N, D) for a round: row r is token ``tok[r]``'s. The
+  backward sums a row's gradient into its token (autodiff would
+  scatter-add)."""
+  del slot
+  return jnp.take(x, tok, axis=0, mode="clip")
+
+
+def _rows_of_tokens_fwd(x, tok, slot):
+  return _rows_of_tokens(x, tok, slot), slot
+
+
+def _rows_of_tokens_bwd(slot, g):
+  return _sum_rows_by_token(g, slot).astype(g.dtype), None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_tokens(rows, tok, slot):
+  """A round's rows (float32) summed into their tokens, (N, D): the
+  transpose of ``_rows_of_tokens``, whose backward is its gather."""
+  del tok
+  return _sum_rows_by_token(rows, slot)
+
+
+def _rows_to_tokens_fwd(rows, tok, slot):
+  return _rows_to_tokens(rows, tok, slot), tok
+
+
+def _rows_to_tokens_bwd(tok, g):
+  return jnp.take(g, tok, axis=0, mode="clip"), None, None
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+@jax.custom_vjp
+def _sorted_values(v, order, inv):
+  """``v[order]`` for a permutation ``order`` of the N x k pairs and its
+  inverse, as a sort of ``v`` by ``inv``, and backward a sort by
+  ``order``: on the TPU a gather of 32,768 scalars takes 0.23 ms and a
+  sort of them 0.025 ms (PERF.md section 6, PR 28)."""
   del order
-  return jnp.take(ys, inv, axis=0)
+  return lax.sort((inv, v), num_keys=1)[1]
 
 
-def _collect_fwd(ys, order, inv):
-  return jnp.take(ys, inv, axis=0), order
+def _sorted_values_fwd(v, order, inv):
+  return _sorted_values(v, order, inv), order
 
 
-def _collect_bwd(order, g):
-  return jnp.take(g, order, axis=0), None, None
+def _sorted_values_bwd(order, g):
+  return lax.sort((order, g), num_keys=1)[1], None, None
 
 
-_collect.defvjp(_collect_fwd, _collect_bwd)
+_sorted_values.defvjp(_sorted_values_fwd, _sorted_values_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def grouped_matmul(lhs, rhs, group_sizes, live, impl="gmm"):
-  """``out[r] = lhs[r] @ rhs[group of r]`` for the sorted rows in use
-  (``live``), zero elsewhere; ``group_sizes`` (G,) int32 rows per held
-  expert, in order. ``impl``: ``gmm`` is the TPU kernel (Pallas
-  megablox: its grid covers the tiles in use alone, so the unused tail
-  of the worst-case buffer costs nothing; rows it does not visit are
-  left unwritten, hence the masks), ``gmm_interpret`` the same kernel
-  interpreted (CPU tests), ``ragged_dot`` XLA's own grouped product
-  (CPU; on a TPU it expands to one dense product per group)."""
+  """``out[r] = lhs[r] @ rhs[group of r]`` for the rows of a round in
+  use (``live``), zero elsewhere; ``group_sizes`` (G,) int32 rows per
+  held expert, in order. ``impl``: ``gmm`` is the TPU kernel (Pallas
+  megablox: its grid covers the tiles in use alone, and the rows it does
+  not visit are left unwritten, hence the masks, which like everything
+  XLA runs around the kernel pass over the whole round: the reason a
+  round is sized by ``compact_rows`` and not for the worst case),
+  ``gmm_interpret`` the same kernel interpreted (CPU tests),
+  ``ragged_dot`` XLA's own grouped product (CPU; on a TPU it expands to
+  one dense product per group)."""
   if impl == "ragged_dot":
     out = lax.ragged_dot(lhs, rhs, group_sizes,
                          preferred_element_type=jnp.float32)
   else:
+    # The kernel accumulates in float32 and stores ``lhs.dtype``: the
+    # value a float32 output cast afterwards has, without that pass.
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-    out = gmm(lhs, rhs, group_sizes, jnp.float32,
+    out = gmm(lhs, rhs, group_sizes, lhs.dtype,
               gmm_tiling(lhs.shape[0], rhs.shape[1], rhs.shape[2]),
               interpret=impl == "gmm_interpret")
   return jnp.where(live[:, None], out, 0).astype(lhs.dtype)
@@ -276,10 +344,10 @@ def _grouped_matmul_bwd(impl, res, g):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
     interpret = impl == "gmm_interpret"
     rows, (_, d_in, d_out) = lhs.shape[0], rhs.shape
-    dlhs = gmm(g, rhs, group_sizes, jnp.float32,
+    dlhs = gmm(g, rhs, group_sizes, lhs.dtype,
                gmm_tiling(rows, d_out, d_in), transpose_rhs=True,
                interpret=interpret)
-    drhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
+    drhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
                 gmm_tiling(rows, d_in, d_out),
                 num_actual_groups=rhs.shape[0], interpret=interpret)
   dlhs = jnp.where(live[:, None], dlhs, 0).astype(lhs.dtype)
@@ -289,55 +357,143 @@ def _grouped_matmul_bwd(impl, res, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
+@functools.partial(jax.jit, static_argnames=("rows", "impl"))
+def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
+                  rows: int, impl: str):
+  """Round ``i`` of the routed path: sorted rows ``[i x rows, (i + 1) x
+  rows)``. Returns ``(y, computed)``: y (N, D) float32, the weighted
+  SiLU-gated outputs of the held experts for the pairs in these rows,
+  summed into their tokens; ``computed`` the pairs the products computed
+  for their expert (``pairs_inside_groups``). A held pair outside the
+  round is another round's: left out here, and not counted. (Jitted, as
+  ``_round_pullback`` is, so that every mixture block of a model shares
+  ONE trace and one lowering of it; XLA inlines the calls.)"""
+  k = pair_w.shape[1]
+  start = i * rows
+  pad = -plan.order.shape[0] % rows      # the last round may be short
+
+  def cut(v, fill=0):
+    return lax.dynamic_slice_in_dim(
+        jnp.pad(v, (0, pad), constant_values=fill), start, rows)
+  tok = cut(plan.order) // k
+  ends = jnp.clip(plan.ends - start, 0, rows)
+  sizes = jnp.diff(ends, prepend=0)
+  live = jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+  slot = plan.inv - start
+  slot = jnp.where((slot >= 0) & (slot < rows), slot, rows).reshape(-1, k)
+  xs = jnp.where(live[:, None], _rows_of_tokens(x, tok, slot), 0)
+  with jax.named_scope("moe_experts"):
+    h = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, live, impl))
+    h = h * grouped_matmul(xs, w_up, sizes, live, impl)
+    ys = grouped_matmul(h, w_down, sizes, live, impl)
+  w = cut(_sorted_values(pair_w.reshape(-1), plan.order, plan.inv))
+  y = _rows_to_tokens(ys.astype(jnp.float32) * w[:, None], tok, slot)
+  return y, pairs_inside_groups(cut(plan.key, sizes.shape[0]), sizes, live)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl):
+  """``experts_round`` summed over the rounds that hold a pair of a held
+  expert. Differentiated as a whole: the backward runs each round's
+  forward again inside its own loop, so nothing of a round outlives it
+  (and the forward that remat repeats has no reader and is never run)."""
+  args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl)
+  return _while_pairs_left(lambda i: experts_round(i, *args), plan, rows)
+
+
+def _while_pairs_left(one_round, plan, rows):
+  """``one_round(0) + one_round(1) + ...`` (a tuple of arrays) over the
+  rounds that start before the held pairs end. The loop starts from
+  zeros, so that the executable holds a round ONCE (a first round
+  outside the loop saved the zero-fill and the sums, 1.1 ms a layer, and
+  cost 2 s of every process's set-up: PERF.md section 6, PR 28)."""
+  if plan.key.shape[0] <= rows:
+    return one_round(jnp.int32(0))
+  zeros = tuple(jnp.zeros(o.shape, o.dtype)
+                for o in jax.eval_shape(one_round, jnp.int32(0)))
+  more = lambda carry: carry[0] * rows < plan.ends[-1]
+  add = lambda carry: (carry[0] + 1, tuple(
+      a + b for a, b in zip(carry[1], one_round(carry[0]))))
+  return lax.while_loop(more, add, (jnp.int32(0), zeros))[1]
+
+
+def _all_rounds_fwd(x, pair_w, w_gate, w_up, w_down, plan, rows, impl):
+  return (_all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl),
+          (x, pair_w, w_gate, w_up, w_down, plan))
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "impl"))
+def _round_pullback(i, g, inputs, plan, rows, impl):
+  """The gradients of round ``i``'s y with respect to ``inputs`` (x,
+  pair_w and the three weights) at the cotangent ``g``."""
+  _, vjp = jax.vjp(lambda *a: experts_round(i, *a, plan, rows, impl)[0],
+                   *inputs)
+  return vjp(g)
+
+
+def _all_rounds_bwd(rows, impl, res, g):
+  *inputs, plan = res
+  pull = lambda i: _round_pullback(i, g[0], tuple(inputs), plan, rows, impl)
+  return _while_pairs_left(pull, plan, rows) + (None,)
+
+
+_all_rounds.defvjp(_all_rounds_fwd, _all_rounds_bwd)
+
+
 def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
-                     first_expert: int, impl: str = "ragged_dot"):
+                     first_expert: int, impl: str = "ragged_dot",
+                     rows: Optional[int] = None):
   """The held experts' part of a dropless top-k layer.
 
   x (N, D) tokens; weights, idx (N, k) from ``route_topk`` over ALL the
   experts; w_gate, w_up (G, D, F) and w_down (G, F, D) the G experts
-  held, which are experts ``first_expert .. first_expert + G - 1``.
-  Returns ``(y, counts)``: y (N, D) the weighted sum of the held
-  experts' SiLU-gated outputs over each token's pairs that chose one
-  (zero for a token that chose none), and ``counts`` a dict of int32
-  scalars / vectors: ``held_load`` (G,) pairs per held expert,
-  ``pairs_here`` their sum (both counted from the choices), and
-  ``pairs_computed``, counted from the other side: the rows of the
-  sorted buffer that the grouped products were told are an expert's and
-  that hold a pair of that expert (``pairs_inside_groups``). ``pairs_here -
-  pairs_computed`` is the drop count: 0 while the buffer holds all
-  N x k pairs and the sort agrees with the group sizes.
+  held, which are experts ``first_expert .. first_expert + G - 1``;
+  ``rows`` the sorted rows of one round (``compact_rows``; static), all
+  N x k by default. Returns ``(y, counts)``: y (N, D) the weighted sum
+  of the held experts' SiLU-gated outputs over each token's pairs that
+  chose one (zero for a token that chose none), and ``counts`` a dict of
+  int32 scalars: ``pairs_here``, the pairs whose expert is held (counted
+  from the choices), ``pairs_computed``, counted from the other side:
+  the rows of the rounds that ran that the grouped products were told
+  are an expert's and that hold a pair of that expert
+  (``pairs_inside_groups``), and ``compact``, 1 where the step's pairs
+  fit one round. ``pairs_here - pairs_computed`` is the drop count: 0
+  while the rounds reach every held pair and the sort agrees with the
+  group sizes.
 
   Scopes: the caller wraps this in ``moe_route``; the three grouped
-  products sit under ``moe_experts`` inside it.
+  products sit under ``moe_experts`` inside it, in every round.
   """
   n, k = idx.shape
-  g = w_gate.shape[0]
+  rows = n * k if rows is None else rows
+  plan, held = sort_pairs(idx, first_expert, w_gate.shape[0])
+  pair_w = jnp.where(held, weights, 0).astype(jnp.float32)
+  y, computed = _all_rounds(
+      x, pair_w, w_gate.astype(x.dtype), w_up.astype(x.dtype),
+      w_down.astype(x.dtype), plan, rows, impl)
+  pairs_here = plan.ends[-1]
+  counts = {"pairs_here": pairs_here, "pairs_computed": computed,
+            "compact": (pairs_here <= rows).astype(jnp.int32)}
+  return y.astype(x.dtype), counts
+
+
+def sort_pairs(idx, first_expert: int, g: int):
+  """``idx`` (N, k), every token's chosen experts, as the ``SortedPairs``
+  of the ``g`` experts held from ``first_expert`` on; and ``held``
+  (N, k), whether a pair's expert is held. The loads are counted from
+  the choices, not from the sort."""
+  n, k = idx.shape
   local = idx - first_expert
   held = (local >= 0) & (local < g)
   # Absent experts' pairs sort to the end, under a key of their own.
   key = jnp.where(held, local, g).reshape(-1)
   sorted_key, order = lax.sort(
       (key, jnp.arange(n * k, dtype=jnp.int32)), num_keys=1, is_stable=True)
-  inv = jnp.argsort(order).astype(jnp.int32)
   held_load = jnp.sum(
       (key[:, None] == jnp.arange(g, dtype=key.dtype)[None, :]).astype(
           jnp.int32), axis=0)
-  pairs_here = jnp.sum(held_load)
-  # The buffer is the worst case, all N x k pairs, so every group fits.
-  live = jnp.arange(n * k, dtype=jnp.int32) < pairs_here
-  xs = _dispatch(x, order // k, inv, live, k)
-  with jax.named_scope("moe_experts"):
-    h = jax.nn.silu(grouped_matmul(xs, w_gate.astype(x.dtype), held_load,
-                                   live, impl))
-    h = h * grouped_matmul(xs, w_up.astype(x.dtype), held_load, live, impl)
-    ys = grouped_matmul(h, w_down.astype(x.dtype), held_load, live, impl)
-  pair_w = jnp.where(held, weights, 0).astype(jnp.float32)
-  y_pairs = _collect(ys, order, inv).reshape(n, k, -1)
-  y = jnp.sum(y_pairs.astype(jnp.float32) * pair_w[..., None], axis=1)
-  counts = {"held_load": held_load, "pairs_here": pairs_here,
-            "pairs_computed": pairs_inside_groups(sorted_key, held_load,
-                                                  live)}
-  return y.astype(x.dtype), counts
+  return SortedPairs(sorted_key, order, jnp.argsort(order).astype(jnp.int32),
+                     jnp.cumsum(held_load)), held
 
 
 def pairs_inside_groups(sorted_key, group_sizes, live):

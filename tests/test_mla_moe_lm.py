@@ -227,42 +227,141 @@ def test_vocabulary_slices_concatenate_to_the_uncut_logits():
 
 # -- C3: dropless under imbalance ---------------------------------------------
 
-def _forced(cfg, experts):
+def _forced(cfg, experts, seq=16, **module_kwargs):
   """A MoE layer whose selection bias forces every token onto
   ``experts``."""
-  moe = lm.MoE(cfg=cfg)
-  x = jax.random.normal(jax.random.PRNGKey(3), (2, 16, cfg.hidden_size))
+  moe = lm.MoE(cfg=cfg, **module_kwargs)
+  x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, cfg.hidden_size))
   variables = moe.init({"params": jax.random.PRNGKey(4)}, x)
   params = jax.tree.map(lambda p: p * 8, variables["params"])
-  bias = jnp.zeros((cfg.n_routed_experts,)).at[jnp.asarray(experts)].set(9.0)
+  bias = jnp.zeros((cfg.n_routed_experts,))
+  if experts:
+    bias = bias.at[jnp.asarray(experts)].set(9.0)
   stats = dict(variables["batch_stats"], select_bias=bias)
   return moe, params, stats, x
+
+
+def _as_ref(p):
+  """A MoE layer's parameters as the reference's ``mixture`` reads them."""
+  return {"router": p["router"], "experts_gate": p["experts_gate"],
+          "experts_up": p["experts_up"], "experts_down": p["experts_down"],
+          "shared": {k: p["shared_experts"][k]["kernel"]
+                     for k in ("gate_proj", "up_proj", "down_proj")}}
+
+
+def _layer_against_reference(cfg, moe, params, stats, x):
+  """Output and gradients (parameters and input) of a MoE layer against
+  ``ref.mixture``; returns what the layer left in ``batch_stats``."""
+  d, share = ref_cfg(cfg)
+  out, updates = moe.apply({"params": params, "batch_stats": stats}, x,
+                           mutable=["batch_stats"])
+  close(out, ref.mixture(d, share, _as_ref(params), stats["select_bias"],
+                         x)[0], "output")
+  prog = lambda p, x: jnp.sum(jnp.sin(moe.apply(
+      {"params": p, "batch_stats": stats}, x)))
+  plain = lambda p, x: jnp.sum(jnp.sin(ref.mixture(
+      d, share, _as_ref(p), stats["select_bias"], x)[0]))
+  trees_close(jax.jit(jax.grad(prog, argnums=(0, 1)))(params, x),
+              jax.jit(jax.grad(plain, argnums=(0, 1)))(params, x), "gradient")
+  return updates["batch_stats"]
+
+
+# 1,024 tokens x 2 choices over 4 of 16 experts held: the rule itself
+# gives rounds of 1,024 of the 2,048 sorted rows (at the 32-token cases
+# the 512-row tile makes one round of all the pairs, and no loop is
+# built). Held here: experts 4..7.
+ROUND_REGIMES = {
+    # regime: (experts forced, pairs held here or None, rounds)
+    "no_pair_here": ([0, 15], 0, 1),
+    "under_one_round": ([], None, 1),
+    "exactly_one_round": ([4, 0], 1024, 1),      # the boundary is compact
+    "over_one_round": ([4], None, 2),            # the second round partial
+    "every_pair_here": ([4, 6], 2048, 2),
+}
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
+@pytest.mark.parametrize("regime", list(ROUND_REGIMES))
+def test_rounds_of_the_routed_path_against_reference(regime, impl):
+  experts, want_pairs, rounds = ROUND_REGIMES[regime]
+  cfg = tiny()
+  assert expert_lib.compact_rows(1024 * cfg.num_experts_per_tok,
+                                 cfg.experts_held, cfg.n_routed_experts) == 1024
+  moe, params, stats, x = _forced(cfg, experts, seq=512, moe_impl=impl)
+  new = _layer_against_reference(cfg, moe, params, stats, x)
+  held = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
+  pairs_here = float(jnp.sum(new["load"][held]))
+  if want_pairs is not None:
+    assert pairs_here == want_pairs
+  assert -(-max(pairs_here, 1) // 1024) == rounds
+  assert float(new["pairs_computed"]) == pairs_here
+  assert float(new["compact"]) == (rounds == 1)
+
+
+@pytest.mark.parametrize("pairs, held, experts, tile, want", [
+    (32768, 8, 64, None, 8192),     # the benchmark's cell: a quarter
+    (32768, 64, 64, None, 32768),   # every expert held: all the pairs
+    (32768, 32, 64, None, 32768),   # half of them held: all the pairs
+    (32768, 1, 64, None, 1024),
+    (2048, 4, 16, None, 1024),
+    (1000, 1, 16, None, 512),       # 125 rounds up to one row tile
+    (1000, 5, 16, None, 1000),      # ... and never above all the pairs
+    (64, 4, 16, None, 64),          # the tiny cases: one round
+    (96, 1, 16, 8, 16),             # 12 rounds up to two tiles of 8
+    (100, 3, 7, 8, 88),             # 85.7.. -> 86 -> 88
+])
+def test_compact_rows(pairs, held, experts, tile, want):
+  tiles = {} if tile is None else {"tile": tile}
+  rows = expert_lib.compact_rows(pairs, held, experts, **tiles)
+  assert rows == want and rows <= pairs
+  assert rows == pairs or rows % tiles.get("tile", 512) == 0
+
+
+def _shapes_in(jaxpr):
+  """The shape of every value a jaxpr computes, its sub-jaxprs' too."""
+  for eqn in jaxpr.eqns:
+    for var in eqn.outvars:
+      yield tuple(getattr(var.aval, "shape", ()))
+    for param in eqn.params.values():
+      for sub in (param if isinstance(param, (tuple, list)) else (param,)):
+        sub = getattr(sub, "jaxpr", sub)
+        if hasattr(sub, "eqns"):
+          yield from _shapes_in(sub)
+
+
+def test_routed_path_holds_no_pairs_by_width_array():
+  # Forward and backward work on rounds of 1,024 rows: no array of
+  # N x k = 2,048 rows times a model width (32, or the experts' 16)
+  # exists; index vectors of N x k and token-side (N, 32) are fine.
+  cfg = tiny()
+  pairs, widths = 2048, (cfg.hidden_size, cfg.moe_intermediate_size)
+  moe, params, stats, x = _forced(cfg, [], seq=512)
+
+  def offenders(module):
+    fn = lambda p, x: jnp.sum(jnp.sin(module.apply(
+        {"params": p, "batch_stats": stats}, x)))
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 1)))(params, x)
+    return sorted({s for s in _shapes_in(jaxpr.jaxpr)
+                   if len(s) >= 2 and s[0] == pairs and s[-1] in widths})
+  assert offenders(moe) == []
+  # The control: with every expert held the rule gives one round of all
+  # N x k rows, and the same search finds them.
+  whole = tiny(shards=1, shard_index=0)
+  moe, params, stats, x = _forced(whole, [], seq=512)
+  assert (pairs, cfg.hidden_size) in offenders(moe)
 
 
 def test_every_token_on_held_experts_loses_none():
   cfg = tiny()
   experts = [cfg.first_expert, cfg.first_expert + 2]
   moe, params, stats, x = _forced(cfg, experts)
-  d, share = ref_cfg(cfg)
-  as_ref = lambda p: {"router": p["router"], "experts_gate": p[
-      "experts_gate"], "experts_up": p["experts_up"], "experts_down": p[
-          "experts_down"], "shared": {k: p["shared_experts"][k]["kernel"]
-                                      for k in ("gate_proj", "up_proj",
-                                                "down_proj")}}
-  out, updates = moe.apply({"params": params, "batch_stats": stats}, x,
-                           mutable=["batch_stats"])
-  new = updates["batch_stats"]
+  new = _layer_against_reference(cfg, moe, params, stats, x)
   tokens = x.shape[0] * x.shape[1]
   assert float(new["pairs_computed"]) == 2 * tokens   # every pair, all here
   assert float(new["load"][experts[0]]) == tokens
-  close(out, ref.mixture(d, share, as_ref(params), stats["select_bias"],
-                         x)[0], "output")
-  prog = lambda p, x: jnp.sum(jnp.sin(moe.apply(
-      {"params": p, "batch_stats": stats}, x)))
-  plain = lambda p, x: jnp.sum(jnp.sin(ref.mixture(
-      d, share, as_ref(p), stats["select_bias"], x)[0]))
-  trees_close(jax.grad(prog, argnums=(0, 1))(params, x),
-              jax.grad(plain, argnums=(0, 1))(params, x), "gradient")
+  # 64 pairs are under one row tile: the round is all of them, and the
+  # one round counts as compact (there is no other tier to take).
+  assert float(new["compact"]) == 1
 
 
 def test_every_token_on_absent_experts_leaves_the_shared_expert():
@@ -293,6 +392,34 @@ def test_drop_count_is_taken_from_the_rows_the_products_run(fault, dropped):
     sizes = sizes.at[1].add(-1)   # expert 1's last row falls to expert 2
   computed = int(expert_lib.pairs_inside_groups(jnp.sort(key), sizes, live))
   assert pairs_here - computed == dropped
+
+
+@pytest.mark.parametrize("rows", [16, 24])   # 64 pairs: 4 rounds; 2 and 16
+def test_drop_count_of_one_round_forced_on_pairs_that_need_two(rows):
+  # A wrong predicate -- the first round alone on a step whose pairs do
+  # not fit it -- reads the pairs left out as dropped, so it cannot pass
+  # the benchmark's check (``pairs_dropped`` must be 0).
+  n, k, g, d, f = 32, 2, 3, 8, 4
+  keys = jax.random.split(jax.random.PRNGKey(0), 5)
+  idx = jnp.stack([jnp.arange(n) % g + 2, jnp.full((n,), 9)], -1)  # held 2..4
+  plan, held = expert_lib.sort_pairs(idx, 2, g)
+  pairs_here = int(plan.ends[-1])
+  assert pairs_here == n > rows and bool(jnp.all(held[:, 0] & ~held[:, 1]))
+  x = jax.random.normal(keys[0], (n, d))
+  w = [jax.random.normal(key, shape) for key, shape in zip(
+      keys[1:], [(g, d, f), (g, d, f), (g, f, d)])]
+  pair_w = jnp.where(held, 0.5, 0.0)
+  one = lambda i: expert_lib.experts_round(i, x, pair_w, *w, plan, rows,
+                                           "ragged_dot")
+  (y0, computed0), (y1, computed1) = one(0), one(1)
+  assert pairs_here - int(computed0) == pairs_here - rows
+  assert int(computed0) + int(computed1) == pairs_here
+  # ... and the two rounds together are the layer.
+  y, counts = expert_lib.held_experts_ffn(x, jnp.full((n, k), 0.5), idx, *w,
+                                          first_expert=2, rows=rows)
+  close(y, y0 + y1, "sum of the rounds")
+  assert int(counts["pairs_computed"]) == pairs_here
+  assert int(counts["compact"]) == 0
 
 
 @pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
@@ -373,6 +500,21 @@ def test_one_step_moves_the_bias_by_its_own_rule(tmp_path):
   assert moe["load_max_over_mean"] >= 1
 
 
+def test_tier_counter_reaches_the_stats():
+  from kf_benchmarks_tpu import benchmark
+  from kf_benchmarks_tpu import params as params_lib
+  params = params_lib.make_params(
+      model="mla_moe_lm", lm_config="tiny", seq_len=16, batch_size=2,
+      lm_layer_shards=4, lm_layer_shard_index=1, device="cpu",
+      optimizer="adam", num_batches=2, num_warmup_batches=0,
+      display_every=1, tf_random_seed=5)
+  moe = benchmark.BenchmarkCNN(benchmark.setup(params)).run()["moe"]
+  # 2 x 16 tokens x 2 choices: under one row tile, so one round of all
+  # 64 pairs in each of the 3 mixture layers of each of the 2 steps.
+  assert moe["steps"] == 2 and moe["buffer_rows"] == 64
+  assert moe["compact_share"] == 1.0 and moe["pairs_dropped"] == 0
+
+
 def test_learning_rate_is_the_familys_warm_up_unless_the_job_states_one():
   from kf_benchmarks_tpu import learning_rate
   from kf_benchmarks_tpu import params as params_lib
@@ -399,6 +541,15 @@ def _unrolled(params, stats, depth):
   return unstack(params), unstack(stats)
 
 
+def _step_counters(module, cfg, params, stats, tokens):
+  """``MOE_COUNTERS`` of one forward pass, as the step computes them."""
+  _, updates = module.apply({"params": params, "batch_stats": stats}, tokens,
+                            mutable=["batch_stats"])
+  counters = np.asarray(lm.moe_counters(updates["batch_stats"], cfg))
+  assert counters.shape == (len(lm.MOE_COUNTERS),)
+  return dict(zip(lm.MOE_COUNTERS, counters))
+
+
 def test_scanned_and_unrolled_stacks_agree():
   cfg = tiny()
   module, params, stats, tokens, labels = setup(cfg)
@@ -412,6 +563,10 @@ def test_scanned_and_unrolled_stacks_agree():
   close(mtp2, mtp, "MTP loss")
   trees_close(grads2, _unrolled(grads, {"layers": {}}, cfg.moe_layers)[0],
               "gradient")
+  counters = _step_counters(module, cfg, params, stats, tokens)
+  assert counters == _step_counters(loop, cfg, p2, s2, tokens)
+  # Every mixture layer (two in the stack, the MTP block) ran one round.
+  assert counters["compact_layers"] == 3 and counters["pairs_dropped"] == 0
 
 
 def test_remat_on_and_off_agree():
@@ -426,6 +581,9 @@ def test_remat_on_and_off_agree():
   (loss2, _), grads2 = program(plain, cfg, params, stats, tokens, labels)
   assert np.array_equal(np.asarray(loss), np.asarray(loss2))
   trees_close(grads2, grads, "gradient", rtol=2e-6)
+  counters = _step_counters(module, cfg, params, stats, tokens)
+  assert counters == _step_counters(plain, cfg, params, stats, tokens)
+  assert counters["compact_layers"] == 3
 
 
 # -- C6: the comparison bites -------------------------------------------------

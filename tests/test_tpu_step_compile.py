@@ -85,10 +85,13 @@ def test_vgg16_four_chip_step_on_the_tpu_compiler(topo):
 def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   """The two kernels of the glm-4.7-flash cell at its real widths, forward
   and backward, on the chip's own compiler (PR 27): the grouped product of
-  the routed experts over the worst-case buffer (8,192 tokens x 4 pairs,
-  8 experts of 2048 x 1536), whose tiling has to fit the v5e's VMEM
-  (1024 x 1536 of the weight did not), and the flash attention core at
-  head size 256 for queries, keys and values (block 1024 did not fit)."""
+  the routed experts over one round of the sorted buffer (8,192 of the
+  8,192 tokens x 4 pairs, 8 of 64 experts of 2048 x 1536), whose tiling
+  has to fit the v5e's VMEM (1024 x 1536 of the weight did not), the
+  whole routed path around it (PR 28: rounds in a while loop, and no
+  array of all the pairs times a model width in what the compiler makes
+  of it), and the flash attention core at head size 256 for queries, keys
+  and values (block 1024 did not fit)."""
   import jax
   from jax.sharding import SingleDeviceSharding
   from kf_benchmarks_tpu.models import mla_moe_lm
@@ -96,7 +99,9 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   from kf_benchmarks_tpu.parallel import sequence as sequence_lib
   one = SingleDeviceSharding(topo.devices[0])
   sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-  rows, d, f, g = 8192 * 4, 2048, 1536, 8
+  tokens, k, d, f, g = 8192, 4, 2048, 1536, 8
+  rows = expert_lib.compact_rows(tokens * k, g, 64)
+  assert rows == 8192
 
   def experts(xs, w_up, w_down, sizes, live):
     h = expert_lib.grouped_matmul(xs, w_up, sizes, live, "gmm")
@@ -109,6 +114,21 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   # The first product forward, and for each of the two the rows' and the
   # weights' gradient (nothing reads the second's own output).
   assert text.count('custom_call_target="tpu_custom_call"') >= 5
+
+  def routed(x, weights, idx, w_gate, w_up, w_down):
+    return jnp.sum(expert_lib.held_experts_ffn(
+        x, weights, idx, w_gate, w_up, w_down, 0, impl="gmm",
+        rows=rows)[0].astype(jnp.float32))
+  weight = lambda shape: sds(shape, jnp.float32)
+  text = jax.jit(jax.grad(routed, argnums=(0, 1, 3, 4, 5))).lower(
+      sds((tokens, d), jnp.bfloat16), weight((tokens, k)),
+      sds((tokens, k), jnp.int32), weight((g, d, f)), weight((g, d, f)),
+      weight((g, f, d))).compile().as_text()
+  # The backward's loop holds a round once: its three products forward
+  # again and six backward (nothing reads the forward loop's own output).
+  assert text.count('custom_call_target="tpu_custom_call"') == 9
+  assert " while(" in text
+  assert not re.search(rf"\[{tokens * k},({d}|{f})\]", text)
 
   def core(q, k, v):
     return jnp.sum(sequence_lib.pallas_flash_attention(
