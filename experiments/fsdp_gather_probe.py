@@ -5,7 +5,7 @@ Measures the SAME small scanned-transformer training config with
 --shard_optimizer_state alone (params replicated between steps, the
 round-11 steady state) and with --shard_params (full FSDP: params live
 as 1/n shard stacks and each scan iteration re-assembles ONE block
-inside the loop body, ops/overlap.py gather_params), with
+inside the loop body, ops/sharded.py gather_params), with
 utils.sync.drain() at every window boundary and differential K-step
 timing.
 
@@ -17,7 +17,7 @@ per-block gathers/scatters the scheduler can overlap with the
 neighbouring blocks' compute (the one-slot-ahead position the
 custom_vjp hook earns).
 
-CPU-mesh caveat, on record (same as overlap_reduction_probe.py): on 8
+CPU-mesh caveat, on record: on 8
 virtual CPU devices collectives are memcpy-speed and XLA:CPU does not
 run compute and collectives concurrently, so the wall A/B bounds the
 OVERHEAD of the gather machinery rather than demonstrating wall-clock
@@ -60,7 +60,7 @@ print(f"probe device: platform={PLATFORM} device_kind={DEVICE.device_kind} "
 from kf_benchmarks_tpu import benchmark  # noqa: E402
 from kf_benchmarks_tpu import params as params_lib  # noqa: E402
 from kf_benchmarks_tpu import train_step as train_step_lib  # noqa: E402
-from kf_benchmarks_tpu.ops import overlap as overlap_lib  # noqa: E402
+from kf_benchmarks_tpu.ops import sharded as sharded_lib  # noqa: E402
 from kf_benchmarks_tpu.parallel import mesh as mesh_lib  # noqa: E402
 from kf_benchmarks_tpu.parallel import strategies  # noqa: E402
 from kf_benchmarks_tpu.utils import sync  # noqa: E402
@@ -113,7 +113,7 @@ class _ProbeModel:
       block_template = jax.tree.map(
           lambda s: jax.ShapeDtypeStruct(tuple(s.shape)[1:], s.dtype),
           vs["params"]["blocks"])
-      hook = overlap_lib.fsdp_block_gatherer(
+      hook = sharded_lib.fsdp_block_gatherer(
           block_template, mesh_lib.BATCH_AXIS, mesh_lib.MODEL_AXIS)
     self.module = _ScannedLM(fsdp_block_hook=hook)
 

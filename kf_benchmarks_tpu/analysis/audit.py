@@ -42,10 +42,6 @@ def _accum(contract) -> int:
   return int(_cfg(contract, "num_grad_accum", 1) or 1)
 
 
-def _overlap(contract) -> bool:
-  return bool(_cfg(contract, "overlap_gradient_reduction", False))
-
-
 def _replicated_sync(contract) -> bool:
   vu = _cfg(contract, "variable_update", "replicated")
   sync = bool(_cfg(contract, "cross_replica_sync", True))
@@ -81,12 +77,12 @@ def rule_accum_one_collective(contract, tracer):
   return out
 
 
-def rule_overlap_in_backward(contract, tracer):
-  """PR 3: in-backward collectives iff --overlap_gradient_reduction.
+def rule_no_collective_in_loop(contract, tracer):
+  """No collective inside a loop body unless the module gathers there:
+  the replicated family's exchange trails the backward pass, once per
+  step (PR 3's in-backward hooks, the one mode that put it inside the
+  backward scan, lost on the chip and went in PR 29).
 
-  Overlap ON with a scanned-layers model: the per-block collective must
-  sit INSIDE the backward scan's while body. Overlap OFF (or hooks
-  disengaged under --num_grad_accum): NO collective may be in-loop.
   Manual TRAIN programs only: GSPMD decides collective placement
   itself (in-or-out of the scanned backward), so the twin referee
   owns that program shape (rule_partitioner_twin) -- and a tensor-
@@ -95,42 +91,25 @@ def rule_overlap_in_backward(contract, tracer):
   if _gspmd(contract) or contract.program not in ("train_step",
                                                   "train_chunk"):
     return []
-  engaged = _overlap(contract) and _accum(contract) == 1
-  in_loop = contract.in_loop_collectives()
-  if not engaged:
-    if not _replicated_sync(contract):
-      # async-PS sequential apply / gossip schedules legitimately issue
-      # collectives inside scans; the iff only binds the replicated
-      # family the overlap mode is defined for.
-      return []
-    if _accum(contract) > 1:
-      # The microbatch scan is rule_accum_one_collective's territory
-      # (one owner per seeded violation, so mutation self-tests can
-      # assert exactly one rule fires).
-      return []
-    if _cfg(contract, "shard_params", False):
-      # Full FSDP's per-block gathers/scatters live inside the scan
-      # body by DESIGN; rule_fsdp_residency owns that program shape
-      # (one owner per seeded violation).
-      return []
-    if in_loop:
-      return [f"{len(in_loop)} collective(s) inside a scanned body with "
-              "the in-backward hooks off -- a collective leaked into a "
-              "while loop"]
+  if not _replicated_sync(contract):
+    # async-PS sequential apply / gossip schedules legitimately issue
+    # collectives inside scans; the rule binds the replicated family.
     return []
-  out = []
-  if contract.aux.get("overlap_module_prefixes"):
-    if not in_loop:
-      out.append("overlap engaged on a scanned-layers model but no "
-                 "collective sits inside the backward scan body")
-  expected = contract.aux.get("overlap_step_buckets")
-  if expected is not None:
-    step_grads = [c for c in contract.gradient_collectives()
-                  if not c.in_loop]
-    if len(step_grads) != expected:
-      out.append(f"step-level gradient collectives {len(step_grads)} != "
-                 f"planned bucket count {expected}")
-  return out
+  if _accum(contract) > 1:
+    # The microbatch scan is rule_accum_one_collective's territory
+    # (one owner per seeded violation, so mutation self-tests can
+    # assert exactly one rule fires).
+    return []
+  if _cfg(contract, "shard_params", False):
+    # Full FSDP's per-block gathers/scatters live inside the scan
+    # body by DESIGN; rule_fsdp_residency owns that program shape
+    # (one owner per seeded violation).
+    return []
+  in_loop = contract.in_loop_collectives()
+  if in_loop:
+    return [f"{len(in_loop)} collective(s) inside a scanned body -- a "
+            "collective leaked into a while loop"]
+  return []
 
 
 def rule_no_btv_buffer(contract, tracer):
@@ -242,22 +221,6 @@ def rule_wire_dtype(contract, tracer):
   return []
 
 
-def _plain_mean(contract) -> bool:
-  """The step reduces the whole per-replica gradient tree once, by the
-  plain replica mean (strategies.py ``plain_mean``; the predicate of
-  train_step.make_step_fns, read from the config)."""
-  sync_sgd = (_cfg(contract, "variable_update") == "kungfu"
-              and _cfg(contract, "kungfu_option", "sync_sgd") == "sync_sgd")
-  reducer = any(_cfg(contract, flag) for flag in (
-      "all_reduce_spec", "gradient_repacking", "agg_small_grads_max_bytes",
-      "hierarchical_copy"))
-  return ((sync_sgd or (_replicated_sync(contract) and not reducer))
-          and not _sharded(contract) and not _overlap(contract)
-          and _accum(contract) == 1
-          and not _cfg(contract, "track_grad_noise_scale", False)
-          and int(contract.aux.get("num_devices") or 0) > 1)
-
-
 def rule_gradient_reduced_once(contract, tracer):
   """ISSUE 25: under the plain replica mean every gradient leaf is
   reduced EXACTLY once, one of two ways: in the all-reduce of the
@@ -268,9 +231,10 @@ def rule_gradient_reduced_once(contract, tracer):
   plane's add up to the tree (with the batch statistics, which sync
   under the same scope), and each factor layer shows its two gathers.
   A claimed leaf that is all-reduced as well, or one that is neither,
-  breaks the sum."""
-  if (contract.program != "train_step" or not _plain_mean(contract)
-      or "gradient_elems" not in contract.aux):
+  breaks the sum. Binds where the step's plan says so
+  (train_step.plan_step's exchange kind, in the contract's aux)."""
+  if (contract.program != "train_step"
+      or contract.aux.get("exchange") != "factored_mean"):
     return []
   aux = contract.aux
   exchange = [c for c in contract.collectives if c.in_exchange]
@@ -872,7 +836,7 @@ def rule_full_mesh_replica_groups(contract, tracer):
 # above, made checkable. Each row declares (owning rule, property,
 # binds(contract)): the rule that owns checking `property` on contracts
 # where `binds` holds. The stand-down comments in
-# rule_accum_one_collective / rule_overlap_in_backward /
+# rule_accum_one_collective / rule_no_collective_in_loop /
 # rule_fsdp_residency / rule_serving_bounded_decode /
 # rule_state_donated are the prose versions of these predicates; this
 # table is what rule_one_owner enforces, so a future rule (or a widened
@@ -883,7 +847,7 @@ OWNERSHIP = [
     ("accum-one-collective", "in-scan-gradient-exchange",
      lambda c: c.program in ("train_step", "train_chunk")
      and not _gspmd(c) and _accum(c) > 1),
-    ("overlap-in-backward", "in-scan-gradient-exchange",
+    ("no-collective-in-loop", "in-scan-gradient-exchange",
      lambda c: c.program in ("train_step", "train_chunk")
      and not _gspmd(c) and _accum(c) == 1 and _replicated_sync(c)
      and not _fsdp(c)),
@@ -985,7 +949,7 @@ RULES: Dict[str, Callable] = {
     "trace-twin": rule_trace_twin,
     "metrics-twin": rule_metrics_twin,
     "accum-one-collective": rule_accum_one_collective,
-    "overlap-in-backward": rule_overlap_in_backward,
+    "no-collective-in-loop": rule_no_collective_in_loop,
     "no-btv-buffer": rule_no_btv_buffer,
     "health-no-extra-collective": rule_health_no_extra_collective,
     "wire-dtype": rule_wire_dtype,

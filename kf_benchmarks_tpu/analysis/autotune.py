@@ -115,8 +115,7 @@ def default_axes(base_params) -> "collections.OrderedDict[str, tuple]":
   axes: "collections.OrderedDict[str, tuple]" = collections.OrderedDict()
   axes["steps_per_dispatch"] = (1, 2, 4, 8)
   axes["num_grad_accum"] = (1, 2, 4)
-  if bool(getattr(base_params, "overlap_gradient_reduction", False)) or \
-      bool(getattr(base_params, "shard_params", False)):
+  if bool(getattr(base_params, "shard_params", False)):
     axes["reduce_bucket_mb"] = (None, 1, 4, 16)
   if getattr(base_params, "model", None) == "transformer_lm":
     axes["attn_block"] = (None, 256, 512, 1024)
@@ -195,14 +194,12 @@ def prune_reasons(contract, *,
   if max_collectives and n > max_collectives:
     out.append(f"{n} collectives exceed the per-step cap "
                f"{max_collectives}")
-  for aux_key, what in (("overlap_step_buckets", "overlap bucket"),
-                        ("fsdp_step_gathers", "FSDP gather bucket")):
-    planned = contract.aux.get(aux_key)
-    if planned is not None and max_step_buckets and \
-        int(planned) > max_step_buckets:
-      out.append(f"{planned} planned {what}s exceed the cap "
-                 f"{max_step_buckets} (per-bucket dispatch latency "
-                 "would dominate the overlap win)")
+  planned = contract.aux.get("fsdp_step_gathers")
+  if planned is not None and max_step_buckets and \
+      int(planned) > max_step_buckets:
+    out.append(f"{planned} planned FSDP gather buckets exceed the cap "
+               f"{max_step_buckets} (per-bucket dispatch latency "
+               "would dominate what the in-loop gathers win)")
   return out
 
 

@@ -1,15 +1,16 @@
 """Program-contract extraction: trace a config's train step, never run it.
 
 The single source of the HLO-scraping conventions the test suite pins
-against (previously triplicated across tests/test_overlap_reduction.py,
-tests/test_grad_accum.py and tests/test_telemetry.py):
+against (tests/test_grad_accum.py, tests/test_telemetry.py,
+tests/test_fsdp.py):
 
 * an *all-reduce definition* is an instruction-definition line matching
   :data:`ALL_REDUCE_DEF` (``-start`` covers async pairs);
 * a collective is *in the backward loop* when its jax ``op_name``
   metadata places it inside a scanned (``while``) body -- the backward
   of a lax.scan/nn.scan lowers to a while loop, and a collective issued
-  by an in-backward hook carries the loop in its op_name;
+  by an in-loop hook (FSDP's per-block gather) carries the loop in its
+  op_name;
 * *gradient traffic* is the non-scalar all-reduce
   (:data:`GRAD_MIN_ELEMS` guards the packed health/metric vectors);
   ``f32[]`` reductions are the step's metric pmeans.
@@ -58,12 +59,6 @@ GRAD_MIN_ELEMS = 128
 def all_reduce_defs(hlo: str) -> List[str]:
   """All-reduce instruction definition lines of a compiled-HLO dump."""
   return [ln for ln in hlo.splitlines() if ALL_REDUCE_DEF.search(ln)]
-
-
-def in_backward_loop(defs) -> List[str]:
-  """Defs whose jax op_name places them inside a scanned (while) body --
-  the in-backward position the overlap hooks pin."""
-  return [ln for ln in defs if "while" in ln]
 
 
 _SCALAR_ALL_REDUCE = re.compile(r"=\s+\w+\[\]\s+all-reduce")
@@ -327,7 +322,7 @@ def trace_contract(overrides: Dict[str, Any],
   from kf_benchmarks_tpu import benchmark
   from kf_benchmarks_tpu import params as params_lib
   from kf_benchmarks_tpu import tracing
-  from kf_benchmarks_tpu.ops import overlap as overlap_lib
+  from kf_benchmarks_tpu import train_step as train_step_lib
 
   kw = dict(device="cpu", num_devices=N_REPLICAS, num_batches=2)
   kw.update(overrides)
@@ -338,13 +333,22 @@ def trace_contract(overrides: Dict[str, Any],
   with tracing.session() as session:
     state_sds, lowered = lower_step_program(bench, program)
   in_shapes = bench.model.get_input_shapes("train")
-  in_dtypes = bench.model.get_input_data_types("train")
   n = bench.num_devices
   n_data = int(getattr(bench, "num_data_replicas", n))
   compiled = compile_for_audit(lowered)
+  # The step's own plan, from the training module as the step builder
+  # makes it: which exchange the rules hold the program to, and FSDP's
+  # template, prefixes and bucket bound.
+  plan = train_step_lib.plan_step(
+      bench.strategy, bench.params, bench.mesh, bench.model,
+      module=bench.model.make_module(
+          nclass=bench.dataset.num_classes, phase_train=True,
+          data_format=bench.params.data_format,
+          dtype=bench.compute_dtype, param_dtype=bench.param_dtype))
 
   aux: Dict[str, Any] = {
       "model": bench.model.get_name(),
+      "exchange": plan.exchange.value,
       "num_devices": n,
       "num_data_replicas": n_data,
       "per_device_batch": int(in_shapes[0][0]),
@@ -382,29 +386,14 @@ def trace_contract(overrides: Dict[str, Any],
   # inventory must not exceed), and the module-gathered scanned
   # prefixes (whose per-block gathers must sit INSIDE the scan body).
   if bool(getattr(bench.params, "shard_params", False)):
-    from kf_benchmarks_tpu.ops import overlap as fsdp_overlap_lib
+    from kf_benchmarks_tpu.ops import sharded as sharded_lib
     aux["fsdp_params"] = True
-    prefixes = tuple(
-        getattr(bench.model, "fsdp_gathered_prefixes", ()) or ())
-    aux["fsdp_scan_prefixes"] = list(prefixes)
-    # Template exactly as the step builder derives it (train_step.py):
-    # abstract init of the training module.
-    train_module = bench.model.make_module(
-        nclass=bench.dataset.num_classes, phase_train=True,
-        data_format=bench.params.data_format,
-        dtype=bench.compute_dtype, param_dtype=bench.param_dtype)
-    template = jax.eval_shape(
-        lambda: train_module.init(
-            {"params": jax.random.PRNGKey(0),
-             "dropout": jax.random.PRNGKey(0)},
-            jnp.zeros(tuple(in_shapes[0]), in_dtypes[0])))["params"]
-    aux["fsdp_param_full_bytes"] = sum(
-        int(math.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
-        for l in jax.tree_util.tree_leaves(template))
-    mb = (getattr(bench.params, "reduce_bucket_mb", None)
-          or fsdp_overlap_lib.DEFAULT_BUCKET_MB)
-    buckets, _ = fsdp_overlap_lib.fsdp_plan_buckets(
-        template, int(mb) * 1024 * 1024, exclude_prefixes=prefixes)
+    template = plan.fsdp_template
+    aux["fsdp_scan_prefixes"] = list(plan.fsdp_prefixes)
+    aux["fsdp_param_full_bytes"] = sharded_lib.fsdp_param_bytes(template)
+    buckets, _ = sharded_lib.fsdp_plan_buckets(
+        template, plan.fsdp_bucket_bytes,
+        exclude_prefixes=plan.fsdp_prefixes)
     aux["fsdp_step_gathers"] = len(buckets)
     # Exact planned bytes of the largest step-level gather RESULT
     # (bucket leaves re-assemble as n * ceil(size/n) elements each):
@@ -422,7 +411,8 @@ def trace_contract(overrides: Dict[str, Any],
       return total
     aux["fsdp_max_gather_bytes"] = max(
         (_gather_bytes(b) for b in buckets), default=0)
-    aux["fsdp_engaged"] = int(bench.params.num_grad_accum or 1) == 1
+    aux["fsdp_engaged"] = (
+        plan.exchange is train_step_lib.Exchange.FSDP_IN_BACKWARD)
   # Shape/dtype-based, so the ONE accounting serves both the bench
   # JSON field (concrete arrays) and this abstract state.
   aux["opt_state_bytes_per_device"] = benchmark.opt_state_bytes_per_device(
@@ -439,22 +429,6 @@ def trace_contract(overrides: Dict[str, Any],
     itemsize = jnp.dtype(bench.compute_dtype).itemsize
     aux["btv_bytes"] = (int(in_shapes[0][0]) * int(in_shapes[0][1]) *
                         bench.model.cfg.vocab_rows * itemsize)
-  # Expected step-level bucket count when the overlap hooks engage
-  # (module-reduced prefixes are excluded -- their reduction is the
-  # in-loop per-block collective).
-  spec = overlap_lib.build(p)
-  if spec is not None and int(p.num_grad_accum or 1) == 1:
-    import types
-    params_tree = jax.tree.map(
-        lambda s: types.SimpleNamespace(
-            size=math.prod(s.shape[1:]), dtype=s.dtype),
-        state_sds.params)
-    module_prefixes = tuple(
-        getattr(bench.model, "in_backward_reduced_prefixes", ()) or ())
-    buckets, _ = overlap_lib.plan_buckets(
-        params_tree, spec.bucket_bytes, exclude_prefixes=module_prefixes)
-    aux["overlap_step_buckets"] = len(buckets)
-    aux["overlap_module_prefixes"] = list(module_prefixes)
 
   # Static flop count (the cost-analysis surface the --tfprof_file dump
   # reads): the autotuner's cost model consumes it from the aux; absent
@@ -492,21 +466,18 @@ GOLDEN_CONFIGS: "OrderedDict[str, Dict[str, Any]]" = OrderedDict([
     ("accum4_packed", dict(model="trivial", batch_size=4, num_grad_accum=4,
                            agg_small_grads_max_bytes=1 << 30,
                            agg_small_grads_max_group=1000)),
-    # PR 3: bucketed in-backward reduction, step-level hooks.
-    ("overlap", dict(model="trivial", batch_size=4,
-                     overlap_gradient_reduction=True)),
-    # PR 3 satellite: the f32-training bf16 wire opt-in.
-    ("overlap_bf16_wire", dict(model="trivial", batch_size=4,
-                               overlap_gradient_reduction=True,
-                               compact_gradient_transfer_f32=True)),
+    # PR 3 satellite: the f32-training bf16 wire opt-in
+    # (--compact_gradient_transfer_f32) on a packed reducer (the
+    # small-gradient aggregation of accum4_packed, one step): the ONE
+    # golden in which audit.rule_wire_dtype's 16-bit leg engages.
+    ("packed_bf16_wire", dict(model="trivial", batch_size=4,
+                              agg_small_grads_max_bytes=1 << 30,
+                              agg_small_grads_max_group=1000,
+                              compact_gradient_transfer_f32=True)),
     # PR 4: in-step health stats ride the loss pmean (no new collective).
     ("health", dict(model="trivial", batch_size=4, health_stats=True)),
     # PR 2: the scanned fused-head LM never materializes (B, T, V).
     ("lm_base", dict(model="transformer_lm", batch_size=8)),
-    # PR 3: the scanned LM's per-block collective lands INSIDE the
-    # backward scan's while body.
-    ("lm_overlap", dict(model="transformer_lm", batch_size=8,
-                        overlap_gradient_reduction=True)),
     # PR 6: ZeRO sharded optimizer state on the named 2-D mesh
     # (--shard_optimizer_state resolves an 8x1 ('batch', 'model') mesh
     # here): gradients meet in reduce-scatter, params return by

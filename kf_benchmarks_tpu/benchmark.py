@@ -209,66 +209,6 @@ def feeder_prefetch(params) -> int:
              params.batch_group_size or 1)
 
 
-# Flags accepted for reference-CLI parity with no TPU effect. Changing
-# them from their defaults logs a note at setup (silent acceptance of an
-# ineffective flag was a round-1 defect); flags with real consumers never
-# belong here.
-_NOOP_PARITY_FLAGS = {
-    "winograd_nonfused": ("cuDNN autotune env knob; no TPU analog (ref :3285-3297)"),
-    "gpu_memory_frac_for_testing": ("per-process GPU memory split for tests; TPU memory is not " "fractionally reservable (ref :336-342)"),
-    "network_topology": ("GPU box topology table index; the TPU mesh topology comes " "from the runtime (ref constants.py:21-24)"),
-    "sparse_to_dense_grads": ("JAX gradients are already dense (ref :518-519)"),
-    "allreduce_merge_scope": ("ScopedAllocator merge hint; XLA schedules collectives itself " "(ref :561-566)"),
-    "server_protocol": ("the coordination service speaks its own protocol " "(ref :578)"),
-    "trt_max_workspace_size_bytes": ("TensorRT knob"),
-    "xla": ("XLA is the only execution path on TPU"),
-    "xla_compile": ("the whole step is always jitted"),
-    "freeze_when_forward_only": ("freezing IS the AOT export; " "use --aot_save_path"),
-    "fuse_decode_and_crop": ("the host pipeline always crops " "before resizing"),
-    "distort_color_in_yiq": ("color jitter runs via PIL " "enhancers"),
-    "datasets_use_prefetch": ("the DeviceFeeder always prefetches"),
-    "datasets_parallel_interleave_cycle_length": ("shard reads interleave via the thread pool"),
-    "datasets_sloppy_parallel_interleave": ("tf.data knob"),
-    "datasets_parallel_interleave_prefetch": ("tf.data knob"),
-    "use_multi_device_iterator": ("the DeviceFeeder is the " "MultiDeviceIterator analog"),
-    "multi_device_iterator_max_buffer_size": ("MultiDeviceIterator " "knob"),
-    "use_resource_vars": ("JAX state is functional"),
-    "use_tf_layers": ("one flax layer path"),
-    "use_python32_barrier": ("CPython barrier workaround"),
-    "compute_lr_on_cpu": ("the LR schedule is fused into the " "jitted step"),
-    "enable_optimizations": ("XLA optimizations are always on"),
-    "rewriter_config": ("grappler knob"),
-    "allow_growth": ("GPU memory knob"),
-    "force_gpu_compatible": ("GPU pinned-memory knob"),
-    "gpu_indices": ("GPU ring-order indices"),
-    "gpu_thread_mode": ("GPU thread pools"),
-    "per_gpu_thread_count": ("GPU thread pools"),
-    "use_unified_memory": ("CUDA unified memory"),
-    "batchnorm_persistent": ("cuDNN batchnorm knob"),
-    "autotune_threshold": ("cuDNN autotune"),
-    "horovod_device": ("the SPMD data plane covers device pinning"),
-    "mkl": ("MKL build knob"),
-    "kmp_blocktime": ("MKL env var"),
-    "kmp_affinity": ("MKL env var"),
-    "kmp_settings": ("MKL env var"),
-    "local_parameter_device": (
-        "PS-style variable placement maps to sharded state on TPU "
-        "(SURVEY 5.8); the mesh determines placement"),
-    "num_inter_threads": (
-        "host inter-op scheduling belongs to XLA (ref :209-214)"),
-}
-
-
-def report_noop_parity_flags(params) -> None:
-  from kf_benchmarks_tpu import flags as flags_lib
-  for name, why in _NOOP_PARITY_FLAGS.items():
-    spec = flags_lib.param_specs.get(name)
-    default = spec.default_value if spec is not None else None
-    if getattr(params, name, default) != default:
-      log_fn(f"Note: --{name} is accepted for reference-CLI parity but "
-             f"has no effect on TPU: {why}")
-
-
 def setup(params):
   """Process-level setup (ref: benchmark_cnn.py:3356-3395).
 
@@ -313,7 +253,6 @@ def setup(params):
   from kf_benchmarks_tpu.platforms import util as platforms_util
   platforms_util.initialize(params)
   platforms_util.get_cluster_manager(params)
-  report_noop_parity_flags(params)
   # The one backend init of the process (ref dummy session :3383-3393),
   # in-process: a chip belongs to one process at a time, so no child
   # probes it first. --device=tpu without a TPU is an error in single-

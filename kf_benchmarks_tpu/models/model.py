@@ -42,13 +42,6 @@ class Model:
     # for fp16_vars mode (ref: models/model.py:55-60).
     self.fp16_loss_scale = fp16_loss_scale
     self.params = params
-    # Top-level param-tree keys whose gradients the model's module
-    # reduces IN-BACKWARD itself under --overlap_gradient_reduction
-    # (e.g. transformer_lm's scanned 'blocks' hook per layer inside the
-    # nn.scan); make_module sets it when it builds such hooks, and
-    # train_step's bucket planner excludes those leaves so each
-    # gradient reduces exactly once (ops/overlap.py).
-    self.in_backward_reduced_prefixes = ()
 
   def get_name(self) -> str:
     return self.name

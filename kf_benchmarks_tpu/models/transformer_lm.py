@@ -228,28 +228,17 @@ class _TransformerLMModule(nn.Module):
   # materialize logits (the monolithic head the oracle tests pin
   # against).
   fused_head: bool = True
-  # Mesh axis for in-backward gradient reduction of the scanned layer
-  # stack (--overlap_gradient_reduction, ops/overlap.py): each scan
-  # backward iteration then reduces THAT layer's gradient slice inside
-  # the loop body, overlapped with the next iteration's backward
-  # compute. None = no hooks (the post-hoc reduction path). Only
-  # meaningful with scan_layers; requires apply() to run inside a
-  # shard_map body where the axis is bound.
-  grad_reduce_axis: Any = None
-  # Optional 16-bit wire dtype for the hook's collectives
-  # (allreduce.compact_wire_dtype); None = the gradient's own dtype.
-  grad_reduce_compact: Any = None
   # --shard_params (full FSDP): per-block gather hook
-  # (ops/overlap.fsdp_block_gatherer). The 'blocks' stack is STORED as
+  # (ops/sharded.fsdp_block_gatherer). The 'blocks' stack is STORED as
   # flat per-layer parameter shards ((L, k) locally; ops/sharded.py
   # fsdp_stacked_shards); each nn.scan iteration re-assembles ONE
   # block's full params with a packed all-gather INSIDE the scan body
   # (under nn.remat, so the backward re-gathers during recompute), and
   # the hook's custom_vjp backward reduce-scatters that block's
   # cotangent in the same position -- the full layer stack never
-  # materializes. None = plain replicated-param storage. Exclusive
-  # with grad_reduce_axis (validation.py rejects --shard_params +
-  # --overlap_gradient_reduction upstream).
+  # materializes. None = plain replicated-param storage. Only
+  # meaningful with scan_layers; requires apply() to run inside a
+  # shard_map body where the mesh axes are bound.
   fsdp_block_hook: Any = None
   max_len: int = SEQ_LEN
   dtype: Any = jnp.float32
@@ -317,28 +306,13 @@ class _TransformerLMModule(nn.Module):
       block_cls = _Block
       if self.fsdp_block_hook is not None:
         # FSDP storage -> full block params, one packed all-gather per
-        # scan iteration (ops/overlap.py gather_params). Init stays
+        # scan iteration (ops/sharded.py gather_params). Init stays
         # full-shape and collective-free: the hook passes the empty
         # pre-creation store through, so module.init creates FULL
         # params under plain jit and the train step's init_state
         # re-stacks them into the shard layout host-side.
         block_cls = nn.map_variables(
             _Block, "params", trans_in_fn=self.fsdp_block_hook,
-            init=True)
-      elif self.grad_reduce_axis is not None:
-        # In-backward reduction hook (ops/overlap.py): the block's
-        # per-layer param slice passes through an identity-with-
-        # custom_vjp whose backward pmeans the slice's cotangent, so
-        # the collective lands INSIDE the backward scan's loop body
-        # (pinned at the HLO level by tests/test_overlap_reduction.py).
-        # The forward transform is the identity, so init (init=True)
-        # and eval apply are unaffected.
-        from kf_benchmarks_tpu.ops import overlap as overlap_lib
-        block_cls = nn.map_variables(
-            _Block, "params",
-            trans_in_fn=overlap_lib.scan_block_hook(
-                self.grad_reduce_axis,
-                compact_dtype=self.grad_reduce_compact),
             init=True)
       blocks = nn.scan(
           nn.remat(block_cls, prevent_cse=False),
@@ -508,27 +482,10 @@ class TransformerLMModel(model_lib.Model):
     # (PR 2): observability.SummaryWriter unstacks histogram keys per
     # layer via this attribute (tests/test_observability.py).
     self.scanned_param_prefixes = ("blocks",) if layers == "scan" else ()
-    # --overlap_gradient_reduction: hook the scanned layer stack so
-    # each backward scan iteration reduces its OWN layer's gradient
-    # slice inside the loop body (ops/overlap.py scan_block_hook). The
-    # training module only (eval has no backward); disengaged under
-    # --num_grad_accum, where reduction stays post-hoc on the
-    # accumulated tree (train_step.py). in_backward_reduced_prefixes
-    # tells the step-level bucket planner these leaves are covered.
-    grad_reduce_axis = None
-    grad_reduce_compact = None
     p = self.params
-    if (phase_train and layers == "scan" and p is not None
-        and getattr(p, "overlap_gradient_reduction", False)
-        and (getattr(p, "num_grad_accum", 1) or 1) == 1):
-      from kf_benchmarks_tpu.ops import allreduce
-      from kf_benchmarks_tpu.parallel.mesh import REPLICA_AXIS
-      grad_reduce_axis = REPLICA_AXIS
-      grad_reduce_compact = allreduce.compact_wire_dtype(p)
-      self.in_backward_reduced_prefixes = ("blocks",)
     # --shard_params (full FSDP): the scanned 'blocks' stack stores as
     # per-layer parameter shards and each scan iteration gathers ONE
-    # block inside the loop body (ops/overlap.fsdp_block_gatherer).
+    # block inside the loop body (ops/sharded.fsdp_block_gatherer).
     # fsdp_gathered_prefixes tells the step-level bucket gather
     # (train_step.py) these leaves are module-gathered. Training module
     # only: eval applies the PLAIN module to the step-gathered full
@@ -537,7 +494,7 @@ class TransformerLMModel(model_lib.Model):
     fsdp_block_hook = None
     if (phase_train and layers == "scan" and p is not None
         and getattr(p, "shard_params", False)):
-      from kf_benchmarks_tpu.ops import overlap as overlap_lib
+      from kf_benchmarks_tpu.ops import sharded as sharded_lib
       from kf_benchmarks_tpu.parallel.mesh import BATCH_AXIS, MODEL_AXIS
       plain = _TransformerLMModule(dtype=dtype, param_dtype=param_dtype,
                                    attn_impl=impl,
@@ -558,7 +515,7 @@ class TransformerLMModel(model_lib.Model):
       # has no tuple-axis all_gather batching rule (jax 0.9.0): the
       # hook's forward gather decomposes per axis there (element-
       # identical; ops/sharded.combined_all_gather).
-      fsdp_block_hook = overlap_lib.fsdp_block_gatherer(
+      fsdp_block_hook = sharded_lib.fsdp_block_gatherer(
           block_template, BATCH_AXIS, MODEL_AXIS,
           nested=getattr(p, "partitioner", None) == "gspmd")
       self.fsdp_gathered_prefixes = ("blocks",)
@@ -568,8 +525,6 @@ class TransformerLMModel(model_lib.Model):
                                 attn_impl=impl,
                                 fused_head=head == "fused",
                                 scan_layers=layers == "scan",
-                                grad_reduce_axis=grad_reduce_axis,
-                                grad_reduce_compact=grad_reduce_compact,
                                 fsdp_block_hook=fsdp_block_hook,
                                 **tiling)
 

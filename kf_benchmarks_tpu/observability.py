@@ -321,8 +321,8 @@ def collective_overlap_stats(hlo_text: str, peaks: DevicePeaks):
   """Static comm/compute overlap accounting from an optimized-HLO dump.
 
   A collective that lives INSIDE a loop body (a computation referenced
-  by a while instruction's ``body=``) was issued in-backward -- e.g.
-  per scanned block under --overlap_gradient_reduction -- and the
+  by a while instruction's ``body=``) was issued in the loop -- e.g.
+  FSDP's per-scanned-block gathers under --shard_params -- and the
   scheduler can interleave it with the remaining loop iterations'
   compute; a top-level collective serializes after the compute feeding
   it. Returns {num_collectives, comm_s, comm_in_loop_s,
@@ -366,8 +366,8 @@ def collective_overlap_stats(hlo_text: str, peaks: DevicePeaks):
 def overlap_fraction_line(hlo_text: str, peaks: DevicePeaks) -> str:
   """One roofline-table line for the comm/compute overlap axis: how
   much of the program's collective time is issued inside loop bodies
-  (in-backward, schedulable against remaining compute -- what
-  --overlap_gradient_reduction moves) vs trailing the compute."""
+  (schedulable against remaining compute -- where --shard_params'
+  per-block gathers sit) vs trailing the compute."""
   stats = collective_overlap_stats(hlo_text, peaks)
   if not stats["num_collectives"]:
     return ("comm/compute overlap: no collectives in program "

@@ -127,14 +127,14 @@ def parse_all_reduce_spec(spec: str) -> List[AllReduceSpecTuple]:
 def plan_size_buckets(sizes: Sequence[int], bucket_bytes: int):
   """Greedy size-bounded bucketing of an ordered size list.
 
-  The scheduler behind --reduce_bucket_mb (ops/overlap.py): consecutive
-  items merge into a bucket until adding the next would exceed
-  ``bucket_bytes``; an item alone larger than the bound keeps its own
-  bucket (reduction units cannot split below the granularity the caller
-  hands in). Order is preserved -- the overlap hooks rely on buckets
-  covering ADJACENT layers so each bucket's cotangent completes in one
-  contiguous stretch of the backward. Returns a list of index lists
-  covering ``range(len(sizes))`` exactly.
+  The scheduler behind --reduce_bucket_mb (ops/sharded.py
+  fsdp_plan_buckets): consecutive items merge into a bucket until adding
+  the next would exceed ``bucket_bytes``; an item alone larger than the
+  bound keeps its own bucket (a unit cannot split below the granularity
+  the caller hands in). Order is preserved -- FSDP's gathers rely on
+  buckets covering ADJACENT layers so each bucket's cotangent completes
+  in one contiguous stretch of the backward. Returns a list of index
+  lists covering ``range(len(sizes))`` exactly.
   """
   buckets = []
   cur, cur_bytes = [], 0
@@ -150,9 +150,9 @@ def plan_size_buckets(sizes: Sequence[int], bucket_bytes: int):
 
 
 # One precision note per process: compact_wire_dtype is consulted by
-# every builder that can consume the wire format (strategy reducer,
-# overlap spec, module hooks), and repeating the identical note per
-# consumer would read as several distinct engagements.
+# every builder that can consume the wire format, and repeating the
+# identical note per consumer would read as several distinct
+# engagements.
 _compact_f32_noted = False
 
 

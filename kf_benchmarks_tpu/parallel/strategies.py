@@ -33,7 +33,7 @@ built), that mean has two data planes (parallel/kungfu.py): the
 all-reduce of the per-replica products (``allreduce_mean``), and for a
 dense kernel larger than its batch the product of the all-gathered
 factors (``factor_mean_dot``), formed in the backward pass. The train
-step decides once which leaves go which way (train_step.make_step_fns)
+step decides once which leaves go which way (train_step.plan_step)
 and hands ``reduce_gradients`` the rest.
 """
 

@@ -209,27 +209,27 @@ def _fsdp_block_hook(block_template, axes):
   block's cotangent as one packed psum_scatter in the same loop
   position -- the SUM the pre-summed gradient convention of
   make_train_step expects (the /n_data divide happens outside, as for
-  every other leaf). Built on ops/overlap.py's shared packing
+  every other leaf). Built on ops/sharded.py's shared packing
   primitives (packed_gather_rows / pack_cotangent_rows /
   split_shard_row) so the row addressing cannot drift from the
   benchmark leg's gather_params; only the reduction differs: SUM over
   the combined axes (one shard row per device) instead of
   gather_params' batch-mean + model sub-slice."""
-  from kf_benchmarks_tpu.ops import overlap as overlap_lib
+  from kf_benchmarks_tpu.ops import sharded as sharded_lib
   t_leaves = jax.tree_util.tree_flatten(block_template)[0]
   shapes = tuple(tuple(t.shape) for t in t_leaves)
   dtypes = tuple(jnp.dtype(t.dtype).name for t in t_leaves)
 
   @functools.partial(jax.custom_vjp, nondiff_argnums=())
   def gather(shards):
-    return overlap_lib.packed_gather_rows(axes, shapes, dtypes, shards)
+    return sharded_lib.packed_gather_rows(axes, shapes, dtypes, shards)
 
   def fwd(shards):
     return gather(shards), None
 
   def bwd(_, cots):
     n = math.prod(lax.axis_size(a) for a in axes)
-    mat, ks = overlap_lib.pack_cotangent_rows(cots, shapes, n,
+    mat, ks = sharded_lib.pack_cotangent_rows(cots, shapes, n,
                                               jnp.float32)
     # SUM over the data-parallel peers (matching the pre-summed
     # gradients of the replicated leaves): the tiled scatter over the
@@ -237,7 +237,7 @@ def _fsdp_block_hook(block_template, axes):
     # shard row -- the transpose of the gather's concatenation order.
     row = lax.psum_scatter(mat, axes, scatter_dimension=0,
                            tiled=True)[0]
-    return (overlap_lib.split_shard_row(row, ks, dtypes),)
+    return (sharded_lib.split_shard_row(row, ks, dtypes),)
 
   gather.defvjp(fwd, bwd)
 
@@ -273,27 +273,6 @@ def fsdp_param_specs(data_axis: str):
                      "wqkv": blocks_spec, "wo": blocks_spec,
                      "w1": blocks_spec, "b1": blocks_spec,
                      "w2": blocks_spec, "b2": blocks_spec}}
-
-
-def _scan_grad_hook(data_axes):
-  """In-backward data-axis gradient reduction for the scanned layer
-  stack (--overlap_gradient_reduction's composed-trainer analog): the
-  returned hook wraps one layer's param slice at the top of the scan
-  body so that layer's data-parallel gradient reduction is issued
-  INSIDE the backward scan iteration -- overlapped with the next
-  iteration's backward compute -- instead of trailing the whole
-  backward.
-
-  The hook pcasts the slice to varying on the data axes. Downstream
-  ops then need no implicit pbroadcast, and pcast's TRANSPOSE is the
-  psum -- placed exactly here, in the scan body. Total reduction
-  semantics are unchanged (the implicit machinery inserted the same
-  psum); only its schedule position moves.
-  """
-  def hook(lp):
-    return jax.tree.map(
-        lambda t: lax.pcast(t, data_axes, to="varying"), lp)
-  return hook
 
 
 def _rmsnorm(x, scale, eps=1e-6):
@@ -365,7 +344,7 @@ def forward_local(params, tokens, *, seq_axis=SEQ_AXIS,
                   tensor_axis=TENSOR_AXIS, expert_axis=REPLICA_AXIS,
                   moe_capacity=None, sp_layout: str = "contiguous",
                   attn_inner_block=None, remat_policy=None,
-                  grad_reduce_axes=None, fsdp_gather_hook=None):
+                  fsdp_gather_hook=None):
   """Per-shard forward: tokens (B_local, T_local) -> (logits, moe_aux).
 
   Runs inside a shard_map body; params are the LOCAL shards
@@ -386,9 +365,6 @@ def forward_local(params, tokens, *, seq_axis=SEQ_AXIS,
   (None = save nothing, recompute the whole block;
   e.g. jax.checkpoint_policies.dots_with_no_batch_dims_saveable keeps
   the matmul outputs and recomputes only the cheap elementwise work).
-  ``grad_reduce_axes`` (scanned path only) hooks each layer's param
-  slice with :func:`_scan_grad_hook` so the layer's data-axis gradient
-  reduction runs inside the backward scan iteration.
   """
   b, t = tokens.shape
   x = _embed_positions(params, tokens, seq_axis=seq_axis,
@@ -396,9 +372,6 @@ def forward_local(params, tokens, *, seq_axis=SEQ_AXIS,
   moe_aux = jnp.zeros((), jnp.float32)
   if not isinstance(params["blocks"], (list, tuple)):
     # Scanned stack (homogeneous by stack_blocks construction).
-    block_hook = (_scan_grad_hook(grad_reduce_axes)
-                  if grad_reduce_axes else None)
-
     def one_block(xm, lp):
       if fsdp_gather_hook is not None:
         # --shard_params's composed-trainer leg: lp arrives as flat
@@ -408,8 +381,6 @@ def forward_local(params, tokens, *, seq_axis=SEQ_AXIS,
         # backward reduce-scatters the block's cotangent in the same
         # position (_fsdp_block_hook).
         lp = fsdp_gather_hook(lp)
-      if block_hook is not None:
-        lp = block_hook(lp)
       xm, h = _attention_residual(lp, xm, seq_axis=seq_axis,
                                   tensor_axis=tensor_axis,
                                   sp_layout=sp_layout,
@@ -585,9 +556,7 @@ def make_train_step(mesh: Mesh, params_template, learning_rate: float,
                     moe_capacity=None, moe_aux_weight: float = 0.01,
                     sp_layout: str = "contiguous",
                     attn_inner_block=None, scan_layers: bool = False,
-                    remat_policy=None,
-                    overlap_grad_reduce: bool = False,
-                    fsdp_blocks: bool = False):
+                    remat_policy=None, fsdp_blocks: bool = False):
   """Jitted SGD train step over GLOBAL (params, tokens, labels):
   tokens/labels (batch, seq) in NORMAL order, sharded (data, seq) --
   the data axis is 'batch' on compose_on_model_axis meshes, 'replica'
@@ -604,21 +573,9 @@ def make_train_step(mesh: Mesh, params_template, learning_rate: float,
   layer stack as one scanned+rematerialized body (forward_local);
   ``remat_policy`` is its explicit jax.checkpoint policy. Losses and
   trained parameters stay numerically equivalent to the unscanned
-  step (tests/test_transformer_parallel.py pins it).
-
-  overlap_grad_reduce=True (scanned path only) hooks each layer's
-  param slice in the scan body (_scan_grad_hook) so the layer's
-  data-axis gradient reduction is issued inside the backward scan
-  iteration, overlapped with the preceding layer's backward, instead
-  of trailing the whole backward. Reduction semantics are unchanged
-  (the hook only moves the psum's schedule position)."""
+  step (tests/test_transformer_parallel.py pins it)."""
   if sp_layout not in ("contiguous", "zigzag"):
     raise ValueError(f"unknown sp_layout {sp_layout!r}")
-  if overlap_grad_reduce and not scan_layers:
-    raise ValueError(
-        "overlap_grad_reduce=True requires scan_layers=True: the hooks "
-        "live in the scanned block body (an unscanned stack already "
-        "exposes every layer's reduction to the scheduler separately)")
   data_axis = _data_axis(mesh)
   fsdp_hook = None
   if fsdp_blocks:
@@ -636,12 +593,6 @@ def make_train_step(mesh: Mesh, params_template, learning_rate: float,
           "gather lives in the scanned body (an unscanned stack would "
           "re-assemble every layer at once -- full residency, nothing "
           "sharded)")
-    if overlap_grad_reduce:
-      raise ValueError(
-          "fsdp_blocks=True cannot compose with overlap_grad_reduce: "
-          "the gather hook's backward IS the block's in-loop gradient "
-          "reduce-scatter; a second in-backward reduction would "
-          "double-reduce the block cotangents")
     if int(mesh.shape[TENSOR_AXIS]) != 1:
       raise ValueError(
           "fsdp_blocks=True requires a 1-wide tensor axis: tensor "
@@ -685,8 +636,6 @@ def make_train_step(mesh: Mesh, params_template, learning_rate: float,
           attn_inner_block=attn_inner_block,
           remat_policy=remat_policy,
           expert_axis=data_axis,
-          grad_reduce_axes=((data_axis, SEQ_AXIS)
-                            if overlap_grad_reduce else None),
           fsdp_gather_hook=fsdp_hook)
       return (_loss_from_logits(logits, labels)
               + moe_aux_weight * moe_aux)

@@ -75,8 +75,7 @@ flags.DEFINE_boolean("packed_sequences", False,
                      "DeviceFeeder (prefetch overlap measured via "
                      "feed_stall_fraction). transformer_lm training "
                      "only; composes with --steps_per_dispatch/"
-                     "--num_grad_accum/--overlap_gradient_reduction; "
-                     "exclusions in validation.py.")
+                     "--num_grad_accum; exclusions in validation.py.")
 flags.DEFINE_integer("input_prefetch_depth", None,
                      "Host->device prefetch depth of the DeviceFeeder "
                      "in batches (the StagingArea/MultiDeviceIterator "
@@ -198,8 +197,6 @@ flags.DEFINE_string("data_name", None,
 flags.DEFINE_boolean("distortions", False,
                      "Enable full image distortions (ref :199-202; reference "
                      "default True, flipped off here: synthetic-first).")
-flags.DEFINE_float("gpu_memory_frac_for_testing", 0.0,
-                   "Kept for CLI parity; no-op on TPU (ref :336-342).")
 flags.DEFINE_boolean("use_fp16", False,
                      "Use reduced precision activations/gradients. On TPU "
                      "this means bfloat16 (ref use_fp16 :464-470).")
@@ -247,7 +244,7 @@ flags.DEFINE_boolean("shard_params", False,
                      "the step re-assembles them per builder-layer "
                      "bucket / per scanned transformer block INSIDE "
                      "the forward/backward with one packed all-gather "
-                     "each (ops/overlap.py gather_params; the bucket "
+                     "each (ops/sharded.py gather_params; the bucket "
                      "bound is --reduce_bucket_mb, default 4 MiB), so "
                      "peak param residency is one bucket/block and "
                      "steady-state per-device param HBM is |params|/n "
@@ -259,13 +256,13 @@ flags.DEFINE_boolean("shard_params", False,
                      "--shard_optimizer_state (elementwise-optimizer "
                      "family, same exclusions; validation.py); under "
                      "--num_grad_accum the in-compute gathers "
-                     "disengage (one whole-tree gather per step, like "
-                     "the overlap hooks' accum rule).")
+                     "disengage (one whole-tree gather and one scatter "
+                     "of the accumulated tree per step).")
 flags.DEFINE_enum("partitioner", None, ("manual", "gspmd"),
                   "Who places the collectives in the sharded training "
                   "step. 'manual' (the None default) = the hand-placed "
-                  "shard_map programs (ops/sharded.py + ops/overlap.py; "
-                  "every golden contract pins them byte-identically). "
+                  "shard_map programs (ops/sharded.py; every golden "
+                  "contract pins them byte-identically). "
                   "'gspmd' = the SAME step body lowered under plain "
                   "jit with NamedSharding-annotated state/batch on the "
                   "same ('batch', 'model') mesh, letting the XLA SPMD "
@@ -300,10 +297,6 @@ flags.DEFINE_integer("agg_small_grads_max_bytes", 0,
                      "before the all-reduce (ref :554-557; 0 = off).")
 flags.DEFINE_integer("agg_small_grads_max_group", 10,
                      "Max number of small gradients per pack (ref :558-560).")
-flags.DEFINE_integer("allreduce_merge_scope", 1,
-                     "Accepted for parity, no TPU effect: ScopedAllocator "
-                     "merge hint; XLA schedules collectives itself "
-                     "(ref :561-566).")
 flags.DEFINE_integer("gradient_repacking", 0,
                      "Re-split the concatenated gradient vector into this "
                      "many evenly-sized chunks for reduction (ref "
@@ -320,43 +313,20 @@ flags.DEFINE_boolean("compact_gradient_transfer_f32", False,
                      "bytes; a precision note is logged -- NOT "
                      "bit-identical to the f32 wire). Requires "
                      "--compact_gradient_transfer AND a reduction path "
-                     "that repacks the wire (--overlap_gradient_reduction "
-                     "or a packed reducer flag); the default per-leaf "
-                     "pmean has nothing to compact (validation.py).")
-flags.DEFINE_boolean("overlap_gradient_reduction", False,
-                     "Overlap gradient communication with backward "
-                     "compute: size-bounded gradient buckets "
-                     "(--reduce_bucket_mb) each reduce as one collective "
-                     "issued IN the backward pass (identity-with-"
-                     "custom_vjp hooks at layer boundaries; per scanned "
-                     "block for scan-over-layers models), so layer L's "
-                     "all-reduce runs while layer L-1's backward is still "
-                     "computing -- the pipelining the reference's chunked "
-                     "batch_allreduce/--gradient_repacking existed for "
-                     "(ref: batch_allreduce.py:391-481). f32 wire "
-                     "gradients stay bit-identical to the post-hoc path "
-                     "(ops/overlap.py). Replicated-family "
-                     "--variable_update only; under --num_grad_accum the "
-                     "reduction stays post-hoc on the accumulated tree "
-                     "(one collective per step); exclusive with the "
-                     "spec/repacking/small-grad/hierarchical reducers "
+                     "that repacks the wire (a packed reducer flag); the "
+                     "default per-leaf pmean has nothing to compact "
                      "(validation.py).")
 flags.DEFINE_integer("reduce_bucket_mb", None,
-                     "Gradient-reduction bucket bound in MiB for "
-                     "--overlap_gradient_reduction (default 4): leaves "
-                     "group at builder-layer granularity and merge into "
-                     "buckets of at most this size, one collective per "
-                     "bucket (ops/overlap.py; the granularity lever the "
-                     "reference's --gradient_repacking chunk count "
-                     "turned, ref :499-502).", lower_bound=1)
+                     "Bound in MiB of one FSDP gather bucket under "
+                     "--shard_params (default 4): parameter leaves group "
+                     "at builder-layer granularity and merge into "
+                     "buckets of at most this size, one packed "
+                     "all-gather (and, going back, one reduce-scatter) "
+                     "per bucket (ops/sharded.py fsdp_plan_buckets).",
+                     lower_bound=1)
 flags.DEFINE_boolean("hierarchical_copy", False,
                      "Two-level reduction: grouped psum within contiguous "
                      "device groups, then across them (ref :507-513).")
-flags.DEFINE_integer("network_topology", 0,
-                     "Topology hint index (ref constants.py:21-24).")
-flags.DEFINE_enum("local_parameter_device", "cpu", ("cpu", "gpu", "tpu"),
-                  "Device for parameter-server-style variable placement "
-                  "(ref :514-517).")
 flags.DEFINE_enum("optimizer", "sgd", ("sgd", "momentum", "rmsprop", "adam",
                                        "lars"),
                   "Optimizer (ref :414-417; lars added: standard for "
@@ -661,9 +631,6 @@ flags.DEFINE_list("ps_hosts", [], "Parameter-server hosts (ref :574).")
 flags.DEFINE_list("worker_hosts", [], "Worker hosts (ref :575).")
 flags.DEFINE_string("controller_host", None, "Controller host (ref :576).")
 flags.DEFINE_integer("task_index", 0, "Task index (ref :577).")
-flags.DEFINE_string("server_protocol", "grpc", "Cluster wire protocol "
-                    "(ref :578); the TPU coordination service speaks its "
-                    "own protocol, flag kept for parity.")
 flags.DEFINE_string("coordinator_address", None,
                     "host:port of the DCN coordination service "
                     "(kungfu-run analog, SURVEY 2.9).")
@@ -673,8 +640,6 @@ flags.DEFINE_integer("process_index", 0, "This process's rank.")
 # Input pipeline knobs (ref :203-269).
 flags.DEFINE_integer("num_intra_threads", None,
                      "Host compute threads (ref :203-208).")
-flags.DEFINE_integer("num_inter_threads", None,
-                     "Host inter-op threads (ref :209-214).")
 flags.DEFINE_integer("datasets_prefetch_buffer_size", 2,
                      "Device prefetch depth (ref datasets_* :243-269).")
 flags.DEFINE_integer("datasets_num_private_threads", None,
@@ -694,20 +659,16 @@ flags.DEFINE_enum("resize_method", "bilinear",
 flags.DEFINE_string("input_preprocessor", "default",
                     "Name of the input preprocessor to use "
                     "(ref: benchmark_cnn.py:179-182).")
-flags.DEFINE_boolean("winograd_nonfused", True,
-                     "No-op on TPU; kept for CLI parity (ref :3285-3297).")
-flags.DEFINE_boolean("sparse_to_dense_grads", False,
-                     "Densify sparse gradients (ref :518-519; JAX grads are "
-                     "dense, kept for parity).")
 flags.DEFINE_enum("loss_type_to_report", "total_loss",
                   ("base_loss", "total_loss"),
                   "Which loss the step line prints (ref :346-353).")
 
 # -- Reference-CLI parity corpus ---------------------------------------------
-# The remaining reference flags, so its command lines parse here. Wired
-# ones say so; the rest are accepted no-ops (changing them from their
-# defaults logs a note at setup -- benchmark._NOOP_PARITY_FLAGS) or are
-# rejected in validation with the TPU-native alternative named.
+# The remaining reference flags that mean something here: wired ones
+# say so, the rest are rejected in validation with the TPU-native
+# alternative named. Reference flags with no TPU meaning (cuDNN, MKL,
+# grappler, tf.data, GPU thread pools) are not defined: MIGRATION.md
+# lists them.
 flags.DEFINE_boolean("datasets_repeat_cached_sample", False,
                      "Repeat the first input sample forever to emulate "
                      "memory-speed IO (wired into the record stream; "
@@ -732,12 +693,6 @@ flags.DEFINE_string("trt_mode", "",
                     "post-training quantization, quantization.py). "
                     "Requires --forward_only with --aot_save_path; "
                     "empty keeps the training compute dtype.")
-flags.DEFINE_boolean("freeze_when_forward_only", False,
-                     "Accepted for parity: freezing IS the AOT export "
-                     "(--aot_save_path folds weights into constants; "
-                     "ref :155-157).")
-flags.DEFINE_integer("trt_max_workspace_size_bytes", 4 << 30,
-                     "No-op on TPU (TensorRT knob, ref :619-620).")
 flags.DEFINE_boolean("use_chrome_trace_format", True,
                      "Export --trace_events_file as Chrome trace-event "
                      "JSON (the reference's timeline.Timeline toggle, "
@@ -745,75 +700,6 @@ flags.DEFINE_boolean("use_chrome_trace_format", True,
                      "tracing.py); false writes the raw span records as "
                      "JSONL instead. The jax.profiler --trace_file "
                      "capture is unaffected (it writes its own format).")
-flags.DEFINE_boolean("xla", False,
-                     "No-op: XLA is the only execution path on TPU "
-                     "(ref :413).")
-flags.DEFINE_boolean("xla_compile", False,
-                     "No-op: the whole step is always jitted "
-                     "(ref :414-416).")
-flags.DEFINE_boolean("fuse_decode_and_crop", True,
-                     "No-op: the host pipeline always crops before the "
-                     "expensive resize (ref :227-230).")
-flags.DEFINE_boolean("distort_color_in_yiq", True,
-                     "No-op: color jitter runs via PIL enhancers, not "
-                     "the YIQ rotation (ref :231-234).")
-flags.DEFINE_boolean("datasets_use_prefetch", True,
-                     "No-op: the DeviceFeeder always prefetches "
-                     "(ref :243-247).")
-flags.DEFINE_integer("datasets_parallel_interleave_cycle_length", None,
-                     "No-op: shard reads interleave via the thread pool "
-                     "(ref :264-266).")
-flags.DEFINE_boolean("datasets_sloppy_parallel_interleave", False,
-                     "No-op (tf.data interleave knob, ref :267-269).")
-flags.DEFINE_integer("datasets_parallel_interleave_prefetch", None,
-                     "No-op (tf.data interleave knob, ref :270-272).")
-flags.DEFINE_boolean("use_multi_device_iterator", True,
-                     "No-op: the DeviceFeeder is the MultiDeviceIterator "
-                     "analog (ref :254-258).")
-flags.DEFINE_integer("multi_device_iterator_max_buffer_size", 1,
-                     "No-op (MultiDeviceIterator knob, ref :259-261).")
-flags.DEFINE_boolean("use_resource_vars", False,
-                     "No-op: JAX state is functional (ref :417-421).")
-flags.DEFINE_boolean("use_tf_layers", True,
-                     "No-op: one flax layer path (ref :422-425).")
-flags.DEFINE_boolean("use_python32_barrier", False,
-                     "No-op (CPython barrier workaround, ref :426-428).")
-flags.DEFINE_boolean("compute_lr_on_cpu", False,
-                     "No-op: the LR schedule is fused into the jitted "
-                     "step (ref :429-431).")
-flags.DEFINE_boolean("enable_optimizations", True,
-                     "No-op: XLA optimizations are always on "
-                     "(ref :432-434).")
-flags.DEFINE_string("rewriter_config", None,
-                    "No-op (grappler RewriterConfig, ref :435-438).")
-flags.DEFINE_boolean("allow_growth", None,
-                     "No-op (GPU memory growth, ref :330-332).")
-flags.DEFINE_boolean("force_gpu_compatible", False,
-                     "No-op (GPU pinned-memory knob, ref :333-335).")
-flags.DEFINE_string("gpu_indices", "",
-                    "No-op (GPU ring-order indices, ref :319-320).")
-flags.DEFINE_enum("gpu_thread_mode", "gpu_private",
-                  ("global", "gpu_private", "gpu_shared"),
-                  "No-op (GPU thread pools, ref :321-324).")
-flags.DEFINE_integer("per_gpu_thread_count", 0,
-                     "No-op (GPU thread pools, ref :325-329).")
-flags.DEFINE_boolean("use_unified_memory", False,
-                     "No-op (CUDA unified memory, ref :336-338).")
-flags.DEFINE_boolean("batchnorm_persistent", True,
-                     "No-op (cuDNN CUDNN_BATCHNORM_SPATIAL_PERSISTENT, "
-                     "ref :407-409).")
-flags.DEFINE_integer("autotune_threshold", None,
-                     "No-op (cuDNN autotune, ref :316-318).")
-flags.DEFINE_string("horovod_device", "",
-                    "No-op (Horovod device pinning; the SPMD data plane "
-                    "covers it, ref :568-569).")
-flags.DEFINE_boolean("mkl", False, "No-op (MKL build knob, ref :451).")
-flags.DEFINE_integer("kmp_blocktime", 0,
-                     "No-op (MKL env var, ref :452-455).")
-flags.DEFINE_string("kmp_affinity", "granularity=fine,verbose,compact,1,0",
-                    "No-op (MKL env var, ref :456-458).")
-flags.DEFINE_integer("kmp_settings", 1,
-                     "No-op (MKL env var, ref :459-460).")
 
 # Accepted in both paths: make_params(**kw) translates them, and
 # define_flags(aliases=ALIASES) materializes them as absl alias flags so

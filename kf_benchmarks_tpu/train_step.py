@@ -29,9 +29,11 @@ benchmark_one_step). Design:
 
 from __future__ import annotations
 
-import functools
+import dataclasses
+import enum
+import inspect
 import math
-from typing import Any, Callable, Optional
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +45,6 @@ import optax
 from kf_benchmarks_tpu import elastic as elastic_lib
 from kf_benchmarks_tpu import telemetry as telemetry_lib
 from kf_benchmarks_tpu import tracing
-from kf_benchmarks_tpu.ops import overlap as overlap_lib
 from kf_benchmarks_tpu.ops import sharded as sharded_lib
 from kf_benchmarks_tpu.parallel import kungfu
 from kf_benchmarks_tpu.parallel import mesh as mesh_lib
@@ -97,11 +98,9 @@ def l2_loss(params, single_op: bool = False):
   """0.5 * sum of squares over non-BN params (tf.nn.l2_loss semantics,
   ref: benchmark_cnn.py:3078-3099). ``single_op`` concatenates first
   (ref --single_l2_loss_op); numerically identical, kept as a knob."""
-  leaves = []
-  flat = jax.tree_util.tree_flatten_with_path(params)[0]
-  for path, leaf in flat:
-    if not _is_batch_norm_param(path):
-      leaves.append(leaf)
+  leaves = [leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(params)[0]
+            if not _is_batch_norm_param(path)]
   if not leaves:
     return jnp.float32(0.0)
   if single_op:
@@ -187,6 +186,797 @@ def _reduce_unclaimed(grads, claimed, reduce):
       [g if d else next(rest) for d, (_, g) in zip(done, flat)])
 
 
+class Exchange(enum.Enum):
+  """How a step's local gradients become the ones its optimizer applies.
+  ONE of these per step program, chosen by :func:`plan_step`."""
+  # ``strategy.reduce_gradients`` over the whole tree: a built reducer,
+  # the sum, nothing at all (local gradients), or the plain replica
+  # mean where the factor plane may not engage.
+  STRATEGY = "strategy"
+  # The plain replica mean with two data planes (parallel/kungfu.py): a
+  # dense kernel larger than its batch leaves the backward pass as the
+  # mean already, ``strategy.reduce_gradients`` takes the leaves left.
+  FACTORED_MEAN = "factored_mean"
+  # ZeRO (ops/sharded.py scatter_mean): reduce-scatter of the batch-axis
+  # mean onto this device's flat 1/n shard.
+  ZERO_SCATTER = "zero_scatter"
+  # FSDP under accumulation: the whole tree gathered before the
+  # microbatch scan, the accumulated gradients scattered after it.
+  FSDP_SCATTER = "fsdp_scatter"
+  # FSDP: the per-bucket / per-block gathers' backward pass has
+  # reduce-scattered every cotangent; nothing is left to exchange.
+  FSDP_IN_BACKWARD = "fsdp_in_backward"
+
+
+class Apply(enum.Enum):
+  """How the optimizer meets the exchanged gradients."""
+  PLAIN = "plain"            # one update of the whole per-replica tree
+  SEQUENTIAL = "sequential"  # async PS: every replica's own, in turn
+  SHARD = "shard"            # ZeRO / FSDP: on this device's 1/n shard
+
+
+@dataclasses.dataclass(frozen=True)
+class StepPlan:
+  """Everything about a step program that is decided before it is
+  traced (:func:`plan_step`); the stages below read it and decide
+  nothing themselves."""
+  # Axis system. 1-D ('replica',) meshes keep the exact legacy program
+  # (the golden contracts pin it); the 2-D ('batch', 'model') mesh
+  # behind --mesh_shape/--shard_optimizer_state shards the batch over
+  # 'batch' only (model-axis peers re-compute the same shard) while the
+  # stacked state spans both axes.
+  axis_data: str
+  axis_all: Any
+  num_replicas: int
+  data_replicas: int
+  exchange: Exchange
+  apply: Apply
+  # --partitioner=gspmd: the per-axis form of the tuple-axis gathers.
+  use_gspmd: bool
+  num_grad_accum: int
+  steps_per_dispatch: int
+  # Loss scale: the value a run starts from, whether the gradients are
+  # unscaled, whether the state machine runs and its doubling period.
+  init_loss_scale: float
+  use_loss_scale: bool
+  auto_loss_scale: bool
+  inc_every_n: Any
+  relaxed: bool
+  staged_vars: bool
+  health_stats: bool
+  noise_scale: bool
+  weight_decay: float
+  single_l2_loss_op: bool
+  training_accuracy: bool
+  # Total steps of a module with a training-progress schedule (NASNet
+  # drop-path's global-step ramp, ref: nasnet_utils.py:407-439, takes
+  # ``progress`` = step / total); 0: the module takes none.
+  progress_steps: int
+  # --shard_params: the full-shape (abstract) parameter tree, the
+  # top-level keys whose stacks the MODULE gathers per scanned block,
+  # and the bound of a step-level gather bucket.
+  fsdp_template: Any
+  fsdp_prefixes: Tuple[str, ...]
+  fsdp_bucket_bytes: int
+
+  @property
+  def two_d(self) -> bool:
+    return self.axis_data == BATCH_AXIS
+
+  @property
+  def sharded_state(self) -> bool:
+    return self.apply is Apply.SHARD
+
+  @property
+  def sharded_params(self) -> bool:
+    return self.exchange in (Exchange.FSDP_SCATTER,
+                             Exchange.FSDP_IN_BACKWARD)
+
+
+def plan_step(strategy, params, mesh, model, module=None,
+              compute_dtype=jnp.float32, total_train_steps=None) -> StepPlan:
+  """The :class:`StepPlan` of the step a run with ``strategy`` and
+  ``params`` on ``mesh`` dispatches. Nothing is traced. ``module`` (the
+  training module) gives the plan its FSDP template and its progress
+  schedule; a caller that asks only which exchange and which apply a
+  configuration gets may leave it out."""
+  num_replicas = mesh.devices.size
+  two_d = BATCH_AXIS in mesh.axis_names
+  axis_data = BATCH_AXIS if two_d else REPLICA_AXIS
+  axis_all = mesh_lib.state_axes(mesh) if two_d else REPLICA_AXIS
+  # --shard_optimizer_state: the strategy is the marker, the mechanics
+  # are the stages' and ops/sharded.py's. Requires the 2-D mesh
+  # (benchmark.py builds Nx1 when --mesh_shape is unset).
+  sharded_state = bool(getattr(strategy, "sharded_state", False))
+  if sharded_state and not two_d:
+    raise ValueError(
+        "--shard_optimizer_state requires the named 2-D ('batch', "
+        "'model') mesh (parallel/mesh.py build_mesh_2d); got axes "
+        f"{mesh.axis_names}")
+  # --shard_params (full FSDP, ZeRO-3): params live as the shard stacks
+  # of ops/sharded.fsdp_stacked_shards between steps and are
+  # re-assembled per builder-layer bucket (loss top) / per scanned block
+  # (the module's own hook, model.fsdp_gathered_prefixes) DURING the
+  # forward/backward; the optimizer applies on the shard and no
+  # trailing full-tree all-gather remains.
+  sharded_params = bool(getattr(params, "shard_params", False))
+  if sharded_params and not sharded_state:
+    raise ValueError(
+        "--shard_params requires --shard_optimizer_state: the FSDP "
+        "forward consumes the sharded family's scatter/apply machinery "
+        "(ops/sharded.py); validation.py rejects the pair upstream")
+  # --partitioner: who places the collectives. 'manual' (default) keeps
+  # the shard_map programs the golden contracts pin; 'gspmd' lowers the
+  # SAME per-replica body under plain jit with NamedSharding-annotated
+  # state/batch and lets XLA's SPMD partitioner place them (the
+  # twin-referee rule of analysis/audit.py diffs the two inventories).
+  # Sharded families only: the other strategies' collectives ARE their
+  # semantics (ppermute gossip, sequential PS apply).
+  use_gspmd = (getattr(params, "partitioner", None) or "manual") == "gspmd"
+  if use_gspmd and not sharded_state:
+    raise ValueError(
+        "--partitioner=gspmd covers the sharded training families "
+        "(--shard_optimizer_state [+ --shard_params]): the other "
+        "strategies' collectives are semantic hand placements, not "
+        "partitioning choices (validation.py rejects these upstream)")
+  # --num_grad_accum=M: the step scans M microbatches, accumulating
+  # gradients in f32 before ONE exchange and ONE optimizer apply (the
+  # memory lever: backward residuals are sized to B/M). M=1 keeps the
+  # exact monolithic program.
+  num_grad_accum = int(getattr(params, "num_grad_accum", None) or 1)
+  data_replicas = int(mesh.shape[axis_data])
+
+  # THE choice of the exchange. Under accumulation every kind reduces
+  # the ACCUMULATED tree once (a pinned invariant), so FSDP's in-compute
+  # gathers give way to one gather up front and one scatter after the
+  # scan (bit-identity is preserved; the param-residency win is
+  # accum=1's), and the factor plane, which reduces in the backward pass
+  # of every microbatch, stays out. It also stays out where anyone reads
+  # the per-replica gradients (the noise scale), on one data replica and
+  # beside a model axis; which LAYERS take it is the shape rule's
+  # (kungfu.factors_beat_product).
+  if sharded_params:
+    exchange = (Exchange.FSDP_IN_BACKWARD if num_grad_accum == 1
+                else Exchange.FSDP_SCATTER)
+  elif sharded_state:
+    exchange = Exchange.ZERO_SCATTER
+  elif (getattr(strategy, "plain_mean", False) and data_replicas > 1
+        and not (two_d and int(mesh.shape[MODEL_AXIS]) > 1)
+        and num_grad_accum == 1 and not params.track_grad_noise_scale):
+    exchange = Exchange.FACTORED_MEAN
+  else:
+    exchange = Exchange.STRATEGY
+  if sharded_state:
+    apply = Apply.SHARD
+  elif getattr(strategy, "sequential_apply", False):
+    apply = Apply.SEQUENTIAL
+  else:
+    apply = Apply.PLAIN
+
+  fsdp_template, fsdp_prefixes, fsdp_bucket_bytes = None, (), 0
+  if sharded_params:
+    fsdp_prefixes = tuple(getattr(model, "fsdp_gathered_prefixes", ()) or ())
+    mb = (getattr(params, "reduce_bucket_mb", None)
+          or sharded_lib.DEFAULT_BUCKET_MB)
+    fsdp_bucket_bytes = int(mb) * 1024 * 1024
+    if module is not None:
+      # Full-shape template (abstract -- nothing executes): the gather
+      # specs, the eval/accum whole-tree re-assembly and the checkpoint
+      # layout all key on it. Mirrors init_state's module.init exactly.
+      in_shapes = model.get_input_shapes("train")
+      in_dtypes = model.get_input_data_types("train")
+      sample = jnp.zeros(tuple(in_shapes[0]), in_dtypes[0])
+      fsdp_template = jax.eval_shape(
+          lambda: module.init({"params": jax.random.PRNGKey(0),
+                               "dropout": jax.random.PRNGKey(0)},
+                              sample))["params"]
+  # Loss-scale resolution (ref: benchmark_cnn.py:471-480 "None = model
+  # default"): float16 compute defaults to the model's scale (128);
+  # bfloat16 needs none unless explicitly requested.
+  init_loss_scale = 1.0
+  if params.use_fp16 and params.fp16_loss_scale is not None:
+    init_loss_scale = float(params.fp16_loss_scale)
+  elif params.use_fp16 and compute_dtype == jnp.float16:
+    init_loss_scale = float(model.get_fp16_loss_scale())
+  auto_loss_scale = bool(params.use_fp16 and
+                         params.fp16_enable_auto_loss_scale)
+  progress_steps = 0
+  if module is not None and "progress" in inspect.signature(
+      type(module).__call__).parameters:
+    # Total steps is the run's RESOLVED count (params.num_batches is
+    # None on default/--num_epochs runs).
+    if total_train_steps is None:
+      total_train_steps = int(getattr(params, "num_batches", None) or 0)
+    progress_steps = int(total_train_steps)
+  return StepPlan(
+      axis_data=axis_data, axis_all=axis_all, num_replicas=num_replicas,
+      data_replicas=data_replicas, exchange=exchange, apply=apply,
+      use_gspmd=use_gspmd, num_grad_accum=num_grad_accum,
+      steps_per_dispatch=int(
+          getattr(params, "steps_per_dispatch", None) or 1),
+      init_loss_scale=init_loss_scale,
+      use_loss_scale=auto_loss_scale or init_loss_scale != 1.0,
+      auto_loss_scale=auto_loss_scale,
+      inc_every_n=params.fp16_inc_loss_scale_every_n,
+      relaxed=getattr(params, "variable_consistency",
+                      "strong") == "relaxed",
+      staged_vars=bool(getattr(params, "staged_vars", False)),
+      # --health_stats (telemetry.py): the CONCRETE boolean benchmark.py
+      # resolved; a direct caller's unresolved None gets the exact legacy
+      # program. The stats read the one full update tree, which only the
+      # plain apply has (validation.py and resolve_health_stats reject /
+      # auto-disable the others; this re-guards direct callers).
+      health_stats=(bool(getattr(params, "health_stats", None))
+                    and apply is Apply.PLAIN),
+      noise_scale=bool(params.track_grad_noise_scale and num_replicas > 1),
+      weight_decay=params.weight_decay or 0.0,
+      single_l2_loss_op=params.single_l2_loss_op,
+      training_accuracy=bool(params.print_training_accuracy),
+      progress_steps=progress_steps, fsdp_template=fsdp_template,
+      fsdp_prefixes=fsdp_prefixes, fsdp_bucket_bytes=fsdp_bucket_bytes)
+
+
+def _squeeze(tree):
+  return jax.tree.map(lambda x: jnp.squeeze(x, axis=0), tree)
+
+
+def _expand(tree):
+  return jax.tree.map(lambda x: x[None], tree)
+
+
+# -- the four stages of a train step ----------------------------------------
+#
+# ``per_replica_train`` (make_step_fns) calls them in this order; each
+# reads the plan and owns one decision. They are plain functions: XLA's
+# ``op_name``s carry the ``named_scope``s and transforms, not these
+# names.
+
+
+class _Backward(NamedTuple):
+  """What :func:`_forward_backward` hands the later stages."""
+  # The local gradients: the full per-replica tree, or FSDP's shard tree
+  # where the gathers' backward pass has scattered them already.
+  grads: Any
+  base_loss: Any
+  total_loss: Any
+  batch_stats: Any
+  # The network's result (None under accumulation, whose scalar
+  # accuracies come averaged over the microbatches in ``accuracy``).
+  net_result: Any
+  accuracy: Any
+  # --packed_sequences under accumulation: the real-label count; or None.
+  token_weight: Any
+  noise_stats: Any
+  # This trace's record of the kernels on the factor plane, or None.
+  factor_plan: Any
+
+
+def _forward_backward(plan, model, module, state, forward_params,
+                      batch_stats, images, labels) -> _Backward:
+  """Stage 1: the loss and its gradients over this replica's batch (one
+  pass, or --num_grad_accum microbatches in a scan), unscaled."""
+  token_weight_fn = getattr(model, "token_weight_fn", None)
+  fsdp_in_backward = plan.exchange is Exchange.FSDP_IN_BACKWARD
+  if plan.exchange is Exchange.FSDP_SCATTER:
+    # FSDP + accumulation: one whole-tree gather up front, full-tree
+    # microbatch scan, post-hoc scatter in the exchange.
+    with jax.named_scope("exchange"):
+      forward_params = sharded_lib.fsdp_gather_full(
+          forward_params, plan.fsdp_template, plan.fsdp_prefixes,
+          nested=plan.use_gspmd)
+  # Data-replica id: on the 2-D mesh, model-axis peers fold the SAME
+  # id (same batch shard, same dropout stream), which is what makes
+  # their local gradients identical by construction -- the free
+  # model-axis sub-slice in ops/sharded.py depends on it.
+  replica_id = lax.axis_index(plan.axis_data)
+  step_rng = jax.random.fold_in(
+      jax.random.fold_in(state.rng, state.step), replica_id)
+
+  apply_kwargs = {}
+  if plan.progress_steps > 0:
+    apply_kwargs["progress"] = (
+        state.step.astype(jnp.float32) / plan.progress_steps)
+  factor_plan = (kungfu.FactorExchange(plan.axis_data, plan.data_replicas)
+                 if plan.exchange is Exchange.FACTORED_MEAN else None)
+
+  def loss_fn(p, mb_images, mb_labels, bs, dropout_rng):
+    if fsdp_in_backward:
+      # FSDP per-bucket gather (ops/sharded.py fsdp_wrap_shards): the
+      # module-gathered scanned stacks stay shards for the per-block
+      # hook inside the nn.scan body, every other leaf of p below is
+      # its RE-ASSEMBLED full value, and jax.grad returns shard-layout
+      # gradients already reduce-scattered. The backward scatters the
+      # SCALED cotangents and the unscale divides by a power-of-two
+      # scale afterwards: bit-identical to dividing first, as the
+      # post-hoc path does.
+      with jax.named_scope("exchange"):
+        p = sharded_lib.fsdp_wrap_shards(
+            p, plan.fsdp_template, plan.fsdp_bucket_bytes, BATCH_AXIS,
+            MODEL_AXIS, exclude_prefixes=plan.fsdp_prefixes,
+            nested=plan.use_gspmd)
+    # One scope for the model, its loss and the weight decay: under
+    # jax.grad XLA's op_name reads ``jvp(forward)`` going forward and
+    # ``transpose(jvp(forward))`` coming back, which is how the
+    # benchmark's trace reader (benchmarks/spans.py) tells the two
+    # passes apart. Metadata only.
+    with jax.named_scope("forward"), kungfu.factor_exchange(factor_plan):
+      variables = {"params": p}
+      if bs:
+        variables["batch_stats"] = bs
+      (logits, aux_logits), updates = module.apply(
+          variables, mb_images, mutable=["batch_stats"],
+          rngs={"dropout": dropout_rng}, **apply_kwargs)
+      new_bs = updates.get("batch_stats", bs)
+      from kf_benchmarks_tpu.models.model import BuildNetworkResult
+      result = BuildNetworkResult(logits=(logits, aux_logits))
+      base_loss = model.loss_function(result, mb_labels)
+      total_loss = base_loss
+      if plan.weight_decay:
+        if fsdp_in_backward and plan.fsdp_prefixes:
+          # The scanned-stack leaves of p are SHARDS here: their L2
+          # term is exact in value but reassociated (_l2_loss_mixed;
+          # the make_step_fns note logs it).
+          total_loss = total_loss + plan.weight_decay * _l2_loss_mixed(
+              p, plan.fsdp_prefixes, plan.axis_all,
+              single_op=plan.single_l2_loss_op)
+        else:
+          total_loss = total_loss + plan.weight_decay * l2_loss(
+              p, single_op=plan.single_l2_loss_op)
+      scaled = total_loss * state.loss_scale
+    return scaled, (base_loss, total_loss, new_bs, result)
+
+  accuracy = None
+  token_weight = None
+  if plan.num_grad_accum > 1:
+    # Microbatched accumulation (--num_grad_accum=M): one scan
+    # iteration per microbatch, so the compiled program carries ONE
+    # microbatch-sized forward+backward regardless of M. Gradients
+    # accumulate in f32 and are divided once: the mean over
+    # microbatches, the monolithic step's estimator up to float
+    # reassociation. Everything downstream sees exactly one gradient
+    # tree per step.
+    m = plan.num_grad_accum
+    if images.shape[0] % m:
+      raise ValueError(
+          f"--num_grad_accum={m} must divide the per-replica batch "
+          f"size {images.shape[0]} (validation.py admits only "
+          "configurations where it can)")
+    split = lambda x: x.reshape((m, x.shape[0] // m) + x.shape[1:])
+    mb_images = split(images)
+    mb_labels = jax.tree.map(split, labels)
+    grad_fn = jax.grad(loss_fn, has_aux=True)
+    # Scan carries start as zeros; inside the shard_map body what they
+    # accumulate varies over every axis the batch OR the parameters vary
+    # over, so the zeros are pcast to match (sequence.py vary_like).
+    from kf_benchmarks_tpu.parallel import sequence as sequence_lib
+    param_axes = tuple(sorted(set().union(
+        *(jax.typeof(p).vma for p in jax.tree.leaves(forward_params)))))
+
+    def _vary(tree):
+      leaves, treedef = jax.tree_util.tree_flatten(tree)
+      return jax.tree_util.tree_unflatten(
+          treedef,
+          list(sequence_lib.vary_like(images, tuple(leaves),
+                                      extra_axes=param_axes)))
+
+    g0 = _vary(jax.tree.map(
+        lambda p: jnp.zeros(p.shape, jnp.float32), forward_params))
+    bl0, tl0, w0 = _vary((jnp.zeros((), jnp.float32),
+                          jnp.zeros((), jnp.float32),
+                          jnp.zeros((), jnp.float32)))
+    bs0 = _vary(batch_stats)
+
+    def mb_body(carry, xs):
+      g_acc, bl_acc, tl_acc, w_acc, acc_acc, bs = carry
+      imgs, lbls, idx = xs
+      # Distinct dropout stream per microbatch (a shared one would
+      # correlate masks across the effective batch).
+      rng_i = jax.random.fold_in(step_rng, idx)
+      g, (bl, tl, bs_next, result) = grad_fn(forward_params, imgs,
+                                             lbls, bs, rng_i)
+      # --packed_sequences: each microbatch's loss is its own
+      # token-MEAN (ops/fused_loss.py); weight the accumulation by
+      # the microbatch's real-label count so the accumulated step is
+      # the PER-REPLICA token-weighted estimator -- sum over tokens /
+      # total tokens -- not a mean-of-means over unevenly packed
+      # microbatches. Deliberate scope: the CROSS-replica exchange
+      # stays the equal-weight pmean (token counts concentrate tightly
+      # at ~97% packing; weighting it would rebuild every pinned
+      # reduction path for a second-order correction), while the
+      # REPORTED metrics are exactly token-weighted
+      # (pmean(loss*w)/pmean(w)). Unpacked runs keep mb_w = 1.
+      if token_weight_fn is None:
+        # Exact legacy equal-weight accumulation (bit-pinned).
+        mb_w = jnp.float32(1.0)
+        g_acc = jax.tree.map(lambda a, x: a + x.astype(jnp.float32),
+                             g_acc, g)
+        wb, wt = bl, tl
+      else:
+        mb_w = jnp.sum(token_weight_fn(imgs))
+        g_acc = jax.tree.map(
+            lambda a, x: a + x.astype(jnp.float32) * mb_w, g_acc, g)
+        wb, wt = bl * mb_w, tl * mb_w
+      if acc_acc is not None:
+        mb_acc = model.accuracy_function(result, lbls)
+        acc_acc = {k: acc_acc[k] + (v if token_weight_fn is None
+                                    else v * mb_w)
+                   for k, v in mb_acc.items() if k in acc_acc}
+      return (g_acc, bl_acc + wb, tl_acc + wt,
+              w_acc + mb_w, acc_acc, bs_next), None
+
+    acc0 = None
+    if plan.training_accuracy:
+      # Keys from an abstract eval (no FLOPs): scalar metrics only.
+      lb0 = jax.tree.map(lambda x: x[0], mb_labels)
+      shapes = jax.eval_shape(
+          lambda: model.accuracy_function(
+              loss_fn(forward_params, mb_images[0], lb0,
+                      batch_stats, step_rng)[1][3], lb0))
+      acc0 = _vary({k: jnp.zeros((), jnp.float32)
+                    for k, v in shapes.items() if not v.shape})
+    (g_acc, bl_acc, tl_acc, w_sum, acc_acc, new_bs), _ = lax.scan(
+        mb_body, (g0, bl0, tl0, w0, acc0, bs0),
+        (mb_images, mb_labels, jnp.arange(m)))
+    # Normalizer: microbatch count on the legacy path; the summed
+    # real-label count on the packed path (w_sum = sum of mb_w), so
+    # gradients and losses come out as the monolithic token-weighted
+    # estimator up to float reassociation of the batch split.
+    norm = (jnp.float32(m) if token_weight_fn is None
+            else jnp.maximum(w_sum, 1.0))
+    if token_weight_fn is not None:
+      # The scan's summed per-microbatch counts ARE this batch's
+      # real-label total (0/1 weights in exact f32 integer range):
+      # reused at metrics time so the two normalizers cannot drift.
+      token_weight = w_sum
+    grads = jax.tree.map(lambda a, p: (a / norm).astype(p.dtype),
+                         g_acc, forward_params)
+    base_loss = bl_acc / norm
+    total_loss = tl_acc / norm
+    net_result = None
+    if acc_acc is not None:
+      accuracy = {k: v / norm for k, v in acc_acc.items()}
+  else:
+    grads, (base_loss, total_loss, new_bs, net_result) = jax.grad(
+        loss_fn, has_aux=True)(forward_params, images, labels,
+                               batch_stats, step_rng)
+  if plan.use_loss_scale:
+    grads = jax.tree.map(lambda g: g / state.loss_scale, grads)
+  noise_stats = None
+  if plan.noise_scale:
+    # Measured on the pre-reduction per-replica grads (the small-batch
+    # estimate) vs their replica mean (the large-batch estimate):
+    # elastic.noise_scale_stats, KungFu's in-collective monitoring
+    # (SURVEY 2.9).
+    with jax.named_scope("metrics"):
+      noise_stats = elastic_lib.noise_scale_stats(
+          grads, plan.axis_data, images.shape[0])
+  return _Backward(grads, base_loss, total_loss, new_bs, net_result,
+                   accuracy, token_weight, noise_stats, factor_plan)
+
+
+def _exchange(plan, strategy, grads, factor_plan):
+  """Stage 2: the local gradients -> the ones the optimizer applies
+  (the per-replica tree, or this device's shards where the state is
+  sharded), by the plan's ONE exchange."""
+  if plan.exchange is Exchange.FSDP_IN_BACKWARD:
+    # jax.grad's output IS the shard tree (ops/sharded.py gather_params
+    # bwd -- elementwise identical to the post-hoc scatter below). No
+    # full gradient tree ever existed.
+    return grads
+  # "exchange" names the whole reduction -- the casts, concatenations
+  # and scalings around the collectives too, which a reader that goes
+  # by opcode alone would miss (benchmarks/spans.py).
+  with jax.named_scope("exchange"):
+    if plan.exchange is Exchange.FSDP_SCATTER:
+      # Post-hoc scatter of the accumulated full tree onto the FSDP
+      # layout (per-layer rows for the scanned stacks) -- elementwise
+      # the same values as scatter_mean.
+      return sharded_lib.fsdp_scatter_mean(grads, plan.fsdp_prefixes)
+    if plan.exchange is Exchange.ZERO_SCATTER:
+      # Reduce-scatter of the batch-axis mean, BIT-IDENTICAL to the
+      # replicated pmean (ops/sharded.py layout contract), then the
+      # free model-axis sub-slice. The full gradient tree dies here;
+      # only this device's 1/n flat shard flows on.
+      return sharded_lib.scatter_mean(grads)
+    reduce = lambda g: strategy.reduce_gradients(g, plan.axis_data)
+    if factor_plan is None or not factor_plan.claimed:
+      return reduce(grads)
+    grads = _reduce_unclaimed(grads, factor_plan.claimed, reduce)
+    counters = factor_plan.counters()
+    tracing.active().set_static("factor_exchange", counters)
+    from kf_benchmarks_tpu.utils import log as log_util
+    log_util.log_fn(
+        "factor exchange: %d dense layer(s) form the mean gradient "
+        "from all-gathered factors: %.1f MB kept off the "
+        "all-reduce, %.1f MB gathered instead" % (
+            counters["layers"],
+            counters["bytes_off_allreduce"] / 1e6,
+            counters["bytes_gathered"] / 1e6))
+    return grads
+
+
+def _bank_deferred(plan, grads, buffers):
+  """Between exchange and apply: the auto loss scale's finite check of
+  THIS step's gradients and --variable_consistency=relaxed's bank.
+  Returns (the gradients to apply, ``fresh_finite`` or None, the new
+  buffers)."""
+  # The loss-scale state machine keys on THIS step's fresh gradients
+  # (they reflect the current scale), even when the applied gradients
+  # are the deferred ones (ref: variable_mgr_util.py:51-139). The pmin
+  # over BOTH axes is exact on the sharded path (the shards tile the
+  # reduced tree once) and on the replicated 2-D path (model-axis peers
+  # hold identical gradients).
+  fresh_finite = None
+  if plan.auto_loss_scale:
+    ok = jnp.all(jnp.stack(
+        [jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(grads)]))
+    # Globally uniform decision (pmin across replicas) so every carried
+    # scalar stays replicated (ref chief-only NaN check + broadcast,
+    # variable_mgr.py:186-193).
+    fresh_finite = lax.pmin(ok.astype(jnp.int32),
+                            plan.axis_all).astype(bool)
+  new_buffers = dict(buffers)
+  if plan.relaxed:
+    # Apply the PREVIOUS step's reduced gradients and bank this step's
+    # for the next (the reference's deferred StagingArea gradients,
+    # batch_allreduce.py:353-388; SURVEY 7.4). Non-finite fresh
+    # gradients are never banked: the old bank stays.
+    banked = grads
+    if fresh_finite is not None:
+      banked = jax.tree.map(
+          lambda a, b: jnp.where(fresh_finite, a, b),
+          grads, buffers["deferred_grads"])
+    new_buffers["deferred_grads"] = banked
+    grads = buffers["deferred_grads"]
+  return grads, fresh_finite, new_buffers
+
+
+class _Applied(NamedTuple):
+  """What :func:`_apply` leaves: the new state's parts, and the update
+  tree the health stats read (None under the sequential apply)."""
+  params: Any
+  opt_state: Any
+  batch_stats: Any
+  loss_scale: Any
+  normal_steps: Any
+  updates: Any
+
+
+def _apply(plan, strategy, tx, state, model_params, opt_state,
+           batch_stats, grads, new_bs, fresh_finite) -> _Applied:
+  """Stage 3: the optimizer's application by the plan's ONE kind, the
+  strategy's weight transforms around it, and the auto loss scale's
+  skip."""
+  with jax.named_scope("exchange"):
+    model_params_pre = strategy.pre_update(model_params, state.step,
+                                           plan.axis_data)
+  updates = None
+  if plan.apply is Apply.SHARD:
+    # The ZeRO apply (the reference's central variable placement
+    # rendered SPMD, variable_mgr.py:201-243): run the optimizer on
+    # the 1/n shard ONLY (elementwise optimizers; validation.py
+    # rejects LARS). --shard_params: the state ALREADY holds this
+    # device's shards and the updated shards flow straight back into
+    # it. Without it, params are replicated: the shard is a free local
+    # slice and the updated params return by all-gather.
+    param_shards = (model_params_pre if plan.sharded_params
+                    else sharded_lib.local_shards(model_params_pre))
+    with jax.named_scope("optimizer_apply"):
+      updates, new_opt_state = tx.update(grads, opt_state, param_shards)
+      new_shards = optax.apply_updates(param_shards, updates)
+    with jax.named_scope("exchange"):
+      new_params = (new_shards if plan.sharded_params else
+                    sharded_lib.gather_tree(new_shards, model_params_pre,
+                                            nested=plan.use_gspmd))
+  elif plan.apply is Apply.SEQUENTIAL:
+    # Async PS with a stateful optimizer (strategies.py): serialize
+    # every replica's unaveraged gradient through the SHARED optimizer
+    # state, in replica-index order -- the deterministic SPMD rendering
+    # of the PS's one-at-a-time applications (benchmark_cnn.py:520-522).
+    with jax.named_scope("exchange"):
+      g_all = jax.tree.map(
+          lambda g: lax.all_gather(g, plan.axis_data, axis=0), grads)
+
+    def _apply_one(carry, g):
+      prms, ost = carry
+      upd, ost2 = tx.update(g, ost, prms)
+      # Every application within the round sees the ROUND's schedule
+      # count (momentum/variance state still advances per
+      # application); the round bump happens once, below.
+      ost2 = _sync_schedule_counts(ost, ost2)
+      return (optax.apply_updates(prms, upd), ost2), None
+
+    # The named_scope rides into HLO op_name metadata; the program-
+    # contract auditor (analysis/contracts.py) keys the one-apply-
+    # per-step check on it.
+    with jax.named_scope("optimizer_apply"):
+      (new_params, new_opt_state), _ = lax.scan(
+          _apply_one, (model_params_pre, opt_state), g_all)
+    new_opt_state = _sync_schedule_counts(opt_state, new_opt_state,
+                                          bump=1)
+  else:
+    with jax.named_scope("optimizer_apply"):
+      updates, new_opt_state = tx.update(grads, opt_state,
+                                         model_params_pre)
+      new_params = optax.apply_updates(model_params_pre, updates)
+  with jax.named_scope("exchange"):
+    new_params = strategy.post_update(new_params, state.step,
+                                      plan.axis_data)
+    new_bs = strategy.sync_batch_stats(new_bs, plan.axis_data)
+
+  if not plan.auto_loss_scale:
+    return _Applied(new_params, new_opt_state, new_bs, state.loss_scale,
+                    state.loss_scale_normal_steps, updates)
+  # Auto loss-scale state machine (ref: variable_mgr_util.py:51-139):
+  # any non-finite FRESH grad -> skip the update, halve scale; else
+  # count a normal step and double the scale every ``inc_every_n``.
+  # Under relaxed consistency the APPLIED gradients are the previous
+  # bank, which only ever admits finite values (banking gate), so the
+  # params/opt_state skip is unnecessary there by induction.
+  keep = lambda new, old: jax.tree.map(
+      lambda a, b: jnp.where(fresh_finite, a, b), new, old)
+  if not plan.relaxed:
+    new_params = keep(new_params, model_params)
+    new_opt_state = keep(new_opt_state, opt_state)
+  # batch_stats come from THIS step's forward in both modes: an
+  # overflowing forward must not poison the running statistics.
+  new_bs = keep(new_bs, batch_stats)
+  normal_steps = jnp.where(fresh_finite,
+                           state.loss_scale_normal_steps + 1, 0)
+  do_double = jnp.logical_and(fresh_finite,
+                              normal_steps >= plan.inc_every_n)
+  new_scale = jnp.where(
+      fresh_finite,
+      jnp.where(do_double, state.loss_scale * 2.0, state.loss_scale),
+      jnp.maximum(state.loss_scale / 2.0, 1.0))
+  normal_steps = jnp.where(do_double, 0, normal_steps)
+  return _Applied(new_params, new_opt_state, new_bs, new_scale,
+                  normal_steps, updates)
+
+
+def _step_metrics(plan, model, lr_fn, state, images, labels, model_params,
+                  grads, back: _Backward, applied: _Applied, fresh_finite):
+  """Stage 4: the step line's loss / accuracy / health reductions, apart
+  from the training arithmetic (benchmarks/spans.py). ``grads`` are the
+  APPLIED gradients (under relaxed consistency the one-step-stale
+  bank)."""
+  token_weight_fn = getattr(model, "token_weight_fn", None)
+  axis_data = plan.axis_data
+  base_loss, total_loss = back.base_loss, back.total_loss
+  with jax.named_scope("metrics"):
+    lr = lr_fn(state.step)
+    # Token-weighted metric combine (--packed_sequences: the model
+    # exposes images -> (B, T) per-token loss weights): per-replica
+    # losses are already normalized by this replica's real-label count
+    # (ops/fused_loss.py) and replicas pack different document mixes,
+    # so the global token-mean is pmean(loss * w) / pmean(w), from the
+    # SAME packed vector collective that carries the losses (no more
+    # collectives than unpacked: the lm_packed audit rule pins it).
+    tok_w = None
+    if token_weight_fn is not None:
+      tok_w = (back.token_weight if back.token_weight is not None
+               else jnp.sum(token_weight_fn(images)))
+    wm_safe = None
+    if plan.health_stats:
+      # In-step health stats (telemetry.py): grad norm, update/param
+      # ratio, non-finite leaf count, loss scale + skip flag, read from
+      # the step's post-reduction values. Each replica reduces a 1/n
+      # SLICE of every tree (telemetry.health_partials) and the
+      # pre-scaled partial sums ride the LOSS pmean: one f32 vector
+      # all-reduce replaces the two scalar loss pmeans, so the
+      # health-on program carries NO extra collective and computes
+      # bit-identical loss values (both pinned in
+      # tests/test_telemetry.py). ``updates`` exists on every
+      # health-admitted path (the plain apply: plan_step).
+      skipped = (1.0 - fresh_finite.astype(jnp.float32)
+                 if fresh_finite is not None else jnp.float32(0.0))
+      # The fresh-grad overflow skip only suppresses the applied
+      # update on the non-relaxed path (the relaxed bank admits finite
+      # gradients only, so its apply always lands).
+      suppressed = jnp.float32(0.0) if plan.relaxed else skipped
+      # Under --packed_sequences the two loss slots ride token-weighted
+      # (loss * w) and w itself is appended to the SAME vector, so the
+      # weighted combine still costs the one loss pmean.
+      bl32 = base_loss.astype(jnp.float32)
+      tl32 = total_loss.astype(jnp.float32)
+      loss_slots = (jnp.stack([bl32, tl32]) if tok_w is None else
+                    jnp.stack([bl32 * tok_w, tl32 * tok_w]))
+      vec = [loss_slots, telemetry_lib.health_partials(
+          grads, model_params, applied.updates, axis_data)]
+      if tok_w is not None:
+        vec.append(jnp.stack([tok_w]))
+      packed = lax.pmean(jnp.concatenate(vec), axis_data)
+      health_totals = packed[2:] if tok_w is None else packed[2:-1]
+      if tok_w is None:
+        bl_m, tl_m = packed[0], packed[1]
+      else:
+        wm_safe = jnp.maximum(packed[-1], 1e-30)
+        bl_m, tl_m = packed[0] / wm_safe, packed[1] / wm_safe
+      metrics = {
+          "base_loss": bl_m,
+          "total_loss": tl_m,
+          "learning_rate": lr,
+          "health": telemetry_lib.health_finalize(
+              health_totals, applied.loss_scale, skipped, suppressed),
+      }
+    elif tok_w is not None:
+      # One 3-vector pmean replaces the two scalar loss pmeans: the
+      # packed program's collective count stays <= the unpacked one.
+      packed = lax.pmean(
+          jnp.stack([base_loss.astype(jnp.float32) * tok_w,
+                     total_loss.astype(jnp.float32) * tok_w, tok_w]),
+          axis_data)
+      wm_safe = jnp.maximum(packed[2], 1e-30)
+      metrics = {
+          "base_loss": packed[0] / wm_safe,
+          "total_loss": packed[1] / wm_safe,
+          "learning_rate": lr,
+      }
+    else:
+      # Metric pmeans reduce over the DATA axis only: model-axis peers
+      # compute the identical loss from the identical batch shard, so
+      # the batch-group mean is already the global value -- and it is
+      # bit-identical to the replicated path's B-contribution pmean.
+      metrics = {
+          "base_loss": lax.pmean(base_loss, axis_data),
+          "total_loss": lax.pmean(total_loss, axis_data),
+          "learning_rate": lr,
+      }
+    if tok_w is not None and wm_safe is not None:
+      # Label coverage of the packed batch (real label positions /
+      # slots): the in-step packing-efficiency signal next to the
+      # host-side feed line (observability.packing_feed_line). Post-
+      # collective scalar math, no extra communication.
+      metrics["real_token_fraction"] = wm_safe / jnp.float32(
+          sum(math.prod(l.shape) for l in jax.tree.leaves(labels)) or 1)
+    if plan.steps_per_dispatch > 1:
+      # Replica-mean global norm of the reduced gradients (under relaxed
+      # consistency: of the APPLIED, one-step-stale bank), the per-step
+      # health scalar the chunked mode stacks beside loss and lr. K=1
+      # omits it: the single-step program stays the exact pinned one.
+      if "health" in metrics:
+        # The health vector already carries this exact norm (same grads
+        # tree, sharded reduction): no second full-tree pass.
+        metrics["grad_norm"] = metrics["health"][0]
+      elif plan.sharded_state:
+        # The flat shards tile the reduced gradient exactly once: the
+        # psum of per-shard square-sums over BOTH axes is the global one.
+        metrics["grad_norm"] = jnp.sqrt(lax.psum(
+            sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads)), plan.axis_all))
+      else:
+        metrics["grad_norm"] = lax.pmean(
+            jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                         for g in jax.tree.leaves(grads))), axis_data)
+    if plan.training_accuracy:
+      # Under microbatching the per-microbatch scalar accuracies were
+      # averaged inside the scan (equal microbatch sizes make that the
+      # effective-batch value); monolithic computes them here.
+      acc = (back.accuracy if back.accuracy is not None
+             else model.accuracy_function(back.net_result, labels))
+      # Scalars only: detection accuracy_functions also return per-box
+      # arrays (decoded predictions), which are not replicated step
+      # metrics. Packed runs weight each replica's (already token-
+      # weighted) accuracy by its real-label count, like the losses.
+      if tok_w is not None and wm_safe is not None:
+        metrics.update({k: lax.pmean(v * tok_w, axis_data) / wm_safe
+                        for k, v in acc.items() if jnp.ndim(v) == 0})
+      else:
+        metrics.update({k: lax.pmean(v, axis_data)
+                        for k, v in acc.items() if jnp.ndim(v) == 0})
+    # A model's own per-step counters (models/model.py
+    # ``step_counters``; mla_moe_lm: the expert layer's loads), read
+    # from what this step's forward left in ``batch_stats``: one small
+    # vector beside the losses, fetched with them, so no host sync and
+    # no collective of its own. None for a model without any.
+    counters = model.step_counters(applied.batch_stats)
+    if counters is not None:
+      metrics["counters"] = counters
+  if back.noise_stats is not None:
+    metrics["noise_scale_g2"], metrics["noise_scale_s"] = back.noise_stats
+  return metrics
+
+
 def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
                   mesh, compute_dtype=jnp.float32, total_train_steps=None):
   """Build (init_fn, train_step, eval_step, broadcast_init, train_chunk)
@@ -200,215 +990,38 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
   ``train_chunk`` is the device-resident multi-step program
   (--steps_per_dispatch=K > 1, else None): K applications of the SAME
   per-replica train step under one ``lax.scan``, so host dispatch is
-  paid once per K steps. Inputs carry a leading
-  staged-steps axis -- size K for real-data chunks, size 1 for the
-  synthetic resident batch (reused every scanned step, folding batch
-  "generation" into the program: no staged-batch HBM footprint and no
-  H2D at all). Per-step metrics come back stacked on a leading K axis;
-  the carry is the ordinary TrainState, so step numbering, the
-  fold_in(rng, step) dropout stream, LR schedules, and the loss-scale
-  state machine advance exactly as in K dispatches of ``train_step``.
-
-  ``--num_grad_accum=M`` > 1 microbatches INSIDE each train step (an
-  inner lax.scan over M batch slices accumulating f32 gradients before
-  one reduction + one optimizer apply), orthogonal to the K-step
-  dispatch chunking outside: K amortizes host/dispatch cost, M bounds
-  backward-residual HBM. Both default off (the exact monolithic
-  program).
+  paid once per K steps. Inputs carry a leading staged-steps axis --
+  size K for real-data chunks, size 1 for the synthetic resident batch
+  (reused every scanned step: no staged-batch HBM footprint and no H2D
+  at all). Per-step metrics come back stacked on a leading K axis; the
+  carry is the ordinary TrainState, so step numbering, the dropout
+  stream, LR schedules and the loss-scale state machine advance exactly
+  as in K dispatches of ``train_step``. ``--num_grad_accum=M``
+  microbatches INSIDE each step, orthogonal to it: K amortizes dispatch
+  cost, M bounds backward-residual HBM. Both default off.
   """
-  num_replicas = mesh.devices.size
-  # Axis system. 1-D ('replica',) meshes keep the exact legacy program
-  # (every golden contract is pinned against it); the named 2-D
-  # ('batch', 'model') mesh behind --mesh_shape/--shard_optimizer_state
-  # shards the batch over 'batch' only (model-axis peers re-compute the
-  # same shard) while the stacked state and the metric pmeans span both
-  # axes.
-  two_d = BATCH_AXIS in mesh.axis_names
-  axis_data = BATCH_AXIS if two_d else REPLICA_AXIS
-  axis_all = mesh_lib.state_axes(mesh) if two_d else REPLICA_AXIS
-  # --shard_optimizer_state: the strategy is the marker; the mechanics
-  # (reduce-scatter mean, shard apply, param all-gather) live below +
-  # ops/sharded.py. Requires the 2-D mesh (benchmark.py builds Nx1 when
-  # --mesh_shape is unset).
-  sharded_state = bool(getattr(strategy, "sharded_state", False))
-  if sharded_state and not two_d:
-    raise ValueError(
-        "--shard_optimizer_state requires the named 2-D ('batch', "
-        "'model') mesh (parallel/mesh.py build_mesh_2d); got axes "
-        f"{mesh.axis_names}")
-  # --shard_params (full FSDP, ZeRO-3): params live as the (n, k) /
-  # (n, L, k) shard stacks of ops/sharded.fsdp_stacked_shards between
-  # steps and are re-assembled per builder-layer bucket (loss top) /
-  # per scanned block (inside the nn.scan body -- the module's own
-  # gather hook, model.fsdp_gathered_prefixes) DURING the
-  # forward/backward; the optimizer applies on the shard and NO
-  # trailing full-tree all-gather remains -- peak param residency is
-  # one bucket/block, steady-state per-device param HBM is |params|/n.
-  sharded_params = bool(getattr(params, "shard_params", False))
-  if sharded_params and not sharded_state:
-    raise ValueError(
-        "--shard_params requires --shard_optimizer_state: the FSDP "
-        "forward consumes the sharded family's scatter/apply machinery "
-        "(ops/sharded.py); validation.py rejects the pair upstream")
-  # --partitioner: who places the collectives. 'manual' (default) keeps
-  # the exact legacy shard_map programs every golden contract pins;
-  # 'gspmd' lowers the SAME per-replica body under plain jit with
-  # NamedSharding-annotated state/batch and lets the XLA SPMD
-  # partitioner insert/re-place them (SNIPPETS [2]/[3] idiom; the
-  # analysis/audit.py twin-referee rule diffs the two inventories).
-  # Sharded families only: the replicated/gossip/PS strategies are
-  # hand-placed BY DESIGN (their collectives ARE the semantics --
-  # ppermute gossip, sequential PS apply); validation.py rejects the
-  # combinations upstream, this re-guards direct callers.
-  partitioner = getattr(params, "partitioner", None) or "manual"
-  use_gspmd = partitioner == "gspmd"
-  if use_gspmd and not sharded_state:
-    raise ValueError(
-        "--partitioner=gspmd covers the sharded training families "
-        "(--shard_optimizer_state [+ --shard_params]): the other "
-        "strategies' collectives are semantic hand placements, not "
-        "partitioning choices (validation.py rejects these upstream)")
-  fsdp_template = None
-  fsdp_module_prefixes = ()
-  fsdp_bucket_bytes = 0
-  if sharded_params:
-    fsdp_module_prefixes = tuple(
-        getattr(model, "fsdp_gathered_prefixes", ()) or ())
-    mb = (getattr(params, "reduce_bucket_mb", None)
-          or overlap_lib.DEFAULT_BUCKET_MB)
-    fsdp_bucket_bytes = int(mb) * 1024 * 1024
-    # Full-shape template (abstract -- nothing executes): the gather
-    # specs, the eval/accum whole-tree re-assembly and the checkpoint
-    # layout all key on it. Mirrors init_state's module.init exactly.
-    in_shapes = model.get_input_shapes("train")
-    in_dtypes = model.get_input_data_types("train")
-    sample = jnp.zeros(tuple(in_shapes[0]), in_dtypes[0])
-    fsdp_template = jax.eval_shape(
-        lambda: module.init({"params": jax.random.PRNGKey(0),
-                             "dropout": jax.random.PRNGKey(0)},
-                            sample))["params"]
-    if fsdp_module_prefixes and (params.weight_decay or 0.0):
-      from kf_benchmarks_tpu.utils import log as log_util
-      log_util.log_fn(
-          "shard_params: weight decay over the scanned parameter "
-          f"stack(s) {list(fsdp_module_prefixes)} reduces shard-"
-          "locally + one mesh psum (full blocks exist only one at a "
-          "time inside the scan): exact L2 value, reassociated -- "
-          "total_loss is not bit-identical to the replicated-param L2 "
-          "on this model family (pass --weight_decay=0 for bit-exact "
-          "A/Bs)")
-  weight_decay = params.weight_decay or 0.0
-  # Loss-scale resolution (ref: benchmark_cnn.py:471-480 "None = model
-  # default"): float16 compute defaults to the model's scale (128);
-  # bfloat16 needs none unless explicitly requested.
-  if params.use_fp16:
-    if params.fp16_loss_scale is not None:
-      init_loss_scale = float(params.fp16_loss_scale)
-    elif compute_dtype == jnp.float16:
-      init_loss_scale = float(model.get_fp16_loss_scale())
-    else:
-      init_loss_scale = 1.0
-  else:
-    init_loss_scale = 1.0
-  auto_loss_scale = bool(params.use_fp16 and
-                         params.fp16_enable_auto_loss_scale)
-  use_loss_scale = auto_loss_scale or init_loss_scale != 1.0
-  inc_every_n = params.fp16_inc_loss_scale_every_n
-
+  plan = plan_step(strategy, params, mesh, model, module=module,
+                   compute_dtype=compute_dtype,
+                   total_train_steps=total_train_steps)
+  axis_data = plan.axis_data
+  axis_all = plan.axis_all
+  token_weight_fn = getattr(model, "token_weight_fn", None)
+  if plan.fsdp_prefixes and plan.weight_decay:
+    from kf_benchmarks_tpu.utils import log as log_util
+    log_util.log_fn(
+        "shard_params: weight decay over the scanned parameter "
+        f"stack(s) {list(plan.fsdp_prefixes)} reduces shard-"
+        "locally + one mesh psum (full blocks exist only one at a "
+        "time inside the scan): exact L2 value, reassociated -- "
+        "total_loss is not bit-identical to the replicated-param L2 "
+        "on this model family (pass --weight_decay=0 for bit-exact "
+        "A/Bs)")
+  tracing.active().set_static("factor_exchange",
+                              kungfu.NO_FACTOR_EXCHANGE)
   state_specs = TrainState(
       step=P(), params=P(axis_all), opt_state=P(axis_all),
       batch_stats=P(axis_all), loss_scale=P(),
       loss_scale_normal_steps=P(), rng=P(), buffers=P(axis_all))
-  staged_vars = bool(getattr(params, "staged_vars", False))
-  relaxed = getattr(params, "variable_consistency", "strong") == "relaxed"
-  steps_per_dispatch = int(
-      getattr(params, "steps_per_dispatch", None) or 1)
-  # --num_grad_accum=M: the step scans M microbatches (leading batch
-  # split) accumulating gradients in f32 before ONE reduction collective
-  # and ONE optimizer apply -- the Megatron-style memory lever (Shoeybi
-  # et al. 2019): backward residuals are sized to B/M instead of B.
-  # M=1 keeps the exact monolithic program (the PERF.md envelope).
-  num_grad_accum = int(getattr(params, "num_grad_accum", None) or 1)
-  # --overlap_gradient_reduction: bucketed in-backward all-reduce
-  # (ops/overlap.py). Under microbatching the hooks disengage --
-  # reduction stays post-hoc on the ACCUMULATED tree, preserving the
-  # one-collective-per-step invariant (in-backward hooks inside the
-  # microbatch scan would reduce M times per step).
-  overlap_spec = overlap_lib.build(params)
-  overlap_in_step = overlap_spec is not None and num_grad_accum == 1
-  if overlap_spec is not None and num_grad_accum > 1:
-    from kf_benchmarks_tpu.utils import log as log_util
-    log_util.log_fn(
-        f"overlap_gradient_reduction: --num_grad_accum="
-        f"{num_grad_accum} keeps reduction post-hoc on the accumulated "
-        "tree (one collective per step is the pinned invariant); "
-        "in-backward hooks disengaged")
-  # The factor data plane of the mean gradient (parallel/kungfu.py):
-  # dense kernels larger than their batch leave the backward pass as the
-  # replica mean already and the exchange below skips them. THE one
-  # predicate for it: the step reduces by the plain replica mean over
-  # more than one data replica, once, on the whole per-replica tree.
-  # Every other mode keeps its program: local gradients (independent,
-  # async_sgd, sma, the async parameter server), a built reducer,
-  # in-backward hooks, ZeRO / FSDP's reduce-scatter, accumulation (one
-  # reduction of the ACCUMULATED tree is a pinned invariant), the noise
-  # scale (it reads the per-replica gradients), a model axis. Which
-  # LAYERS take it is the shape rule's, in the layer
-  # (kungfu.factors_beat_product).
-  factor_exchange = (
-      bool(getattr(strategy, "plain_mean", False))
-      and int(mesh.shape[axis_data]) > 1
-      and not (two_d and int(mesh.shape[MODEL_AXIS]) > 1)
-      and overlap_spec is None
-      and num_grad_accum == 1
-      and not params.track_grad_noise_scale)
-  tracing.active().set_static("factor_exchange",
-                              kungfu.NO_FACTOR_EXCHANGE)
-  # --health_stats: in-step device health stats (telemetry.py). The
-  # step builder takes the CONCRETE boolean benchmark.py resolved
-  # (None/auto never reaches here from the runtime); direct callers
-  # passing an unresolved None get the exact legacy program, which is
-  # what keeps the collective-count HLO pins in older tests meaningful.
-  # (sequential_apply has no single optimizer-update tree to measure;
-  # async PS is already health-rejected by validation/resolve -- this
-  # keeps direct make_step_fns callers safe too.)
-  # (sharded state never reaches here with health on -- validation.py
-  # rejects the pair and resolve_health_stats auto-disables -- but the
-  # builder re-guards for direct callers: the stats read the full
-  # update tree, which the shard apply never materializes.)
-  health_stats = (bool(getattr(params, "health_stats", None)) and
-                  not getattr(strategy, "sequential_apply", False) and
-                  not sharded_state)
-  # --packed_sequences (models/transformer_lm.py): the model exposes
-  # images -> (B, T) per-token loss weights; the cross-replica metric
-  # combine then weights each replica by ITS real-label count (token-
-  # weighted, not replica-weighted -- replicas pack different document
-  # mixes), with the weighted loss terms PACKED into one vector pmean
-  # so the packed program carries no more collectives than the
-  # unpacked one (the lm_packed audit rule pins this).
-  token_weight_fn = getattr(model, "token_weight_fn", None)
-  # Top-level param-tree keys whose gradients the MODULE already
-  # reduces in-backward (e.g. transformer_lm's scanned 'blocks' stack
-  # hooks per layer inside the nn.scan); the step-level buckets skip
-  # them so each gradient is reduced exactly once.
-  module_reduced_prefixes = tuple(
-      getattr(model, "in_backward_reduced_prefixes", ()) or ()
-  ) if overlap_in_step else ()
-  # Modules with a training-progress schedule (NASNet drop-path's
-  # global-step ramp, ref: nasnet_utils.py:407-439) take ``progress`` =
-  # step / total_training_steps; total steps is the run's --num_batches.
-  import inspect
-  module_takes_progress = (
-      "progress" in inspect.signature(type(module).__call__).parameters)
-  if total_train_steps is None:
-    total_train_steps = int(getattr(params, "num_batches", None) or 0)
-  total_train_steps = int(total_train_steps)
-
-  def _squeeze(tree):
-    return jax.tree.map(lambda x: jnp.squeeze(x, axis=0), tree)
-
-  def _expand(tree):
-    return jax.tree.map(lambda x: x[None], tree)
 
   # -- init -----------------------------------------------------------------
 
@@ -416,21 +1029,19 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     variables = module.init({"params": rng, "dropout": rng}, sample_images)
     model_params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
-    if sharded_params:
+    if plan.sharded_params:
       # Full FSDP: the PARAM storage itself is the shard stack (per-
       # layer rows for the scanned prefixes), and the per-shard
-      # optimizer state mirrors it leaf-for-leaf -- tx.init vmapped
-      # over the uniform leading shard-row dim.
+      # optimizer state mirrors it leaf-for-leaf.
       params_store = sharded_lib.fsdp_stacked_shards(
-          model_params, num_replicas, fsdp_module_prefixes)
+          model_params, plan.num_replicas, plan.fsdp_prefixes)
       return params_store, jax.vmap(tx.init)(params_store), batch_stats
-    if sharded_state:
+    if plan.sharded_state:
       # Per-shard optimizer state: vmap tx.init over the stacked flat
       # param shards (ops/sharded.py layout), so every opt-state leaf
-      # comes out (n, k) with row i = device i's shard -- global bytes
-      # ~|state| instead of the replicated stack's n * |state|.
+      # comes out (n, k) with row i = device i's shard.
       opt_state = jax.vmap(tx.init)(
-          sharded_lib.stacked_shards(model_params, num_replicas))
+          sharded_lib.stacked_shards(model_params, plan.num_replicas))
     else:
       opt_state = tx.init(model_params)
     return model_params, opt_state, batch_stats
@@ -440,38 +1051,29 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     replica == the reference's post-init broadcast, variable_mgr.py:342-356).
     Under --shard_optimizer_state the opt_state rows are per-device
     SHARDS, not copies (see _init); under --shard_params the params
-    rows are shards too (the FSDP steady state -- per-device param HBM
-    |params|/n)."""
+    rows are shards too."""
     params_store, opt_state, batch_stats = _init(rng, sample_images)
     stack = lambda t: jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (num_replicas,) + x.shape), t)
+        lambda x: jnp.broadcast_to(x[None], (plan.num_replicas,) + x.shape), t)
     buffers = {}
-    if relaxed:
+    if plan.relaxed:
       # Warmed up with zero gradients, like the reference's StagingArea
       # warmup put (ref: batch_allreduce.py:357-359).
       buffers["deferred_grads"] = stack(
           jax.tree.map(jnp.zeros_like, params_store))
-    if staged_vars:
+    if plan.staged_vars:
       buffers["staged_params"] = stack(params_store)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
-        params=params_store if sharded_params else stack(params_store),
-        opt_state=opt_state if sharded_state else stack(opt_state),
+        params=params_store if plan.sharded_params else stack(params_store),
+        opt_state=opt_state if plan.sharded_state else stack(opt_state),
         batch_stats=stack(batch_stats),
-        loss_scale=jnp.asarray(init_loss_scale, jnp.float32),
+        loss_scale=jnp.asarray(plan.init_loss_scale, jnp.float32),
         loss_scale_normal_steps=jnp.zeros((), jnp.int32),
         rng=rng,
         buffers=buffers)
 
   # -- train step -----------------------------------------------------------
-
-  # --shard_params engagement mirrors the overlap hooks' rule: under
-  # --num_grad_accum the in-compute per-bucket gathers DISENGAGE -- the
-  # full tree is re-assembled once before the microbatch scan and the
-  # accumulated gradient is scattered post-hoc (so the scatter still
-  # meets the accumulated sums in the same order as the round-11 path:
-  # bit-identity is preserved; the param-residency win is accum=1's).
-  fsdp_in_step = sharded_params and num_grad_accum == 1
 
   def per_replica_train(state, images, labels):
     model_params = _squeeze(state.params)
@@ -481,573 +1083,33 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     # --staged_vars: forward/backward read one-step-stale weights while
     # updates land on the live ones (ref: StagedVariableGetter,
     # variable_mgr_util.py:313-393).
-    forward_params = (buffers["staged_params"] if staged_vars
+    forward_params = (buffers["staged_params"] if plan.staged_vars
                       else model_params)
-    if sharded_params and not fsdp_in_step:
-      # FSDP + accumulation: one whole-tree gather up front (the
-      # round-11 steady state, rotated to the step top), full-tree
-      # microbatch scan, post-hoc scatter below.
-      with jax.named_scope("exchange"):
-        forward_params = sharded_lib.fsdp_gather_full(
-            model_params, fsdp_template, fsdp_module_prefixes,
-            nested=use_gspmd)
-    # Data-replica id: on the 2-D mesh, model-axis peers fold the SAME
-    # id (same batch shard, same dropout stream), which is what makes
-    # their local gradients identical by construction -- the free
-    # model-axis sub-slice in ops/sharded.py depends on it.
-    replica_id = lax.axis_index(axis_data)
-    step_rng = jax.random.fold_in(
-        jax.random.fold_in(state.rng, state.step), replica_id)
-
-    apply_kwargs = {}
-    if module_takes_progress and total_train_steps > 0:
-      apply_kwargs["progress"] = (
-          state.step.astype(jnp.float32) / total_train_steps)
-    # This trace's record of the kernels on the factor plane (None:
-    # none may take it, and the context below does nothing).
-    factor_plan = (kungfu.FactorExchange(axis_data, mesh.shape[axis_data])
-                   if factor_exchange else None)
-
-    def loss_fn(p, mb_images, mb_labels, bs, dropout_rng):
-      if overlap_in_step:
-        # Bucketed in-backward reduction (ops/overlap.py): every use of
-        # p below flows through the wrapped copy, so jax.grad returns
-        # ALREADY replica-reduced gradients, one collective per bucket
-        # issued where that bucket's backward completes. The post-hoc
-        # strategy reduction is skipped (overlap_in_step below).
-        # Ordering vs the loss-scale unscale is exact: the hooks reduce
-        # the SCALED cotangents and the unscale divides by a
-        # power-of-two scale afterwards (exponent shift; bit-identical
-        # to dividing first, as the post-hoc path does).
-        with jax.named_scope("exchange"):
-          p = overlap_lib.wrap_tree(
-              p, axis_data, overlap_spec.bucket_bytes,
-              compact_dtype=overlap_spec.compact_dtype,
-              exclude_prefixes=module_reduced_prefixes)
-      if fsdp_in_step:
-        # FSDP per-bucket gather (ops/overlap.py gather_params): every
-        # non-module-gathered leaf of p below is the RE-ASSEMBLED full
-        # value (one packed all-gather per builder-layer bucket), the
-        # module-gathered scanned stacks stay shards for the per-block
-        # hook inside the nn.scan body; jax.grad then returns shard-
-        # layout gradients already reduce-scattered (batch mean + free
-        # model sub-slice), one collective per bucket/block, each
-        # issued where that bucket's backward completes. The unscale-
-        # after-scatter ordering is exact for the same power-of-two
-        # reason as the overlap hooks above.
-        with jax.named_scope("exchange"):
-          p = overlap_lib.fsdp_wrap_shards(
-              p, fsdp_template, fsdp_bucket_bytes, BATCH_AXIS, MODEL_AXIS,
-              exclude_prefixes=fsdp_module_prefixes, nested=use_gspmd)
-      # One scope for the model, its loss and the weight decay: under
-      # jax.grad XLA's op_name reads ``jvp(forward)`` going forward and
-      # ``transpose(jvp(forward))`` coming back, which is how the
-      # benchmark's trace reader (benchmarks/spans.py) tells the two
-      # passes apart. Metadata only.
-      with jax.named_scope("forward"), kungfu.factor_exchange(factor_plan):
-        variables = {"params": p}
-        if bs:
-          variables["batch_stats"] = bs
-        (logits, aux_logits), updates = module.apply(
-            variables, mb_images, mutable=["batch_stats"],
-            rngs={"dropout": dropout_rng}, **apply_kwargs)
-        new_bs = updates.get("batch_stats", bs)
-        from kf_benchmarks_tpu.models.model import BuildNetworkResult
-        result = BuildNetworkResult(logits=(logits, aux_logits))
-        base_loss = model.loss_function(result, mb_labels)
-        total_loss = base_loss
-        if weight_decay:
-          if fsdp_in_step and fsdp_module_prefixes:
-            # The scanned-stack leaves of p are SHARDS here (their full
-            # values exist only block-at-a-time inside the scan), so
-            # their L2 term reduces shard-locally + one scalar psum over
-            # the mesh -- exact in value (shards tile the stack once,
-            # pad is zero) but reassociated, so total_loss is NOT
-            # bit-identical to the replicated-param L2 for scanned
-            # models with weight decay (the make_step_fns note logs
-            # this; the gathered non-scanned leaves keep the exact
-            # legacy term).
-            total_loss = total_loss + weight_decay * _l2_loss_mixed(
-                p, fsdp_module_prefixes, axis_all,
-                single_op=params.single_l2_loss_op)
-          else:
-            total_loss = total_loss + weight_decay * l2_loss(
-                p, single_op=params.single_l2_loss_op)
-        scaled = total_loss * state.loss_scale
-      return scaled, (base_loss, total_loss, new_bs, result)
-
-    accum_acc_metrics = None
-    accum_tok_w = None
-    if num_grad_accum > 1:
-      # Microbatched accumulation (--num_grad_accum=M): one scan
-      # iteration per microbatch, so the compiled program carries ONE
-      # microbatch-sized forward+backward regardless of M, and XLA
-      # reuses that iteration's activation buffers M times. Gradients
-      # accumulate in f32 (the master precision) and are divided once,
-      # so the accumulated gradient is the mean over microbatches --
-      # the same estimator as the monolithic step up to float
-      # reassociation of the batch reduction. Everything downstream
-      # (ONE strategy reduction, the loss-scale state machine, the
-      # optimizer apply) sees exactly one gradient tree per step.
-      m = num_grad_accum
-      if images.shape[0] % m:
-        raise ValueError(
-            f"--num_grad_accum={m} must divide the per-replica batch "
-            f"size {images.shape[0]} (validation.py admits only "
-            "configurations where it can)")
-      split = lambda x: x.reshape((m, x.shape[0] // m) + x.shape[1:])
-      mb_images = split(images)
-      mb_labels = jax.tree.map(split, labels)
-      grad_fn = jax.grad(loss_fn, has_aux=True)
-      want_acc = bool(params.print_training_accuracy)
-      # Scan carries start as zeros; inside the shard_map body the
-      # gradients/metrics they accumulate vary over every axis the
-      # batch OR the parameters vary over (both axes on the 2-D mesh),
-      # so the zeros are pcast to match (sequence.py vary_like).
-      from kf_benchmarks_tpu.parallel import sequence as sequence_lib
-      param_axes = tuple(sorted(set().union(
-          *(jax.typeof(p).vma for p in jax.tree.leaves(forward_params)))))
-
-      def _vary(tree):
-        leaves, treedef = jax.tree_util.tree_flatten(tree)
-        return jax.tree_util.tree_unflatten(
-            treedef,
-            list(sequence_lib.vary_like(images, tuple(leaves),
-                                        extra_axes=param_axes)))
-
-      g0 = _vary(jax.tree.map(
-          lambda p: jnp.zeros(p.shape, jnp.float32), forward_params))
-      bl0, tl0, w0 = _vary((jnp.zeros((), jnp.float32),
-                            jnp.zeros((), jnp.float32),
-                            jnp.zeros((), jnp.float32)))
-      bs0 = _vary(batch_stats)
-
-      def mb_body(carry, xs):
-        g_acc, bl_acc, tl_acc, w_acc, acc_acc, bs = carry
-        imgs, lbls, idx = xs
-        # Distinct dropout stream per microbatch (a shared one would
-        # correlate masks across the effective batch).
-        rng_i = jax.random.fold_in(step_rng, idx)
-        g, (bl, tl, bs_next, result) = grad_fn(forward_params, imgs,
-                                               lbls, bs, rng_i)
-        # --packed_sequences: each microbatch's loss is its own
-        # token-MEAN (ops/fused_loss.py); weight the accumulation by
-        # the microbatch's real-label count so the accumulated step is
-        # the PER-REPLICA monolithic token-weighted estimator -- sum
-        # over tokens / total tokens -- not a mean-of-means over
-        # unevenly packed microbatches. Deliberate scope: the CROSS-
-        # replica gradient exchange stays the equal-weight pmean
-        # (replicas' token counts concentrate tightly at ~97% packing,
-        # and token-weighting the exchange would rebuild every pinned
-        # reduction path -- strategies, overlap hooks, the sharded
-        # scatter -- for a second-order correction), so the optimized
-        # objective weights replicas equally while the REPORTED metrics
-        # are exactly token-weighted (pmean(loss*w)/pmean(w) below).
-        # Unpacked runs keep mb_w = 1 (the exact legacy equal-weight
-        # program).
-        if token_weight_fn is None:
-          # Exact legacy equal-weight accumulation (bit-pinned).
-          mb_w = jnp.float32(1.0)
-          g_acc = jax.tree.map(lambda a, x: a + x.astype(jnp.float32),
-                               g_acc, g)
-          wb, wt = bl, tl
-        else:
-          mb_w = jnp.sum(token_weight_fn(imgs))
-          g_acc = jax.tree.map(
-              lambda a, x: a + x.astype(jnp.float32) * mb_w, g_acc, g)
-          wb, wt = bl * mb_w, tl * mb_w
-        if acc_acc is not None:
-          mb_acc = model.accuracy_function(result, lbls)
-          acc_acc = {k: acc_acc[k] + (v if token_weight_fn is None
-                                      else v * mb_w)
-                     for k, v in mb_acc.items() if k in acc_acc}
-        return (g_acc, bl_acc + wb, tl_acc + wt,
-                w_acc + mb_w, acc_acc, bs_next), None
-
-      acc0 = None
-      if want_acc:
-        # Keys from an abstract eval (no FLOPs): scalar metrics only.
-        lb0 = jax.tree.map(lambda x: x[0], mb_labels)
-        shapes = jax.eval_shape(
-            lambda: model.accuracy_function(
-                loss_fn(forward_params, mb_images[0], lb0,
-                        batch_stats, step_rng)[1][3], lb0))
-        acc0 = _vary({k: jnp.zeros((), jnp.float32)
-                      for k, v in shapes.items() if not v.shape})
-      (g_acc, bl_acc, tl_acc, w_sum, acc_acc, new_bs), _ = lax.scan(
-          mb_body, (g0, bl0, tl0, w0, acc0, bs0),
-          (mb_images, mb_labels, jnp.arange(m)))
-      # Normalizer: microbatch count on the legacy path; the summed
-      # real-label count on the packed path (w_sum = sum of mb_w), so
-      # gradients and losses come out as the monolithic token-weighted
-      # estimator up to float reassociation of the batch split.
-      norm = (jnp.float32(m) if token_weight_fn is None
-              else jnp.maximum(w_sum, 1.0))
-      if token_weight_fn is not None:
-        # The scan's summed per-microbatch counts ARE this batch's
-        # real-label total (0/1 weights in exact f32 integer range):
-        # reused at metrics time so the two normalizers cannot drift.
-        accum_tok_w = w_sum
-      grads = jax.tree.map(lambda a, p: (a / norm).astype(p.dtype),
-                           g_acc, forward_params)
-      base_loss = bl_acc / norm
-      total_loss = tl_acc / norm
-      net_result = None
-      if acc_acc is not None:
-        accum_acc_metrics = {k: v / norm for k, v in acc_acc.items()}
-    else:
-      grads, (base_loss, total_loss, new_bs, net_result) = jax.grad(
-          loss_fn, has_aux=True)(forward_params, images, labels,
-                                 batch_stats, step_rng)
-    if use_loss_scale or auto_loss_scale:
-      grads = jax.tree.map(lambda g: g / state.loss_scale, grads)
-    noise_stats = None
-    if params.track_grad_noise_scale and num_replicas > 1:
-      # Measured on the pre-reduction per-replica grads (the small-batch
-      # estimate) vs their replica mean (the large-batch estimate); see
-      # elastic.noise_scale_stats. This is the in-collective monitoring
-      # KungFu's runtime does (SURVEY 2.9 "monitored gradient noise
-      # scale").
-      with jax.named_scope("metrics"):
-        noise_stats = elastic_lib.noise_scale_stats(
-            grads, axis_data, images.shape[0])
-    grad_shards = None
-    if fsdp_in_step:
-      # Full FSDP: the in-backward gather hooks already reduce-
-      # scattered every bucket/block cotangent onto the shard layout
-      # (ops/overlap.py gather_params bwd -- elementwise identical to
-      # the post-hoc scatter below); jax.grad's output IS the shard
-      # tree. No full gradient tree ever existed.
-      grad_shards = grads
-    elif sharded_params:
-      # FSDP + accumulation: post-hoc scatter of the accumulated full
-      # tree onto the FSDP layout (per-layer rows for the scanned
-      # stacks) -- elementwise the same values as scatter_mean.
-      with jax.named_scope("exchange"):
-        grad_shards = sharded_lib.fsdp_scatter_mean(grads,
-                                                    fsdp_module_prefixes)
-    elif sharded_state:
-      # ZeRO gradient pass (ops/sharded.py): reduce-scatter of the
-      # batch-axis mean -- each scatter group meets the same B distinct
-      # contributions in the same group order as the replicated pmean,
-      # so the scattered mean is BIT-IDENTICAL to it -- then the free
-      # model-axis sub-slice. The full gradient tree dies here; only
-      # this device's 1/n flat shard flows on.
-      with jax.named_scope("exchange"):
-        grad_shards = sharded_lib.scatter_mean(grads)
-    elif not overlap_in_step:
-      # "exchange" names the strategy's whole reduction -- the casts,
-      # concatenations and scalings around the collectives too, which a
-      # reader that goes by opcode alone would miss (benchmarks/spans.py).
-      with jax.named_scope("exchange"):
-        reduce = lambda g: strategy.reduce_gradients(g, axis_data)
-        if factor_plan is not None and factor_plan.claimed:
-          grads = _reduce_unclaimed(grads, factor_plan.claimed, reduce)
-          counters = factor_plan.counters()
-          tracing.active().set_static("factor_exchange", counters)
-          from kf_benchmarks_tpu.utils import log as log_util
-          log_util.log_fn(
-              "factor exchange: %d dense layer(s) form the mean gradient "
-              "from all-gathered factors: %.1f MB kept off the "
-              "all-reduce, %.1f MB gathered instead" % (
-                  counters["layers"],
-                  counters["bytes_off_allreduce"] / 1e6,
-                  counters["bytes_gathered"] / 1e6))
-        else:
-          grads = reduce(grads)
-    # else: the in-backward hooks already reduced every bucket
-    # (module-internal hooks for module_reduced_prefixes, the loss_fn
-    # wrap for the rest); everything downstream -- the auto-loss-scale
-    # finite check, relaxed-consistency banking, the optimizer apply --
-    # sees the reduced tree exactly as on the post-hoc path.
-
-    def _all_finite(tree, axis):
-      ok = jnp.all(jnp.stack(
-          [jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(tree)]))
-      # Globally uniform decision (pmin across replicas) so every carried
-      # scalar stays replicated (ref chief-only NaN check + broadcast,
-      # variable_mgr.py:186-193).
-      return lax.pmin(ok.astype(jnp.int32), axis).astype(bool)
-
-    # The loss-scale state machine keys on THIS step's fresh gradients
-    # (they reflect the current scale), even when the applied gradients
-    # are the deferred ones (ref: variable_mgr_util.py:51-139). On the
-    # sharded path the shards tile the full reduced tree, so the pmin
-    # over BOTH axes covers every element exactly once; on the
-    # replicated 2-D path the model-axis peers hold identical gradients,
-    # so the same all-axes pmin is exact and keeps the carried loss-scale
-    # scalars typed replicated.
-    if auto_loss_scale:
-      fresh_finite = _all_finite(
-          grad_shards if sharded_state else grads, axis_all)
-    else:
-      fresh_finite = None
-    new_buffers = dict(buffers)
-    if relaxed:
-      # --variable_consistency=relaxed: apply the PREVIOUS step's reduced
-      # gradients and bank this step's for the next -- the double-buffered
-      # reformulation of the reference's deferred StagingArea gradients
-      # (ref: batch_allreduce.py:353-388; SURVEY 7.4). Non-finite fresh
-      # gradients are never banked (the deferred analog of the skipped
-      # update): the old bank stays.
-      banked = grads
-      if fresh_finite is not None:
-        banked = jax.tree.map(
-            lambda a, b: jnp.where(fresh_finite, a, b),
-            grads, buffers["deferred_grads"])
-      new_buffers["deferred_grads"] = banked
-      grads = buffers["deferred_grads"]
-
-    with jax.named_scope("exchange"):
-      model_params_pre = strategy.pre_update(model_params, state.step,
-                                             axis_data)
-    if sharded_state:
-      # The ZeRO apply (the reference's central variable placement
-      # rendered SPMD, variable_mgr.py:201-243): run the optimizer on
-      # the 1/n shard ONLY (elementwise optimizers; validation.py
-      # rejects LARS). Optimizer HBM per device is |state|/n.
-      # --shard_params: the state ALREADY holds this device's shards
-      # (the FSDP steady state) and the updated shards flow straight
-      # back into it -- the round-11 trailing full-tree all-gather is
-      # GONE; re-assembly happens inside the next step's compute, one
-      # bucket/block at a time. Without it, params are replicated: the
-      # shard is a free local slice and the updated params return by
-      # all-gather for the next forward.
-      param_shards = (model_params_pre if sharded_params
-                      else sharded_lib.local_shards(model_params_pre))
-      with jax.named_scope("optimizer_apply"):
-        updates, new_opt_state = tx.update(grad_shards, opt_state,
-                                           param_shards)
-        new_shards = optax.apply_updates(param_shards, updates)
-      with jax.named_scope("exchange"):
-        new_params = (new_shards if sharded_params else
-                      sharded_lib.gather_tree(new_shards, model_params_pre,
-                                              nested=use_gspmd))
-    elif getattr(strategy, "sequential_apply", False):
-      # Async PS with a stateful optimizer (strategies.py): serialize
-      # every replica's unaveraged gradient through the SHARED optimizer
-      # state, in replica-index order -- the deterministic SPMD
-      # rendering of the PS's one-at-a-time applications (ref async
-      # mode: benchmark_cnn.py:520-522).
-      with jax.named_scope("exchange"):
-        g_all = jax.tree.map(
-            lambda g: lax.all_gather(g, axis_data, axis=0), grads)
-
-      def _apply_one(carry, g):
-        prms, ost = carry
-        upd, ost2 = tx.update(g, ost, prms)
-        # Every application within the round sees the ROUND's schedule
-        # count (momentum/variance state still advances per
-        # application); the round bump happens once, below.
-        ost2 = _sync_schedule_counts(ost, ost2)
-        return (optax.apply_updates(prms, upd), ost2), None
-
-      # The named_scope rides into HLO op_name metadata; the program-
-      # contract auditor (analysis/contracts.py) keys the one-apply-
-      # per-step check on it.
-      with jax.named_scope("optimizer_apply"):
-        (new_params, new_opt_state), _ = lax.scan(
-            _apply_one, (model_params_pre, opt_state), g_all)
-      new_opt_state = _sync_schedule_counts(opt_state, new_opt_state,
-                                            bump=1)
-    else:
-      with jax.named_scope("optimizer_apply"):
-        updates, new_opt_state = tx.update(grads, opt_state,
-                                           model_params_pre)
-        new_params = optax.apply_updates(model_params_pre, updates)
-    with jax.named_scope("exchange"):
-      new_params = strategy.post_update(new_params, state.step, axis_data)
-      new_bs = strategy.sync_batch_stats(new_bs, axis_data)
-
-    if auto_loss_scale:
-      # Auto loss-scale state machine (ref: variable_mgr_util.py:51-139):
-      # any non-finite FRESH grad -> skip the update, halve scale; else
-      # count a normal step and double the scale every ``inc_every_n``.
-      # Under relaxed consistency the APPLIED gradients are the previous
-      # bank, which only ever admits finite values (banking gate above),
-      # so the params/opt_state skip is unnecessary there by induction.
-      keep = lambda new, old: jax.tree.map(
-          lambda a, b: jnp.where(fresh_finite, a, b), new, old)
-      if not relaxed:
-        new_params = keep(new_params, model_params)
-        new_opt_state = keep(new_opt_state, opt_state)
-      # batch_stats come from THIS step's forward in both modes: an
-      # overflowing forward must not poison the running statistics.
-      new_bs = keep(new_bs, batch_stats)
-      normal_steps = jnp.where(fresh_finite,
-                               state.loss_scale_normal_steps + 1,
-                               0)
-      do_double = jnp.logical_and(fresh_finite,
-                                  normal_steps >= inc_every_n)
-      new_scale = jnp.where(
-          fresh_finite,
-          jnp.where(do_double, state.loss_scale * 2.0, state.loss_scale),
-          jnp.maximum(state.loss_scale / 2.0, 1.0))
-      normal_steps = jnp.where(do_double, 0, normal_steps)
-    else:
-      new_scale = state.loss_scale
-      normal_steps = state.loss_scale_normal_steps
-
-    # "metrics": the loss / accuracy / health reductions of the step
-    # line, apart from the training arithmetic (benchmarks/spans.py).
-    with jax.named_scope("metrics"):
-      lr = lr_fn(state.step)
-      # Token-weighted metric combine (--packed_sequences): this
-      # replica's real-label count; per-replica losses are already
-      # normalized by it (ops/fused_loss.py), so the global token-mean is
-      # pmean(loss * w) / pmean(w) -- computed from the SAME packed
-      # vector collective that carries the losses.
-      tok_w = None
-      if token_weight_fn is not None:
-        tok_w = (accum_tok_w if accum_tok_w is not None
-                 else jnp.sum(token_weight_fn(images)))
-      wm_safe = None
-      if health_stats:
-        # In-step health stats (telemetry.py): grad norm, update/param
-        # ratio, non-finite leaf count, loss scale + skip flag -- all
-        # read from the step's post-reduction values, so they are
-        # replica-identical for the replica-synchronous strategies
-        # validation admits. Each replica reduces a 1/n SLICE of every
-        # tree (telemetry.health_partials) and the pre-scaled partial
-        # sums ride the LOSS pmean: one f32 vector all-reduce replaces
-        # the two scalar loss pmeans, so the health-on program carries
-        # NO extra collective (acceptance-pinned in
-        # tests/test_telemetry.py) and no replicated full-tree passes.
-        # Elementwise, the vector all-reduce computes bit-identical loss
-        # values to the scalar ones (equivalence pinned in the same
-        # tests). ``updates`` exists on every health-admitted path:
-        # sequential_apply (async PS) is rejected/auto-disabled by
-        # validation.py and resolve_health_stats.
-        skipped = (1.0 - fresh_finite.astype(jnp.float32)
-                   if fresh_finite is not None else jnp.float32(0.0))
-        # The fresh-grad overflow skip only suppresses the applied
-        # update on the non-relaxed path (the relaxed bank admits finite
-        # gradients only, so its apply always lands).
-        suppressed = jnp.float32(0.0) if relaxed else skipped
-        # Under --packed_sequences the two loss slots ride token-weighted
-        # (loss * w) and w itself is appended to the SAME vector, so the
-        # weighted combine still costs the one loss pmean.
-        bl32 = base_loss.astype(jnp.float32)
-        tl32 = total_loss.astype(jnp.float32)
-        loss_slots = (jnp.stack([bl32, tl32]) if tok_w is None else
-                      jnp.stack([bl32 * tok_w, tl32 * tok_w]))
-        vec = [loss_slots, telemetry_lib.health_partials(
-            grads, model_params, updates, axis_data)]
-        if tok_w is not None:
-          vec.append(jnp.stack([tok_w]))
-        packed = lax.pmean(jnp.concatenate(vec), axis_data)
-        health_totals = packed[2:] if tok_w is None else packed[2:-1]
-        if tok_w is None:
-          bl_m, tl_m = packed[0], packed[1]
-        else:
-          wm_safe = jnp.maximum(packed[-1], 1e-30)
-          bl_m, tl_m = packed[0] / wm_safe, packed[1] / wm_safe
-        metrics = {
-            "base_loss": bl_m,
-            "total_loss": tl_m,
-            "learning_rate": lr,
-            "health": telemetry_lib.health_finalize(
-                health_totals, new_scale, skipped, suppressed),
-        }
-      elif tok_w is not None:
-        # One 3-vector pmean replaces the two scalar loss pmeans: the
-        # packed program's collective count stays <= the unpacked one.
-        packed = lax.pmean(
-            jnp.stack([base_loss.astype(jnp.float32) * tok_w,
-                       total_loss.astype(jnp.float32) * tok_w, tok_w]),
-            axis_data)
-        wm_safe = jnp.maximum(packed[2], 1e-30)
-        metrics = {
-            "base_loss": packed[0] / wm_safe,
-            "total_loss": packed[1] / wm_safe,
-            "learning_rate": lr,
-        }
-      else:
-        # Metric pmeans reduce over the DATA axis only: model-axis peers
-        # compute the identical loss from the identical batch shard, so
-        # the batch-group mean is already the global value -- and it is
-        # bit-identical to the replicated path's B-contribution pmean.
-        metrics = {
-            "base_loss": lax.pmean(base_loss, axis_data),
-            "total_loss": lax.pmean(total_loss, axis_data),
-            "learning_rate": lr,
-        }
-      if tok_w is not None and wm_safe is not None:
-        # Label coverage of the packed batch (real label positions /
-        # slots): the in-step packing-efficiency signal next to the
-        # host-side feed line (observability.packing_feed_line). Post-
-        # collective scalar math, no extra communication.
-        metrics["real_token_fraction"] = wm_safe / jnp.float32(
-            sum(math.prod(l.shape) for l in jax.tree.leaves(labels)) or 1)
-      if steps_per_dispatch > 1:
-        # Replica-mean global norm of the reduced gradients (under relaxed
-        # consistency: of the APPLIED, one-step-stale bank) -- the
-        # per-step training-health scalar the chunked mode stacks
-        # alongside loss and lr, replacing what an operator would
-        # otherwise probe with per-step fetches. K=1 omits it so the
-        # single-step program stays the exact program behind PERF.md's
-        # pinned envelope numbers.
-        if "health" in metrics:
-          # The health vector already carries this exact norm (same grads
-          # tree, sharded reduction): reuse it rather than paying a second,
-          # full-tree replicated square-sum pass -- the replicated pass is
-          # the ~2x-step-time cost _sharded_sumsq exists to avoid.
-          metrics["grad_norm"] = metrics["health"][0]
-        elif sharded_state:
-          # The flat shards tile the reduced gradient exactly once, so
-          # the psum of per-shard square-sums over BOTH axes is the global
-          # square-sum -- no full-tree pass, same cost argument as the
-          # health path's sharded reduction.
-          metrics["grad_norm"] = jnp.sqrt(lax.psum(
-              sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                  for g in jax.tree.leaves(grad_shards)), axis_all))
-        else:
-          metrics["grad_norm"] = lax.pmean(
-              jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                           for g in jax.tree.leaves(grads))), axis_data)
-      if params.print_training_accuracy:
-        # Under microbatching the per-microbatch scalar accuracies were
-        # averaged inside the scan (equal microbatch sizes make that the
-        # effective-batch value); monolithic computes them here.
-        acc = (accum_acc_metrics if accum_acc_metrics is not None
-               else model.accuracy_function(net_result, labels))
-        # Scalars only: detection accuracy_functions also return per-box
-        # arrays (decoded predictions), which are not replicated step
-        # metrics. Packed runs weight each replica's (already token-
-        # weighted) accuracy by its real-label count, like the losses.
-        if tok_w is not None and wm_safe is not None:
-          metrics.update({k: lax.pmean(v * tok_w, axis_data) / wm_safe
-                          for k, v in acc.items() if jnp.ndim(v) == 0})
-        else:
-          metrics.update({k: lax.pmean(v, axis_data)
-                          for k, v in acc.items() if jnp.ndim(v) == 0})
-      # A model's own per-step counters (models/model.py
-      # ``step_counters``; mla_moe_lm: the expert layer's loads), read
-      # from what this step's forward left in ``batch_stats``: one small
-      # vector beside the losses, fetched with them, so no host sync and
-      # no collective of its own. None for a model without any.
-      counters = model.step_counters(new_bs)
-      if counters is not None:
-        metrics["counters"] = counters
-    if noise_stats is not None:
-      metrics["noise_scale_g2"], metrics["noise_scale_s"] = noise_stats
-
-    if staged_vars:
+    back = _forward_backward(plan, model, module, state, forward_params,
+                             batch_stats, images, labels)
+    grads = _exchange(plan, strategy, back.grads, back.factor_plan)
+    grads, fresh_finite, new_buffers = _bank_deferred(plan, grads, buffers)
+    applied = _apply(plan, strategy, tx, state, model_params, opt_state,
+                     batch_stats, grads, back.batch_stats, fresh_finite)
+    metrics = _step_metrics(plan, model, lr_fn, state, images, labels,
+                            model_params, grads, back, applied,
+                            fresh_finite)
+    if plan.staged_vars:
       # Next step's reads see this step's PRE-update weights: the value
       # that was in the staging area at read time (one-step staleness).
       new_buffers["staged_params"] = model_params
     # Writing the updated state back belongs to the apply: XLA fuses the
     # stacking reshape with the update and names the fusion after it.
     with jax.named_scope("optimizer_apply"):
-      stacked_params = _expand(new_params)
-      stacked_opt_state = _expand(new_opt_state)
+      stacked_params = _expand(applied.params)
+      stacked_opt_state = _expand(applied.opt_state)
     new_state = TrainState(
         step=state.step + 1,
         params=stacked_params,
         opt_state=stacked_opt_state,
-        batch_stats=_expand(new_bs),
-        loss_scale=new_scale,
-        loss_scale_normal_steps=normal_steps,
+        batch_stats=_expand(applied.batch_stats),
+        loss_scale=applied.loss_scale,
+        loss_scale_normal_steps=applied.normal_steps,
         rng=state.rng,
         buffers=_expand(new_buffers))
     return new_state, metrics
@@ -1062,28 +1124,25 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
 
   # Models built on library-internal scans (optax ctc_loss, flax RNN)
   # seed carries from unvarying constants, which trips the strict
-  # varying-manual-axes checker even though the program is correct. Those
-  # models opt out via relax_shard_map_vma; everyone else keeps the
-  # checker (it catches missing pmeans under out_specs=P()).
+  # varying-manual-axes checker; they opt out via relax_shard_map_vma.
+  # Everyone else keeps it (it catches missing pmeans under P()).
   check_vma = not getattr(model, "relax_shard_map_vma", False)
 
   # 2-D mesh: the step metrics are reduced over 'batch' only, and the
-  # model-axis peers hold bit-identical copies by construction (same
-  # batch shard, same parameters) -- which the varying-manual-axes types
-  # cannot know. So the metrics leave the manual region stacked over
-  # 'model' (the honest out_spec, dim ``dim``) and the jitted wrapper
-  # keeps row 0: free on the Nx1 meshes, the partitioner's choice on
-  # BxM. 1-D meshes keep out_specs=P().
+  # model-axis peers hold bit-identical copies by construction, which
+  # the varying-manual-axes types cannot know. So the metrics leave the
+  # manual region stacked over 'model' (dim ``dim``) and the jitted
+  # wrapper keeps row 0: free on the Nx1 meshes. 1-D meshes keep P().
   def _metric_spec(dim=0):
-    return P(*([None] * dim), MODEL_AXIS) if two_d else P()
+    return P(*([None] * dim), MODEL_AXIS) if plan.two_d else P()
 
   def _stack_model(tree, dim=0):
-    if not two_d:
+    if not plan.two_d:
       return tree
     return jax.tree.map(lambda x: jnp.expand_dims(x, dim), tree)
 
   def _pick_model(tree, dim=0):
-    if not two_d:
+    if not plan.two_d:
       return tree
     return jax.tree.map(lambda x: jnp.take(x, 0, axis=dim), tree)
 
@@ -1107,21 +1166,17 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
   # -- the gspmd twin (--partitioner=gspmd) ---------------------------------
   #
   # Same per-replica body, compiler-placed collectives: the body still
-  # speaks bound axis names (every lax.p* above), so instead of
+  # speaks bound axis names (every lax.p* of the stages), so instead of
   # shard_map it is traced under two nested jax.vmap's -- outer
   # 'batch', inner 'model' -- each binding axis_name AND
   # spmd_axis_name over the (B, M)-regridded stacked state. The
-  # spmd_axis_name pins each vmap dimension to its mesh axis, the
   # surrounding plain jit carries the SAME NamedShardings the manual
-  # path's specs induce, and GSPMD is then free to choose/re-place the
-  # collectives (the twin-referee rule in analysis/audit.py diffs the
-  # result against the hand placement). Batch inputs map on the outer
-  # vmap only (model peers see the same shard, exactly like in_specs
-  # P(axis_data)); scalars replicate in (in_axes=None) and come back
-  # broadcast (out_axes=0 everywhere -- the [0, 0] pick below avoids
-  # proving replication to vmap). eval_step and broadcast_init stay on
-  # the manual shard_map path in both modes: neither is on the
-  # steady-state hot path the twin A/B measures.
+  # path's specs induce, and GSPMD is then free to place the
+  # collectives. Batch inputs map on the outer vmap only (model peers
+  # see the same shard, like in_specs P(axis_data)); scalars replicate
+  # in (in_axes=None) and come back broadcast (the [0, 0] pick below
+  # avoids proving replication to vmap). eval_step and broadcast_init
+  # stay on the manual shard_map path in both modes.
   def _gspmd_wrap(per_fn, batch_dim):
     grid_b = int(mesh.shape[BATCH_AXIS])
     grid_m = int(mesh.shape[MODEL_AXIS])
@@ -1178,7 +1233,7 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         out_shardings=(init_shardings, NamedSharding(mesh, P())),
         donate_argnums=(0,))
 
-  if use_gspmd:
+  if plan.use_gspmd:
     train_step = _gspmd_wrap(per_replica_train, 0)
   else:
     train_step = _sharded_step(per_replica_train, P(axis_data))
@@ -1191,12 +1246,12 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     scan closes over it and runs K steps with no staged inputs -- the
     in-program analog of the reference's reused synthetic feed
     (ref: benchmark_cnn.py:3008-3011) at K steps per dispatch."""
-    if images.shape[0] == 1 and steps_per_dispatch > 1:
+    if images.shape[0] == 1 and plan.steps_per_dispatch > 1:
       im0 = images[0]
       lb0 = jax.tree.map(lambda x: x[0], labels)
       new_state, metrics = lax.scan(
           lambda st, _: per_replica_train(st, im0, lb0), state, None,
-          length=steps_per_dispatch)
+          length=plan.steps_per_dispatch)
       return new_state, metrics
     new_state, metrics = lax.scan(
         lambda st, batch: per_replica_train(st, *batch), state,
@@ -1204,8 +1259,8 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     return new_state, metrics
 
   train_chunk = None
-  if steps_per_dispatch > 1:
-    if use_gspmd:
+  if plan.steps_per_dispatch > 1:
+    if plan.use_gspmd:
       train_chunk = _gspmd_wrap(per_replica_train_chunk, 1)
     else:
       # Per-step metrics come back stacked on a leading K axis; the
@@ -1217,13 +1272,12 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
 
   def per_replica_eval(state, images, labels):
     model_params = _squeeze(state.params)
-    if sharded_params:
+    if plan.sharded_params:
       # Mid-training eval re-assembles the full tree (the eval module
-      # carries no FSDP hooks); eval is occasional, so the transient
-      # full-tree residency is acceptable -- the steady-state training
-      # program is what the residency contract binds.
+      # carries no FSDP hooks): eval is occasional, and the residency
+      # contract binds the steady-state training program.
       model_params = sharded_lib.fsdp_gather_full(
-          model_params, fsdp_template, fsdp_module_prefixes)
+          model_params, plan.fsdp_template, plan.fsdp_prefixes)
     batch_stats = _squeeze(state.batch_stats)
     variables = {"params": model_params}
     if batch_stats:
@@ -1235,11 +1289,9 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
     loss = model.loss_function(result, labels)
     if token_weight_fn is not None:
       # Packed runs (mid-training eval; --eval itself is rejected in
-      # validation.py): same token-weighted cross-replica combine as
-      # the train metrics -- each replica's loss/accuracy is already
-      # normalized by ITS real-label count, and replicas pack different
-      # document mixes, so an equal-weight pmean would bias the global
-      # value toward lightly-packed replicas.
+      # validation.py): the train metrics' token-weighted cross-replica
+      # combine, since an equal-weight pmean would bias the global value
+      # toward lightly-packed replicas.
       tok_w = jnp.sum(token_weight_fn(images))
       wm = jnp.maximum(lax.pmean(tok_w, axis_data), 1e-30)
       metrics = {k: lax.pmean(v * tok_w, axis_data) / wm

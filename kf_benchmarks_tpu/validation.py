@@ -109,30 +109,16 @@ NO_CROSS_FLAG_VALIDATION = {
     "data_name": "dataset selector; inferred from data_dir when unset",
     "batch_group_size": "host pipeline batching depth",
     "distortions": "preprocessing toggle",
-    "distort_color_in_yiq": "preprocessing toggle",
     "resize_method": "preprocessing method selector",
-    "fuse_decode_and_crop": "preprocessing toggle",
     "input_preprocessor": "preprocessor selector (datasets resolve it)",
     "input_preprocessing_parallelism": "host thread count",
     "datasets_num_private_threads": "host thread count",
-    "datasets_parallel_interleave_cycle_length": "accepted for reference "
-                                                 "CLI parity; interleave "
-                                                 "is TF-pipeline-only",
-    "datasets_parallel_interleave_prefetch": "accepted for reference CLI "
-                                             "parity; TF-pipeline-only",
     "datasets_prefetch_buffer_size": "feeder prefetch depth",
     "input_prefetch_depth": "explicit feeder prefetch depth override "
                             "(benchmark.feeder_prefetch); any depth "
                             ">= 1 is valid with every input path",
     "datasets_repeat_cached_sample": "pipeline toggle",
-    "datasets_sloppy_parallel_interleave": "accepted for reference CLI "
-                                           "parity; TF-pipeline-only",
     "datasets_use_caching": "pipeline toggle",
-    "datasets_use_prefetch": "pipeline toggle",
-    "use_multi_device_iterator": "accepted for reference CLI parity; the "
-                                 "DeviceFeeder is the only input path",
-    "multi_device_iterator_max_buffer_size": "accepted for reference CLI "
-                                             "parity (see above)",
     # Telemetry knobs (PR 4): numeric thresholds with registry bounds;
     # engagement is validated through health_stats above.
     "health_grad_norm_sigma": "anomaly threshold (registry bounds only)",
@@ -143,52 +129,16 @@ NO_CROSS_FLAG_VALIDATION = {
     "ps_hosts": "cluster wiring string (cluster.py)",
     "task_index": "cluster wiring index (cluster.py)",
     "process_index": "cluster wiring index (cluster.py)",
-    "horovod_device": "accepted for reference CLI parity; TPU runs have "
-                      "no per-process device pick",
-    "server_protocol": "accepted for reference CLI parity; no grpc "
-                       "server exists here",
     "sync_on_finish": "accepted for reference CLI parity; drain() is "
                       "unconditional at run end",
-    # GPU/TF-graph knobs accepted for reference command-line parity but
-    # inert on this backend (params.validate_params notes them; SURVEY
-    # 5.6 library/CLI duality keeps reference invocations working).
-    "allow_growth": "inert GPU allocator knob (reference parity)",
-    "autotune_threshold": "inert TF autotune knob (reference parity)",
+    # Model-private paths, host pools and sinks with no cross-flag
+    # interaction.
     "backbone_model_path": "SSD backbone restore path; model-private",
-    "batchnorm_persistent": "inert cuDNN knob (reference parity)",
-    "compute_lr_on_cpu": "inert placement knob (reference parity)",
-    "enable_optimizations": "inert TF graph-option (reference parity)",
-    "force_gpu_compatible": "inert GPU knob (reference parity)",
-    "freeze_when_forward_only": "subsumed by aot_save_path validation "
-                                "(the freeze analog)",
-    "gpu_indices": "inert GPU knob (reference parity)",
-    "gpu_memory_frac_for_testing": "inert GPU knob (reference parity)",
-    "gpu_thread_mode": "inert GPU knob (reference parity)",
-    "per_gpu_thread_count": "inert GPU knob (reference parity)",
-    "kmp_affinity": "inert MKL env knob (reference parity)",
-    "kmp_blocktime": "inert MKL env knob (reference parity)",
-    "kmp_settings": "inert MKL env knob (reference parity)",
-    "mkl": "inert MKL toggle (reference parity)",
-    "num_inter_threads": "host thread pool size",
     "num_intra_threads": "host thread pool size",
-    "rewriter_config": "inert TF graph-rewriter knob (reference parity)",
-    "sparse_to_dense_grads": "inert: JAX grads are dense already",
-    "use_python32_barrier": "inert TF threading knob (reference parity)",
-    "use_resource_vars": "inert TF variable knob (reference parity)",
-    "use_tf_layers": "builder always uses flax modules (reference parity)",
-    "use_unified_memory": "inert GPU knob (reference parity)",
-    "winograd_nonfused": "inert cuDNN env knob (reference parity)",
     "partitioned_graph_file_prefix": "inert TF graph-dump knob "
                                      "(reference parity)",
-    "trt_max_workspace_size_bytes": "inert TRT knob; trt_mode itself IS "
-                                    "validated above",
-    "xla_compile": "legacy alias surface; use_xla_compile is the "
-                   "validated switch",
-    "allreduce_merge_scope": "reducer batching depth (ops/allreduce.py)",
     "agg_small_grads_max_group": "reducer group bound; engagement "
                                  "validated via agg_small_grads_max_bytes",
-    "network_topology": "hierarchical-copy shape hint (ops/allreduce.py)",
-    "local_parameter_device": "PS placement hint; no TPU cross-check",
 }
 
 
@@ -478,12 +428,6 @@ def validate_cross_flags(params) -> None:
           "noise-scale estimator contrasts PRE-reduction per-replica "
           "gradients with their replica mean (elastic.py), and the "
           "scattered reduction never materializes the replica mean")
-    if getattr(p, "overlap_gradient_reduction", False):
-      raise ParamError(
-          "--shard_optimizer_state cannot be combined with "
-          "--overlap_gradient_reduction: the in-backward hooks issue "
-          "bucket pmeans (all-reduce), which is exactly the collective "
-          "the sharded path replaces with reduce-scatter")
     for flag, name in ((p.all_reduce_spec, "--all_reduce_spec"),
                        (p.gradient_repacking, "--gradient_repacking"),
                        (p.agg_small_grads_max_bytes > 0,
@@ -510,12 +454,12 @@ def validate_cross_flags(params) -> None:
   if getattr(p, "shard_params", False):
     # --shard_params (full FSDP): params join the optimizer state on
     # the (n, k) shard layout and re-assemble inside the compute
-    # (train_step.py + ops/overlap.py). Requiring
+    # (train_step.py + ops/sharded.py). Requiring
     # --shard_optimizer_state makes the whole sharded exclusion matrix
     # above binding here too -- elementwise-optimizer family only (no
     # LARS), synchronous replicated/parameter_server only (no
     # async-PS, no independent/gossip), no staged vars / relaxed
-    # consistency / overlap reducers, single-process.
+    # consistency / packed reducers, single-process.
     if not sharded:
       raise ParamError(
           "--shard_params requires --shard_optimizer_state: the FSDP "
@@ -883,69 +827,23 @@ def validate_cross_flags(params) -> None:
           "format engages (f32 training too), it cannot engage a "
           "compaction that is switched off")
     if not (p.use_fp16 or p.all_reduce_spec or p.gradient_repacking
-            or p.agg_small_grads_max_bytes > 0 or p.hierarchical_copy
-            or getattr(p, "overlap_gradient_reduction", False)):
+            or p.agg_small_grads_max_bytes > 0 or p.hierarchical_copy):
       raise ParamError(
           "--compact_gradient_transfer_f32 has no effect without a "
           "reduction path that repacks the wire: the default per-leaf "
           "pmean never re-encodes gradients (ops/allreduce.py "
           "build_reducer returns None). Select a packed path -- "
-          "--overlap_gradient_reduction, --all_reduce_spec, "
+          "--all_reduce_spec, "
           "--gradient_repacking, --agg_small_grads_max_bytes or "
           "--hierarchical_copy -- or drop the flag (a silent no-op "
           "that logs a halved-bytes note would misrecord the run)")
   if getattr(p, "reduce_bucket_mb", None) and \
-      not (getattr(p, "overlap_gradient_reduction", False)
-           or getattr(p, "shard_params", False)):
+      not getattr(p, "shard_params", False):
     raise ParamError(
-        "--reduce_bucket_mb sizes the in-backward collective buckets "
-        "and requires --overlap_gradient_reduction (reduction buckets) "
-        "or --shard_params (FSDP gather buckets); the post-hoc paths' "
-        "granularity levers are --gradient_repacking / "
-        "--agg_small_grads_max_bytes / --all_reduce_spec")
-  if getattr(p, "overlap_gradient_reduction", False):
-    # In-backward reduction replaces the strategy's post-hoc gradient
-    # pass with per-bucket pmeans issued inside the backward; it is
-    # therefore only defined for strategies whose aggregation IS the
-    # replica mean, and it cannot coexist with reducers that own
-    # reduction granularity themselves (ref: batch_allreduce.py:300-317
-    # selects exactly one algorithm).
-    if p.variable_update not in ("replicated", "distributed_replicated",
-                                 "parameter_server",
-                                 "collective_all_reduce",
-                                 "distributed_all_reduce", "horovod"):
-      raise ParamError(
-          "--overlap_gradient_reduction requires a replicated-family "
-          f"--variable_update (got {p.variable_update!r}): "
-          "independent/gossip modes have no gradient reduction to "
-          "overlap")
-    if p.variable_update == "parameter_server" and not p.cross_replica_sync:
-      raise ParamError(
-          "--overlap_gradient_reduction cannot be combined with async "
-          "parameter_server (--cross_replica_sync=false): the async path "
-          "consumes each replica's UNAVERAGED gradient (train_step.py "
-          "sequential_apply / psum-sum collapse); in-backward pmeans "
-          "would silently average them. Use a synchronous "
-          "--variable_update")
-    for flag, name in ((p.all_reduce_spec, "--all_reduce_spec"),
-                       (p.gradient_repacking, "--gradient_repacking"),
-                       (p.agg_small_grads_max_bytes > 0,
-                        "--agg_small_grads_max_bytes"),
-                       (p.hierarchical_copy, "--hierarchical_copy")):
-      if flag:
-        raise ParamError(
-            f"--overlap_gradient_reduction cannot be combined with "
-            f"{name}: each reducer owns the reduction granularity "
-            "(ref: batch_allreduce.py:300-317 selects one algorithm); "
-            "the overlap path's granularity lever is --reduce_bucket_mb")
-    if p.track_grad_noise_scale:
-      raise ParamError(
-          "--overlap_gradient_reduction cannot be combined with "
-          "--track_grad_noise_scale: the noise-scale estimator contrasts "
-          "PRE-reduction per-replica gradients with their replica mean "
-          "(elastic.noise_scale_stats), and in-backward reduction never "
-          "materializes the pre-reduction tree. Cost of the exclusion: "
-          "use the post-hoc default when monitoring noise scale")
+        "--reduce_bucket_mb bounds FSDP's gather buckets and requires "
+        "--shard_params; the post-hoc reducers' granularity levers are "
+        "--gradient_repacking / --agg_small_grads_max_bytes / "
+        "--all_reduce_spec")
   if getattr(p, "health_stats", None):
     # Explicit --health_stats (unset = auto-resolve, telemetry.py): the
     # in-step stats read the APPLIED gradient tree and are only global

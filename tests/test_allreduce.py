@@ -291,3 +291,87 @@ def test_psum_spec_does_not_warn():
   finally:
     log_util.log_fn = orig
   assert not [l for l in logs if "unvalidated" in l], logs
+
+
+# -- the size-bounded bucket scheduler (FSDP's gather buckets) ----------------
+
+def test_plan_size_buckets_bounds_and_order():
+  # 3+4 > 6 closes the first bucket; the oversized 9 keeps its own.
+  assert allreduce.plan_size_buckets([3, 4, 9, 1, 1], 6) == \
+      [[0], [1], [2], [3, 4]]
+  assert allreduce.plan_size_buckets([1, 1, 1], 100) == [[0, 1, 2]]
+  assert allreduce.plan_size_buckets([], 10) == []
+
+
+# -- the f32 wire-compaction opt-in (--compact_gradient_transfer_f32) ---------
+
+def test_compact_wire_dtype_decoupled_from_fp16():
+  from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu.utils import log as log_util
+  assert allreduce.compact_wire_dtype(params_lib.make_params(
+      use_fp16=True)) == jnp.bfloat16
+  assert allreduce.compact_wire_dtype(params_lib.make_params()) is None
+  assert allreduce.compact_wire_dtype(params_lib.make_params(
+      compact_gradient_transfer=False,
+      use_fp16=True)) is None
+  logs = []
+  orig = log_util.log_fn
+  log_util.log_fn = logs.append
+  allreduce._compact_f32_noted = False  # once-per-process note
+  try:
+    got = allreduce.compact_wire_dtype(params_lib.make_params(
+        compact_gradient_transfer_f32=True))
+    again = allreduce.compact_wire_dtype(params_lib.make_params(
+        compact_gradient_transfer_f32=True))
+  finally:
+    log_util.log_fn = orig
+  assert got == jnp.bfloat16 and again == jnp.bfloat16
+  notes = [l for l in logs if "NOT bit-identical" in l]
+  # The note names the precision change and fires ONCE however many
+  # builders consult compact_wire_dtype.
+  assert len(notes) == 1 and "bfloat16" in notes[0]
+
+
+def test_compact_f32_requires_compact_flag_and_consumer():
+  from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu import validation
+  with pytest.raises(validation.ParamError,
+                     match="compact_gradient_transfer_f32"):
+    validation.validate_cross_flags(params_lib.make_params(
+        compact_gradient_transfer_f32=True,
+        compact_gradient_transfer=False))
+  # Default per-leaf pmean repacks nothing: the flag would be a silent
+  # no-op under a logged halved-bytes claim, so it is rejected without
+  # a consuming packed path (review-caught).
+  with pytest.raises(validation.ParamError, match="no effect"):
+    validation.validate_cross_flags(params_lib.make_params(
+        compact_gradient_transfer_f32=True))
+  for consumer in (dict(gradient_repacking=4),
+                   dict(agg_small_grads_max_bytes=1024)):
+    validation.validate_cross_flags(params_lib.make_params(
+        compact_gradient_transfer_f32=True, **consumer))
+
+
+def test_packed_reducer_with_f32_compaction_rounds_to_bf16():
+  """The opt-in engages on a packed reducer (the small-gradient
+  aggregation of the ``packed_bf16_wire`` golden): the mean over a bf16
+  wire matches the f32 mean to bf16 rounding, and is not the f32 mean."""
+  from kf_benchmarks_tpu import params as params_lib
+  kw = dict(agg_small_grads_max_bytes=1 << 30,
+            agg_small_grads_max_group=1000, num_devices=N, device="cpu")
+  vals = jnp.stack([jnp.linspace(0.1, 1.0, 33, dtype=jnp.float32) * (r + 1)
+                    for r in range(N)])
+
+  def mean(**flags):
+    reducer = allreduce.build_reducer(params_lib.make_params(**kw, **flags))
+    body = lambda v: reducer({"g": jnp.squeeze(v, 0)}, "replica")["g"][None]
+    return np.asarray(jax.jit(jax.shard_map(
+        body, mesh=build_mesh(N, "cpu"), in_specs=(P("replica"),),
+        out_specs=P("replica")))(vals))
+
+  f32 = mean()
+  bf16 = mean(compact_gradient_transfer_f32=True)
+  np.testing.assert_allclose(f32, np.broadcast_to(
+      np.mean(np.asarray(vals), axis=0), f32.shape), rtol=1e-6)
+  np.testing.assert_allclose(bf16, f32, rtol=1e-2)
+  assert not np.array_equal(bf16, f32)

@@ -47,12 +47,14 @@ def _contract(n_coll=2, elems=1024, temp=1000, flops=1e9, aux=None):
 
 # -- fingerprints: tuned knobs key runs apart, plumbing does not --------------
 
-# One legal non-default value per tuned knob (reduce_bucket_mb needs an
-# overlap consumer; attn_block needs the LM family).
+# One legal non-default value per tuned knob (reduce_bucket_mb needs its
+# FSDP consumer; attn_block needs the LM family).
 _KNOB_CASES = {
     "steps_per_dispatch": (dict(BASE), 4),
     "num_grad_accum": (dict(BASE), 2),
-    "reduce_bucket_mb": (dict(BASE, overlap_gradient_reduction=True), 8),
+    "reduce_bucket_mb": (dict(BASE, optimizer="momentum",
+                              shard_optimizer_state=True,
+                              shard_params=True), 8),
     "input_prefetch_depth": (dict(BASE), 3),
     "attn_block": (dict(BASE, model="transformer_lm", batch_size=8),
                    256),
@@ -153,7 +155,7 @@ def test_prune_reasons_bounds():
   assert reasons and "HBM budget" in reasons[0]
   chatty = _contract(n_coll=9)
   assert autotune.prune_reasons(chatty, max_collectives=8)
-  bucketed = _contract(aux={"overlap_step_buckets": 99})
+  bucketed = _contract(aux={"fsdp_step_gathers": 99})
   assert autotune.prune_reasons(bucketed, max_step_buckets=64)
 
 

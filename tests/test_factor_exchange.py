@@ -293,7 +293,6 @@ MUST_NOT_ENGAGE = {
     "reducer_hierarchical_copy": dict(hierarchical_copy=True),
     "reducer_compact_wire": dict(gradient_repacking=2,
                                  compact_gradient_transfer_f32=True),
-    "overlap_gradient_reduction": dict(overlap_gradient_reduction=True),
     "zero_sharded_state": dict(shard_optimizer_state=True, **MOMENTUM),
     "fsdp_sharded_params": dict(shard_optimizer_state=True,
                                 shard_params=True, **MOMENTUM),

@@ -83,7 +83,7 @@ def test_per_op_profile_table(tmp_path):
   assert lines[1] == observability.PER_OP_TABLE_HEADER
   # The table closes with the two whole-program lines the per-op rows
   # cannot carry: the roofline MFU ceiling and the comm/compute overlap
-  # fraction (--overlap_gradient_reduction).
+  # fraction.
   assert lines[-2].startswith("MFU: ")
   assert lines[-1].startswith("comm/compute overlap:")
   ranked = lines[2:-2]

@@ -6,7 +6,7 @@ Layers, reference-style (SURVEY 7.1):
     injected inside a scan body -- that the extractor must place
     in-loop and the rule engine must reject.
   * golden configs: every earned contract (one-collective accum,
-    in-backward overlap, no-(B,T,V)-buffer LM, health-no-extra-
+    no-collective-in-loop, no-(B,T,V)-buffer LM, health-no-extra-
     collective, bf16-wire flag) verified by tracing each golden config
     on the 8-device mesh, passing the full rule set, and matching the
     checked-in golden fingerprint field-for-field.
@@ -89,7 +89,7 @@ def test_requested_wire_parser():
 def test_injected_in_scan_psum_is_placed_in_loop_and_rejected():
   """The end-to-end seed: a step-shaped program with a pmean inside a
   lax.scan body. The extractor must place the collective in-loop, and
-  the rule engine must reject it for an overlap-off config."""
+  the rule engine must reject it."""
   if len(jax.devices()) < 8:
     pytest.skip("needs the 8-device virtual CPU mesh")
   mesh = Mesh(np.array(jax.devices()[:8]), (REPLICA_AXIS,))
@@ -107,9 +107,9 @@ def test_injected_in_scan_psum_is_placed_in_loop_and_rejected():
   contract = contracts.extract_contract(hlo, config={})
   assert contract.in_loop_collectives(), "extractor missed the in-scan psum"
   violations = audit.audit_contract(
-      contract, rules={"overlap-in-backward":
-                       audit.rule_overlap_in_backward})
-  assert [v.rule for v in violations] == ["overlap-in-backward"]
+      contract, rules={"no-collective-in-loop":
+                       audit.rule_no_collective_in_loop})
+  assert [v.rule for v in violations] == ["no-collective-in-loop"]
 
 
 # -- golden configs: the earned contracts hold across the lattice -------------
@@ -141,12 +141,9 @@ def test_earned_contract_shapes(tracer):
   lm = tracer(contracts.GOLDEN_CONFIGS["lm_base"], "train_step")
   assert lm.largest_tensor_bytes < lm.aux["btv_bytes"]
   assert not lm.in_loop_collectives()
-  lm_over = tracer(contracts.GOLDEN_CONFIGS["lm_overlap"], "train_step")
-  assert len(lm_over.in_loop_collectives()) == 1
-  bf16 = tracer(contracts.GOLDEN_CONFIGS["overlap_bf16_wire"], "train_step")
+  bf16 = tracer(contracts.GOLDEN_CONFIGS["packed_bf16_wire"], "train_step")
   assert bf16.aux["requested_grad_wires"] == ["bf16"]
-  plain = tracer(contracts.GOLDEN_CONFIGS["overlap"], "train_step")
-  assert plain.aux["requested_grad_wires"] == ["f32"]
+  assert accum.aux["requested_grad_wires"] == ["f32"]
   health = tracer(contracts.GOLDEN_CONFIGS["health"], "train_step")
   base = tracer(contracts.GOLDEN_CONFIGS["base"], "train_step")
   n = lambda c: sum(1 for x in c.collectives if x.kind == "all-reduce")
@@ -165,14 +162,14 @@ def _add_collective(contract, **kw):
 MUTATIONS = [
     ("extra_in_loop_psum", "base",
      lambda c: _add_collective(c, in_loop=True),
-     "overlap-in-backward"),
+     "no-collective-in-loop"),
     ("extra_grad_collective_under_accum", "accum4_packed",
      lambda c: _add_collective(c),
      "accum-one-collective"),
     ("psum_inside_microbatch_scan", "accum4_packed",
      lambda c: _add_collective(c, in_loop=True),
      "accum-one-collective"),
-    ("leaked_f32_wire", "overlap_bf16_wire",
+    ("leaked_f32_wire", "packed_bf16_wire",
      lambda c: c.aux.update(requested_grad_wires=["bf16", "f32"]),
      "wire-dtype"),
     ("silent_bf16_downcast", "base",
@@ -225,10 +222,6 @@ MUTATIONS = [
      lambda c: _add_collective(c, elems=1 << 20,
                                replica_groups="{{0,1,2,3},{4,5,6,7}}"),
      "full-mesh-replica-groups"),
-    ("dropped_in_backward_hook", "lm_overlap",
-     lambda c: c.collectives.__setitem__(
-         slice(None), [x for x in c.collectives if not x.in_loop]),
-     "overlap-in-backward"),
     # PR 6 seeds. Replacing the scatter with a full all-reduce is the
     # exact regression --shard_optimizer_state exists to rule out: the
     # replicated exchange returns, and with it the 2(n-1)/n wire.
@@ -309,7 +302,7 @@ MUTATIONS = [
     # partitioner=gspmd contracts): a gradient collective seeded into
     # the microbatch scan on the gspmd side fires exactly the
     # referee's in-loop bug leg -- accum-one-collective and
-    # overlap-in-backward are gspmd-guarded off, so nothing else may
+    # no-collective-in-loop are gspmd-guarded off, so nothing else may
     # bite.
     ("gspmd_in_loop_gradient_collective", "gspmd_accum",
      lambda c: _add_collective(c, in_loop=True),

@@ -168,13 +168,19 @@ def test_packed_prefill_matches_incremental_decode(tiny_setup):
   builds -- so continuous batching can mix prefilled and decoded slots
   freely.
 
-  Equality structure: a prompt packed at row offset 0 rebuilds the
-  incremental cache BIT-IDENTICALLY (same block partition, and the
-  packed neighbors' masked keys contribute exactly zero); a prompt at
-  a nonzero offset sees the online softmax's K/V block boundaries
-  shifted relative to its tokens, so layers past the first agree to
-  float rounding instead -- asserted as such, with greedy sampling
-  (the engine's actual consumer) identical either way."""
+  Equality structure: positions and greedy tokens (the engine's actual
+  consumer) are identical; the caches agree to float rounding. A prompt
+  at a nonzero row offset sees the online softmax's K/V block
+  boundaries shifted relative to its tokens, and under jax 0.9.0's
+  XLA:CPU a prompt at offset 0 no longer rebuilds the incremental cache
+  to the bit either: the prefill's projections are products over
+  4 x 16 rows and the decode step's over 2, the backend picks its
+  kernel by shape, and already the FIRST layer's keys differ by up to
+  9.5e-7 (a drift of the backend, not a defect: 36 prompts over init
+  seeds 0-11, every first token equal, PR 29). One bar for both, read
+  from those 36: the largest difference is 2.50e-6 on values up to 3.5
+  (seed 2, second layer), and at rtol 1e-5 the largest atol any prompt
+  needs is 7.5e-7."""
   spec, variables, _ = tiny_setup
   prompts = [np.array([3, 1, 4, 1, 5], np.int32),
              np.array([9, 2, 6, 5, 3, 5, 8, 9, 7], np.int32),
@@ -218,10 +224,8 @@ def test_packed_prefill_matches_incremental_decode(tiny_setup):
     n = prm.size
     assert int(pos[i]) == n == int(p1[0])
     assert int(tok[i]) == int(first[i]) == int(nxt[0])
-    check = (np.testing.assert_array_equal
-             if placements[i][1] == 0 else
-             lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
-                                                     atol=1e-6))
+    check = lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
+                                                    atol=1e-6)
     check(np.asarray(ck[:, i, :n]), np.asarray(k1[:, 0, :n]))
     check(np.asarray(cv[:, i, :n]), np.asarray(v1[:, 0, :n]))
 

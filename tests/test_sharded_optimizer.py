@@ -161,7 +161,6 @@ def test_model_axis_requires_sharded_state():
     (dict(variable_consistency="relaxed"), "relaxed"),
     (dict(adaptive_batch_size=True), "adaptive_batch_size"),
     (dict(track_grad_noise_scale=True), "noise-scale"),
-    (dict(overlap_gradient_reduction=True), "overlap_gradient_reduction"),
     (dict(all_reduce_spec="rsag"), "all_reduce_spec"),
     (dict(gradient_repacking=2), "gradient_repacking"),
     (dict(agg_small_grads_max_bytes=1024), "agg_small_grads_max_bytes"),

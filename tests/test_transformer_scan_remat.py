@@ -280,11 +280,6 @@ def test_fsdp_blocks_rejections():
   with pytest.raises(ValueError, match="tensor"):
     transformer.make_train_step(mesh, stacked, learning_rate=0.1,
                                 scan_layers=True, fsdp_blocks=True)
-  mesh_dp = transformer.build_mesh(4, 2, 1)
-  with pytest.raises(ValueError, match="double-reduce"):
-    transformer.make_train_step(mesh_dp, stacked, learning_rate=0.1,
-                                scan_layers=True, fsdp_blocks=True,
-                                overlap_grad_reduce=True)
 
 
 def test_fsdp_blocks_forward_loss_matches_scanned():
