@@ -17,6 +17,13 @@ the window.
 
 Prints a markdown table (ms/step and tokens/s per L, both arms) for
 PERF.md.
+
+``--grad`` times forward AND backward (the gradient for q, k and v),
+and ``--heads`` / ``--head_dim`` set the shape: the attention core of
+the glm-4.7-flash cell alone, one layer of it, is
+
+    python experiments/long_context_probe.py --impls flash --grad \
+        --lengths 4096 --batch 2 --heads 20 --head_dim 256
 """
 
 from __future__ import annotations
@@ -38,9 +45,10 @@ H, D = 8, 128
 BLOCK = 512  # default; --block overrides
 
 
-def make_rep(impl, l, dtype, block=BLOCK, batch=1, q_block=None):
+def make_rep(impl, l, dtype, block=BLOCK, batch=1, q_block=None,
+             heads=H, head_dim=D, grad=False):
   ks = jax.random.split(jax.random.PRNGKey(0), 3)
-  q, k, v = (jax.random.normal(kk, (batch, l, H, D), dtype)
+  q, k, v = (jax.random.normal(kk, (batch, l, heads, head_dim), dtype)
              for kk in ks)
 
   if impl == "full":
@@ -62,10 +70,16 @@ def make_rep(impl, l, dtype, block=BLOCK, batch=1, q_block=None):
     attn = lambda q, k, v: sequence.blockwise_attention(
         q, k, v, block_size=block, causal=True)
 
+  def gradients(q, k, v):
+    # All three gradients feed the next query, so none is dead code.
+    dq, dk, dv = jax.grad(lambda *a: jnp.sum(
+        attn(*a).astype(jnp.float32)) * 1e-3, (0, 1, 2))(q, k, v)
+    return q + dq + ((dk + dv) * 1e-6).astype(q.dtype)
+
   @functools.partial(jax.jit, static_argnums=(3,))
   def rep(q, k, v, reps):
     def body(c, _):
-      out = attn(c, k, v)
+      out = gradients(c, k, v) if grad else attn(c, k, v)
       # Feed the output back as the next query so the scan chains on
       # the device (nothing constant-folds away).
       return out, None
@@ -94,18 +108,21 @@ def sync_time(f, args, reps, iters):
   return min(ts)
 
 
-def measure(impl, l, dtype, block=BLOCK, batch=1, q_block=None):
+def measure(impl, l, dtype, block=BLOCK, batch=1, q_block=None, **shape):
   reps_small, reps_big, iters = _reps_for(l)
-  rep, args = make_rep(impl, l, dtype, block, batch, q_block)
+  rep, args = make_rep(impl, l, dtype, block, batch, q_block, **shape)
   t_small = sync_time(rep, args, reps_small, iters)
   t_big = sync_time(rep, args, reps_big, iters)
   return (t_big - t_small) / (reps_big - reps_small)
 
 
-def causal_tflops(l, batch):
+def causal_tflops(l, batch, heads=H, head_dim=D, grad=False):
   """Useful (unmasked) causal attention FLOPs: 2 matmuls x B H L^2/2 D
-  MACs x 2 flops/MAC."""
-  return 2 * 2 * batch * H * (l * l / 2) * D / 1e12
+  MACs x 2 flops/MAC; with the backward, the 7 the mathematics needs
+  (scores, weighted values; the probabilities' gradient, dq, dk, dv, and
+  the scores once more, since no schedule keeps them)."""
+  products = 7 if grad else 2
+  return (products * 2 * batch * heads * (l * l / 2) * head_dim / 1e12)
 
 
 def main():
@@ -119,7 +136,12 @@ def main():
   ap.add_argument("--impls", nargs="+",
                   choices=["full", "blockwise", "tiled", "flash"],
                   default=["full", "blockwise", "tiled"])
+  ap.add_argument("--heads", type=int, default=H)
+  ap.add_argument("--head_dim", type=int, default=D)
+  ap.add_argument("--grad", action="store_true",
+                  help="time forward and backward, not forward alone")
   args = ap.parse_args()
+  shape = dict(heads=args.heads, head_dim=args.head_dim, grad=args.grad)
   dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
 
   print(f"devices: {jax.devices()}")
@@ -129,11 +151,12 @@ def main():
       row = {"L": l, "B": batch}
       for impl in args.impls:
         try:
-          dt = measure(impl, l, dtype, args.block, batch, args.q_block)
+          dt = measure(impl, l, dtype, args.block, batch, args.q_block,
+                       **shape)
           row[impl] = dt
           print(f"B={batch} L={l} {impl}: {dt*1e3:.2f} ms "
                 f"({batch*l/dt:,.0f} tok/s, "
-                f"{causal_tflops(l, batch)/dt:.1f} TFLOP/s eff)",
+                f"{causal_tflops(l, batch, **shape)/dt:.1f} TFLOP/s eff)",
                 flush=True)
         except Exception as e:  # noqa: BLE001 -- OOM is an expected arm
           row[impl] = None
@@ -141,8 +164,9 @@ def main():
                 f"{str(e)[:120]})", flush=True)
       rows.append(row)
 
-  print(f"\nH={H} D={D} block={args.block} q_block="
-        f"{args.q_block or args.block} dtype={args.dtype}, causal")
+  print(f"\nH={args.heads} D={args.head_dim} block={args.block} q_block="
+        f"{args.q_block or args.block} dtype={args.dtype}, causal, "
+        f"{'forward + backward' if args.grad else 'forward'}")
   hdr = " | ".join(f"{i} ms | {i} TFLOP/s" for i in args.impls)
   print(f"| B | L | {hdr} |")
   print("|---" * (2 + 2 * len(args.impls)) + "|")
@@ -153,7 +177,7 @@ def main():
         cells += ["OOM", "-"]
       else:
         cells += [f"{r[impl]*1e3:.2f}",
-                  f"{causal_tflops(r['L'], r['B'])/r[impl]:.1f}"]
+                  f"{causal_tflops(r['L'], r['B'], **shape)/r[impl]:.1f}"]
     print(f"| {r['B']} | {r['L']} | " + " | ".join(cells) + " |")
 
 

@@ -2001,6 +2001,11 @@ class BenchmarkCNN:
         "moe": (dict(self._trace.static("moe") or {},
                      **self.model.counter_stats(np.stack(counter_rows)))
                 if counter_rows else None),
+        # The attention core as the model stated it at the build
+        # (models/mla_moe_lm.py, parallel/sequence.flash_plan): layers,
+        # backward kernel passes a layer, the kernel's tiles. Static.
+        # None for a model without one.
+        "attention": self._trace.static("attention"),
         # The allocator's own account of the fullest device of the
         # mesh, read as the timed loop ends: live buffers at their peak,
         # what the runtime reserved for loaded programs at its peak, and

@@ -445,6 +445,11 @@ NON_METRIC_KEYS = frozenset({
     # nested table (benchmarks/layer_metrics/hbm_peak_in_use_gib.py);
     # None on a backend without memory statistics.
     "device_memory",
+    # PR 30: the attention core as models/mla_moe_lm.py states it at the
+    # build ({core_layers, backward_kernel_passes, block, block_q,
+    # block_kv, block_kv_dkv, dq_partials}; parallel/sequence.flash_plan): a
+    # description of the program that ran, None for every other model.
+    "attention",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

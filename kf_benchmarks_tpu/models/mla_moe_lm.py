@@ -95,7 +95,16 @@ DEFAULT_SEQ_LEN = 4096
 MTP_LOSS_WEIGHT = 0.3
 BIAS_UPDATE_SPEED = 0.001
 INIT_STD = 0.02
-ATTN_BLOCK = 512   # 1024 overflows the v5e's VMEM at head size 256
+# The block of scores the core's kernels compute at a time, forward and
+# backward (parallel/sequence.flash_plan, which has what the v5e's VMEM
+# allows at head size 256 field by field of the kernel's tiling). At this
+# block the forward fetches 1024 queries and 1024 keys a grid step (2048
+# keys fit only beside 512 queries, 4096 not at all), and the backward
+# holds 1024 keys across its sweep over the queries in tiles of 512, which
+# leaves 4 partial dq at 4096 keys (2048 keys held do not fit, nor 1024
+# queries beside 1024 keys). The two-kernel backward this one replaced
+# fitted 512 in every field and nothing larger.
+ATTN_BLOCK = 512
 ROUTER_PROBE_TOKENS = 1024
 # The family's learning rate: a linear warm-up from 0 to the peak over
 # 2,000 steps (arXiv:2412.19437 section 4.2), held there; the decay that
@@ -283,9 +292,10 @@ class MLAttention(_Options):
           [kv[..., :nope], jnp.broadcast_to(k_rot, (b, t, h, rot))], -1)
       v = kv[..., nope:]
       with jax.named_scope("attention_core"):
-        # The Pallas flash kernel on a TPU (one kernel serves queries,
-        # keys and values: nope + rope = v_head_dim here), materialised
-        # scores on the CPU (parallel/sequence.py).
+        # The Pallas kernel on a TPU (one forward and ONE backward
+        # kernel; one head size serves queries, keys and values: nope +
+        # rope = v_head_dim here), materialised scores on the CPU
+        # (parallel/sequence.py).
         att = sequence_lib.pallas_flash_attention(
             q, k, v, causal=True, scale=1.0 / math.sqrt(nope + rot),
             block=min(ATTN_BLOCK, t))
@@ -504,6 +514,17 @@ class MLAMoELMModel(model_lib.Model):
           "buffer_rows": expert_lib.compact_rows(
               self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
               c.experts_held, c.n_routed_experts)})
+      core = self.attention_core_stats()
+      trace.set_static("attention", core)
+      log_util.log_fn(
+          f"attention core: {core['core_layers']} layer(s), " + (
+              "backward in {backward_kernel_passes} kernel pass a layer; "
+              "scores in blocks of {block}, the forward fetching "
+              "{block_q} queries x {block_kv} keys a grid step, "
+              "{block_kv_dkv} keys held across a backward sweep, "
+              "{dq_partials} partial dq summed outside the kernel"
+              if core["backward_kernel_passes"] else
+              "materialised scores (no kernel off the TPU)").format(**core))
       log_util.log_fn(
           f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
           f"layers ({c.dense_layers} dense, {c.moe_layers} mixture, "
@@ -517,6 +538,19 @@ class MLAMoELMModel(model_lib.Model):
   @cfg.setter
   def cfg(self, value: LMConfig) -> None:
     self._cfg = value
+
+  def attention_core_stats(self):
+    """The run's ``stats["attention"]``: what the core of every attention
+    layer (the MTP block's too) runs, as ``MLAttention`` will ask for it
+    when the step is traced: from the shapes and the backend, so it
+    cannot vary by step."""
+    c = self._cfg
+    plan = sequence_lib.flash_plan(
+        self.seq_len, self.seq_len, c.qk_nope_head_dim + c.qk_rope_head_dim,
+        min(ATTN_BLOCK, self.seq_len),
+        cpu_fallback=jax.default_backend() != "tpu")
+    return dict(dataclasses.asdict(plan),
+                core_layers=c.layers_held + c.num_nextn_predict_layers)
 
   def counter_stats(self, rows):
     """``rows`` (steps, len(MOE_COUNTERS)) of ``moe_counters`` as the

@@ -35,6 +35,8 @@ stores per step is the O(block) carry/operand set, not the score tile
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -744,9 +746,9 @@ def ulysses_attention(q, k, v, axis_name: str = SEQ_AXIS,
 
 
 def uniform_flash_block_sizes(block: int):
-  """All-fields-equal BlockSizes for the Pallas kernel -- ONE place to
-  build 'matched tiling' configurations, so A/Bs against the XLA-scan
-  paths cannot silently diverge between call sites."""
+  """All-fields-equal BlockSizes for the library's older flash kernel,
+  which ``decode_attention`` calls forward only (one query a slot: the
+  kernel of ``pallas_flash_attention`` tiles queries by 128 and more)."""
   from jax.experimental.pallas.ops.tpu import flash_attention as fa
   return fa.BlockSizes(
       block_q=block, block_k_major=block, block_k=block, block_b=1,
@@ -755,31 +757,133 @@ def uniform_flash_block_sizes(block: int):
       block_k_dq=block, block_q_dq=block)
 
 
+# The largest tile of queries or of keys (rows x head size, the head size
+# padded to the lanes) that the kernels hold in the v5e's VMEM beside
+# their other tiles, at head size 256 (the TPU's compiler, no chip
+# needed: tests/test_tpu_step_compile.py). Forward: 1024 queries and 1024
+# keys fit together; 2048 keys fit beside 512 queries and not beside
+# 1024; 4096 keys do not fit. Backward: 1024 keys (with their values, two
+# float32 accumulators and the two gradients' tiles) fit beside 512
+# queries; 2048 do not, with 512 or with 256 queries; nor do 1024 beside
+# 1024 queries.
+_FLASH_TILE = 1024 * 256
+_FLASH_DEFAULT_BLOCK = 512
+_LANES = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashPlan:
+  """What ``pallas_flash_attention`` runs for one shape: ``flash_plan``
+  decides it from the shapes alone, and a model states it in its run's
+  ``stats["attention"]``.
+
+  ``backward_kernel_passes``: 1 = ONE backward kernel forms each (query
+  block, key block) tile's scores, probabilities and probability
+  gradient once and takes dq, dk and dv from them (5 products of
+  seq^2 x head size with the forward's 2); 0 = no kernel at all, the
+  materialised scores of ``full_attention`` (off the TPU).
+  ``block`` is the tile of scores either kernel computes at a time
+  (block x block), and the backward's tile of queries. The forward
+  fetches ``block_q`` queries and ``block_kv`` keys a grid step.
+  ``block_kv_dkv`` keys stay in VMEM across a backward sweep over the
+  queries, which leaves ``dq_partials`` = kv_len / block_kv_dkv partial
+  dq of the queries' shape, summed by XLA outside the kernel."""
+  backward_kernel_passes: int
+  block: int = 0
+  block_q: int = 0
+  block_kv: int = 0
+  block_kv_dkv: int = 0
+  dq_partials: int = 0
+
+
+def flash_plan(q_len: int, kv_len: int, head_dim: int,
+               block: Optional[int] = None,
+               cpu_fallback: bool = False) -> FlashPlan:
+  """The kernel plan of ``pallas_flash_attention`` for these shapes.
+
+  ``block`` (default 512) is clamped to both lengths. Both kernels work
+  on block x block scores at a time and fetch more a grid step where it
+  divides the lengths and fits the VMEM (``_FLASH_TILE``): the forward
+  twice the block in queries and in keys (fewer grid steps: 2.90 ms for
+  3.31 a pass at the glm-4.7-flash cell's shape, PERF.md section 6,
+  PR 30), the backward the largest power-of-two multiple in keys (fewer
+  partial dq to write and to sum). Shapes the kernel cannot tile are
+  refused here, by name, and not inside its lowering."""
+  if cpu_fallback:
+    return FlashPlan(backward_kernel_passes=0)
+  block = min(block or _FLASH_DEFAULT_BLOCK, q_len, kv_len)
+  if block % _LANES or q_len % block or kv_len % block:
+    raise ValueError(
+        f"the flash kernel tiles queries and keys in blocks that are a "
+        f"multiple of {_LANES} and divide both lengths; got block "
+        f"{block} for {q_len} queries and {kv_len} keys")
+  width = -(-head_dim // _LANES) * _LANES   # as VMEM pads it
+  fits = lambda rows: rows * width <= _FLASH_TILE
+  fwd = block
+  if fits(2 * block) and q_len % (2 * block) == kv_len % (2 * block) == 0:
+    fwd = 2 * block
+  dkv = block
+  while fits(2 * dkv) and kv_len % (2 * dkv) == 0:
+    dkv *= 2
+  return FlashPlan(backward_kernel_passes=1, block=block, block_q=fwd,
+                   block_kv=fwd, block_kv_dkv=dkv,
+                   dq_partials=kv_len // dkv)
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_kernel(q_len: int, kv_len: int, heads: int, causal: bool,
+                   plan: FlashPlan, interpret: bool):
+  """The library's splash kernel object for one (lengths, heads, plan):
+  built once a process and shared by every layer and every trace, so the
+  mask's host-side pre-processing (0.2 s at 4096 x 4096) is paid once."""
+  from jax.experimental.pallas.ops.tpu.splash_attention import (
+      splash_attention_kernel as splash, splash_attention_mask as masks)
+  mask = (masks.CausalMask if causal else masks.FullMask)((q_len, kv_len))
+  sizes = splash.BlockSizes(
+      block_q=plan.block_q, block_kv=plan.block_kv,
+      block_kv_compute=plan.block, block_q_dkv=plan.block,
+      block_kv_dkv=plan.block_kv_dkv, block_kv_dkv_compute=plan.block,
+      use_fused_bwd_kernel=True)
+  # The mask's tables become constants of whatever program is being
+  # traced when the first layer asks; the next trace reuses them.
+  with jax.ensure_compile_time_eval():
+    return splash.make_splash_mha_single_device(
+        masks.MultiHeadMask([mask] * heads), block_sizes=sizes,
+        interpret=interpret)
+
+
 def pallas_flash_attention(q, k, v, causal: bool = False,
                            scale: Optional[float] = None,
-                           block_sizes=None, block: Optional[int] = None,
+                           block: Optional[int] = None,
                            segment_ids=None,
-                           cpu_fallback: Optional[bool] = None):
-  """JAX's TPU Pallas flash-attention kernel behind this module's
-  (B, L, H, D) layout -- the hand-tiled alternative to the XLA-scan
-  blockwise schedule, for A/B measurement on hardware
-  (experiments/long_context_probe.py --impls flash).
+                           cpu_fallback: Optional[bool] = None,
+                           interpret: bool = False):
+  """JAX's TPU Pallas attention kernel behind this module's
+  (B, L, H, D) layout: the core of models/mla_moe_lm.py, the ``flash``
+  arm of models/transformer_lm.py, and the hand-tiled alternative to the
+  XLA-scan blockwise schedule (experiments/long_context_probe.py
+  --impls flash).
 
-  ``segment_ids`` (B, L) int rides the kernel's native SegmentIds
-  support (packed sequences): the kernel masks cross-segment tiles and
-  skips fully-masked blocks inside its own grid schedule, so packing
-  composes with the hand-tiled path without a dense (L, L) mask.
+  The kernel is the library's splash attention (jax.experimental.pallas.
+  ops.tpu.splash_attention) with its fused backward: differentiated, it
+  is ONE forward kernel and ONE backward kernel, which forms each tile's
+  scores once and takes dq, dk and dv from them (``flash_plan`` has the
+  tiling; bfloat16 or float32 in, float32 accumulation and softmax
+  statistics inside). The kernel takes no scale, so q is scaled before
+  it, in q's dtype: exact for a power of two (head sizes 64 and 256).
 
-  The kernel itself (jax.experimental.pallas.ops.tpu.flash_attention)
-  has no CPU lowering. ``cpu_fallback=None`` (the default) therefore
-  routes non-TPU backends to ``full_attention`` with the identical
-  mask semantics -- the kernel's own reference form -- so CPU suites
-  can EXECUTE flash-configured models (the packed-sequence oracle
-  tests), not just trace them; ``False`` forces the kernel path (the
-  trace-level BlockSizes drift guard wants the real call graph), and
-  ``True`` forces the reference path on any backend. Differentiable on
-  both paths -- the library ships fused dq/dkv backward kernels via
-  custom_vjp.
+  ``segment_ids`` (B, L) int rides the kernel's own SegmentIds (packed
+  sequences): cross-segment tiles are masked inside its grid, without a
+  dense (L, L) mask.
+
+  The kernel has no CPU lowering. ``cpu_fallback=None`` (the default)
+  therefore routes non-TPU backends to ``full_attention`` with the
+  identical mask semantics -- the kernel's own reference form -- so CPU
+  suites can EXECUTE flash-configured models (the packed-sequence oracle
+  tests), not just trace them; ``False`` forces the kernel path (with
+  ``interpret=True`` the CPU runs the kernel's body itself, a test's
+  way to hold its numbers to the reference), and ``True`` forces the
+  reference path on any backend. Differentiable on both paths.
 
   This is a CPU path for the CPU suites, not a fallback that can hide
   the device: under ``--device=tpu`` benchmark.setup() has already
@@ -790,25 +894,22 @@ def pallas_flash_attention(q, k, v, causal: bool = False,
     cpu_fallback = jax.default_backend() != "tpu"
   d = q.shape[-1]
   scale = (1.0 / math.sqrt(d)) if scale is None else scale
-  if cpu_fallback:
+  plan = flash_plan(q.shape[1], k.shape[1], d, block, cpu_fallback)
+  if not plan.backward_kernel_passes:
     return full_attention(q, k, v, causal=causal, scale=scale,
                           segment_ids=segment_ids)
-  from jax.experimental.pallas.ops.tpu import flash_attention as fa
-  if block is not None:
-    if block_sizes is not None:
-      raise ValueError("pass block OR block_sizes, not both")
-    # Clamp to BOTH sequence lengths: the uniform BlockSizes tile the
-    # K/V axis too, so a short-KV (cross-attention-shaped) input with
-    # kv_len < block would otherwise mis-tile the k-major grid
-    # (advisor round-5).
-    block_sizes = uniform_flash_block_sizes(
-        min(block, q.shape[1], k.shape[1]))
-  seg = None
-  if segment_ids is not None:
-    seg = fa.SegmentIds(q=segment_ids, kv=segment_ids)
-  qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
-  out = fa.flash_attention(qt, kt, vt, None, seg, causal=causal,
-                           sm_scale=scale, block_sizes=block_sizes)
+  kernel = _splash_kernel(q.shape[1], k.shape[1], q.shape[2], causal, plan,
+                          interpret)
+  qt, kt, vt = (x.swapaxes(1, 2) for x in (q * jnp.asarray(scale, q.dtype),
+                                           k, v))
+  if segment_ids is None:
+    out = jax.vmap(kernel)(qt, kt, vt)
+  else:
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash)
+    out = jax.vmap(lambda q_, k_, v_, s: kernel(
+        q_, k_, v_, segment_ids=splash.SegmentIds(q=s, kv=s)))(
+            qt, kt, vt, segment_ids)
   return out.swapaxes(1, 2).astype(q.dtype)
 
 

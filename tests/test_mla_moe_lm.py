@@ -500,7 +500,8 @@ def test_one_step_moves_the_bias_by_its_own_rule(tmp_path):
   assert moe["load_max_over_mean"] >= 1
 
 
-def test_tier_counter_reaches_the_stats():
+@pytest.fixture(scope="module")
+def two_step_stats():
   from kf_benchmarks_tpu import benchmark
   from kf_benchmarks_tpu import params as params_lib
   params = params_lib.make_params(
@@ -508,11 +509,50 @@ def test_tier_counter_reaches_the_stats():
       lm_layer_shards=4, lm_layer_shard_index=1, device="cpu",
       optimizer="adam", num_batches=2, num_warmup_batches=0,
       display_every=1, tf_random_seed=5)
-  moe = benchmark.BenchmarkCNN(benchmark.setup(params)).run()["moe"]
+  return benchmark.BenchmarkCNN(benchmark.setup(params)).run()
+
+
+def test_tier_counter_reaches_the_stats(two_step_stats):
+  moe = two_step_stats["moe"]
   # 2 x 16 tokens x 2 choices: under one row tile, so one round of all
   # 64 pairs in each of the 3 mixture layers of each of the 2 steps.
   assert moe["steps"] == 2 and moe["buffer_rows"] == 64
   assert moe["compact_share"] == 1.0 and moe["pairs_dropped"] == 0
+
+
+def test_attention_core_states_itself_in_the_stats(two_step_stats):
+  # Off the TPU no kernel runs (materialised scores), in the 3 layers
+  # held and the MTP block; the keys are the ones a TPU run fills.
+  assert two_step_stats["attention"] == {
+      "core_layers": 4, "backward_kernel_passes": 0, "block": 0,
+      "block_q": 0, "block_kv": 0, "block_kv_dkv": 0, "dq_partials": 0}
+
+
+def test_attention_core_of_the_glm_cell_on_a_tpu(monkeypatch):
+  # What the benchmark's cell states (2 x 4096 tokens, head size 256, 5
+  # layers and the MTP block): ONE backward kernel pass a layer on scores
+  # of 512 x 512, the forward fetching 1,024 queries and keys a grid
+  # step, 1,024 keys held across the backward's sweep, four partial dq.
+  from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu.utils import log as log_util
+  model = lm.MLAMoELMModel(params_lib.make_params(
+      model="mla_moe_lm", seq_len=4096, batch_size=2, lm_layers_held=5,
+      lm_layer_shards=8, device="cpu"))
+  lines = []
+  monkeypatch.setattr(log_util, "log_fn", lines.append)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  assert model.cfg.layers_held == 5       # the first read states the core
+  assert [ln for ln in lines if ln.startswith("attention core: ")] == [
+      "attention core: 6 layer(s), backward in 1 kernel pass a layer; "
+      "scores in blocks of 512, the forward fetching 1024 queries x 1024 "
+      "keys a grid step, 1024 keys held across a backward sweep, 4 "
+      "partial dq summed outside the kernel"]
+  assert model.attention_core_stats() == {
+      "core_layers": 6, "backward_kernel_passes": 1, "block": 512,
+      "block_q": 1024, "block_kv": 1024, "block_kv_dkv": 1024,
+      "dq_partials": 4}
+  monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+  assert model.attention_core_stats()["backward_kernel_passes"] == 0
 
 
 def test_learning_rate_is_the_familys_warm_up_unless_the_job_states_one():

@@ -38,6 +38,9 @@ from kf_benchmarks_tpu.parallel.mesh import REPLICA_AXIS
 # compile): temporaries, and the peak with arguments and outputs.
 PARENT_TEMP_BYTES = 4_257_519_104
 PARENT_PEAK_BYTES = 5_395_633_664
+# ... and of the glm-4.7-flash cell's attention core alone, forward and
+# backward, on the parent of PR 30 (commit 0ea3274).
+PARENT_CORE_TEMP_BYTES = 503_380_992
 
 
 @pytest.fixture(scope="module")
@@ -90,8 +93,11 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   has to fit the v5e's VMEM (1024 x 1536 of the weight did not), the
   whole routed path around it (PR 28: rounds in a while loop, and no
   array of all the pairs times a model width in what the compiler makes
-  of it), and the flash attention core at head size 256 for queries, keys
-  and values (block 1024 did not fit)."""
+  of it), and the attention core at head size 256 for queries, keys and
+  values (PR 30: ONE forward and ONE backward kernel, the backward
+  holding 1,024 keys across its sweep over the queries; 2,048 did not
+  fit), in no more of XLA's temporaries than the two-kernel backward it
+  replaced."""
   import jax
   from jax.sharding import SingleDeviceSharding
   from kf_benchmarks_tpu.models import mla_moe_lm
@@ -135,6 +141,14 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
         q, k, v, causal=True, scale=1 / 16.0, block=mla_moe_lm.ATTN_BLOCK,
         cpu_fallback=False).astype(jnp.float32))
   qkv = sds((2, 4096, 20, 256), jnp.bfloat16)
-  text = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
-      qkv, qkv, qkv).compile().as_text()
-  assert text.count('custom_call_target="tpu_custom_call"') >= 3
+  compiled = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
+      qkv, qkv, qkv).compile()
+  text = compiled.as_text()
+  assert text.count('custom_call_target="tpu_custom_call"') == 2
+  assert "splash_mha_fwd_residuals" in text
+  assert "splash_mha_dkv_no_residuals" in text
+  # The four partial dq of the queries' shape are the kernel's, summed
+  # by XLA; the pair of kernels it replaced left 503,380,992 bytes of
+  # temporaries in the same compile (parent 0ea3274).
+  assert "bf16[2,4,20,4096,256]" in text
+  assert compiled.memory_analysis().temp_size_in_bytes <= PARENT_CORE_TEMP_BYTES
