@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
-"""The controls of the glm-4.7-flash cells: the benchmark's own command
+"""The controls of the language-model cells: the benchmark's own command
 on a program with ONE fault planted from outside. Each has to come out
 NOT ``correct``, by the number of the cell's reference check
-(``benchmarks/named_checks/glm-4.7-flash_reference_agrees.py``) named
-beside it; PERF.md section 6 (PR 27) has the chip's readings.
+(``benchmarks/named_checks/<config>_reference_agrees.py``) named beside
+it; PERF.md section 6 (PR 27, PR 32) has the chip's readings.
 
     python3 experiments/lm_precision_control.py [--fault <name>] \
         --workload <cell> --seed <n> --seconds 10 --trace 0
+
+Both configurations:
 
 * ``router_bf16`` (the default; the lower-precision control): the ROUTER
   of ``mla_moe_lm`` computed in bfloat16 (product, sigmoid, top-k and
@@ -16,15 +18,30 @@ beside it; PERF.md section 6 (PR 27) has the chip's readings.
 * ``state_unchanged``: the step program the check calls moves Adam's
   moments and leaves the parameters where they were. Fails
   ``param_change_err``, which reads 1.
-* ``half_batch``: both losses over the first half of the batch. Fails
-  every ``grad_err.*``.
+* ``half_batch``: the losses over the first half of the batch (glm), of
+  the one sequence (trinity-mini). Fails every ``grad_err.*``.
+* ``no_scaling``: the routed experts' part without its
+  ``routed_scaling_factor`` / ``route_scale``. Fails ``layer_output_err``.
+
+glm-4.7-flash alone:
+
 * ``no_mtp``: the MTP term left out of the loss. Fails ``step_loss_err``
   and ``grad_err.eh_proj``.
-* ``no_scaling``: the routed experts' part without its
-  ``routed_scaling_factor``. Fails ``layer_output_err``.
+
+trinity-mini alone (each ONE departure from the published layer):
+
+* ``window_2047`` / ``window_2049``: the window layers see one key fewer
+  / one more. Fails ``window_edge_err``.
+* ``rope_in_full``: the full layers rotate q and k like the window
+  layers. Fails ``layer_output_err``.
+* ``no_gate``: the core's output reaches ``o_proj`` without its gate.
+  Fails ``layer_output_err``.
+* ``no_post_norms``: the norms AFTER the two sublayers pass their input
+  through. Fails ``layer_output_err``.
 
 The program has no option for any of this: ``plant`` steers it from
-outside, here and in ``tests/benchmarks/test_bench_lm.py``.
+outside, here and in ``tests/benchmarks/test_bench_lm.py`` /
+``test_bench_afmoe.py``.
 """
 
 import dataclasses
@@ -35,7 +52,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 FAULTS = ("router_bf16", "state_unchanged", "half_batch", "no_mtp",
-          "no_scaling")
+          "no_scaling", "window_2047", "window_2049", "rope_in_full",
+          "no_gate", "no_post_norms")
 
 
 def plant(fault, setattr_=setattr):
@@ -55,9 +73,12 @@ def plant(fault, setattr_=setattr):
     losses = model.losses
 
     def half(self, heads, labels):
-      n = labels.shape[0] // 2
-      hidden = tuple(h if h is None else h[:n] for h in heads.hidden)
-      return losses(self, heads._replace(hidden=hidden), labels[:n])
+      # Half of the sequences; of the one sequence where there is one.
+      axis = 0 if labels.shape[0] > 1 else 1
+      cut = lambda x: jax.lax.slice_in_dim(x, 0, x.shape[axis] // 2,
+                                           axis=axis)
+      hidden = tuple(h if h is None else cut(h) for h in heads.hidden)
+      return losses(self, heads._replace(hidden=hidden), cut(labels))
     setattr_(model, "losses", half)
   elif fault == "no_mtp":
     setattr_(model, "loss_function", lambda self, result, labels:
@@ -66,6 +87,36 @@ def plant(fault, setattr_=setattr):
     load = mla_moe_lm.load_lm_config
     setattr_(mla_moe_lm, "load_lm_config", lambda *a, **k:
              dataclasses.replace(load(*a, **k), routed_scaling_factor=1.0))
+  elif fault in ("window_2047", "window_2049"):
+    load = mla_moe_lm.load_lm_config
+    off = -1 if fault == "window_2047" else 1
+
+    def one_off(*a, **k):
+      cfg = load(*a, **k)
+      return dataclasses.replace(cfg, sliding_window=cfg.sliding_window + off)
+    setattr_(mla_moe_lm, "load_lm_config", one_off)
+  elif fault == "rope_in_full":
+    # A window no sequence reaches is the causal half (parallel/
+    # sequence.py), and a layer with a window rotates q and k.
+    window = mla_moe_lm.LMConfig.window
+    setattr_(mla_moe_lm.LMConfig, "window", lambda self, i: (
+        2 ** 30 if window(self, i) is None else window(self, i)))
+  elif fault == "no_gate":
+    setattr_(mla_moe_lm, "gated", lambda core, gate: core)
+  elif fault == "no_post_norms":
+    import flax.linen as nn
+
+    class PassThrough(mla_moe_lm.RMSNorm):
+      @nn.compact
+      def __call__(self, x):
+        self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                   self.param_dtype)
+        return x.astype(jnp.float32)
+    norm = mla_moe_lm.Block.norm
+    setattr_(mla_moe_lm.Block, "norm", lambda self, name: (
+        PassThrough(self.cfg.rms_norm_eps, self.param_dtype, name=name)
+        if name.startswith("post_") and self.cfg.post_norms
+        else norm(self, name)))
   elif fault == "state_unchanged":
     def get(self):
       step = self.__dict__.get("timed_step")

@@ -24,6 +24,14 @@ the glm-4.7-flash cell alone, one layer of it, is
 
     python experiments/long_context_probe.py --impls flash --grad \
         --lengths 4096 --batch 2 --heads 20 --head_dim 256
+
+``--kv_heads`` (fewer key heads than query heads), ``--window`` (a
+causal band) and ``--tiles Q KV HELD`` (the forward's tile and the keys
+the backward holds, in place of ``flash_plan``'s own choice) shape the
+``flash`` arm alone: a window layer of the trinity-mini cell is
+
+    python experiments/long_context_probe.py --impls flash --grad \
+        --lengths 8192 --heads 32 --kv_heads 4 --head_dim 128 --window 2048
 """
 
 from __future__ import annotations
@@ -46,10 +54,14 @@ BLOCK = 512  # default; --block overrides
 
 
 def make_rep(impl, l, dtype, block=BLOCK, batch=1, q_block=None,
-             heads=H, head_dim=D, grad=False):
+             heads=H, head_dim=D, grad=False, kv_heads=None, window=None,
+             tiles=None):
   ks = jax.random.split(jax.random.PRNGKey(0), 3)
-  q, k, v = (jax.random.normal(kk, (batch, l, heads, head_dim), dtype)
-             for kk in ks)
+  q, k, v = (jax.random.normal(kk, (batch, l, n, head_dim), dtype)
+             for kk, n in zip(ks, (heads, kv_heads or heads,
+                                   kv_heads or heads)))
+  if impl != "flash" and (kv_heads or window or tiles):
+    raise ValueError("--kv_heads, --window and --tiles shape the flash arm")
 
   if impl == "full":
     attn = lambda q, k, v: sequence.full_attention(q, k, v, causal=True)
@@ -64,8 +76,13 @@ def make_rep(impl, l, dtype, block=BLOCK, batch=1, q_block=None,
     # scan lowering leaves on the table, if anything. --block sets the
     # kernel's q/k tiles so the A/B against tiled/blockwise compares
     # matched tilings (one shared BlockSizes builder in sequence.py).
+    plan = None
+    if tiles:
+      block_q, block_kv, held = tiles
+      plan = sequence.FlashPlan(1, min(block, l), block_q, block_kv, held,
+                                l // held)
     attn = lambda q, k, v: sequence.pallas_flash_attention(
-        q, k, v, causal=True, block=block)
+        q, k, v, causal=True, block=block, window=window, plan=plan)
   else:
     attn = lambda q, k, v: sequence.blockwise_attention(
         q, k, v, block_size=block, causal=True)
@@ -74,7 +91,9 @@ def make_rep(impl, l, dtype, block=BLOCK, batch=1, q_block=None,
     # All three gradients feed the next query, so none is dead code.
     dq, dk, dv = jax.grad(lambda *a: jnp.sum(
         attn(*a).astype(jnp.float32)) * 1e-3, (0, 1, 2))(q, k, v)
-    return q + dq + ((dk + dv) * 1e-6).astype(q.dtype)
+    # (A scalar of dk and dv: they have the key heads' shape.)
+    return q + dq + (jnp.mean((dk + dv).astype(jnp.float32)) * 1e-6).astype(
+        q.dtype)
 
   @functools.partial(jax.jit, static_argnums=(3,))
   def rep(q, k, v, reps):
@@ -116,7 +135,7 @@ def measure(impl, l, dtype, block=BLOCK, batch=1, q_block=None, **shape):
   return (t_big - t_small) / (reps_big - reps_small)
 
 
-def causal_tflops(l, batch, heads=H, head_dim=D, grad=False):
+def causal_tflops(l, batch, heads=H, head_dim=D, grad=False, **_):
   """Useful (unmasked) causal attention FLOPs: 2 matmuls x B H L^2/2 D
   MACs x 2 flops/MAC; with the backward, the 7 the mathematics needs
   (scores, weighted values; the probabilities' gradient, dq, dk, dv, and
@@ -140,8 +159,13 @@ def main():
   ap.add_argument("--head_dim", type=int, default=D)
   ap.add_argument("--grad", action="store_true",
                   help="time forward and backward, not forward alone")
+  ap.add_argument("--kv_heads", type=int, default=None)
+  ap.add_argument("--window", type=int, default=None)
+  ap.add_argument("--tiles", type=int, nargs=3, default=None,
+                  metavar=("Q", "KV", "HELD"))
   args = ap.parse_args()
-  shape = dict(heads=args.heads, head_dim=args.head_dim, grad=args.grad)
+  shape = dict(heads=args.heads, head_dim=args.head_dim, grad=args.grad,
+               kv_heads=args.kv_heads, window=args.window, tiles=args.tiles)
   dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
 
   print(f"devices: {jax.devices()}")

@@ -1,47 +1,89 @@
-"""A config-driven decoder with latent attention and a dropless mixture
-of experts: the zoo's ``mla_moe_lm`` (``model_type: glm4_moe_lite``,
-the DeepSeek-V3 block).
+"""A config-driven decoder with a dropless mixture of experts: the zoo's
+``mla_moe_lm``. ONE decoder whose layer recipe comes from the
+configuration's family (``model_type``, ``_FAMILIES``):
+
+* ``glm4_moe_lite`` (the DeepSeek-V3 block): latent attention, pre-norm,
+  one multi-token-prediction module;
+* ``afmoe`` (Arcee's Trinity family): grouped-query gated attention over
+  WINDOW and FULL layers in one stack, norms on both sides of each
+  sublayer, muP's embedding scale, no MTP.
+
+``MoE``, ``SwiGLU``, ``RMSNorm``, ``rope``, the losses and the share are
+the same code for both (the zoo name stays ``mla_moe_lm``: the module
+grew a second attention, not a second decoder).
 
 BEYOND-REFERENCE: the reference zoo has no language model. Every size
 comes from ONE data file, the model's published ``config.json`` held
 verbatim under ``lm_configs/`` (``--lm_config=<name>``); a second
-configuration of this family is a second file there, not code.
+configuration of a family is a second file there, not code, and a
+family's own key names (``num_experts`` / ``n_routed_experts``,
+``route_scale`` / ``routed_scaling_factor``, ...) are mapped onto one
+set in ``_FAMILIES``.
 
     python -m kf_benchmarks_tpu.cli --model=mla_moe_lm \
         --lm_config=glm-4.7-flash --seq_len=4096 --batch_size=2 \
         --lm_layers_held=5 --lm_layer_shards=8 --lm_layer_shard_index=0 \
         --optimizer=adam --use_fp16=true
+    python -m kf_benchmarks_tpu.cli --model=mla_moe_lm \
+        --lm_config=trinity-mini --seq_len=8192 --batch_size=1 \
+        --lm_first_layer_held=1 --lm_layers_held=5 --lm_layer_shards=8 \
+        --optimizer=adam --use_fp16=true
 
-The layer (pre-norm, RMSNorm, no biases):
+The layer (RMSNorm, no biases):
 
-* latent attention (MLA): queries through ``q_lora_rank`` (down,
-  RMSNorm, up to heads x (nope + rope)); keys and values through
-  ``kv_lora_rank`` plus ONE rotary key shared by the heads
+* latent attention (MLA, ``MLAttention``): queries through
+  ``q_lora_rank`` (down, RMSNorm, up to heads x (nope + rope)); keys and
+  values through ``kv_lora_rank`` plus ONE rotary key shared by the heads
   (``kv_a_proj_with_mqa``, RMSNorm on the latent, ``kv_b_proj`` to heads
   x (nope + v)); RoPE on the rotary dimensions only; scores scaled by
   1/sqrt(nope + rope); the core is ``parallel/sequence.py``'s flash
   path, causal;
-* layers ``< first_k_dense_replace``: a SiLU-gated feed-forward of
-  ``intermediate_size``; the others a mixture: ``n_routed_experts`` of
-  ``moe_intermediate_size``, top-``num_experts_per_tok`` by
-  ``sigmoid(router)`` + a selection bias (``parallel/expert.py``),
-  dropless, beside ``n_shared_experts`` shared ones every token passes;
+* grouped-query gated attention (``GQAttention``): ``q_proj`` to heads x
+  head size, ``k_proj`` and ``v_proj`` to FEWER key heads, ``gate_proj``
+  to heads x head size; RMSNorm over each head of q and of k; a layer
+  the configuration's ``layer_types`` calls ``sliding_attention`` rotates
+  q and k (RoPE, all dimensions) and sees ``sliding_window`` keys, its
+  own position included; a ``full_attention`` layer rotates nothing and
+  sees every earlier position; query head n reads key head n // group
+  (never repeated in memory: the core's kernel fetches a key head's
+  tiles for its group, and under a window SKIPS the tiles outside the
+  band); 1/sqrt(head size) is folded into ``q_norm``'s float32 output;
+  the core's output times sigmoid(gate) goes through ``o_proj``;
+* norms: pre-norm (``input_layernorm``, ``post_attention_layernorm``
+  before the feed-forward), or, ``post_norms``, one on each side of each
+  sublayer (``input_layernorm`` / ``post_attention_layernorm`` around
+  attention, ``pre_mlp_layernorm`` / ``post_mlp_layernorm`` around the
+  feed-forward), the normed OUTPUT added to the residual;
+* layers ``< first_k_dense_replace`` (``num_dense_layers``): a
+  SiLU-gated feed-forward of ``intermediate_size``; the others a
+  mixture: ``n_routed_experts`` of ``moe_intermediate_size``,
+  top-``num_experts_per_tok`` by ``sigmoid(router)`` + a selection bias
+  (``parallel/expert.py``), dropless, beside ``n_shared_experts`` shared
+  ones every token passes;
+* the mixture layers held are ONE scanned body where they are of one
+  kind (every latent-attention stack), and unrolled where window and
+  full layers mix (two bodies cannot be one scan body; a stage a chip
+  holds is shorter than two periods of the pattern, so a scan over
+  periods would have length 1);
 * ``num_nextn_predict_layers`` = 1 multi-token-prediction module:
   RMSNorm of the next token's embedding and of the last block's output,
   concatenated, projected back to the hidden size, one more mixture
   block, the main model's embedding and head, a second cross-entropy on
   the token after next (``ops/fused_loss.fused_softmax_xent_pair``: one
-  head kernel, no (B, T, V) tensor in either loss).
+  head kernel, no (B, T, V) tensor in either loss); 0: one loss through
+  the same kernel.
 
 The share of a deployment (model-configs guide, section 4) is said by
-flags: ``--lm_layers_held`` layers of the stack live here (the others on
-further chips, as pipeline stages), and each layer is divided over
-``--lm_layer_shards`` chips of which this is ``--lm_layer_shard_index``:
-it holds a contiguous block of the routed experts and of the
-vocabulary's rows (token ids, labels, logits and both losses are over
-the slice). Attention, the shared expert, the router and the norms are
-held whole. What the absent experts would add is left out, and no code
-stands in for the absent chips.
+flags: ``--lm_layers_held`` layers of the stack live here, from
+published layer ``--lm_first_layer_held`` on (the others on further
+chips, as pipeline stages; each held layer is dense or a mixture, window
+or full, by its OWN published index, and the embedding feeds the first
+of them), and each layer is divided over ``--lm_layer_shards`` chips of
+which this is ``--lm_layer_shard_index``: it holds a contiguous block of
+the routed experts and of the vocabulary's rows (token ids, labels,
+logits and the losses are over the slice). Attention, the shared expert,
+the router and the norms are held whole. What the absent experts would
+add is left out, and no code stands in for the absent chips.
 
 The router's selection bias is STATE THE OPTIMIZER DOES NOT OWN: it
 lives in the ``batch_stats`` collection (the path batch-norm statistics
@@ -62,9 +104,12 @@ step).
 
 Named scopes inside the step's ``forward`` scope, for the device trace
 (``benchmarks/lm_scopes.py``): ``mla_attention`` (projections, RoPE and
-the core), ``attention_core`` inside it, ``moe_route`` (router, top-k,
-sort, gather and scatter of rows), ``moe_experts`` inside it (the
-grouped products), ``shared_expert``, ``mtp``, ``lm_head`` (the model's
+the core) with ``attention_core`` inside it, or ``gqa_attention``
+(projections, head norms, RoPE, gate and the core) with
+``attention_core_window`` / ``attention_core_full`` inside it, so that
+each kind of layer is read apart; ``moe_route`` (router, top-k, sort,
+gather and scatter of rows), ``moe_experts`` inside it (the grouped
+products), ``shared_expert``, ``mtp``, ``lm_head`` (the model's
 ``loss_function``). They are components OTHER than the four
 ``train_step.STEP_SCOPES``.
 """
@@ -115,16 +160,13 @@ WARMUP_STEPS = 2000
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-  """The published keys this family reads, and the share held here."""
+  """The published keys the decoder reads, under ONE set of names (a
+  family's own key names are mapped onto them by ``_FAMILIES``), the
+  layer recipe of its family, and the share held here."""
   hidden_size: int
   intermediate_size: int
   moe_intermediate_size: int
   num_attention_heads: int
-  q_lora_rank: int
-  kv_lora_rank: int
-  qk_nope_head_dim: int
-  qk_rope_head_dim: int
-  v_head_dim: int
   n_routed_experts: int
   n_shared_experts: int
   num_experts_per_tok: int
@@ -132,13 +174,36 @@ class LMConfig:
   norm_topk_prob: bool
   first_k_dense_replace: int
   num_hidden_layers: int
-  num_nextn_predict_layers: int
   rms_norm_eps: float
   rope_theta: float
   vocab_size: int
-  # The share: layers held (of num_hidden_layers, from the first), and
-  # which of how many chips that divide each layer this is.
+  # Latent attention (``attention == "mla"``).
+  q_lora_rank: int = 0
+  kv_lora_rank: int = 0
+  qk_nope_head_dim: int = 0
+  qk_rope_head_dim: int = 0
+  v_head_dim: int = 0
+  num_nextn_predict_layers: int = 0
+  # Grouped-query gated attention (``attention == "gqa"``): key heads,
+  # the head size, and per PUBLISHED layer whether it sees a window
+  # (``sliding_attention``, with RoPE) or everything before it
+  # (``full_attention``, with no positional rotation at all).
+  num_key_value_heads: int = 0
+  head_dim: int = 0
+  layer_types: tuple = ()
+  sliding_window: int = 0
+  # The family's layer recipe: the attention module, a norm AFTER each
+  # sublayer beside the one before it, what the embedding is multiplied
+  # by, the speed of the router bias's update.
+  attention: str = "mla"
+  post_norms: bool = False
+  embed_scale: float = 1.0
+  bias_update_speed: float = BIAS_UPDATE_SPEED
+  # The share: ``layers_held`` layers of num_hidden_layers from published
+  # layer ``first_layer`` on, and which of how many chips that divide
+  # each layer this is.
   layers_held: int = 0
+  first_layer: int = 0
   shards: int = 1
   shard_index: int = 0
 
@@ -156,7 +221,9 @@ class LMConfig:
 
   @property
   def dense_layers(self) -> int:
-    return min(self.first_k_dense_replace, self.layers_held)
+    """Of the layers held, those before ``first_k_dense_replace``."""
+    return max(0, min(self.first_k_dense_replace,
+                      self.first_layer + self.layers_held) - self.first_layer)
 
   @property
   def moe_layers(self) -> int:
@@ -166,16 +233,51 @@ class LMConfig:
   def qk_head_dim(self) -> int:
     return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+  def window(self, held_layer: int) -> Optional[int]:
+    """The window of the ``held_layer``-th layer held here (None: it
+    sees every earlier position)."""
+    kinds = self.layer_types
+    if kinds and kinds[self.first_layer + held_layer] == "sliding_attention":
+      return self.sliding_window
+    return None
 
-# What the family's code does not implement is refused, never ignored.
-_REQUIRED = {"hidden_act": "silu", "attention_bias": False,
-             "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
-             "rope_scaling": None, "tie_word_embeddings": False,
-             "partial_rotary_factor": 1}
+  @property
+  def windows(self) -> tuple:
+    return tuple(self.window(i) for i in range(self.layers_held))
+
+
+# A family (``model_type``) is a layer recipe, the names its config.json
+# gives the keys above, and what its code here does not implement, which
+# is refused, never ignored.
+_FAMILIES = {
+    # The DeepSeek-V3 block: latent attention, pre-norm, one MTP module.
+    "glm4_moe_lite": dict(
+        recipe=dict(attention="mla"), names={},
+        required={"hidden_act": "silu", "attention_bias": False,
+                  "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+                  "rope_scaling": None, "tie_word_embeddings": False,
+                  "partial_rotary_factor": 1}),
+    # Arcee's Trinity family: grouped-query gated attention over window
+    # and full layers, norms on both sides of each sublayer, muP's
+    # embedding scale, sigmoid routing with a balance bias.
+    "afmoe": dict(
+        recipe=dict(attention="gqa", post_norms=True),
+        names={"num_experts": "n_routed_experts",
+               "num_shared_experts": "n_shared_experts",
+               "route_scale": "routed_scaling_factor",
+               "route_norm": "norm_topk_prob",
+               "num_dense_layers": "first_k_dense_replace",
+               "load_balance_coeff": "bias_update_speed"},
+        required={"hidden_act": "silu", "score_func": "sigmoid",
+                  "n_group": 1, "topk_group": 1, "num_expert_groups": 1,
+                  "num_limited_groups": 1, "rope_scaling": None,
+                  "tie_word_embeddings": False, "mup_enabled": True}),
+}
 
 
 def load_lm_config(name: str, layers_held: Optional[int] = None,
-                   shards: int = 1, shard_index: int = 0) -> LMConfig:
+                   shards: int = 1, shard_index: int = 0,
+                   first_layer: int = 0) -> LMConfig:
   """``lm_configs/<name>.json`` as an LMConfig with the share applied."""
   path = os.path.join(CONFIG_DIR, name + ".json")
   if not os.path.isfile(path):
@@ -184,20 +286,42 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
     raise ValueError(f"--lm_config={name}: no file {path}; have {have}")
   with open(path, encoding="utf-8") as f:
     raw = json.load(f)
-  for key, want in _REQUIRED.items():
+  family = _FAMILIES.get(raw.get("model_type"))
+  if family is None:
+    raise ValueError(f"{path}: model_type={raw.get('model_type')!r} is not "
+                     f"a family this decoder builds ({sorted(_FAMILIES)})")
+  for key, want in family["required"].items():
     if raw.get(key, want) != want:
       raise ValueError(f"{path}: {key}={raw[key]!r} is not implemented "
-                       f"(mla_moe_lm implements {want!r})")
-  if raw.get("num_key_value_heads", raw["num_attention_heads"]) != \
-      raw["num_attention_heads"]:
-    raise ValueError(f"{path}: latent attention has one key per head")
-  if raw["num_nextn_predict_layers"] not in (0, 1):
-    raise ValueError(f"{path}: at most one MTP module is implemented")
+                       f"({raw['model_type']} here implements {want!r})")
+  raw = {family["names"].get(k, k): v for k, v in raw.items()}
+  recipe = dict(family["recipe"])
+  if recipe["attention"] == "mla":
+    if raw.get("num_key_value_heads", raw["num_attention_heads"]) != \
+        raw["num_attention_heads"]:
+      raise ValueError(f"{path}: latent attention has one key per head")
+    if raw["num_nextn_predict_layers"] not in (0, 1):
+      raise ValueError(f"{path}: at most one MTP module is implemented")
+  else:
+    if raw["num_attention_heads"] % raw["num_key_value_heads"]:
+      raise ValueError(f"{path}: {raw['num_key_value_heads']} key heads do "
+                       f"not divide {raw['num_attention_heads']} query heads")
+    kinds = raw["layer_types"] = tuple(raw["layer_types"])
+    unknown = set(kinds) - {"sliding_attention", "full_attention"}
+    if unknown or len(kinds) != raw["num_hidden_layers"]:
+      raise ValueError(f"{path}: layer_types names {sorted(unknown)} or is "
+                       f"not one entry a layer ({len(kinds)})")
+    # muP: the embedding's output times sqrt(hidden size) (assumed to be
+    # all it changes in the forward pass).
+    recipe["embed_scale"] = math.sqrt(raw["hidden_size"])
   fields = {f.name for f in dataclasses.fields(LMConfig)}
-  cfg = LMConfig(**{k: v for k, v in raw.items() if k in fields})
-  held = cfg.num_hidden_layers if layers_held is None else layers_held
-  if not 1 <= held <= cfg.num_hidden_layers:
-    raise ValueError(f"--lm_layers_held={held}: the model has "
+  cfg = LMConfig(**{k: v for k, v in raw.items() if k in fields}, **recipe)
+  held = (cfg.num_hidden_layers - first_layer if layers_held is None
+          else layers_held)
+  if not (0 <= first_layer and 1 <= held and
+          first_layer + held <= cfg.num_hidden_layers):
+    raise ValueError(f"--lm_layers_held={held} from "
+                     f"--lm_first_layer_held={first_layer}: the model has "
                      f"{cfg.num_hidden_layers} layers")
   if not 0 <= shard_index < shards:
     raise ValueError(f"--lm_layer_shard_index={shard_index} of "
@@ -207,8 +331,8 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
     if count % shards:
       raise ValueError(f"--lm_layer_shards={shards} does not divide "
                        f"{what}={count}")
-  return dataclasses.replace(cfg, layers_held=held, shards=shards,
-                             shard_index=shard_index)
+  return dataclasses.replace(cfg, layers_held=held, first_layer=first_layer,
+                             shards=shards, shard_index=shard_index)
 
 
 def _normal():
@@ -303,6 +427,47 @@ class MLAttention(_Options):
           att.reshape(b, t, h * vd))
 
 
+def gated(core, gate):
+  """The attention core's output under its gate (``afmoe``)."""
+  return core * jax.nn.sigmoid(gate)
+
+
+class GQAttention(_Options):
+  """Grouped-query attention with a gate on its output (``afmoe``):
+  RMSNorm over each head of q and of k, RoPE in the WINDOW layers only,
+  a full layer rotating nothing; query head n reads key head
+  n // (heads / key heads); the core's output times sigmoid(gate_proj(x))
+  before ``o_proj``."""
+  window: Optional[int] = None
+
+  @nn.compact
+  def __call__(self, x):
+    c = self.cfg
+    b, t, _ = x.shape
+    h, g, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    with jax.named_scope("gqa_attention"):
+      q = self.dense(h * hd, "q_proj")(x).reshape(b, t, h, hd)
+      k = self.dense(g * hd, "k_proj")(x).reshape(b, t, g, hd)
+      v = self.dense(g * hd, "v_proj")(x).reshape(b, t, g, hd)
+      gate = self.dense(h * hd, "gate_proj")(x)
+      # The scores' scale 1/sqrt(head size) is no power of two at 128:
+      # the kernel would scale q in q's dtype, a second bfloat16
+      # rounding. It is folded into q_norm's float32 output (RoPE is
+      # linear), one rounding at the cast, and the kernel scales nothing.
+      q = self.norm("q_norm")(q) * (1.0 / math.sqrt(hd))
+      k = self.norm("k_norm")(k)
+      if self.window is not None:
+        q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+      q, k = q.astype(self.dtype), k.astype(self.dtype)
+      with jax.named_scope("attention_core_window" if self.window is not None
+                           else "attention_core_full"):
+        att = sequence_lib.pallas_flash_attention(
+            q, k, v, causal=True, scale=1.0, block=min(ATTN_BLOCK, t),
+            window=self.window)
+      return self.dense(c.hidden_size, "o_proj")(
+          gated(att.reshape(b, t, h * hd), gate))
+
+
 class SwiGLU(_Options):
   width: int = 0
 
@@ -370,7 +535,7 @@ class MoE(_Options):
         chosen.value = idx.astype(jnp.float32)
         probe_in.value = flat[:probe].astype(self.dtype)
         probe_scores.value = scores[:probe].astype(jnp.float32)
-        bias.value = bias.value + BIAS_UPDATE_SPEED * jnp.sign(
+        bias.value = bias.value + c.bias_update_speed * jnp.sign(
             jnp.mean(all_load) - all_load)
     with jax.named_scope("shared_expert"):
       shared = SwiGLU(width=f * c.n_shared_experts, name="shared_experts",
@@ -379,20 +544,41 @@ class MoE(_Options):
 
 
 class Block(_Options):
-  """One decoder layer; ``(carry, None) -> (carry, None)`` for nn.scan."""
+  """One decoder layer; ``(carry, None) -> (carry, None)`` for nn.scan.
+  ``window``: the keys a query sees in this layer (None: all before it;
+  read by the grouped-query module alone)."""
   mixture: bool = True
+  window: Optional[int] = None
 
   @nn.compact
   def __call__(self, x, _=None):
+    c = self.cfg
     self.sow("intermediates", "hidden_in", x)
-    h = self.norm("input_layernorm")(x).astype(self.dtype)
-    x = x + MLAttention(name="self_attn", **self.options())(h)
-    h = self.norm("post_attention_layernorm")(x).astype(self.dtype)
-    if self.mixture:
-      x = x + MoE(name="mlp", **self.options())(h)
+    if c.attention == "mla":
+      attend = MLAttention(name="self_attn", **self.options())
     else:
-      x = x + SwiGLU(width=self.cfg.intermediate_size, name="mlp",
-                     **self.options())(h)
+      attend = GQAttention(name="self_attn", window=self.window,
+                           **self.options())
+    if self.mixture:
+      feed = MoE(name="mlp", **self.options())
+    else:
+      feed = SwiGLU(width=c.intermediate_size, name="mlp", **self.options())
+    normed = lambda name, y: self.norm(name)(y).astype(self.dtype)
+    if c.post_norms:
+      # A norm on both sides of each sublayer.
+      x = x + normed("post_attention_layernorm",
+                     attend(normed("input_layernorm", x)))
+      # The feed-forward's input is ONE array for all its readers: XLA
+      # otherwise formed the probe the step leaves for the check from a
+      # float32 copy of the residual and the router's input from the
+      # bfloat16 one, two roundings of one value (router scores 4.8e-3
+      # apart on the chip, a bfloat16 router's reading: PERF.md section 6,
+      # PR 32).
+      h = jax.lax.optimization_barrier(normed("pre_mlp_layernorm", x))
+      x = x + normed("post_mlp_layernorm", feed(h))
+    else:
+      x = x + attend(normed("input_layernorm", x))
+      x = x + feed(normed("post_attention_layernorm", x))
     return x, None
 
 
@@ -409,21 +595,34 @@ class MLAMoELM(_Options):
                      dtype=self.dtype, param_dtype=self.param_dtype,
                      embedding_init=_normal())
     emb = embed(tokens)
+    if c.embed_scale != 1.0:
+      emb = (emb.astype(jnp.float32) * c.embed_scale).astype(self.dtype)
     x = emb
+    windows = c.windows
     for i in range(c.dense_layers):
-      x, _ = block_cls(mixture=False, name=f"dense_{i}",
+      x, _ = block_cls(mixture=False, window=windows[i], name=f"dense_{i}",
                        **self.options())(x, None)
-    if c.moe_layers and self.scan_layers:
+    mixture_windows = windows[c.dense_layers:]
+    if c.moe_layers and self.scan_layers and len(set(mixture_windows)) == 1:
       # One block body whatever the depth; per-layer parameters, router
       # state and sown values stack on a leading layer axis.
       x, _ = nn.scan(
           block_cls,
           variable_axes={"params": 0, "batch_stats": 0, "intermediates": 0},
           split_rngs={"params": True}, length=c.moe_layers)(
-              name="layers", **self.options())(x, None)
+              name="layers", window=mixture_windows[0],
+              **self.options())(x, None)
     else:
-      for i in range(c.moe_layers):
-        x, _ = block_cls(name=f"layer_{i}", **self.options())(x, None)
+      # Layers of two kinds are two bodies: the held layers unrolled,
+      # each traced once (a deeper stage would scan one PERIOD of the
+      # pattern; the stage a chip holds is shorter than two periods).
+      # Outside a scan XLA merges the forward that nn.remat would repeat
+      # with the first one (prevent_cse=False), so these layers KEEP
+      # their activations and run their forward once: five layers at
+      # 8,192 tokens fit the chip (PERF.md section 5, PR 32).
+      for i, window in enumerate(mixture_windows):
+        x, _ = block_cls(window=window, name=f"layer_{i}",
+                         **self.options())(x, None)
     self.sow("intermediates", "hidden_last", x)
     h_main = self.norm("norm")(x).astype(self.dtype)
     h_mtp = None
@@ -483,7 +682,8 @@ class MLAMoELMModel(model_lib.Model):
     self.seq_len = get("seq_len", DEFAULT_SEQ_LEN)
     self._share = (get("lm_config", DEFAULT_CONFIG),
                    get("lm_layers_held", None), get("lm_layer_shards", 1),
-                   get("lm_layer_shard_index", 0))
+                   get("lm_layer_shard_index", 0),
+                   get("lm_first_layer_held", 0))
     self._cfg = None
     self.scanned_param_prefixes = ("layers",)
 
@@ -516,18 +716,28 @@ class MLAMoELMModel(model_lib.Model):
               c.experts_held, c.n_routed_experts)})
       core = self.attention_core_stats()
       trace.set_static("attention", core)
-      log_util.log_fn(
-          f"attention core: {core['core_layers']} layer(s), " + (
-              "backward in {backward_kernel_passes} kernel pass a layer; "
-              "scores in blocks of {block}, the forward fetching "
-              "{block_q} queries x {block_kv} keys a grid step, "
-              "{block_kv_dkv} keys held across a backward sweep, "
-              "{dq_partials} partial dq summed outside the kernel"
-              if core["backward_kernel_passes"] else
-              "materialised scores (no kernel off the TPU)").format(**core))
+      # One kind of core: its fields; two: a table by kind.
+      for kind, one in ([("", core)] if "core_layers" in core else
+                        [(f" ({k})", v) for k, v in core.items()]):
+        log_util.log_fn(
+            f"attention core{kind}: {one['core_layers']} layer(s), " + (
+                "backward in {backward_kernel_passes} kernel pass a layer; "
+                "scores in blocks of {block}, the forward fetching "
+                "{block_q} queries x {block_kv} keys a grid step, "
+                "{block_kv_dkv} keys held across a backward sweep, "
+                "{dq_partials} partial dq summed outside the kernel"
+                if one["backward_kernel_passes"] else
+                "materialised scores (no kernel off the TPU)").format(**one)
+            + ("; window {window}, {query_heads} query heads over "
+               "{key_heads} key heads".format(**one)
+               if "key_heads" in one else "")
+            + ("; {tiles_visited} of {tiles_causal} causal score tiles "
+               "visited".format(**one) if "tiles_causal" in one else ""))
+      last = c.first_layer + c.layers_held - 1
       log_util.log_fn(
           f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
-          f"layers ({c.dense_layers} dense, {c.moe_layers} mixture, "
+          f"layers ({c.first_layer}-{last}: {c.dense_layers} dense, "
+          f"{c.moe_layers} mixture, "
           f"{c.num_nextn_predict_layers} MTP module(s)); chip "
           f"{c.shard_index} of {c.shards} per layer: experts "
           f"{c.first_expert}-{c.first_expert + c.experts_held - 1} of "
@@ -541,16 +751,39 @@ class MLAMoELMModel(model_lib.Model):
 
   def attention_core_stats(self):
     """The run's ``stats["attention"]``: what the core of every attention
-    layer (the MTP block's too) runs, as ``MLAttention`` will ask for it
-    when the step is traced: from the shapes and the backend, so it
-    cannot vary by step."""
+    layer (the MTP block's too) runs, as the attention module will ask
+    for it when the step is traced: from the shapes and the backend, so
+    it cannot vary by step. Latent attention has one kind of core, whose
+    fields are the table; grouped-query attention has up to two,
+    ``{"window": ..., "full": ...}``, each with its window, its heads
+    and the score tiles (block x block, the forward's and the backward's
+    grids together, a head and a sequence) that its kernels VISIT beside
+    those of the causal half at the same tiling: the block skip as a
+    number, equal for a full layer (``sequence.plan_tiles``)."""
     c = self._cfg
-    plan = sequence_lib.flash_plan(
-        self.seq_len, self.seq_len, c.qk_nope_head_dim + c.qk_rope_head_dim,
-        min(ATTN_BLOCK, self.seq_len),
-        cpu_fallback=jax.default_backend() != "tpu")
-    return dict(dataclasses.asdict(plan),
-                core_layers=c.layers_held + c.num_nextn_predict_layers)
+    t = self.seq_len
+    off_tpu = jax.default_backend() != "tpu"
+
+    def core(head_dim, window, layers):
+      plan = sequence_lib.flash_plan(t, t, head_dim, min(ATTN_BLOCK, t),
+                                     cpu_fallback=off_tpu, window=window)
+      return plan, dict(dataclasses.asdict(plan), core_layers=layers)
+    if c.attention == "mla":
+      return core(c.qk_head_dim, None,
+                  c.layers_held + c.num_nextn_predict_layers)[1]
+    out = {}
+    for kind, window in (("window", c.sliding_window), ("full", None)):
+      layers = sum(w == window for w in c.windows)
+      if not layers:
+        continue
+      plan, out[kind] = core(c.head_dim, window, layers)
+      out[kind].update(window=window, query_heads=c.num_attention_heads,
+                       key_heads=c.num_key_value_heads)
+      if plan.backward_kernel_passes:
+        out[kind].update(
+            tiles_visited=sequence_lib.plan_tiles(t, t, plan, window),
+            tiles_causal=sequence_lib.plan_tiles(t, t, plan))
+    return out
 
   def counter_stats(self, rows):
     """``rows`` (steps, len(MOE_COUNTERS)) of ``moe_counters`` as the

@@ -25,6 +25,15 @@ online softmax is plain jnp), accumulate in float32 regardless of input
 dtype, and match ``full_attention`` to numerical tolerance -- pinned by
 tests/test_sequence_parallel.py on the 8-device virtual mesh.
 
+On ONE chip the attention core of the language models is
+``pallas_flash_attention``: the library's splash kernel with its fused
+backward behind this module's (B, L, H, D) layout, tiled by
+``flash_plan`` from the shapes. Its mask is causal, full, or a causal
+BAND of ``window`` keys whose out-of-band tiles the kernel skips, forward
+and backward; k and v may hold fewer heads than q (grouped queries,
+never repeated in memory). ``full_attention`` is its CPU form, with the
+same band and the same grouping.
+
 Memory: every block update runs under ``jax.checkpoint``
 (flash-style recompute-in-backward), so the blockwise bound holds for
 TRAINING too -- autodiff recomputes the per-block score/probability
@@ -42,6 +51,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -54,7 +64,8 @@ _NEG = -1e30
 
 
 def full_attention(q, k, v, causal: bool = False,
-                   scale: Optional[float] = None, segment_ids=None):
+                   scale: Optional[float] = None, segment_ids=None,
+                   window: Optional[int] = None):
   """Plain O(L^2) multi-head attention; (batch, seq, heads, head_dim).
 
   The single-device reference the parallel schedules are tested
@@ -64,15 +75,27 @@ def full_attention(q, k, v, causal: bool = False,
   attends only keys of ITS segment (equality, the Pallas SegmentIds
   convention: padding id 0 attends padding, so no row is ever fully
   masked and the causal diagonal keeps every row finite).
+
+  ``window`` (with ``causal``): query i sees key j iff
+  ``0 <= i - j < window``, the query's own position included. k and v
+  may hold fewer heads than q (grouped queries): query head n reads key
+  head ``n // (q heads / key heads)``.
   """
   d = q.shape[-1]
   scale = (1.0 / math.sqrt(d)) if scale is None else scale
+  if k.shape[2] != q.shape[2]:
+    k, v = (jnp.repeat(x, q.shape[2] // k.shape[2], axis=2) for x in (k, v))
   s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                  k.astype(jnp.float32)) * scale
   mask = None
   if causal:
     lq, lk = q.shape[1], k.shape[1]
     mask = (jnp.arange(lq)[:, None] >= jnp.arange(lk)[None, :])[None, None]
+    if window is not None:
+      mask &= (jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :] <
+               window)[None, None]
+  elif window is not None:
+    raise ValueError("a window is a causal band: pass causal=True")
   if segment_ids is not None:
     seg_mask = (segment_ids[:, :, None] ==
                 segment_ids[:, None, :])[:, None]
@@ -765,10 +788,19 @@ def uniform_flash_block_sizes(block: int):
 # 1024; 4096 keys do not fit. Backward: 1024 keys (with their values, two
 # float32 accumulators and the two gradients' tiles) fit beside 512
 # queries; 2048 do not, with 512 or with 256 queries; nor do 1024 beside
-# 1024 queries.
+# 1024 queries. At head size 128 twice the rows fit each.
 _FLASH_TILE = 1024 * 256
 _FLASH_DEFAULT_BLOCK = 512
 _LANES = 128
+# What a score tile and a partial dq cost the backward of a BANDED mask
+# on the v5e, for ``flash_plan``'s choice of the keys held (a visited
+# tile is computed whole, so larger tiles compute more of what lies
+# outside the band; smaller ones leave more partial dq to write and to
+# sum): the kernels' own rate in the glm-4.7-flash cell, 69% of 197
+# TFLOP/s over 5 products, and 819 GB/s written and read once each
+# (PERF.md section 6, PR 30 and PR 32).
+_BAND_FLOPS_PER_S = 0.69 * 197e12
+_BAND_BYTES_PER_S = 819e9
 
 
 @dataclasses.dataclass(frozen=True)
@@ -796,9 +828,36 @@ class FlashPlan:
   dq_partials: int = 0
 
 
+def band_tiles(q_len: int, kv_len: int, block_q: int, block_kv: int,
+               window: Optional[int] = None) -> int:
+  """How many (block_q x block_kv) tiles of the scores hold a pair
+  (i, j) with ``0 <= i - j < window`` (no window: the causal half): the
+  tiles a kernel over that mask visits; the others it skips whole."""
+  first_q = np.arange(0, q_len, block_q)[:, None]
+  first_k = np.arange(0, kv_len, block_kv)[None, :]
+  seen = first_q + block_q - 1 >= first_k          # some i >= some j
+  if window is not None:
+    seen &= first_q - (first_k + block_kv - 1) < window
+  return int(seen.sum())
+
+
+def plan_tiles(q_len: int, kv_len: int, plan: FlashPlan,
+               window: Optional[int] = None) -> int:
+  """The score tiles (``plan.block`` x ``plan.block``) that the kernels
+  of ``plan`` visit for one head of one sequence under a causal mask of
+  ``window`` keys (None: the causal half), the forward's grid and the
+  fused backward's together: a visited tile of either grid counts at its
+  area, whatever part of it the mask leaves."""
+  forward = band_tiles(q_len, kv_len, plan.block_q, plan.block_kv, window)
+  backward = band_tiles(q_len, kv_len, plan.block, plan.block_kv_dkv, window)
+  return (forward * plan.block_q * plan.block_kv +
+          backward * plan.block * plan.block_kv_dkv) // plan.block ** 2
+
+
 def flash_plan(q_len: int, kv_len: int, head_dim: int,
                block: Optional[int] = None,
-               cpu_fallback: bool = False) -> FlashPlan:
+               cpu_fallback: bool = False,
+               window: Optional[int] = None) -> FlashPlan:
   """The kernel plan of ``pallas_flash_attention`` for these shapes.
 
   ``block`` (default 512) is clamped to both lengths. Both kernels work
@@ -807,8 +866,12 @@ def flash_plan(q_len: int, kv_len: int, head_dim: int,
   twice the block in queries and in keys (fewer grid steps: 2.90 ms for
   3.31 a pass at the glm-4.7-flash cell's shape, PERF.md section 6,
   PR 30), the backward the largest power-of-two multiple in keys (fewer
-  partial dq to write and to sum). Shapes the kernel cannot tile are
-  refused here, by name, and not inside its lowering."""
+  partial dq to write and to sum). Under a ``window`` shorter than the
+  keys a larger tile is also more work, since a visited tile is computed
+  whole: the forward then fetches what it computes (block x block), and
+  the backward holds the keys that cost least by ``_band_backward_s``.
+  Shapes the kernel cannot tile are refused here, by name, and not
+  inside its lowering."""
   if cpu_fallback:
     return FlashPlan(backward_kernel_passes=0)
   block = min(block or _FLASH_DEFAULT_BLOCK, q_len, kv_len)
@@ -819,26 +882,51 @@ def flash_plan(q_len: int, kv_len: int, head_dim: int,
         f"{block} for {q_len} queries and {kv_len} keys")
   width = -(-head_dim // _LANES) * _LANES   # as VMEM pads it
   fits = lambda rows: rows * width <= _FLASH_TILE
+  held = [block]
+  while fits(2 * held[-1]) and kv_len % (2 * held[-1]) == 0:
+    held.append(2 * held[-1])
+  banded = window is not None and window < kv_len
   fwd = block
-  if fits(2 * block) and q_len % (2 * block) == kv_len % (2 * block) == 0:
+  if not banded and fits(2 * block) and \
+      q_len % (2 * block) == kv_len % (2 * block) == 0:
     fwd = 2 * block
-  dkv = block
-  while fits(2 * dkv) and kv_len % (2 * dkv) == 0:
-    dkv *= 2
+  dkv = held[-1] if not banded else min(
+      held, key=lambda rows: _band_backward_s(q_len, kv_len, width, block,
+                                              rows, window))
   return FlashPlan(backward_kernel_passes=1, block=block, block_q=fwd,
                    block_kv=fwd, block_kv_dkv=dkv,
                    dq_partials=kv_len // dkv)
 
 
+def _band_backward_s(q_len, kv_len, width, block, held, window) -> float:
+  """Seconds a head that the fused backward takes over a banded mask
+  with ``held`` keys a sweep, as far as ``held`` moves them: 5 products
+  of every visited (block x held) tile, and kv_len / held partial dq of
+  the queries' shape in bfloat16, written by the kernel and read by the
+  sum."""
+  pairs = band_tiles(q_len, kv_len, block, held, window) * block * held
+  partials = (kv_len // held) * q_len * width * 2
+  return (5 * 2.0 * pairs * width / _BAND_FLOPS_PER_S +
+          2.0 * partials / _BAND_BYTES_PER_S)
+
+
 @functools.lru_cache(maxsize=16)
 def _splash_kernel(q_len: int, kv_len: int, heads: int, causal: bool,
-                   plan: FlashPlan, interpret: bool):
-  """The library's splash kernel object for one (lengths, heads, plan):
-  built once a process and shared by every layer and every trace, so the
-  mask's host-side pre-processing (0.2 s at 4096 x 4096) is paid once."""
+                   plan: FlashPlan, interpret: bool,
+                   window: Optional[int] = None):
+  """The library's splash kernel object for one (lengths, heads, plan,
+  window): built once a process and shared by every layer and every
+  trace, so the mask's host-side pre-processing (0.2 s at 4096 x 4096)
+  is paid once. ``heads`` are the query heads; the kernel reads how many
+  key heads they share from its operands."""
   from jax.experimental.pallas.ops.tpu.splash_attention import (
       splash_attention_kernel as splash, splash_attention_mask as masks)
-  mask = (masks.CausalMask if causal else masks.FullMask)((q_len, kv_len))
+  if window is not None:
+    # The band: ``window`` keys INCLUDING the query's own position.
+    mask = masks.LocalMask((q_len, kv_len), window_size=(window - 1, 0),
+                           offset=0)
+  else:
+    mask = (masks.CausalMask if causal else masks.FullMask)((q_len, kv_len))
   sizes = splash.BlockSizes(
       block_q=plan.block_q, block_kv=plan.block_kv,
       block_kv_compute=plan.block, block_q_dkv=plan.block,
@@ -857,7 +945,9 @@ def pallas_flash_attention(q, k, v, causal: bool = False,
                            block: Optional[int] = None,
                            segment_ids=None,
                            cpu_fallback: Optional[bool] = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           window: Optional[int] = None,
+                           plan: Optional[FlashPlan] = None):
   """JAX's TPU Pallas attention kernel behind this module's
   (B, L, H, D) layout: the core of models/mla_moe_lm.py, the ``flash``
   arm of models/transformer_lm.py, and the hand-tiled alternative to the
@@ -870,7 +960,17 @@ def pallas_flash_attention(q, k, v, causal: bool = False,
   scores once and takes dq, dk and dv from them (``flash_plan`` has the
   tiling; bfloat16 or float32 in, float32 accumulation and softmax
   statistics inside). The kernel takes no scale, so q is scaled before
-  it, in q's dtype: exact for a power of two (head sizes 64 and 256).
+  it, in q's dtype: exact for a power of two (head sizes 64 and 256); a
+  caller whose scale is none folds it into q in float32 and passes
+  ``scale=1.0``, which multiplies nothing.
+
+  ``window`` (with ``causal``): query i sees key j iff
+  ``0 <= i - j < window``. The mask is the kernel's own band, so a tile
+  that lies wholly outside it is SKIPPED, forward and backward, not
+  computed and masked. k and v may hold fewer heads than q (grouped
+  queries, head n reading key head n // group): the kernel fetches each
+  key head's tiles for its group of query heads, and K and V are never
+  repeated in memory.
 
   ``segment_ids`` (B, L) int rides the kernel's own SegmentIds (packed
   sequences): cross-segment tiles are masked inside its grid, without a
@@ -884,6 +984,8 @@ def pallas_flash_attention(q, k, v, causal: bool = False,
   ``interpret=True`` the CPU runs the kernel's body itself, a test's
   way to hold its numbers to the reference), and ``True`` forces the
   reference path on any backend. Differentiable on both paths.
+  ``plan`` replaces ``flash_plan``'s own choice (the probe's way to time
+  another tiling).
 
   This is a CPU path for the CPU suites, not a fallback that can hide
   the device: under ``--device=tpu`` benchmark.setup() has already
@@ -892,16 +994,22 @@ def pallas_flash_attention(q, k, v, causal: bool = False,
   """
   if cpu_fallback is None:
     cpu_fallback = jax.default_backend() != "tpu"
+  if window is not None and not causal:
+    raise ValueError("a window is a causal band: pass causal=True")
   d = q.shape[-1]
   scale = (1.0 / math.sqrt(d)) if scale is None else scale
-  plan = flash_plan(q.shape[1], k.shape[1], d, block, cpu_fallback)
+  if plan is None:
+    plan = flash_plan(q.shape[1], k.shape[1], d, block, cpu_fallback, window)
   if not plan.backward_kernel_passes:
     return full_attention(q, k, v, causal=causal, scale=scale,
-                          segment_ids=segment_ids)
+                          segment_ids=segment_ids, window=window)
+  if window is not None and window >= k.shape[1]:
+    window = None     # the band is the causal half: one kernel for both
   kernel = _splash_kernel(q.shape[1], k.shape[1], q.shape[2], causal, plan,
-                          interpret)
-  qt, kt, vt = (x.swapaxes(1, 2) for x in (q * jnp.asarray(scale, q.dtype),
-                                           k, v))
+                          interpret, window)
+  if scale != 1.0:
+    q = q * jnp.asarray(scale, q.dtype)
+  qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
   if segment_ids is None:
     out = jax.vmap(kernel)(qt, kt, vt)
   else:

@@ -126,9 +126,18 @@ flags.DEFINE_string("lm_config", None,
                     "comes from it. None = glm-4.7-flash.")
 flags.DEFINE_integer("lm_layers_held", None,
                      "mla_moe_lm: how many of the configuration's layers "
-                     "this job holds, from the first (the others lie on "
-                     "further chips, as pipeline stages). None = all.",
+                     "this job holds, from --lm_first_layer_held on (the "
+                     "others lie on further chips, as pipeline stages). "
+                     "None = all that follow it.",
                      lower_bound=1)
+flags.DEFINE_integer("lm_first_layer_held", None,
+                     "mla_moe_lm: the published index of the first layer "
+                     "this job holds, for a pipeline stage that does not "
+                     "start at the model's first layer (its layers' kinds "
+                     "and whether each is dense come from the "
+                     "configuration at their own indices; the embedding "
+                     "still feeds the first layer held). None = 0.",
+                     lower_bound=0)
 flags.DEFINE_integer("lm_layer_shards", None,
                      "mla_moe_lm: over how many chips each layer is "
                      "divided (expert parallelism with a vocabulary-"

@@ -307,7 +307,8 @@ def validate_cross_flags(params) -> None:
           f"sequence length {_lm.SEQ_LEN} (blockwise_attention tiles "
           "the K/V axis in whole blocks)")
   lm_flags = [f for f in ("seq_len", "lm_config", "lm_layers_held",
-                          "lm_layer_shards", "lm_layer_shard_index")
+                          "lm_first_layer_held", "lm_layer_shards",
+                          "lm_layer_shard_index")
               if getattr(p, f, None) is not None]
   if p.model != "mla_moe_lm":
     if lm_flags:
@@ -336,12 +337,14 @@ def validate_cross_flags(params) -> None:
     if getattr(p, "packed_sequences", False):
       raise ParamError(
           "--model=mla_moe_lm cannot be combined with --packed_sequences: "
-          "its attention and its two losses take no segment ids yet")
+          "neither of its attention modules nor its fused losses take "
+          "segment ids yet")
     if p.eval or p.forward_only or getattr(p, "aot_save_path", None):
       raise ParamError(
           "--model=mla_moe_lm trains only: --eval, --forward_only and "
-          "serving export are not wired for the multi-token-prediction "
-          "head and the router state")
+          "serving export are not wired for the router state, a "
+          "multi-token-prediction head, or a cache that holds window and "
+          "full layers side by side")
     if (getattr(p, "num_grad_accum", 1) or 1) > 1:
       raise ParamError(
           "--model=mla_moe_lm cannot be combined with --num_grad_accum > "

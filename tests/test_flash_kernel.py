@@ -189,3 +189,124 @@ def test_differentiated_it_is_one_forward_and_one_backward_kernel():
   assert text.count("pallas_call[") == 2, text.count("pallas_call[")
   assert names == ["splash_mha_dkv_no_residuals",
                    "splash_mha_fwd_residuals"], names
+
+
+# -- a causal band, and fewer key heads than query heads (PR 32) --------------
+
+def _grouped_inputs(t, heads, kv_heads, d=128):
+  keys = jax.random.split(jax.random.PRNGKey(1), 4)
+  q, w = (jax.random.normal(k, (1, t, heads, d)) for k in keys[:2])
+  k, v = (jax.random.normal(k, (1, t, kv_heads, d)) for k in keys[2:])
+  return q, k, v, w
+
+
+@pytest.mark.parametrize("window", [
+    128,    # a whole tile
+    129,    # one key into the tile before
+    200,    # mid-tile
+    256,    # two whole tiles
+    512,    # the sequence: the causal half
+], ids=lambda w: f"window_{w}")
+def test_band_edges_agree_with_materialised_scores(window):
+  # T 512 in blocks of 128, 4 query heads over 2 key heads: output and
+  # the ONE backward's dq, dk, dv under the band of `window` keys (the
+  # query's own included) against scores materialised and masked.
+  q, k, v, w = _grouped_inputs(512, 4, 2)
+  got, want = _both(q, k, v, w, causal=True, scale=1.0, block=128,
+                    window=window)
+  for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+    assert a.shape == b.shape and a.dtype == b.dtype, name
+    assert _err(a, b) < 2e-5, (name, _err(a, b))
+  # ... and the reference form's band is the definition itself.
+  s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2))
+  i, j = jnp.arange(512)[:, None], jnp.arange(512)[None, :]
+  p = jax.nn.softmax(jnp.where((i - j >= 0) & (i - j < window), s, -jnp.inf),
+                     -1)
+  assert _err(want[0], jnp.einsum("bhqk,bkhd->bqhd", p,
+                                  jnp.repeat(v, 2, axis=2))) < 2e-6
+
+
+def test_a_key_one_past_the_window_moves_nothing():
+  # The edge itself: moving key j moves query j + window - 1 (the last
+  # that sees it) and leaves query j + window exactly where it was.
+  q, k, v, _ = _grouped_inputs(512, 2, 1)
+  window, j = 200, 100
+  run = lambda v_: sequence.pallas_flash_attention(
+      q, k, v_, causal=True, scale=0.05, block=128, window=window,
+      cpu_fallback=False, interpret=True)
+  delta = np.abs(np.asarray(run(v.at[0, j].add(3.0)) - run(v))).max(
+      axis=(0, 2, 3))
+  assert np.all(delta[:j] == 0) and np.all(delta[j + window:] == 0)
+  assert np.all(delta[j:j + window] > 1e-5)
+
+
+def test_grouped_heads_agree_with_repeated_keys_and_values():
+  q, k, v, w = _grouped_inputs(256, 8, 2)
+  grouped, _ = _both(q, k, v, w, causal=True, scale=0.25, block=128)
+  rep = lambda x: jnp.repeat(x, 4, axis=2)
+  repeated, _ = _both(q, rep(k), rep(v), w, causal=True, scale=0.25,
+                      block=128)
+  fold = lambda x: x.reshape(1, 256, 2, 4, 128).sum(3)
+  for name, a, b in zip(("out", "dq", "dk", "dv"), grouped,
+                        repeated[:2] + tuple(map(fold, repeated[2:]))):
+    assert _err(a, b) < 2e-5, (name, _err(a, b))
+  # (That K and V are never repeated in memory is the chip's compiler's
+  # to show: tests/test_tpu_step_compile.py.)
+
+
+# (lengths, head size, block, window) -> (block, forward tile, keys held
+# a backward sweep, partial dq). A full layer at head size 128 holds
+# twice the rows of head size 256; under a band a larger tile is also
+# more work, since a visited tile is computed whole.
+WINDOW_PLANS = [
+    ((8192, 8192, 128, 512, None), (512, 1024, 2048, 4)),
+    ((8192, 8192, 128, 512, 2048), (512, 512, 1024, 8)),
+    ((8192, 8192, 128, 512, 8192), (512, 1024, 2048, 4)),   # no band at all
+    ((8192, 8192, 128, 512, 9000), (512, 1024, 2048, 4)),
+    ((4096, 4096, 128, 512, 2048), (512, 512, 512, 8)),
+    ((4096, 4096, 256, 512, 1024), (512, 512, 512, 8)),
+    ((512, 512, 128, 128, 200), (128, 128, 256, 2)),
+]
+
+
+@pytest.mark.parametrize("shape, want", WINDOW_PLANS)
+def test_the_plan_under_a_window(shape, want):
+  q_len, kv_len, head, block, window = shape
+  plan = sequence.flash_plan(q_len, kv_len, head, block, window=window)
+  assert (plan.block, plan.block_q, plan.block_kv_dkv,
+          plan.dq_partials) == want
+  assert plan.block_kv == plan.block_q
+  assert plan.block_kv_dkv * plan.dq_partials == kv_len
+
+
+@pytest.mark.parametrize("window", [None, 200, 256, 129])
+def test_tiles_visited_are_the_kernels_own_tables(window):
+  # What `band_tiles` counts from the shapes is what the kernel's mask
+  # tables hold: the forward's grid (shrunk to the visited tiles) and the
+  # fused backward's.
+  plan = sequence.flash_plan(512, 512, 128, 128, window=window)
+  kernel = sequence._splash_kernel(512, 512, 2, True, plan, True, window)
+  visited = lambda info: int((np.asarray(info.block_mask) != 0).sum())
+  assert visited(kernel.fwd_mask_info) == sequence.band_tiles(
+      512, 512, plan.block_q, plan.block_kv, window)
+  assert visited(kernel.dkv_mask_info) == sequence.band_tiles(
+      512, 512, plan.block, plan.block_kv_dkv, window)
+
+
+def test_band_tiles_at_the_trinity_cells_shape():
+  # ISSUE 32's table: of the causal tiles at T 8,192 a band of 2,048
+  # visits 0.51 at 512 x 512, 0.58 at 1,024 keys, 0.70 at 2,048 keys
+  # held; 0.44 of the pairs lie in the band.
+  share = lambda bq, bkv: (sequence.band_tiles(8192, 8192, bq, bkv, 2048) /
+                           sequence.band_tiles(8192, 8192, bq, bkv))
+  assert round(share(512, 512), 2) == 0.51
+  assert round(share(512, 1024), 2) == round(share(1024, 1024), 2) == 0.58
+  assert round(share(512, 2048), 2) == 0.70
+  assert round(share(1, 1), 2) == 0.44
+
+
+def test_a_window_is_a_causal_band():
+  q, k, v, _ = _inputs(1, 256, 2, 128)
+  for attn in (sequence.pallas_flash_attention, sequence.full_attention):
+    with pytest.raises(ValueError, match="causal band"):
+      attn(q, k, v, causal=False, window=64)

@@ -152,3 +152,63 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   # temporaries in the same compile (parent 0ea3274).
   assert "bf16[2,4,20,4096,256]" in text
   assert compiled.memory_analysis().temp_size_in_bytes <= PARENT_CORE_TEMP_BYTES
+
+
+def test_trinity_mini_kernels_on_the_tpu_compiler(topo):
+  """The kernels of the trinity-mini cell at its real widths, forward
+  and backward, on the chip's own compiler (PR 32): the attention core at
+  head size 128 over 8,192 positions, 32 query heads over 4 key heads,
+  under the window of 2,048 and without one, each ONE forward and ONE
+  backward kernel in the plan ``flash_plan`` gives it (2,048 keys held a
+  backward sweep fit at this head size; they did not at 256), with no
+  copy of K or V at the query heads' count; and the routed path at its
+  second shape, 16 of 128 experts of 2048 x 1024 at top-8: one round of
+  16,384 of the 65,536 sorted rows."""
+  import jax
+  from jax.sharding import SingleDeviceSharding
+  from kf_benchmarks_tpu.models import mla_moe_lm
+  from kf_benchmarks_tpu.parallel import expert as expert_lib
+  from kf_benchmarks_tpu.parallel import sequence as sequence_lib
+  one = SingleDeviceSharding(topo.devices[0])
+  sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+  t, heads, kv_heads, size = 8192, 32, 4, 128
+  for window in (2048, None):
+    def core(q, k, v):
+      return jnp.sum(sequence_lib.pallas_flash_attention(
+          q, k, v, causal=True, scale=1.0, block=mla_moe_lm.ATTN_BLOCK,
+          cpu_fallback=False, window=window).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
+        sds((1, t, heads, size), jnp.bfloat16),
+        sds((1, t, kv_heads, size), jnp.bfloat16),
+        sds((1, t, kv_heads, size), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2, window
+    assert "splash_mha_fwd_residuals" in text
+    assert "splash_mha_dkv_no_residuals" in text
+    # The partial dq are the kernel's, at the query heads; K, V, dk and dv
+    # stay at the 4 key heads: nothing of (32 heads x 8192 x 128) exists
+    # beyond q, the output, their gradients and the partials.
+    plan = sequence_lib.flash_plan(t, t, size, mla_moe_lm.ATTN_BLOCK,
+                                   window=window)
+    assert f"bf16[{plan.dq_partials},{heads},{t},{size}]" in text
+    assert f"bf16[{kv_heads},{t},{size}]" in text
+    partials = plan.dq_partials * heads * t * size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < partials + 2 ** 24
+
+  tokens, k, d, f, g, experts = 8192, 8, 2048, 1024, 16, 128
+  rows = expert_lib.compact_rows(tokens * k, g, experts)
+  assert rows == 16384
+  assert expert_lib.gmm_tiling(rows, d, f) == (512, 1024, 512)
+
+  def routed(x, weights, idx, w_gate, w_up, w_down):
+    return jnp.sum(expert_lib.held_experts_ffn(
+        x, weights, idx, w_gate, w_up, w_down, 0, impl="gmm",
+        rows=rows)[0].astype(jnp.float32))
+  weight = lambda shape: sds(shape, jnp.float32)
+  text = jax.jit(jax.grad(routed, argnums=(0, 1, 3, 4, 5))).lower(
+      sds((tokens, d), jnp.bfloat16), weight((tokens, k)),
+      sds((tokens, k), jnp.int32), weight((g, d, f)), weight((g, d, f)),
+      weight((g, f, d))).compile().as_text()
+  assert text.count('custom_call_target="tpu_custom_call"') == 9
+  assert " while(" in text
+  assert not re.search(rf"\[{tokens * k},({d}|{f})\]", text)
