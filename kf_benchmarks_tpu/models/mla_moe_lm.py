@@ -711,9 +711,7 @@ class MLAMoELMModel(model_lib.Model):
         self._cfg = c = load_lm_config(*self._share)
       trace.set_static("moe", {
           "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
-          "buffer_rows": expert_lib.compact_rows(
-              self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
-              c.experts_held, c.n_routed_experts)})
+          "buffer_rows": self._round_rows(c)})
       core = self.attention_core_stats()
       trace.set_static("attention", core)
       # One kind of core: its fields; two: a table by kind.
@@ -804,8 +802,38 @@ class MLAMoELMModel(model_lib.Model):
     # TPU only, and their outputs carry no vma type, which the step's
     # shard_map checker refuses (transformer_lm.py has the whole story).
     self.relax_shard_map_vma = on_tpu
+    self._state_combine(dtype)
     return MLAMoELM(cfg=self.cfg, dtype=dtype, param_dtype=param_dtype,
                     moe_impl="gmm" if on_tpu else "ragged_dot")
+
+  def _round_rows(self, c: LMConfig) -> int:
+    """The sorted rows of one round of the routed path at this job's
+    batch (what ``MoE`` asks ``held_experts_ffn`` for)."""
+    return expert_lib.compact_rows(
+        self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
+        c.experts_held, c.n_routed_experts)
+
+  def _state_combine(self, dtype):
+    """``stats["moe"]["combine"]`` and its log line: what a round's
+    combine gathers, and from a table of which type. Stated where the
+    module's type is known, beside the share's own counters (``cfg``),
+    and once (the build makes a training and an evaluation module)."""
+    from kf_benchmarks_tpu import tracing
+    from kf_benchmarks_tpu.utils import log as log_util
+    c, trace = self.cfg, tracing.active()
+    tokens = self.get_batch_size() * self.seq_len
+    combine = expert_lib.combine_stats(
+        tokens, c.num_experts_per_tok, self._round_rows(c), c.hidden_size,
+        dtype)
+    moe = trace.static("moe") or {}
+    if moe.get("combine") == combine:
+      return
+    trace.set_static("moe", dict(moe, combine=combine))
+    log_util.log_fn(
+        "moe combine: {gathers} gathers of {0} rows a round "
+        "({rows_gathered} rows) from the products' own {table_dtype} rows "
+        "({table_bytes} bytes), weighted on the token side".format(
+            tokens, **combine))
 
   def get_input_shapes(self, subset):
     n = self.get_batch_size()

@@ -165,12 +165,21 @@ def reference_switch_moe(x_grouped, gate_w, w1, b1, w2, b2,
 # further rounds, decided on the chip (ONE ``lax.while_loop`` on a device
 # scalar: no host sync, no recompilation, no pair dropped). No array of
 # N x k rows times a model width exists, forward or backward: a round
-# gathers ``rows`` rows of the tokens, runs the three grouped products
-# on them, weights each row by its pair's score on the sorted side and
-# sums the rows back into their tokens (``_rows_of_tokens`` /
-# ``_rows_to_tokens``, each the other's transpose). Where ``rows`` is
-# all N x k pairs (half of the experts or more held) there is one round
-# and no loop in the program.
+# gathers ``rows`` rows of the tokens (``_rows_of_tokens``), runs the
+# three grouped products on them, and combines on the TOKEN side
+# (``_rows_to_tokens``): each token gathers its k pairs' rows from the
+# products' own output, in the type the products stored, and sums them
+# in float32 under the pairs' weights, which are in token order already.
+# A pair outside the round reads a row in range and is selected away: no
+# zero row is appended, and no float32 copy of the round is made. Where
+# ``rows`` is all N x k pairs (half of the experts or more held) there is
+# one round and no loop in the program.
+#
+# Under ``nn.remat`` the whole path runs AGAIN in the backward pass
+# wherever something reads its output there: a block that normalises the
+# feed-forward's output does (23.0 + 5.6 ms a step of the trinity-mini
+# cell at PR 32, PERF.md section 5); a pre-norm block, whose output goes
+# to the residual sum alone, does not, and XLA drops the repeat.
 
 
 def compact_rows(pairs: int, held: int, experts: int, tile: int = 512) -> int:
@@ -180,6 +189,18 @@ def compact_rows(pairs: int, held: int, experts: int, tile: int = 512) -> int:
   of the grouped products, and never more than all the pairs."""
   share = -(-2 * pairs * held // experts)
   return min(pairs, -(-share // tile) * tile)
+
+
+def combine_stats(tokens: int, k: int, rows: int, width: int, dtype):
+  """What the combine of ONE round reads (``_rows_to_tokens``), from the
+  shapes alone: ``gathers`` row gathers of ``tokens`` rows each
+  (``rows_gathered`` together) from a table of the round's ``rows`` rows
+  of ``width`` in the type the grouped products store, ``table_bytes``
+  in all. It holds for every step: nothing here is chosen at run time."""
+  dtype = jnp.dtype(dtype)
+  return {"gathers": k, "rows_gathered": tokens * k,
+          "table_dtype": dtype.name,
+          "table_bytes": rows * width * dtype.itemsize}
 
 
 def gmm_tiling(rows: int, contraction: int, columns: int):
@@ -231,16 +252,32 @@ class SortedPairs(NamedTuple):
   ends: jnp.ndarray
 
 
-def _sum_rows_by_token(rows, slot):
+def _round_slice(v, start, rows: int, fill=0):
+  """``v[start : start + rows]`` of a vector over the sorted pairs, filled
+  past its end (the last round may be short)."""
+  return lax.dynamic_slice_in_dim(
+      jnp.pad(v, (0, -v.shape[0] % rows), constant_values=fill), start, rows)
+
+
+def _sum_rows_by_token(rows, slot, weight=None):
   """(N, D) float32: each token's sum of the rows of a round that are
-  its pairs'. ``slot`` (N, k) is the row of each of a token's pairs in
-  the round, or the row count for a pair outside it: k gathers of N rows
-  from the round's rows and one zero row (a segment sum over the rows'
-  tokens, a scatter-add, took 0.8 ms a layer more: PERF.md section 6,
-  PR 28)."""
-  table = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
-  return sum(jnp.take(table, slot[:, j], axis=0, mode="clip").astype(
-      jnp.float32) for j in range(slot.shape[1]))
+  its pairs', each times its pair's ``weight`` (N, k) where one is given.
+  ``slot`` (N, k) is the row of each of a token's pairs in the round, or
+  the row count for a pair outside it. k gathers of N rows from ``rows``
+  as they are stored: a pair outside the round reads the last row and is
+  selected away, so the table is neither copied to append a zero row nor
+  widened to float32 (on the chip a gather of 8,192 rows of 2,048 took
+  0.356 ms from a float32 table and 0.053 ms from a bfloat16 one:
+  PERF.md section 6, PR 33; a segment sum over the rows' tokens, a
+  scatter-add, took 0.8 ms a layer more than the gathers: PR 28)."""
+  inside = slot < rows.shape[0]
+
+  def term(j):
+    row = jnp.take(rows, slot[:, j], axis=0, mode="clip").astype(jnp.float32)
+    if weight is not None:
+      row = row * weight[:, j, None]
+    return jnp.where(inside[:, j, None], row, 0)
+  return sum(term(j) for j in range(slot.shape[1]))
 
 
 @jax.custom_vjp
@@ -264,25 +301,6 @@ _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
-def _rows_to_tokens(rows, tok, slot):
-  """A round's rows (float32) summed into their tokens, (N, D): the
-  transpose of ``_rows_of_tokens``, whose backward is its gather."""
-  del tok
-  return _sum_rows_by_token(rows, slot)
-
-
-def _rows_to_tokens_fwd(rows, tok, slot):
-  return _rows_to_tokens(rows, tok, slot), tok
-
-
-def _rows_to_tokens_bwd(tok, g):
-  return jnp.take(g, tok, axis=0, mode="clip"), None, None
-
-
-_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
-
-
-@jax.custom_vjp
 def _sorted_values(v, order, inv):
   """``v[order]`` for a permutation ``order`` of the N x k pairs and its
   inverse, as a sort of ``v`` by ``inv``, and backward a sort by
@@ -301,6 +319,38 @@ def _sorted_values_bwd(order, g):
 
 
 _sorted_values.defvjp(_sorted_values_fwd, _sorted_values_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_tokens(ys, pair_w, tok, slot, start, plan):
+  """The combine of a round, (N, D) float32: ``y[t] = sum_j pair_w[t, j]
+  x ys[slot[t, j]]`` over token t's pairs inside the round. ``ys``
+  (rows, D) is the products' output as they stored it, ``pair_w`` (N, k)
+  float32 in token order. The weighted transpose of ``_rows_of_tokens``,
+  whose backward is its gather: that side works on the SORTED rows (row
+  r's gradient is its token's times its pair's weight, its weight's the
+  inner product of the two rows), so it sorts the weights into the
+  round, which the forward has no use for."""
+  del tok, start, plan
+  return _sum_rows_by_token(ys, slot, pair_w)
+
+
+def _rows_to_tokens_fwd(ys, pair_w, tok, slot, start, plan):
+  return (_rows_to_tokens(ys, pair_w, tok, slot, start, plan),
+          (ys, pair_w, tok, start, plan))
+
+
+def _rows_to_tokens_bwd(res, g):
+  ys, pair_w, tok, start, plan = res
+  w, unsort = jax.vjp(lambda p: _round_slice(_sorted_values(
+      p.reshape(-1), plan.order, plan.inv), start, ys.shape[0]), pair_w)
+  g_rows = jnp.take(g, tok, axis=0, mode="clip")
+  d_w = jnp.sum(g_rows * ys.astype(jnp.float32), axis=1)
+  return ((g_rows * w[:, None]).astype(ys.dtype), *unsort(d_w),
+          None, None, None, None)
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -370,11 +420,7 @@ def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
   ONE trace and one lowering of it; XLA inlines the calls.)"""
   k = pair_w.shape[1]
   start = i * rows
-  pad = -plan.order.shape[0] % rows      # the last round may be short
-
-  def cut(v, fill=0):
-    return lax.dynamic_slice_in_dim(
-        jnp.pad(v, (0, pad), constant_values=fill), start, rows)
+  cut = functools.partial(_round_slice, start=start, rows=rows)
   tok = cut(plan.order) // k
   ends = jnp.clip(plan.ends - start, 0, rows)
   sizes = jnp.diff(ends, prepend=0)
@@ -386,17 +432,19 @@ def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
     h = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, live, impl))
     h = h * grouped_matmul(xs, w_up, sizes, live, impl)
     ys = grouped_matmul(h, w_down, sizes, live, impl)
-  w = cut(_sorted_values(pair_w.reshape(-1), plan.order, plan.inv))
-  y = _rows_to_tokens(ys.astype(jnp.float32) * w[:, None], tok, slot)
-  return y, pairs_inside_groups(cut(plan.key, sizes.shape[0]), sizes, live)
+  y = _rows_to_tokens(ys, pair_w, tok, slot, start, plan)
+  return y, pairs_inside_groups(cut(plan.key, fill=sizes.shape[0]), sizes,
+                                live)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl):
   """``experts_round`` summed over the rounds that hold a pair of a held
   expert. Differentiated as a whole: the backward runs each round's
-  forward again inside its own loop, so nothing of a round outlives it
-  (and the forward that remat repeats has no reader and is never run)."""
+  forward again inside its own loop, so nothing of a round outlives it.
+  (Where ``nn.remat`` repeats this forward in the backward pass, it runs
+  there only if its y has a reader: a post-norm block's does, a pre-norm
+  block's does not. The comment above ``compact_rows`` has the cost.)"""
   args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl)
   return _while_pairs_left(lambda i: experts_round(i, *args), plan, rows)
 

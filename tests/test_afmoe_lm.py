@@ -357,6 +357,8 @@ def test_counters_and_cores_reach_the_stats(two_step_stats):
   assert moe["steps"] == 2 and moe["buffer_rows"] == 256
   assert moe["compact_share"] == 1.0 and moe["pairs_dropped"] == 0
   assert moe["experts_held"] == 4 and moe["vocab_rows"] == 512
+  assert moe["combine"]["gathers"] == 4
+  assert moe["combine"]["rows_gathered"] == moe["buffer_rows"]
   # Off the TPU no kernel runs; layers 1-4 are [window, full, window,
   # window]; the tiles are a kernel's, so a CPU run states none.
   att = two_step_stats["attention"]

@@ -317,16 +317,27 @@ def test_compact_rows(pairs, held, experts, tile, want):
   assert rows == pairs or rows % tiles.get("tile", 512) == 0
 
 
-def _shapes_in(jaxpr):
-  """The shape of every value a jaxpr computes, its sub-jaxprs' too."""
+def _eqns_in(jaxpr):
+  """Every equation of a jaxpr, its sub-jaxprs' too."""
   for eqn in jaxpr.eqns:
-    for var in eqn.outvars:
-      yield tuple(getattr(var.aval, "shape", ()))
+    yield eqn
     for param in eqn.params.values():
       for sub in (param if isinstance(param, (tuple, list)) else (param,)):
         sub = getattr(sub, "jaxpr", sub)
         if hasattr(sub, "eqns"):
-          yield from _shapes_in(sub)
+          yield from _eqns_in(sub)
+
+
+def _avals_in(jaxpr):
+  """(shape, dtype) of every value a jaxpr computes."""
+  for eqn in _eqns_in(jaxpr):
+    for var in eqn.outvars:
+      yield (tuple(getattr(var.aval, "shape", ())),
+             getattr(var.aval, "dtype", None))
+
+
+def _shapes_in(jaxpr):
+  return (shape for shape, _ in _avals_in(jaxpr))
 
 
 def test_routed_path_holds_no_pairs_by_width_array():
@@ -349,6 +360,81 @@ def test_routed_path_holds_no_pairs_by_width_array():
   whole = tiny(shards=1, shard_index=0)
   moe, params, stats, x = _forced(whole, [], seq=512)
   assert (pairs, cfg.hidden_size) in offenders(moe)
+
+
+def test_combine_gathers_the_products_own_rows():
+  # A bfloat16 layer, 1,024 tokens x 2 choices over 2 of 16 experts held:
+  # rounds of 512 rows (so a round's rows and the tokens differ in
+  # number). The combine reads the round as the products stored it: no
+  # copy with a zero row appended (513 rows), no float32 copy of the
+  # round in the forward, and every gather from an array of the round's
+  # shape (the combine's k, and the k of ``_rows_of_tokens``' backward)
+  # reads bfloat16. (The interpreted kernel stores bfloat16 as the TPU's
+  # does; XLA's grouped product on a CPU computes float32 and casts.)
+  cfg = tiny(shards=8, shard_index=1)
+  k, rows, width = cfg.num_experts_per_tok, 512, cfg.hidden_size
+  assert expert_lib.compact_rows(1024 * k, cfg.experts_held,
+                                 cfg.n_routed_experts) == rows
+  moe, params, stats, x = _forced(cfg, [], seq=512, dtype=jnp.bfloat16,
+                                  moe_impl="gmm_interpret")
+  x = x.astype(jnp.bfloat16)
+  fn = lambda p, x: jnp.sum(jnp.sin(moe.apply(
+      {"params": p, "batch_stats": stats}, x).astype(jnp.float32)))
+  forward = jax.make_jaxpr(fn)(params, x).jaxpr
+  both = jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 1)))(params,
+                                                                x).jaxpr
+  assert not [s for s in _shapes_in(both) if s and s[0] == rows + 1]
+  assert (rows, width) in set(_shapes_in(forward))      # the search finds it
+  assert ((rows, width), jnp.float32) not in set(_avals_in(forward))
+  tables = [eqn.invars[0].aval for eqn in _eqns_in(both)
+            if eqn.primitive.name == "gather"
+            and eqn.invars[0].aval.shape == (rows, width)]
+  assert len(tables) >= 2 * k
+  assert {t.dtype for t in tables} == {jnp.dtype(jnp.bfloat16)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("k", [1, 4, 8])
+def test_combine_against_dense_one_hot_reference(k, dtype):
+  # ``_rows_to_tokens`` alone, forward and both gradients, against a
+  # dense one-hot sum in float64: 16 tokens choose k of 16 experts, 4..11
+  # held, two rounds forced. The forward multiplies and sums in float32
+  # whatever the rows' type, so it is held to float32's rounding; the
+  # rows' gradient is stored in their type.
+  n, e, g, first, d = 16, 16, 8, 4, 8
+  rng = np.random.RandomState(k)
+  idx = np.stack([rng.permutation(e)[:k] for _ in range(n)])
+  idx[0] = np.arange(first, first + k)                  # every pair held
+  idx[1] = np.asarray([0, 1, 2, 3, 12, 13, 14, 15])[:k]   # none held
+  plan, held = expert_lib.sort_pairs(jnp.asarray(idx, jnp.int32), first, g)
+  pairs_here = int(plan.ends[-1])
+  rows = pairs_here // 2 + 1           # two rounds, the second not full
+  held, inv = np.asarray(held), np.asarray(plan.inv).reshape(n, k)
+  assert held[0].all() and not held[1].any()
+  assert (held & (inv >= rows)).any()          # a held pair of round 1
+  assert (~held & (inv < 2 * rows)).any()      # an absent one's row in it
+  pair_w = jnp.where(held, jnp.asarray(rng.uniform(0.1, 1, (n, k)),
+                                       jnp.float32), 0)
+  g_out = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+  order = np.concatenate([np.asarray(plan.order), np.zeros(2 * rows, int)])
+  for start in (0, rows):
+    ys = jnp.asarray(rng.standard_normal((rows, d)), dtype)
+    tok = jnp.asarray(order[start:start + rows] // k, jnp.int32)
+    slot = np.where((inv >= start) & (inv < start + rows), inv - start, rows)
+    y, pull = jax.vjp(lambda ys, w: expert_lib._rows_to_tokens(
+        ys, w, tok, jnp.asarray(slot, jnp.int32), jnp.int32(start), plan),
+                      ys, pair_w)
+    d_ys, d_w = pull(g_out)
+    assert y.dtype == jnp.float32 and d_ys.dtype == dtype
+    one_hot = (slot[:, :, None] == np.arange(rows)).astype(np.float64)
+    ys64, w64 = np.asarray(ys, np.float64), np.asarray(pair_w, np.float64)
+    close(y, np.einsum("tjr,tj,rd->td", one_hot, w64, ys64), "y", 1e-6)
+    close(d_ys, np.einsum("tjr,tj,td->rd", one_hot, w64, g_out), "d ys",
+          1e-6 if dtype == jnp.float32 else 4e-3)
+    close(d_w, np.einsum("tjr,td,rd->tj", one_hot, g_out, ys64), "d pair_w",
+          1e-6)
+    if start == 0:
+      assert not np.asarray(y)[1].any()        # the token with no pair
 
 
 def test_every_token_on_held_experts_loses_none():
@@ -518,6 +604,14 @@ def test_tier_counter_reaches_the_stats(two_step_stats):
   # 64 pairs in each of the 3 mixture layers of each of the 2 steps.
   assert moe["steps"] == 2 and moe["buffer_rows"] == 64
   assert moe["compact_share"] == 1.0 and moe["pairs_dropped"] == 0
+
+
+def test_combine_states_itself_in_the_stats(two_step_stats):
+  # k = 2 gathers of 2 x 16 rows from the one round's 64 float32 rows of
+  # width 32 (a CPU run's module is float32).
+  assert two_step_stats["moe"]["combine"] == {
+      "gathers": 2, "rows_gathered": 64, "table_dtype": "float32",
+      "table_bytes": 64 * 32 * 4}
 
 
 def test_attention_core_states_itself_in_the_stats(two_step_stats):

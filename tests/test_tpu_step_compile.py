@@ -135,6 +135,9 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   assert text.count('custom_call_target="tpu_custom_call"') == 9
   assert " while(" in text
   assert not re.search(rf"\[{tokens * k},({d}|{f})\]", text)
+  # The combine gathers the round as the products stored it (PR 33): no
+  # copy of it with a zero row appended, of either type.
+  assert f"[{rows + 1},{d}]" not in text
 
   def core(q, k, v):
     return jnp.sum(sequence_lib.pallas_flash_attention(
@@ -212,3 +215,6 @@ def test_trinity_mini_kernels_on_the_tpu_compiler(topo):
   assert text.count('custom_call_target="tpu_custom_call"') == 9
   assert " while(" in text
   assert not re.search(rf"\[{tokens * k},({d}|{f})\]", text)
+  # The combine gathers the round as the products stored it (PR 33): no
+  # copy of it with a zero row appended, of either type.
+  assert f"[{rows + 1},{d}]" not in text
