@@ -11,9 +11,11 @@ Two kinds of count, kept apart as there:
   (``forward_flops_per_sample``) and ``tests/benchmarks/test_bench_afmoe.py``
   holds the two together.
 * ``*_executed``: what the PROGRAM runs in one training step under a
-  scope, recomputation included: the numerator of a kernel's share of
-  its roofline, which therefore cannot read above 100% for work that was
-  not done.
+  scope, recomputation included, by the kernel launches the traced run
+  shows: the numerator of a kernel's share of its roofline, which
+  therefore cannot read above 100% for work that was not done. (The
+  grouped products' is ``lm_flops.moe_experts_executed``, one function
+  for both families.)
 
 ``c`` is the configuration AS HELD (``benchmarks/configs/trinity-mini.json``:
 ``num_hidden_layers``, ``num_dense_layers``, ``layer_types``,
@@ -25,6 +27,8 @@ operations a multiply-accumulate).
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
+
+from benchmarks import lm_flops
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -92,50 +96,37 @@ def forward_flops_per_token(c: Dict[str, Any], seq_len: int) -> float:
           (layers - dense) * per_mixture + 2.0 * d * c["vocab_size"])
 
 
-def moe_experts_executed(c: Dict[str, Any], pairs: float
-                         ) -> Tuple[float, float]:
-  """(operations, bytes) the grouped products run in ONE training step
-  under the scope ``moe_experts``, over all mixture layers together,
-  where ``pairs`` (token, expert) pairs a step were routed to held
-  experts (the program's counter ``pairs_routed_here``). As
-  ``lm_flops.moe_experts_executed``: a pair passes three products of
-  hidden x moe_intermediate_size, each forward, forward again (remat)
-  and backward, where it is two: 4 x 3 x 2 x pairs x hidden x width.
-  Bytes: per product and pass the rows in and out at 2 bytes, and per
-  layer and pass the held experts' weights once at 2 bytes; the backward
-  also writes the weights' gradient at 4."""
-  d, f = c["hidden_size"], c["moe_intermediate_size"]
-  layers = c["num_hidden_layers"] - min(c["num_dense_layers"],
-                                        c["num_hidden_layers"])
-  flops = 4 * 3 * 2.0 * pairs * d * f
-  weights = layers * c["num_experts"] * 3 * d * f
-  bytes_ = 4 * 3 * pairs * (d + f) * 2.0 + 4 * weights * 2.0 + weights * 4.0
-  return flops, bytes_
-
-
 def attention_core_executed(c: Dict[str, Any], seq_len: int, sequences: int,
-                            kind: str) -> Tuple[float, float]:
+                            kind: str, launches: Dict[str, float]
+                            ) -> Tuple[float, float]:
   """(operations, bytes) under the scope ``attention_core_window`` or
   ``attention_core_full`` in ONE training step, over the layers of that
-  ``kind``.
+  ``kind`` together, by the kernel launches the trace shows under that
+  scope (``lm_scopes.kernel_launches``; one launch is one layer).
 
   Per head and sequence one product is 2 x head size operations a
   (query, key) pair INSIDE the band (or the causal half): what a tile's
   masked part computes beside them is not counted, so the share is of
-  the useful work. The forward kernel runs two products, ONCE (the held
-  layers are unrolled, and outside a scan XLA merges the forward that
-  ``nn.remat`` would repeat with the first one: the traced run shows one
-  forward kernel a layer a step); the ONE backward kernel runs five: 7.
-  Bytes, at 2 a number: the forward reads q and writes the output at the
-  query heads and reads K and V ONCE A KEY HEAD (grouped queries are not
-  repeated in memory); the backward reads q, the output and its gradient
-  and writes dq at the query heads, reads K and V and writes dk and dv
-  at the key heads. Far below the operations' time at these lengths."""
+  the useful work. ``lm_flops.splash_products`` says how many products
+  the launches run. Seen (PR 34's trace of PR 33's program): window 4
+  forward and 4 fused backward launches a step over 4 layers, full 1 and
+  1: 7 products a layer, the forward ONCE (the held layers are unrolled,
+  and outside a scan XLA merges the forward that ``nn.remat`` would
+  repeat with the first one). Bytes, at 2 a number: the forward reads q
+  and writes the output at the query heads and reads K and V ONCE A KEY
+  HEAD (grouped queries are not repeated in memory); the fused backward
+  reads q, the output and its gradient and writes dq at the query heads,
+  reads K and V and writes dk and dv at the key heads (a dq kernel of
+  its own would read and write the same less dk and dv). Far below the
+  operations' time at these lengths."""
   h, g, hd = (c["num_attention_heads"], c["num_key_value_heads"],
               c["head_dim"])
-  layers = layers_of(c, kind) * sequences
+  fwd, dkv, dq = lm_flops.splash_launches(launches)
   pairs = band_pairs(seq_len, _window(c, kind))
-  flops = 7 * 2.0 * pairs * hd * h * layers
-  at_q, at_kv = seq_len * h * hd * 2.0, seq_len * g * hd * 2.0
-  bytes_ = (2 * at_q + 2 * at_kv + 4 * at_q + 4 * at_kv) * layers
+  flops = (lm_flops.splash_products(fwd, dkv, dq) * 2.0 * pairs * hd * h *
+           sequences)
+  at_q = seq_len * h * hd * 2.0 * sequences
+  at_kv = seq_len * g * hd * 2.0 * sequences
+  bytes_ = (fwd * (2 * at_q + 2 * at_kv) + dkv * (4 * at_q + 4 * at_kv) +
+            dq * (4 * at_q + 2 * at_kv))
   return flops, bytes_

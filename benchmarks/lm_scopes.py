@@ -1,7 +1,8 @@
-"""The device time of a language model's own named scopes, from the
-traced run's file: the shared reader of ``mla_attention_ms``,
-``moe_route_ms``, ``moe_experts_ms``, ``lm_head_ms`` and the two
-roofline shares (``benchmarks/layer_metrics/``).
+"""The device time of a language model's own named scopes, and the
+kernels launched under them, from the traced run's file: the shared
+reader of the ``*_attention_ms``, ``attention_core*_ms``, ``moe_*_ms``
+and ``lm_head_ms`` metrics and of the roofline shares
+(``benchmarks/layer_metrics/``).
 
 The program (``kf_benchmarks_tpu/models/mla_moe_lm.py``) names scopes
 INSIDE the step's ``forward`` scope; an operation's ``op_name`` carries
@@ -18,6 +19,15 @@ the chips. None in an untraced run, where no file was written, or where
 the trace names no such scope at all (a program without it, as the
 parent of the PR that added it: the metric is then left out of the
 line, and nothing raises).
+
+``kernel_launches(run, file, include)``: of the same leaf operations
+under ``include``, the custom calls (a Pallas kernel is one), counted:
+``{kernel: launches a step}``, the kernel being the instruction's name
+without its number (``gmm``, ``splash_mha_fwd_residuals``). What a
+roofline share's numerator counts passes by: how often remat, a
+``custom_vjp``'s own recomputation and XLA's merging of equal
+computations make a kernel run is decided when the step is compiled,
+and only the trace says it. None where ``scope_ms`` is None.
 """
 
 from __future__ import annotations
@@ -35,6 +45,9 @@ from benchmarks import xplane
 # many transforms wrap them.
 COMPONENT_RE = re.compile(r"^(?:[a-z_]+\()*([A-Za-z_][A-Za-z0-9_]*)\)*$")
 
+# A Pallas kernel in the device's line of operations.
+KERNEL_OPCODE = "custom-call"
+
 _cache: Dict[Tuple[str, float], List[Tuple[int, List]]] = {}
 
 
@@ -48,8 +61,9 @@ def components(op_name: str) -> frozenset:
 
 
 def _leaves(path: str):
-  """Per device: (whole steps, [(seconds, components)] of the leaf
-  operations inside the steady window)."""
+  """Per device: (whole steps, [(seconds, components, kernel)] of the
+  leaf operations inside the steady window); ``kernel`` is a custom
+  call's name without its number, None for any other operation."""
   key = (path, os.path.getmtime(path))
   if key in _cache:
     return _cache[key]
@@ -72,30 +86,50 @@ def _leaves(path: str):
                                   end=min(e.end, hi))
               for e in dev.ops if min(e.end, hi) > max(e.start, lo)]
     leaves, _ = xplane.split_leaves(inside)
-    out.append((steps, [(e.end - e.start, by_label.get(e.name, frozenset()))
-                        for e in leaves]))
+    out.append((steps, [
+        (e.end - e.start, by_label.get(e.name, frozenset()),
+         re.split(r"[. ]", e.name, maxsplit=1)[0]
+         if e.opcode == KERNEL_OPCODE else None) for e in leaves]))
   _cache[key] = out
   return out
 
 
-def scope_ms(run, metric_file: str, include: str,
-             exclude: Sequence[str] = ()) -> Optional[float]:
+def _under(run, metric_file: str, include: str, exclude: Sequence[str]):
+  """Per device (whole steps, the leaves under ``include`` and none of
+  ``exclude``); None in an untraced run, without a file, or where no
+  leaf names ``include`` at all."""
   if run.reduction is None:
     return None
   path = xplane.find_xplane(os.path.join(
       spans.root_of(metric_file), harness.TRACE_DIR, run.cell["name"]))
   if path is None:
     return None
-  per_device = []
-  seen = False
-  for steps, leaves in _leaves(path):
-    seconds = 0.0
-    for duration, scopes in leaves:
-      if include in scopes:
-        seen = True
-        if not any(x in scopes for x in exclude):
-          seconds += duration
-    per_device.append(1e3 * seconds / steps)
-  if not seen or not per_device:
+  per_device = [(steps, [leaf for leaf in leaves if include in leaf[1]])
+                for steps, leaves in _leaves(path)]
+  if not any(leaves for _, leaves in per_device):
     return None
-  return sum(per_device) / len(per_device)
+  return [(steps, [leaf for leaf in leaves
+                   if not any(x in leaf[1] for x in exclude)])
+          for steps, leaves in per_device]
+
+
+def scope_ms(run, metric_file: str, include: str,
+             exclude: Sequence[str] = ()) -> Optional[float]:
+  per_device = _under(run, metric_file, include, exclude)
+  if per_device is None:
+    return None
+  return sum(1e3 * sum(leaf[0] for leaf in leaves) / steps
+             for steps, leaves in per_device) / len(per_device)
+
+
+def kernel_launches(run, metric_file: str, include: str
+                    ) -> Optional[Dict[str, float]]:
+  per_device = _under(run, metric_file, include, ())
+  if per_device is None:
+    return None
+  out: Dict[str, float] = {}
+  for steps, leaves in per_device:
+    for _, _, kernel in leaves:
+      if kernel is not None:
+        out[kernel] = out.get(kernel, 0.0) + 1.0 / (steps * len(per_device))
+  return out

@@ -18,13 +18,21 @@ parts, so the parts add up to the chip's busy time:
 
   exchange   a collective by opcode (``xplane.collective_kind``), or
              ``op_name`` under the scope ``exchange``
-  backward   under ``transpose(jvp(forward))`` (recomputation included)
-  forward    under ``forward`` otherwise
+  backward   under ``transpose(jvp(forward))`` (recomputation included),
+             however many ``forward`` components lie inside it: where
+             the model is differentiated under the step's own scope, as
+             an unrolled rematerialised stack is, the backward pass's
+             operations are named ``transpose(jvp(forward))/.../
+             jvp(forward)/...``, inner component untransposed
+  forward    under ``forward`` components none of which is transposed
   optimizer  under ``optimizer_apply``
   metrics    under ``metrics``
   unscoped   none of these: the honesty check on the scopes
 
-Where scopes nest the innermost wins. A FUSED operation carries one
+Where scopes nest the innermost decides the part (a reduction hook
+named ``exchange`` inside the model is exchange), and where it is
+``forward`` any transposed ``forward`` around it makes it backward. A
+FUSED operation carries one
 ``op_name``, that of the instruction XLA made its root, and its whole
 time goes to that phase: a weight-gradient fusion that also applies the
 momentum update counts as backward.
@@ -228,8 +236,9 @@ def part_of(op_name: str, collective: bool) -> str:
   scopes = scopes_of(op_name)
   if not scopes:
     return "unscoped"
-  scope, transposed = scopes[-1]
-  if scope == "forward" and transposed:
+  scope, _ = scopes[-1]
+  if scope == "forward" and any(
+      transposed for s, transposed in scopes if s == "forward"):
     return "backward"
   return SCOPE_PART[scope]
 

@@ -15,19 +15,20 @@ import pytest
 from bench_testlib import REPO, read_json
 from benchmarks import afmoe_flops
 from benchmarks import harness
+from benchmarks import lm_flops
 from benchmarks import spec
-from test_bench_spec import metric_rules
+from test_bench_spec import metric_rules, reported_where_named
 
 CELL = "trinity-mini-train-seq8192-bs1-1chip"
 CHECK = "trinity-mini_reference_agrees"
 PUBLISHED = os.path.join(REPO, "kf_benchmarks_tpu", "models", "lm_configs",
                          "trinity-mini.json")
 # The accepted metrics whose readers find something in this cell: each
-# entry's ``workloads`` had the cell appended, and nothing else changed.
+# entry's ``workloads`` names the cell (among whichever others).
 JOINED = ["moe_route_ms", "moe_experts_ms", "lm_head_ms",
           "moe_load_max_over_mean", "train_loss_step_16", "optimizer_ms.lm",
-          "hbm_peak_in_use_gib", "hbm_peak_reserved_gib"]
-GLM_CELL = "glm-4.7-flash-train-seq4096-bs2-1chip"
+          "hbm_peak_in_use_gib", "hbm_peak_reserved_gib",
+          "moe_experts_roofline", "moe_compact_share"]   # the last two: PR 34
 
 
 def _run(stats=None, **kwargs):
@@ -63,26 +64,36 @@ def test_forward_operations_are_the_number_the_cell_states():
 def test_executed_counts():
   config = spec.load_config(REPO, "trinity-mini")
   peaks = spec.load_peaks(REPO)["TPU v5 lite"]
-  # 8,192 pairs a mixture layer, four layers.
-  flops, bytes_ = afmoe_flops.moe_experts_executed(config, 4 * 8192)
-  assert flops == 4 * 3 * 2 * 4 * 8192 * 2048 * 1024
+  # 8,192 pairs a mixture layer, four layers, and the cell's launches a
+  # step (48 gmm, 12 tgmm): five passes, through the one function both
+  # families share, which reads this family's key names.
+  flops, bytes_ = lm_flops.moe_experts_executed(config, 4 * 8192, 48, 12)
+  assert flops == 5 * 3 * 2 * 4 * 8192 * 2048 * 1024
   weights = 4 * 16 * 3 * 2048 * 1024
-  assert bytes_ == 12 * 4 * 8192 * 3072 * 2 + 4 * weights * 2 + weights * 4
-  # At 512 rows an expert the products sit at the chip's ridge: the
-  # operations take 8.4 ms of its peak, the bytes 8.8 ms of its bandwidth.
-  assert 0.9 < (flops / peaks["bf16_flops_per_s"]) / (
-      bytes_ / peaks["hbm_bytes_per_s"]) < 1.0
+  assert bytes_ == 15 * 4 * 8192 * 3072 * 2 + 4 * weights * 2 + weights * 2
+  # At 512 rows an expert the products sit near the chip's ridge: the
+  # operations take 10.5 ms of its peak, the bytes 8.6 ms of its bandwidth.
+  assert 1.1 < (flops / peaks["bf16_flops_per_s"]) / (
+      bytes_ / peaks["hbm_bytes_per_s"]) < 1.3
   for kind, layers, pairs in ((afmoe_flops.WINDOW, 4, 14_681_088),
                               (afmoe_flops.FULL, 1, 33_558_528)):
-    flops, bytes_ = afmoe_flops.attention_core_executed(config, 8192, 1, kind)
-    # 7 products (the forward ONCE, the one backward kernel's five) of
-    # the pairs inside the band, at head size 128.
+    # One forward and one fused backward launch a layer, as the cell's
+    # trace shows: 7 products of the pairs inside the band, at head size
+    # 128.
+    launches = {"splash_mha_fwd_residuals": layers,
+                "splash_mha_dkv_no_residuals": layers, "reduce": 9}
+    flops, bytes_ = afmoe_flops.attention_core_executed(
+        config, 8192, 1, kind, launches)
     assert flops == 7 * 2 * pairs * 128 * 32 * layers
     # K and V at the 4 key heads, q and its like at the 32.
     tensor = lambda heads: 8192 * heads * 128 * 2
     assert bytes_ == (6 * tensor(32) + 6 * tensor(4)) * layers
     assert flops / peaks["bf16_flops_per_s"] > bytes_ / peaks[
         "hbm_bytes_per_s"]
+    # A forward that ran twice a layer would be 9, counted where it runs.
+    twice = dict(launches, splash_mha_fwd_residuals=2 * layers)
+    assert afmoe_flops.attention_core_executed(
+        config, 8192, 1, kind, twice)[0] == 9 * 2 * pairs * 128 * 32 * layers
 
 
 def test_configuration_file_holds_the_published_keys():
@@ -113,21 +124,20 @@ def test_configuration_file_holds_the_published_keys():
 
 
 @pytest.mark.parametrize("name", JOINED)
-def test_the_cell_joins_an_accepted_metric_at_the_end_of_its_list(name):
+def test_the_cell_joins_an_accepted_metric(name):
+  # The cell is among those the entry names, whoever else is: the next
+  # decoder cell joins the same way and fails nothing here.
   metric_rules(REPO, "per_layer", name)
-  entry = next(m for m in spec.load_benchmark(REPO)["per_layer"]
-               if m["name"] == name)
-  assert entry["workloads"] == [GLM_CELL, CELL]
-  assert name in spec.cell_metrics(REPO, "per_layer", CELL)
+  reported_where_named(REPO, name, expected=[CELL])
 
 
 def test_the_cell_reads_no_metric_of_the_other_familys_keys_or_scopes():
   mine = spec.cell_metrics(REPO, "per_layer", CELL)
-  # ``mla_attention`` / ``attention_core`` are no scope of this decoder;
-  # ``lm_flops.moe_experts_executed`` reads the other family's key names;
-  # ``moe_compact_share`` is pinned to its one cell by its own test.
-  assert not {"mla_attention_ms", "attention_core_roofline",
-              "moe_experts_roofline", "moe_compact_share"} & set(mine)
+  # ``mla_attention`` / ``attention_core`` are no scope of this decoder,
+  # and ``lm_flops.attention_core_executed`` reads the other family's
+  # head sizes.
+  assert not {"mla_attention_ms", "attention_core_ms",
+              "attention_core_roofline"} & set(mine)
   # Every metric that names no cell is read in the new cell too.
   generic = [m["name"] for m in spec.load_benchmark(REPO)["per_layer"]
              if "workloads" not in m]
@@ -149,7 +159,7 @@ def test_the_program_states_the_block_skip(monkeypatch):
   # stats["attention"] as the program's model states it for the cell's
   # shapes on a TPU: of the tiles a causal mask visits (forward and
   # backward grids, by area) the band's tables visit under 0.75; the full
-  # layer visits them all. No metric reads it yet (PERF.md section 7).
+  # layer visits them all (``attention_tiles_visited_share`` reads it).
   import jax
   from kf_benchmarks_tpu import params as params_lib
   from kf_benchmarks_tpu.models import mla_moe_lm
@@ -165,6 +175,10 @@ def test_the_program_states_the_block_skip(monkeypatch):
   window, full = stats["window"], stats["full"]
   assert 0.44 < window["tiles_visited"] / window["tiles_causal"] < 0.75
   assert full["tiles_visited"] == full["tiles_causal"] > 0
+  assert spec.load_metric(
+      REPO, "per_layer", "attention_tiles_visited_share").read(
+          _run({"attention": stats})) == (
+              window["tiles_visited"] / window["tiles_causal"])
 
 
 def _controls():

@@ -38,15 +38,86 @@ def test_forward_operations_are_the_number_the_cell_states():
 def test_executed_counts_and_the_roofline_share():
   config = spec.load_config(REPO, "glm-4.7-flash")
   peaks = spec.load_peaks(REPO)["TPU v5 lite"]
-  flops, bytes_ = lm_flops.moe_experts_executed(config, 5 * 4096)
+  # 45 gmm and 15 tgmm launches a step (the cell's trace): four passes.
+  flops, bytes_ = lm_flops.moe_experts_executed(config, 5 * 4096, 45, 15)
   assert flops == 4 * 3 * 2 * 5 * 4096 * 2048 * 1536
+  weights = 5 * 8 * 3 * 2048 * 1536
+  assert bytes_ == (4 * 3 * 5 * 4096 * (2048 + 1536) * 2 +
+                    3 * weights * 2 + weights * 2)
   assert flops / peaks["bf16_flops_per_s"] > bytes_ / peaks[
       "hbm_bytes_per_s"]
-  flops, _ = lm_flops.attention_core_executed(config, 4096, 2)
-  assert flops == 11 * 4096 ** 2 * 256 * 20 * 2 * 6
   # All of a second's peak in a second is 100%.
   assert lm_flops.roofline_share(197e12, 0, 1.0, peaks) == 100.0
   assert lm_flops.roofline_share(0, 819e9, 2.0, peaks) == 50.0
+
+
+# The attention core's products by the kernels launched a step over the
+# cell's six layers: every forward twice and the fused backward (9 a
+# layer); the two backward kernels of PR 30's parent (11); what the
+# cell's trace shows since PR 30 (the two unrolled layers' forward once).
+@pytest.mark.parametrize("launches, products", [
+    ({"splash_mha_fwd_residuals": 12, "splash_mha_dkv_no_residuals": 6},
+     6 * 9),
+    ({"splash_mha_fwd_residuals": 12, "splash_mha_dkv": 6,
+      "splash_mha_dq": 6}, 6 * 11),
+    ({"splash_mha_fwd_residuals": 10, "splash_mha_dkv_no_residuals": 6,
+      "fusion": 40}, 50),
+    ({"splash_mha_fwd_no_residuals": 6}, 6 * 2),          # forward only
+])
+def test_attention_core_products_follow_the_launches(launches, products):
+  config = spec.load_config(REPO, "glm-4.7-flash")
+  flops, bytes_ = lm_flops.attention_core_executed(config, 4096, 2, launches)
+  assert flops == products * 4096 ** 2 * 256 * 20 * 2
+  fwd, dkv, dq = lm_flops.splash_launches(launches)
+  assert lm_flops.splash_products(fwd, dkv, dq) == products
+  assert bytes_ == (4 * fwd + 8 * dkv + 7 * dq) * 4096 * 20 * 256 * 2 * 2
+
+
+# The grouped products' passes by the launches, for both families' key
+# names: the glm cell's four, the trinity-mini cell's five (its post-norm
+# keeps the forward that remat repeats), a backward that recomputes
+# nothing (three).
+@pytest.mark.parametrize("config_name, layers, gmm, tgmm, passes", [
+    ("glm-4.7-flash", 5, 45, 15, 4),
+    ("trinity-mini", 4, 48, 12, 5),
+    ("trinity-mini", 4, 24, 12, 3),
+    ("trinity-mini", 4, 96, 24, 5),      # every layer took two rounds
+    # One layer took a second round in one step of twenty: the mean a
+    # step is a multiple of nothing, and the passes are what they were.
+    ("trinity-mini", 4, 48.6, 12.15, 5),
+])
+def test_moe_experts_passes_follow_the_launches(config_name, layers, gmm,
+                                                tgmm, passes):
+  c = spec.load_config(REPO, config_name)
+  experts = c.get("n_routed_experts", c.get("num_experts"))
+  d, f = c["hidden_size"], c["moe_intermediate_size"]
+  flops, bytes_ = lm_flops.moe_experts_executed(c, 1000.0, gmm, tgmm)
+  assert flops == passes * 3 * 2 * 1000.0 * d * f
+  weights = layers * experts * 3 * d * f
+  assert bytes_ == (passes * 3 * 1000.0 * (d + f) * 2 +
+                    (passes - 1) * weights * 2 + weights * 2)
+
+
+# Launches that are not the pattern the count of passes stands on leave
+# the share silent and not wrong (4 mixture layers: 12 ``tgmm`` a step at
+# one round a layer).
+@pytest.mark.parametrize("gmm, tgmm, one_round", [
+    (12, 12, True),      # the forward kernel renamed: the backward's alone
+    (0, 12, True),       # no ``gmm`` at all
+    (46, 12, True),      # one pass runs other rounds than another
+    (32, 8, True),       # gate and up fused: two products a pass
+    (32, 8, False),      # ... the same with some layer in a second round
+    (96, 24, True),      # two rounds a layer where the counter says one
+    (48, 0, True),
+])
+def test_moe_experts_read_nothing_from_another_pattern(gmm, tgmm, one_round):
+  c = spec.load_config(REPO, "trinity-mini")
+  assert lm_flops.moe_experts_passes(4, gmm, tgmm, one_round) is None
+  assert lm_flops.moe_experts_executed(c, 1000.0, gmm, tgmm,
+                                       one_round) is None
+  # The cell's own launches are the pattern, with one round and without.
+  assert lm_flops.moe_experts_passes(4, 48, 12, True) == 5
+  assert lm_flops.moe_experts_passes(4, 96, 24, False) == 5
 
 
 def test_configuration_file_holds_the_published_keys():
@@ -111,7 +182,8 @@ def test_memory_terms_and_the_optimizer_under_its_split_name():
   assert split.NEEDS == {} and parent.NEEDS == {"chips": 2}
   assert (split.LAYER, split.UNIT, split.MOVES, split.SOURCE) == (
       parent.LAYER, parent.UNIT, parent.MOVES, parent.SOURCE)
-  assert CELL in spec.load_benchmark(REPO)["per_layer"][-3]["workloads"]
+  assert CELL in spec._entry(spec.load_benchmark(REPO)["per_layer"],
+                             "hbm_peak_in_use_gib", "metric")["workloads"]
 
 
 def _controls():

@@ -1,14 +1,15 @@
 """``moe_compact_share`` (PR 28): the share of mixture layers whose
 pairs fit one round of the routed path, read from the program's
 counter; nothing where the program has none; admitted by the rules every
-metric is held to, with the cell it names."""
+metric is held to, and reported in the cells its entry names (found by
+its name: where an entry stands in ``per_layer`` is nobody's business)."""
 
 import pytest
 
 from bench_testlib import REPO
 from benchmarks import harness
 from benchmarks import spec
-from test_bench_spec import metric_rules
+from test_bench_spec import metric_rules, reported_where_named
 
 NAME = "moe_compact_share"
 CELL = "glm-4.7-flash-train-seq4096-bs2-1chip"
@@ -41,15 +42,9 @@ def test_reads_nothing_where_the_program_has_no_such_counter(stats):
   assert spec.load_metric(REPO, "per_layer", NAME).read(_run(stats)) is None
 
 
-def test_is_admitted_with_the_cell_it_names():
+def test_is_reported_in_exactly_the_cells_its_entry_names():
   metric_rules(REPO, "per_layer", NAME)
-  entry = spec.load_benchmark(REPO)["per_layer"][-1]
-  assert entry["name"] == NAME and entry["workloads"] == [CELL]
-  assert NAME in spec.cell_metrics(REPO, "per_layer", CELL)
-  others = [w["name"] for w in spec.load_benchmark(REPO)["workloads"]
-            if w["name"] != CELL]
-  assert others and not any(
-      NAME in spec.cell_metrics(REPO, "per_layer", cell) for cell in others)
+  reported_where_named(REPO, NAME, expected=[CELL])
 
 
 def test_the_program_leaves_what_the_metric_reads():

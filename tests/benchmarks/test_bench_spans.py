@@ -48,6 +48,33 @@ US = 1e-6
      False, "exchange"),
     # A scope is a whole component, not a substring.
     ("jit(f)/forward_hook/metrics_table/add", False, "unscoped"),
+    # A model differentiated under the step's own scope (an unrolled,
+    # rematerialised stack): the backward pass's operations carry an
+    # untransposed ``forward`` INSIDE the transposed one. Backward, the
+    # forward that remat repeats there included; forward only where no
+    # ``forward`` around the operation is transposed.
+    ("transpose(jvp(forward))/x/jvp(forward)/y", False, "backward"),
+    ("jvp(forward)/x", False, "forward"),
+    ("jit(per_replica_train)/transpose(jvp(forward))/MLAMoELM/jvp(forward)/"
+     "MLAMoELM/checkpoint/layer_1/self_attn/gqa_attention/"
+     "attention_core_full/splash_mha_dkv_no_residuals/pallas_call:", False,
+     "backward"),
+    ("jit(per_replica_train)/transpose(jvp(forward))/MLAMoELM/jvp(forward)/"
+     "MLAMoELM/checkpoint/rematted_computation/layer_0/mlp/moe_route/while/"
+     "body/jit(experts_round)/moe_experts/jit(gmm)/pallas_call:", False,
+     "backward"),
+    # The remat paths of the glm trace: the scanned layers' (one
+    # transposed ``forward``) and the MTP block's (two components).
+    ("jit(per_replica_train)/transpose(jvp(forward))/MLAMoELM/layers/while/"
+     "body/checkpoint/rematted_computation/mla_attention/attention_core/"
+     "pallas_call:", False, "backward"),
+    ("jit(per_replica_train)/transpose(jvp(forward))/mtp/jvp(forward)/mtp/"
+     "checkpoint/rematted_computation/mtp_block/mul", False, "backward"),
+    ("jit(per_replica_train)/jvp(forward)/mtp/jvp(forward)/mtp/checkpoint/"
+     "mtp_block/mul", False, "forward"),
+    # The innermost scope still decides where it is not ``forward``.
+    ("transpose(jvp(forward))/x/jvp(forward)/y/transpose(jvp(exchange))/mul",
+     False, "exchange"),
 ])
 def test_part_of(op_name, collective, part):
   assert spans.part_of(op_name, collective) == part
@@ -85,8 +112,10 @@ STEP_HOST = [("train", 1, 99), ("kf/dispatch/train_step", 2, 6),
 ENQUEUE_US = 6.5   # the runtime's enqueue, on another thread
 
 
-def _handmade(host_shift_us=0.0):
-  """The trace above as a serialized XSpace."""
+def _handmade(host_shift_us=0.0, step_ops=None):
+  """The trace above as a serialized XSpace (``step_ops``: another
+  step's operations than ``STEP_OPS``, in its form)."""
+  step_ops = STEP_OPS if step_ops is None else step_ops
   from jax.profiler import ProfileData
   stat_ids = {"tf_op": 1, "_c": 2, "_p": 3, "run_id": 4, "step_num": 5,
               "step": 6}
@@ -122,7 +151,7 @@ def _handmade(host_shift_us=0.0):
     t = k * STEP_US
     # Execution k was enqueued by dispatch k-1 (flow id 1000 + k - 1).
     modules.append(("jit_step(1)", t, t + 95, {"_c": 1000 + k - 1}))
-    ops += [(n, t + b, t + e, {}) for n, b, e, _ in STEP_OPS]
+    ops += [(n, t + b, t + e, {}) for n, b, e, _ in step_ops]
     h = t + host_shift_us
     for name, b, e in STEP_HOST:
       stats = ({"step_num": k} if name == "train" else
@@ -135,7 +164,7 @@ def _handmade(host_shift_us=0.0):
   text = "\n".join([
       plane("/device:TPU:0",
             [(xplane.MODULES_LINE, modules), (xplane.OPS_LINE, ops)],
-            {n: op for n, _, _, op in STEP_OPS}),
+            {n: op for n, _, _, op in step_ops}),
       plane("/host:CPU", [("python3", host), ("tfrt-queue", runtime)], {}),
   ])
   return ProfileData.text_proto_to_serialized_xspace(text)

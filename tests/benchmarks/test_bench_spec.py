@@ -5,6 +5,7 @@ added by new files and new entries alone."""
 import glob
 import os
 import re
+import shutil
 
 import pytest
 
@@ -251,6 +252,23 @@ def metric_rules(root, kind, name):
       "names the cells that have it under `workloads`")
 
 
+def reported_where_named(root, name, expected=()):
+  """What a metric's own test protects about its entry's ``workloads``,
+  and no more: the cells named exist, each of ``expected`` is among them,
+  and the metric is reported in exactly the cells named. Never equality
+  with a list the test keeps: a later cell joins an accepted entry by
+  adding its name there, and that fails nothing."""
+  bench = spec.load_benchmark(root)
+  named = spec._entry(bench["per_layer"], name, "per-layer metric")[
+      "workloads"]
+  cells = [w["name"] for w in bench["workloads"]]
+  assert named and set(named) <= set(cells), name
+  assert set(expected) <= set(named), name
+  assert [cell for cell in cells
+          if name in spec.cell_metrics(root, "per_layer", cell)] == [
+              cell for cell in cells if cell in named], name
+
+
 def file_rules(root):
   """Every file under the benchmark's directories has an entry that names
   it: nothing lies there that no run reads."""
@@ -468,6 +486,20 @@ def _edit(root, rel, change):
   write_json(path, data)
 
 
+def _an_entry(root, bench, needs):
+  """A per-layer entry of the tree to plant a fault in, chosen by what
+  it is and never by where it stands: one that names its cells and,
+  with ``needs``, whose file states a NEEDS that any cell has (so that
+  taking its ``workloads`` away breaks that rule and no cell's)."""
+  for entry in bench["per_layer"]:
+    stated = getattr(spec.load_metric(root, "per_layer", entry["name"]),
+                     "NEEDS", {})
+    if "workloads" in entry and (
+        not needs or stated and all(v <= 1 for v in stated.values())):
+      return entry
+  raise AssertionError("the tree has no such entry")
+
+
 @pytest.mark.parametrize("breakage, error, message", [
     ("unknown", spec.SpecError, "no workload named"),
     ("chips", spec.SpecError, "chips is"),
@@ -521,10 +553,10 @@ def test_disagreements_are_refused(tmp_path, breakage, error, message):
           CUT_CONFIG, lambda d: d["checks"].append("not_there")),
       "no_operation_count": (
           CUT_WORKLOAD, lambda d: d.pop("forward_flops_per_sample")),
-      "metric_names_no_cell": ("BENCHMARK.json", lambda b: b["per_layer"][
-          -1].update(workloads=["not_there"])),
-      "needs_without_workloads": ("BENCHMARK.json", lambda b: b["per_layer"][
-          -1].pop("workloads")),
+      "metric_names_no_cell": ("BENCHMARK.json", lambda b: _an_entry(
+          root, b, needs=False).update(workloads=["not_there"])),
+      "needs_without_workloads": ("BENCHMARK.json", lambda b: _an_entry(
+          root, b, needs=True).pop("workloads")),
   }
   new_metric = "benchmarks/layer_metrics/new_metric.py"
   appended = {
@@ -533,12 +565,12 @@ def test_disagreements_are_refused(tmp_path, breakage, error, message):
       "needs_without_workloads": (new_metric, 'NEEDS = {"chips": 1}\n'),
       "file_without_an_entry": ("benchmarks/layer_metrics/stray.py", "\n"),
   }
-  if breakage in changes:
-    _edit(root, *changes[breakage])
   if breakage in appended:
     rel, text = appended[breakage]
     with open(os.path.join(root, rel), "a", encoding="utf-8") as f:
       f.write(text)
+  if breakage in changes:
+    _edit(root, *changes[breakage])
   if breakage == "width_reduced":   # ... in the entry as in the file
     _edit(root, "BENCHMARK.json", lambda b: b["configs"][-1]["reduced"].append(
         "moe_intermediate_size"))
@@ -586,3 +618,62 @@ def test_metric_file_missing_a_field_is_refused(tmp_path):
     f.write("UNIT = 'ms'\n\n\ndef read(run):\n  return 1\n")
   with pytest.raises(spec.SpecError, match="does not define"):
     spec.load_metric(root, "per_layer", "half")
+
+
+# -- where an entry stands is nobody's business -------------------------------
+
+@pytest.mark.parametrize("order", ["reversed", "appended", "joined"])
+def test_no_rule_or_cell_depends_on_where_an_entry_stands(tmp_path, order):
+  # A later PR appends its metrics' entries at the end of ``per_layer``,
+  # whatever stands there, and its cell's name at the end of an accepted
+  # entry's ``workloads``. The real tree, copied, with the list reversed,
+  # with one entry more at its end, or with one more cell in every entry
+  # that names its cells and whose file asks nothing of them: every rule
+  # admits it, every cell reports what it reported (and what it joined),
+  # in the entries' order, and what each metric's own test holds about
+  # the cells it names (``reported_where_named``) holds there too.
+  root = str(tmp_path)
+  shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+  shutil.copytree(os.path.join(REPO, "benchmarks"),
+                  os.path.join(root, "benchmarks"),
+                  ignore=shutil.ignore_patterns("__pycache__"))
+  before = {cell: spec.cell_metrics(root, "per_layer", cell)
+            for cell in CELLS}
+  named = {m["name"]: list(m["workloads"]) for m in BENCH["per_layer"]
+           if "workloads" in m}
+  new = {cell: [] for cell in CELLS}
+  if order == "reversed":
+    _edit(root, "BENCHMARK.json", lambda b: b["per_layer"].reverse())
+  elif order == "appended":
+    new[CELLS[0]] = ["later_metric"]
+    with open(os.path.join(root, "benchmarks/layer_metrics/later_metric.py"),
+              "w", encoding="utf-8") as f:
+      f.write('"""Steps the run timed."""\nLAYER = "driver_loop"\n'
+              'UNIT = "count"\nBETTER = "higher"\n'
+              'SOURCE = "program_counter"\nMOVES = "samples_per_sec"\n\n\n'
+              'def read(run):\n  return run.timed_steps\n')
+    _edit(root, "BENCHMARK.json", lambda b: b["per_layer"].append({
+        "name": "later_metric", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "driver_loop",
+        "moves": "samples_per_sec", "workloads": [CELLS[0]]}))
+  else:
+    def join(bench):
+      for entry in bench["per_layer"]:
+        needs = getattr(spec.load_metric(root, "per_layer", entry["name"]),
+                        "NEEDS", {})
+        late = [c for c in CELLS if c not in entry.get("workloads", CELLS)]
+        if late and not needs:
+          entry["workloads"].append(late[0])
+          new[late[0]].append(entry["name"])
+    _edit(root, "BENCHMARK.json", join)
+    # The two the trinity-mini cell joined in PR 34 are among them.
+    assert {"moe_compact_share", "moe_experts_roofline"} <= {
+        name for names in new.values() for name in names}
+  admit(root)
+  listed = [m["name"] for m in spec.load_benchmark(root)["per_layer"]]
+  for cell in CELLS:
+    now = spec.cell_metrics(root, "per_layer", cell)
+    assert set(now) == set(before[cell] + new[cell])
+    assert now == [name for name in listed if name in now]
+  for name, cells in named.items():
+    reported_where_named(root, name, expected=cells)
