@@ -64,7 +64,8 @@ The layer (RMSNorm, no biases):
   kind (every latent-attention stack), and unrolled where window and
   full layers mix (two bodies cannot be one scan body; a stage a chip
   holds is shorter than two periods of the pattern, so a scan over
-  periods would have length 1);
+  periods would have length 1); the scanned body is rematerialised, the
+  unrolled layers keep their activations;
 * ``num_nextn_predict_layers`` = 1 multi-token-prediction module:
   RMSNorm of the next token's embedding and of the last block's output,
   concatenated, projected back to the hidden size, one more mixture
@@ -616,13 +617,21 @@ class MLAMoELM(_Options):
       # Layers of two kinds are two bodies: the held layers unrolled,
       # each traced once (a deeper stage would scan one PERIOD of the
       # pattern; the stage a chip holds is shorter than two periods).
-      # Outside a scan XLA merges the forward that nn.remat would repeat
-      # with the first one (prevent_cse=False), so these layers KEEP
-      # their activations and run their forward once: five layers at
-      # 8,192 tokens fit the chip (PERF.md section 5, PR 32).
+      # They are NOT rematerialised, whatever ``remat`` says of the
+      # scanned stack. Outside a scan XLA merges every operation that
+      # nn.remat(prevent_cse=False) repeats with its first copy, so the
+      # activations are kept under it as well (five layers at 8,192
+      # tokens fit the chip); what XLA does not merge would run twice:
+      # the routed path's rounds loop wherever a block's backward pass
+      # reads the feed-forward's output (a post-norm block's
+      # ``post_mlp_layernorm`` does), and the gather of the chosen
+      # experts' scores: 16 ms of the trinity-mini cell's 272 ms step,
+      # for 0.08 GiB MORE memory (PERF.md section 6, PR 35). The stack's
+      # form decides, and that follows from the configuration's
+      # ``layer_types``: no flag.
       for i, window in enumerate(mixture_windows):
-        x, _ = block_cls(window=window, name=f"layer_{i}",
-                         **self.options())(x, None)
+        x, _ = Block(window=window, name=f"layer_{i}",
+                     **self.options())(x, None)
     self.sow("intermediates", "hidden_last", x)
     h_main = self.norm("norm")(x).astype(self.dtype)
     h_mtp = None

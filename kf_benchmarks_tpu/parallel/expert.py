@@ -175,11 +175,14 @@ def reference_switch_moe(x_grouped, gate_w, w1, b1, w2, b2,
 # ``rows`` is all N x k pairs (half of the experts or more held) there is
 # one round and no loop in the program.
 #
-# Under ``nn.remat`` the whole path runs AGAIN in the backward pass
-# wherever something reads its output there: a block that normalises the
-# feed-forward's output does (23.0 + 5.6 ms a step of the trinity-mini
-# cell at PR 32, PERF.md section 5); a pre-norm block, whose output goes
-# to the residual sum alone, does not, and XLA drops the repeat.
+# The path's forward runs ONCE a step in both of ``mla_moe_lm``'s
+# stacks. Under ``nn.remat`` it would run again in the backward pass
+# wherever something reads its output there, because XLA merges no loop
+# with its copy: a block that normalises the feed-forward's output reads
+# it (8.8 + 5.6 ms a step of the trinity-mini cell until PR 35, whose
+# unrolled layers are no longer rematerialised: PERF.md section 6); a
+# pre-norm block, whose output goes to the residual sum alone, does not,
+# and XLA drops the repeat (the glm cell's scanned stack).
 
 
 def compact_rows(pairs: int, held: int, experts: int, tile: int = 512) -> int:
@@ -441,10 +444,10 @@ def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
 def _all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl):
   """``experts_round`` summed over the rounds that hold a pair of a held
   expert. Differentiated as a whole: the backward runs each round's
-  forward again inside its own loop, so nothing of a round outlives it.
-  (Where ``nn.remat`` repeats this forward in the backward pass, it runs
-  there only if its y has a reader: a post-norm block's does, a pre-norm
-  block's does not. The comment above ``compact_rows`` has the cost.)"""
+  forward again inside its own loop, so nothing of a round outlives it
+  and the backward needs nothing of this forward but its inputs. (A
+  caller under ``nn.remat`` pays for this forward twice only where the
+  backward pass reads y itself: the comment above ``compact_rows``.)"""
   args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl)
   return _while_pairs_left(lambda i: experts_round(i, *args), plan, rows)
 

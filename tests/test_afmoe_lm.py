@@ -182,6 +182,74 @@ def test_remat_on_and_off_agree():
   trees_close(grads2, grads, "gradient")
 
 
+# -- what a block keeps for its backward pass ----------------------------------
+
+def _loss_of_params(cfg, **setup_kwargs):
+  """(the training loss as a function of the parameters, the parameters)
+  of ``setup``'s stack, router state updated as a step updates it."""
+  module, params, stats, tokens, labels = setup(cfg, **setup_kwargs)
+  model = lm.MLAMoELMModel()
+  model.cfg = cfg
+
+  def loss(p):
+    (heads, _), _ = module.apply({"params": p, "batch_stats": stats}, tokens,
+                                 mutable=["batch_stats"])
+    return model.loss_function(
+        model_lib.BuildNetworkResult(logits=(heads, None)), labels)
+  return loss, params
+
+
+def _routed_loops_in_the_gradient(cfg, seq=128):
+  """How many rounds loops of the routed path (``expert._while_pairs_left``)
+  the COMPILED gradient of the stack holds, under the model's own remat
+  setting. 2 x 128 tokens x 4 choices are 1,024 pairs in rounds of 512
+  rows (2 of 16 experts held), so the size has the loop; the grouped
+  products sit in its body, so a loop more is a pass of them more. What
+  remat's repeated forward costs is XLA's decision (it merges a repeated
+  operation with its first copy, and never a loop), hence the compiled
+  text and not the jaxpr."""
+  loss, params = _loss_of_params(cfg, seq=seq)
+  text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
+  return sum(" while(" in line and "/moe_route/" in line
+             for line in text.splitlines())
+
+
+@pytest.mark.parametrize("post_norms", [True, False])
+def test_routed_path_runs_once_forward_under_either_norm_placement(
+    post_norms):
+  # A post-norm block's backward pass reads the feed-forward's output
+  # (``post_mlp_layernorm``'s input); a pre-norm block's does not. Either
+  # way a mixture layer runs the rounds loop twice, forward and backward:
+  # under nn.remat the post-norm block ran it a third time to have that
+  # output again (PERF.md section 6, PR 35).
+  cfg = tiny(shards=8, post_norms=post_norms)
+  assert cfg.windows == (24, 24, None, 24, 24) and cfg.moe_layers == 4
+  assert _routed_loops_in_the_gradient(cfg) == 2 * cfg.moe_layers
+
+
+@pytest.mark.parametrize("first_layer, layers_held", [(0, 5), (2, 2)])
+def test_unrolled_layers_without_remat_change_no_bit(first_layer, layers_held,
+                                                     monkeypatch):
+  # Same operations, other residuals: the unrolled layers under nn.remat
+  # (the rule before PR 35) give the loss and every gradient to the last
+  # bit. Operation by operation (no jit): in one compiled program the
+  # last digit is also XLA's choice of fusions, which follows what is
+  # kept.
+  cfg = tiny(layers_held=layers_held, first_layer=first_layer)
+  loss, params = _loss_of_params(cfg)
+  assert "layer_0" in params and "layers" not in params
+
+  def loss_and_gradients():
+    with jax.disable_jit():
+      return jax.tree.leaves(jax.value_and_grad(loss)(params))
+  plain = loss_and_gradients()
+  monkeypatch.setattr(lm, "Block", lm.nn.remat(lm.Block, prevent_cse=False))
+  rematted = loss_and_gradients()
+  assert len(plain) == len(rematted) > 1
+  for a, b in zip(plain, rematted):
+    assert np.array_equal(a, b)
+
+
 # Each control is ONE departure from the published layer, planted in the
 # program from outside (experiments/lm_precision_control.py plants the
 # same in the benchmark's cell); the comparison that passes above has to
