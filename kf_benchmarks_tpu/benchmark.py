@@ -2006,6 +2006,11 @@ class BenchmarkCNN:
         # backward kernel passes a layer, the kernel's tiles. Static.
         # None for a model without one.
         "attention": self._trace.static("attention"),
+        # The fused head's schedule as the model stated it at the build
+        # (ops/fused_loss.weight_grad_stats): positions a softmax chunk,
+        # rows of one weight-gradient product, passes over the kernel's
+        # f32 gradient a step. Static. None for a model without one.
+        "lm_head": self._trace.static("lm_head"),
         # The allocator's own account of the fullest device of the
         # mesh, read as the timed loop ends: live buffers at their peak,
         # what the runtime reserved for loaded programs at its peak, and

@@ -450,6 +450,12 @@ NON_METRIC_KEYS = frozenset({
     # block_kv, block_kv_dkv, dq_partials}; parallel/sequence.flash_plan): a
     # description of the program that ran, None for every other model.
     "attention",
+    # PR 36: the fused head's schedule as models/mla_moe_lm.py states it
+    # at the build ({chunk, rows_per_weight_grad_product,
+    # weight_grad_passes, dlogits_bytes_held, losses};
+    # ops/fused_loss.weight_grad_stats): a description of the program
+    # that ran, None for every other model.
+    "lm_head",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

@@ -812,6 +812,7 @@ class MLAMoELMModel(model_lib.Model):
     # shard_map checker refuses (transformer_lm.py has the whole story).
     self.relax_shard_map_vma = on_tpu
     self._state_combine(dtype)
+    self._state_lm_head(dtype)
     return MLAMoELM(cfg=self.cfg, dtype=dtype, param_dtype=param_dtype,
                     moe_impl="gmm" if on_tpu else "ragged_dot")
 
@@ -844,6 +845,31 @@ class MLAMoELMModel(model_lib.Model):
         "({table_bytes} bytes), weighted on the token side".format(
             tokens, **combine))
 
+  def lm_head_stats(self, dtype):
+    """The run's ``stats["lm_head"]``: the fused head's schedule at this
+    job's shapes (``fused_loss.weight_grad_stats``; two losses where
+    the configuration has an MTP module)."""
+    c = self.cfg
+    return fused_loss_lib.weight_grad_stats(
+        self.get_batch_size(), self.seq_len, self.loss_chunk, c.vocab_rows,
+        2 if c.num_nextn_predict_layers else 1, dtype)
+
+  def _state_lm_head(self, dtype):
+    """``stats["lm_head"]`` and its log line, stated like the combine:
+    where the module's type is known, and once."""
+    from kf_benchmarks_tpu import tracing
+    from kf_benchmarks_tpu.utils import log as log_util
+    head, trace = self.lm_head_stats(dtype), tracing.active()
+    if trace.static("lm_head") == head:
+      return
+    trace.set_static("lm_head", head)
+    log_util.log_fn(
+        "lm head: {losses} loss(es), float32 softmax over {chunk} "
+        "positions at a time; the kernel's gradient from products over "
+        "{rows_per_weight_grad_product} rows a loss, "
+        "{weight_grad_passes} passes over its float32 accumulator a "
+        "step, {dlogits_bytes_held} bytes of dlogits held".format(**head))
+
   def get_input_shapes(self, subset):
     n = self.get_batch_size()
     return [[n, self.seq_len], [n, self.seq_len]]
@@ -860,6 +886,14 @@ class MLAMoELMModel(model_lib.Model):
 
   # The f32 softmax temporaries live one chunk of the sequence at a
   # time: 512 positions, and never more than an eighth of the sequence.
+  # The head kernel's products do NOT go chunk by chunk: they take the
+  # chunks in groups of fused_loss.WEIGHT_GRAD_ROWS rows (2,048: four
+  # chunks of one sequence, two of two), because each product of the
+  # kernel's gradient reads and writes its float32 accumulator whole
+  # and at a chunk's 512 rows that traffic, not the arithmetic, sets its
+  # time (4 passes a step at 8,192 positions where a chunk a product
+  # makes 16). What a group holds is bfloat16 rows, so this constant
+  # alone bounds the float32.
   LOSS_CHUNK = 512
 
   @property

@@ -438,6 +438,42 @@ def test_counters_and_cores_reach_the_stats(two_step_stats):
   assert "tiles_visited" not in att["window"]
 
 
+def test_lm_head_reaches_the_stats(two_step_stats):
+  # 2 x 32 positions in chunks of 4, one loss (no MTP module): groups of
+  # a quarter of the sequence, 2 chunks of 8 float32 rows over the 512
+  # vocabulary rows held.
+  assert two_step_stats["lm_head"] == {
+      "chunk": 4, "rows_per_weight_grad_product": 16,
+      "weight_grad_passes": 4, "dlogits_bytes_held": 16 * 512 * 4,
+      "losses": 1}
+
+
+def test_lm_head_of_the_trinity_cell(monkeypatch):
+  # What the benchmark's cell states (1 x 8192 positions, 25,024
+  # vocabulary rows held, one loss, bfloat16): four chunks of 512 rows a
+  # group, so the kernel's gradient is 4 products over 2,048 rows where
+  # a chunk a product made 16; and the one log line says so.
+  from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu.utils import log as log_util
+  model = lm.MLAMoELMModel(params_lib.make_params(
+      model="mla_moe_lm", lm_config="trinity-mini", seq_len=8192,
+      batch_size=1, lm_layers_held=5, lm_first_layer_held=1,
+      lm_layer_shards=8, device="cpu"))
+  model.set_batch_size(1)   # what the run's set-up does with --batch_size
+  assert model.lm_head_stats(jnp.bfloat16) == {
+      "chunk": 512, "rows_per_weight_grad_product": 2048,
+      "weight_grad_passes": 4, "dlogits_bytes_held": 2048 * 25024 * 2,
+      "losses": 1}
+  lines = []
+  monkeypatch.setattr(log_util, "log_fn", lines.append)
+  model._state_lm_head(jnp.bfloat16)
+  assert [ln for ln in lines if ln.startswith("lm head: ")] == [
+      "lm head: 1 loss(es), float32 softmax over 512 positions at a time; "
+      "the kernel's gradient from products over 2048 rows a loss, 4 passes "
+      "over its float32 accumulator a step, 102498304 bytes of dlogits "
+      "held"]
+
+
 def test_attention_cores_of_the_trinity_cell_on_a_tpu(monkeypatch):
   # What the benchmark's cell states (1 x 8192 tokens, head size 128,
   # published layers 1-5): 4 window layers and 1 full, each with ONE

@@ -614,6 +614,34 @@ def test_combine_states_itself_in_the_stats(two_step_stats):
       "table_bytes": 64 * 32 * 4}
 
 
+def test_lm_head_states_itself_in_the_stats(two_step_stats):
+  # 2 x 16 positions in chunks of 2 (an eighth of the sequence), main and
+  # MTP loss: a group is a quarter of the sequence, 2 chunks of 4 rows a
+  # loss, so FOUR products of the kernel's gradient a loss a step, both
+  # losses' float32 rows (a CPU run's module is float32) over the 512
+  # vocabulary rows held.
+  assert two_step_stats["lm_head"] == {
+      "chunk": 2, "rows_per_weight_grad_product": 8,
+      "weight_grad_passes": 4, "dlogits_bytes_held": 2 * 8 * 512 * 4,
+      "losses": 2}
+
+
+def test_lm_head_of_the_glm_cell():
+  # What the benchmark's cell states (2 x 4096 positions, 19,360
+  # vocabulary rows held, main and MTP loss, bfloat16): chunks of 512
+  # positions are 1,024 rows a loss, so two chunks a group: products over
+  # 2,048 rows, 4 a loss into the one float32 accumulator a step where a
+  # chunk a product made 8 a loss.
+  from kf_benchmarks_tpu import params as params_lib
+  model = lm.MLAMoELMModel(params_lib.make_params(
+      model="mla_moe_lm", seq_len=4096, batch_size=2, lm_layers_held=5,
+      lm_layer_shards=8, device="cpu"))
+  assert model.lm_head_stats(jnp.bfloat16) == {
+      "chunk": 512, "rows_per_weight_grad_product": 2048,
+      "weight_grad_passes": 4, "dlogits_bytes_held": 2 * 2048 * 19360 * 2,
+      "losses": 2}
+
+
 def test_attention_core_states_itself_in_the_stats(two_step_stats):
   # Off the TPU no kernel runs (materialised scores), in the 3 layers
   # held and the MTP block; the keys are the ones a TPU run fills.

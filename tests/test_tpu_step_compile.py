@@ -157,6 +157,46 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   assert compiled.memory_analysis().temp_size_in_bytes <= PARENT_CORE_TEMP_BYTES
 
 
+def test_lm_head_weight_gradient_on_the_tpu_compiler(topo):
+  """The fused head alone at the trinity-mini cell's shapes (1 x 8,192
+  positions of width 2,048 in bfloat16, 25,024 vocabulary rows, chunks of
+  512), loss and gradients, on the chip's own compiler (PR 36): the
+  kernel's gradient is ONE product with its float32 accumulate as the
+  epilogue, fed a group's four stacked chunks of ``dlogits`` as the inner
+  loop wrote them -- no copy of the group to another layout (the first
+  form of the grouping had one, 102 MB read and written a group, and lost
+  most of the gain to it on the chip) -- and what the grouping holds
+  beyond a chunk's temporaries is that one stack, 2,048 x 25,024 x 2
+  bytes."""
+  import jax
+  from jax.sharding import SingleDeviceSharding
+  from kf_benchmarks_tpu.ops import fused_loss
+  one = SingleDeviceSharding(topo.devices[0])
+  sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+  t, d, v, chunk = 8192, 2048, 25024, 512
+  stats = fused_loss.weight_grad_stats(1, t, chunk, v, 1, jnp.bfloat16)
+  assert stats["weight_grad_passes"] == 4
+
+  def loss(hidden, kernel, labels):
+    return fused_loss.fused_softmax_xent(hidden, kernel, labels,
+                                         chunk_size=chunk)
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      sds((1, t, d), jnp.bfloat16), sds((d, v), jnp.float32),
+      sds((1, t), jnp.int32)).compile()
+  text = compiled.as_text()
+  group = rf"bf16\[4,1,{chunk},{v}\]"
+  products = re.findall(
+      rf"= f32\[{d},{v}\]\S* fusion\(([^)]*)\), kind=kOutput", text)
+  assert len(products) == 1, products
+  operand = products[0].split(", ")[1]
+  assert re.search(rf"{re.escape(operand)} = {group}", text), operand
+  assert not re.search(rf"= {group}\S* copy\(", text)
+  # The parent's head left 102,595,072 bytes of temporaries in the same
+  # compile (commit 8202903): a chunk's float32 softmax and its logits.
+  assert (compiled.memory_analysis().temp_size_in_bytes
+          < stats["dlogits_bytes_held"] + 2 ** 27)
+
+
 def test_trinity_mini_kernels_on_the_tpu_compiler(topo):
   """The kernels of the trinity-mini cell at its real widths, forward
   and backward, on the chip's own compiler (PR 32): the attention core at
