@@ -1271,7 +1271,6 @@ class BenchmarkCNN:
     log_fn("Running warm up")
     trace.begin_phase(tracing_lib.PHASE_WARMUP)
     t0 = time.time()
-    t0_warm = trace.now()
     cursor = 0  # consumed slices of the current staged real-data chunk
     if chunked:
       # Exactly num_warmup_batches warmup steps, like K=1: q whole
@@ -1320,8 +1319,6 @@ class BenchmarkCNN:
         sync.drain(metrics)
     log_fn("Warmup (compile + %d steps): %.1f s" %
            (warm_steps, time.time() - t0))
-    trace.add_span("run", "warmup", t0_warm, trace.now() - t0_warm,
-                   {"steps": warm_steps})
     if tele is not None and self.num_warmup_batches:
       # First heartbeat: compile + warmup completed (the drain above is
       # a real value fetch, utils/sync.py) -- the watchdog leaves its
@@ -1438,9 +1435,12 @@ class BenchmarkCNN:
         top1 = float(m["top_1_accuracy"]) if "top_1_accuracy" in m else None
         top5 = float(m["top_5_accuracy"]) if "top_5_accuracy" in m else None
         window = step_train_times[last_display_len:]
-        log_fn(log_util.format_step_line(
-            i1, self.batch_size * max(self.num_workers, 1), window, loss,
-            top1, top5))
+        # The line's LISTENER (a pipe, a tee that starts a profiler) is
+        # not the program's bookkeeping: a span of its own.
+        with trace.span("handle", "log_line"):
+          log_fn(log_util.format_step_line(
+              i1, self.batch_size * max(self.num_workers, 1), window, loss,
+              top1, top5))
         registry.set(
             "step_images_per_sec",
             self.batch_size * max(self.num_workers, 1) /
@@ -1816,8 +1816,6 @@ class BenchmarkCNN:
     for done in pipe.flush():
       _handle(done)
     total_time = time.time() - loop_start
-    trace.add_span("run", "timed_loop", trace.now() - total_time,
-                   total_time, {"steps": len(step_train_times)})
     if controller is not None and controller is not self.elastic_controller:
       controller.close()
 
@@ -1905,12 +1903,17 @@ class BenchmarkCNN:
     if p.train_dir:
       self._save_checkpoint(state)
     # Streaming latency percentiles (chunk wall / feed wait / checkpoint
-    # save) + the compile ledger table -- AFTER the final save so the
+    # save), one ``host stall:`` line per timed iteration that ran far
+    # over the median, with the span it lay under (the step account),
+    # + the compile ledger table -- AFTER the final save so the
     # printed sample counts match the stats fields below; whole lines
     # only (the scrape guard: nothing interleaves inside step lines).
     # The ledger persists to train_dir/compile_ledger.json keyed on
     # contract fingerprints (tracing.py; ROADMAP items 2 and 5).
     for line in self._trace.latency_lines():
+      log_fn(line)
+    step_account = self._trace.step_account()
+    for line in self._trace.stall_lines(step_account):
       log_fn(line)
     for line in self._trace.ledger_lines():
       log_fn(line)
@@ -1978,11 +1981,16 @@ class BenchmarkCNN:
         # bench.py forwards both into its one-line JSON.
         "latency_percentiles": self._trace.percentile_fields() or None,
         "compile_ledger": self._trace.compile_ledger(),
-        # Always-on totals per span name (n, total_s, max_s) and the
-        # compile-cache counters, for set-up and for the timed loop
+        # Always-on totals per span name (n, total_s, max_s, self_s) and
+        # the compile-cache counters, for set-up and for the timed loop
         # (tracing.py span_totals): what the benchmark's set-up and
         # host-side metrics read, with or without a span file.
         "span_totals": self._trace.span_totals() or None,
+        # Every timed iteration's host seconds by span, exclusive, and
+        # the iterations that ran far over the median with the span
+        # each lay under (tracing.py step_account): the ``host stall:``
+        # lines above, for a reader of the stats.
+        "step_account": step_account,
         # The scopes the step program names: a trace reader holds the
         # device operations' op_names to them (a warm compile cache can
         # hand over another version's metadata; benchmarks/spans.py).

@@ -428,6 +428,12 @@ NON_METRIC_KEYS = frozenset({
     # set-up and host-side metrics (benchmarks/spans.py), not a set of
     # registry keys.
     "span_totals",
+    # PR 37: the timed loop's account of every iteration's host seconds
+    # by span ({iterations, median_s, rows, stalls};
+    # tracing.step_account) -- a table of rows read by the benchmark's
+    # host_stalls_in_window / host_step_max_over_median / host_self_ms
+    # (benchmarks/step_account.py), not a set of registry keys.
+    "step_account",
     # PR 23: the scopes the step program names (train_step.STEP_SCOPES),
     # config provenance for the benchmark's trace reader.
     "step_scopes",

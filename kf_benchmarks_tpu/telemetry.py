@@ -58,6 +58,7 @@ import numpy as np
 from jax import lax
 
 from kf_benchmarks_tpu import metrics as metrics_lib
+from kf_benchmarks_tpu import tracing as tracing_lib
 from kf_benchmarks_tpu.utils import log as log_util
 
 
@@ -540,8 +541,9 @@ class StallWatchdog:
     reassurance line every ``patience_s`` and does nothing else.
   * Mid-run: silence longer than ``factor`` x the trailing mean chunk
     wall (floored at ``min_stall_s``) emits ONE diagnostic per stall
-    episode -- the last flight-recorder rows plus the platform env --
-    and counts it. It NEVER kills, signals, or interrupts the process:
+    episode -- the spans the main thread is inside (the run trace's
+    ``open_spans``), the last flight-recorder rows plus the platform
+    env -- and counts it. It NEVER kills, signals, or interrupts the process:
     it diagnoses, the operator decides.
 
   Heartbeats come from the host observing real completed work (the
@@ -658,6 +660,12 @@ class StallWatchdog:
     self._log(
         f"stall watchdog: no dispatch completed for {idle:.1f}s "
         f"({trail_txt}); diagnosing only -- NOT killing the process")
+    # Where the main thread is, in the run trace's own vocabulary (the
+    # one a late iteration's ``host stall:`` line uses); read unlocked.
+    open_spans = tracing_lib.active().open_spans()
+    if open_spans:
+      self._log("stall watchdog: stalled inside "
+                + " > ".join(open_spans) + f" for {idle:.1f} s")
     # Env only: the watchdog thread never touches the backend.
     self._log("stall watchdog: platform env: JAX_PLATFORMS="
               + os.environ.get("JAX_PLATFORMS", "unset"))

@@ -36,10 +36,21 @@ event emitted through the run's ``RunTrace`` (reached everywhere via
   flag. Measured with ``time.monotonic`` and anchored to the wall
   clock once at session start so that ranks merge onto one axis.
 * **Totals, always.** O(1) per span name -- ``n``, ``total_s``,
-  ``max_s`` -- kept separately for set-up, warm-up and the timed loop
-  (``begin_phase``), with the compile-cache counters beside them;
-  ``stats["span_totals"]`` carries them in every run, file or no file.
-  A stretched ``max_s`` names the boundary a slow run stalled on.
+  ``max_s``, ``self_s`` -- kept separately for set-up, warm-up and the
+  timed loop (``begin_phase``), with the compile-cache counters and the
+  garbage collector's passes beside them; ``stats["span_totals"]``
+  carries them in every run, file or no file.
+* **The step account, always.** Live spans nest: each knows its parent
+  (the innermost span open on ITS thread when it began) and, at its
+  close, its self time (its duration less what its direct children
+  covered). Every ``step()`` iteration of the timed loop leaves one row
+  ``{step, t0, dur_s, by_span}`` whose ``by_span`` holds the self time
+  of every span closed inside it, and the iteration's own under
+  ``self``: exclusive seconds that add up to ``dur_s``.
+  ``stats["step_account"]`` carries the newest rows and the STALLS
+  (iterations over ``STALL_FACTOR`` x the median) with the span each
+  lay under, and the run prints one ``host stall:`` line for each: the
+  program itself says which boundary a slow iteration stalled on.
 
 Compilation is read from JAX itself (``jax.monitoring``; listeners
 registered by benchmark.py): each ``/jax/core/compile/*`` time span
@@ -79,7 +90,10 @@ post-mortem dump lays over the timeline.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
+import itertools
 import json
 import os
 import threading
@@ -92,10 +106,12 @@ from typing import Any, Dict, List, Optional
 # ones keep their rows. "serving" is the request engine's lane
 # (serving/engine.py: enqueue/shed instants, prefill/decode-step spans,
 # whole-request spans); "setup" the pieces before warm-up; "fetch" the
-# blocking metric read; "handle" the per-step host bookkeeping.
+# blocking metric read; "handle" the per-step host bookkeeping; "host"
+# what the interpreter does to the loop behind its back (the cyclic
+# garbage collector, ``host/gc``).
 SUBSYSTEMS = ("run", "compile", "dispatch", "device", "feed",
               "checkpoint", "eval", "elastic", "faults", "serving",
-              "setup", "fetch", "handle")
+              "setup", "fetch", "handle", "host")
 
 # Phases the always-on totals are kept for (begin_phase): everything up
 # to the first dispatch, the warm-up (whose first dispatch traces and
@@ -118,7 +134,15 @@ CACHE_COUNT_EVENTS = {
     "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
 }
 COUNTER_KEYS = ("cache_hits", "cache_misses", "cache_requests",
-                "backend_compiles")
+                "backend_compiles", "gc_collections")
+
+# The step account (RunTrace.step_account): the newest rows kept, how far
+# over the median an iteration has to run to be called a stall, how many
+# stalls get a line, and the key of an iteration's own self time.
+ACCOUNT_ROWS = 4096
+STALL_FACTOR = 1.5
+MAX_STALL_LINES = 8
+SELF_KEY = "self"
 
 # Canonical latency-sample keys (the percentile lines / stats fields).
 # The serving/* entries come from the request engine: TTFT per request,
@@ -228,6 +252,33 @@ def validate_chrome_trace(obj) -> List[str]:
   return problems
 
 
+class _Open:
+  """A live span, as a context manager: begun at ``__enter__`` (which
+  yields its mutable arguments), closed at ``__exit__``."""
+
+  __slots__ = ("trace", "subsystem", "name", "factory", "label", "args",
+               "account", "step", "sid", "parent", "children_s",
+               "annotation", "t0")
+
+  def __init__(self, trace, subsystem: str, name: str, factory, label: str,
+               args: Dict[str, Any],
+               account: Optional[Dict[str, float]] = None):
+    self.trace = trace
+    self.subsystem, self.name = subsystem, name
+    self.factory, self.label, self.args = factory, label, args
+    self.account, self.step = account, account is not None
+    self.sid, self.parent, self.children_s = 0, None, 0.0
+    self.annotation = None
+
+  def __enter__(self) -> Dict[str, Any]:
+    self.trace._open(self)
+    return self.args
+
+  def __exit__(self, *exc) -> bool:
+    self.trace._close(self)
+    return False
+
+
 class RunTrace:
   """One process's span timeline + totals + latency samples + compile
   ledger.
@@ -263,7 +314,9 @@ class RunTrace:
     self._time = time_fn
     self._wall = wall_fn
     self._log = log_fn or (lambda s: None)
-    self._lock = threading.Lock()
+    # Re-entrant: the garbage collector's hook (on_gc) closes a span on
+    # whichever thread a pass ran, possibly inside a locked region here.
+    self._lock = threading.RLock()
     # Wall anchor: spans are monotonic-clocked; export maps them onto
     # the epoch axis via this one (wall, mono) pair so ranks merge onto
     # a comparable timeline.
@@ -272,7 +325,18 @@ class RunTrace:
     self._keep_spans = path is not None
     self._spans: List[Dict[str, Any]] = []
     self._dropped = 0
-    self._next_id = 1
+    self._ids = itertools.count(1)
+    # Open live spans, innermost last, per thread; ``_stacks`` lets
+    # another thread (the stall watchdog) read the owner's, unlocked.
+    self._local = threading.local()
+    self._stacks: Dict[int, List["_Open"]] = {}
+    self._owner = threading.get_ident()
+    # The step account: the newest iterations of the timed loop, how
+    # many there were, and the step number of the first.
+    self._account: "collections.deque[Dict[str, Any]]" = \
+        collections.deque(maxlen=ACCOUNT_ROWS)
+    self._iterations = 0
+    self._first_step: Optional[int] = None
     self._tids: Dict[str, int] = {s: i for i, s in enumerate(SUBSYSTEMS)}
     self._samples: Dict[str, List[float]] = {}
     self._sample_counts: Dict[str, int] = {}
@@ -280,9 +344,9 @@ class RunTrace:
     self._ledger: List[Dict[str, Any]] = []
     self._annotation = annotation
     self._step_annotation = step_annotation
-    # Always-on totals: {phase: {"<sub>/<name>": [n, total_s, max_s]}}
-    # and the compile-cache counters, overall (for compile_mark) and
-    # per phase.
+    # Always-on totals: {phase: {"<sub>/<name>": [n, total_s, max_s,
+    # self_s]}} and the counters, overall (for compile_mark) and per
+    # phase.
     self._phase = PHASE_SETUP
     self._totals: Dict[str, Dict[str, List[float]]] = {PHASE_SETUP: {}}
     self._counters: Dict[str, float] = dict.fromkeys(COUNTER_KEYS, 0)
@@ -316,22 +380,24 @@ class RunTrace:
     (the flight recorder's span_id) never references a span absent
     from the exported timeline. The retrospective form exists for
     durations measured elsewhere -- the pipeline's chunk arrival
-    intervals, the feeder's consumer wait -- where wrapping a ``with``
-    block around the measured region is not possible."""
+    intervals, JAX's monitoring events -- where wrapping a ``with``
+    block around the measured region is not possible. It takes no part
+    in the nesting of live spans: nobody's child, its self time its
+    whole duration."""
     dur_s = max(0.0, float(dur_s))
     with self._lock:
-      self._total_row(subsystem, name, dur_s)[1] += dur_s
-    return self._emit("X", subsystem, name, float(t0), dur_s,
-                      dict(args or {}))
+      total = self._total_row(f"{subsystem}/{name}", dur_s)
+      total[1] += dur_s
+      total[3] += dur_s
+      return self._emit("X", subsystem, name, float(t0), dur_s,
+                        dict(args or {}))
 
-  def _total_row(self, subsystem: str, name: str,
-                 dur_s: float) -> List[float]:
+  def _total_row(self, key: str, dur_s: float) -> List[float]:
     """The current phase's totals row of a span name, with ``n`` and
     ``max_s`` already counted; the caller adds what the span is worth
-    to ``total_s`` (all of it, but for the compile lane's nesting).
-    Called under the lock."""
-    row = self._totals[self._phase].setdefault(
-        f"{subsystem}/{name}", [0, 0.0, 0.0])
+    to ``total_s`` and ``self_s`` (all of it, but for the compile lane's
+    nesting and a live span's children). Called under the lock."""
+    row = self._totals[self._phase].setdefault(key, [0, 0.0, 0.0, 0.0])
     row[0] += 1
     if dur_s > row[2]:
       row[2] = dur_s
@@ -344,20 +410,22 @@ class RunTrace:
                       dict(args))
 
   def _emit(self, ph: str, subsystem: str, name: str, t0: float,
-            dur_s: float, args: Dict[str, Any]) -> int:
+            dur_s: float, args: Dict[str, Any], sid: Optional[int] = None,
+            parent: int = 0) -> int:
     with self._lock:
       if not self._keep_spans:
         return 0
       if len(self._spans) >= self.MAX_SPANS:
         self._dropped += 1
         return 0
-      sid = self._next_id
-      self._next_id += 1
-      self._spans.append({
-          "id": sid, "ph": ph, "sub": subsystem,
-          "tid": self._tid(subsystem), "name": name,
-          "t0": t0, "dur": dur_s, "args": args,
-      })
+      if sid is None:
+        sid = next(self._ids)
+      span = {"id": sid, "ph": ph, "sub": subsystem,
+              "tid": self._tid(subsystem), "name": name,
+              "t0": t0, "dur": dur_s, "args": args}
+      if parent:
+        span["parent"] = parent
+      self._spans.append(span)
     return sid
 
   def span(self, subsystem: str, name: str, **args):
@@ -365,30 +433,103 @@ class RunTrace:
     can attach results discovered inside the span (e.g. the elastic
     generation number). Also enters the profiler annotation
     ``kf/<subsystem>/<name>`` with the arguments given at entry."""
-    return self._live(subsystem, name, self._annotation,
-                      f"kf/{subsystem}/{name}", args)
+    return _Open(self, subsystem, name, self._annotation,
+                 f"kf/{subsystem}/{name}", args)
 
   def step(self, name: str, step_num: int):
     """One iteration of a loop: a ``run``-lane span that enters the
     profiler's STEP annotation ``name`` (which profile viewers group
-    device and host activity by) instead of a ``kf/`` one."""
-    return self._live("run", name, self._step_annotation, name,
-                      {"step_num": int(step_num)})
+    device and host activity by) instead of a ``kf/`` one, and whose
+    close leaves one row of the step account."""
+    return _Open(self, "run", name, self._step_annotation, name,
+                 {"step_num": int(step_num)}, account={})
 
-  @contextlib.contextmanager
-  def _live(self, subsystem: str, name: str, factory, label: str,
-            args: Dict[str, Any]):
-    live_args = dict(args)
-    annotation = factory(label, **args) if factory is not None else None
-    t0 = self._time()
-    if annotation is not None:
-      annotation.__enter__()
+  def _stack(self) -> List["_Open"]:
     try:
-      yield live_args
-    finally:
-      if annotation is not None:
-        annotation.__exit__(None, None, None)
-      self.add_span(subsystem, name, t0, self._time() - t0, live_args)
+      return self._local.stack
+    except AttributeError:
+      stack = self._local.stack = []
+      self._stacks[threading.get_ident()] = stack
+      return stack
+
+  def _open(self, frame: "_Open") -> None:
+    """Begin a live span on this thread: a child of the innermost one
+    open here. A ``step()`` starts a new account of exclusive seconds
+    by span name; any other span books into the one its parent books
+    into."""
+    stack = self._stack()
+    if self._keep_spans:
+      frame.sid = next(self._ids)
+    if stack:
+      frame.parent = stack[-1]
+      if not frame.step:
+        frame.account = frame.parent.account
+    if frame.factory is not None:
+      frame.annotation = frame.factory(frame.label, **frame.args)
+    stack.append(frame)
+    frame.t0 = self._time()
+    if frame.annotation is not None:
+      frame.annotation.__enter__()
+
+  def _close(self, frame: "_Open") -> None:
+    if frame.annotation is not None:
+      frame.annotation.__exit__(None, None, None)
+    dur_s = self._time() - frame.t0
+    stack = self._stack()
+    if stack and stack[-1] is frame:
+      stack.pop()
+    elif frame in stack:  # closed out of order (a generator left open)
+      stack.remove(frame)
+    self_s = dur_s - frame.children_s
+    key = f"{frame.subsystem}/{frame.name}"
+    parent, account, row = frame.parent, frame.account, None
+    if parent is not None:
+      parent.children_s += dur_s
+    if account is not None:
+      booked = SELF_KEY if frame.step else key
+      account[booked] = account.get(booked, 0.0) + self_s
+    if frame.step:  # (iterations do not nest: one inside another would
+      # keep its seconds from the outer one's account)
+      row = {"step": frame.args["step_num"], "t0": frame.t0,
+             "dur_s": dur_s, "by_span": account}
+    with self._lock:
+      total = self._total_row(key, dur_s)
+      total[1] += dur_s
+      total[3] += self_s
+      if row is not None and self._phase == PHASE_TIMED:
+        if self._first_step is None:
+          self._first_step = row["step"]
+        self._iterations += 1
+        self._account.append(row)
+      self._emit("X", frame.subsystem, frame.name, frame.t0, dur_s,
+                 frame.args, frame.sid,
+                 parent.sid if parent is not None else 0)
+
+  def open_spans(self) -> List[str]:
+    """The spans open right now on the thread that opened this session
+    (the main path), outermost first. For another thread to read (the
+    stall watchdog): takes no lock, so it never holds the loop up."""
+    return [f"{f.subsystem}/{f.name}"
+            for f in list(self._stacks.get(self._owner, ()))]
+
+  def on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+    """The ``gc.callbacks`` hook (installed by ``activate``): one
+    ``host/gc`` span per pass of the cyclic collector, on whichever
+    thread it ran, a child of whatever span that thread was inside."""
+    if phase == "start":
+      frame = self._local.gc = _Open(
+          self, "host", "gc", self._annotation, "kf/host/gc",
+          {"generation": info.get("generation")})
+      self._open(frame)
+      return
+    frame = getattr(self._local, "gc", None)
+    if frame is None:
+      return  # the pass began before the hook was installed
+    self._local.gc = None
+    frame.args["collected"] = info.get("collected")
+    self._close(frame)
+    with self._lock:
+      self._count("gc_collections", 1)
 
   # -- always-on totals -------------------------------------------------------
 
@@ -403,15 +544,73 @@ class RunTrace:
                                       dict.fromkeys(COUNTER_KEYS, 0))
 
   def span_totals(self) -> Dict[str, Any]:
-    """``{phase: {"spans": {"<subsystem>/<name>": {n, total_s, max_s}},
-    "counters": {...}}}`` -- the ``stats["span_totals"]`` field."""
+    """``{phase: {"spans": {"<subsystem>/<name>": {n, total_s, max_s,
+    self_s}}, "counters": {...}}}`` -- the ``stats["span_totals"]``
+    field. ``self_s`` is ``total_s`` less what the spans' direct
+    children covered."""
     with self._lock:
       return {
           phase: {
-              "spans": {key: {"n": int(n), "total_s": total, "max_s": mx}
-                        for key, (n, total, mx) in sorted(rows.items())},
+              "spans": {key: {"n": int(n), "total_s": total, "max_s": mx,
+                              "self_s": own}
+                        for key, (n, total, mx, own) in sorted(rows.items())},
               "counters": dict(self._phase_counters[phase]),
           } for phase, rows in self._totals.items()}
+
+  # -- the step account -------------------------------------------------------
+
+  def step_account(self) -> Dict[str, Any]:
+    """``{"iterations", "median_s", "rows", "stalls"}`` of the timed
+    loop -- the ``stats["step_account"]`` field. ``rows`` are the newest
+    ``ACCOUNT_ROWS`` iterations, oldest first: ``{step, t0, dur_s,
+    by_span}`` with ``t0`` on this session's clock and ``by_span`` the
+    iteration's exclusive seconds by span name (its own under ``self``),
+    which add up to ``dur_s``. A STALL is a row longer than
+    ``STALL_FACTOR`` x the rows' median; each adds ``timed_step`` (1 for
+    the loop's first step), ``excess_s`` over the median, ``under``, the
+    key of ``by_span`` that holds the most seconds, and ``then_s``, the
+    lengths of the two iterations after it: where the stall lay under
+    the wait for the device and those two took no time, the results
+    were ready and the HOST was held in the wait; where they took a
+    step each, the device was late. Longest first."""
+    with self._lock:
+      rows = list(self._account)
+      iterations, first = self._iterations, self._first_step
+    median = percentile([r["dur_s"] for r in rows], 50)
+    stalls = []
+    for i, r in enumerate(rows):
+      if r["dur_s"] > STALL_FACTOR * median:
+        stalls.append(dict(
+            r, timed_step=r["step"] - first + 1,
+            excess_s=r["dur_s"] - median,
+            under=max(r["by_span"], key=r["by_span"].get),
+            then_s=[n["dur_s"] for n in rows[i + 1:i + 3]]))
+    stalls.sort(key=lambda r: -r["dur_s"])
+    return {"iterations": iterations, "median_s": median, "rows": rows,
+            "stalls": stalls}
+
+  def stall_lines(self, account: Optional[Dict[str, Any]] = None
+                  ) -> List[str]:
+    """Run-end report of the stalled iterations, one WHOLE line each
+    (the scrape-guard contract), at most ``MAX_STALL_LINES``, longest
+    first; none where no iteration stalled. ``host stall: timed step N
+    took D ms (median M): <span> <ms>, ...; then <ms>, <ms>``: the
+    iteration's exclusive milliseconds by span, most first, and the
+    lengths of the two iterations after it. ``account`` is a
+    ``step_account()`` the caller already holds."""
+    if account is None:
+      account = self.step_account()
+    lines = []
+    for r in account["stalls"][:MAX_STALL_LINES]:
+      parts = sorted(r["by_span"].items(), key=lambda kv: -kv[1])
+      lines.append(
+          "host stall: timed step %d took %.1f ms (median %.1f): %s; "
+          "then %s" % (
+              r["timed_step"], 1e3 * r["dur_s"],
+              1e3 * account["median_s"],
+              ", ".join("%s %.1f" % (k, 1e3 * v) for k, v in parts),
+              ", ".join("%.1f" % (1e3 * v) for v in r["then_s"]) or "-"))
+    return lines
 
   # -- jax.monitoring listeners (registered by benchmark.py) ------------------
 
@@ -428,13 +627,15 @@ class RunTrace:
     dur = max(0.0, float(end_time) - float(start_time))
     t0 = self._anchor_mono + (float(start_time) - self._anchor_wall)
     with self._lock:
-      row = self._total_row("compile", name, dur)
+      row = self._total_row(f"compile/{name}", dur)
       cover = self._compile_cover
       while cover and cover[-1][0] >= start_time:
         inner_t0, inner_t1, inner_row = cover.pop()
         inner_row[1] -= inner_t1 - inner_t0
+        inner_row[3] -= inner_t1 - inner_t0
       cover.append((start_time, end_time, row))
       row[1] += dur
+      row[3] += dur
       if name == "backend_compile":
         self._count("backend_compiles", 1)
     self._emit("X", "compile", name, t0, dur,
@@ -655,6 +856,8 @@ class RunTrace:
            "pid": self.rank, "tid": s["tid"],
            "ts": round(self._epoch_us(s["t0"]), 3),
            "args": {"span_id": s["id"], **s["args"]}}
+      if s.get("parent"):
+        e["args"]["parent_id"] = s["parent"]
       if s["ph"] == "X":
         e["dur"] = round(s["dur"] * 1e6, 3)
       else:
@@ -889,6 +1092,15 @@ class _NullTrace:
   def span_totals(self) -> Dict[str, Any]:
     return {}
 
+  def step_account(self) -> Dict[str, Any]:
+    return {"iterations": 0, "median_s": None, "rows": [], "stalls": []}
+
+  def stall_lines(self, account=None) -> List[str]:
+    return []
+
+  def open_spans(self) -> List[str]:
+    return []
+
   def compile_mark(self):
     return (0, 0, 0)
 
@@ -927,15 +1139,27 @@ NULL_TRACE = _NullTrace()
 _active: Any = None
 
 
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+  """The one ``gc.callbacks`` entry: hands each pass of the cyclic
+  collector to the active session (RunTrace.on_gc)."""
+  trace = _active
+  if trace is not None:
+    trace.on_gc(phase, info)
+
+
 def activate(trace: RunTrace) -> RunTrace:
   global _active
   _active = trace
+  if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
   return trace
 
 
 def deactivate() -> None:
   global _active
   _active = None
+  if _on_gc in gc.callbacks:
+    gc.callbacks.remove(_on_gc)
 
 
 def active():

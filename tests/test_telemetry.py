@@ -304,6 +304,39 @@ def test_watchdog_midrun_stall_diagnoses_and_never_kills(tmp_path):
   assert not any("SIGKILL" in l or "terminat" in l for l in diag)
 
 
+def test_watchdog_diagnostic_names_the_open_spans():
+  """A hang is reported in the run trace's vocabulary: the spans the
+  main thread is inside, outermost first. With no session active the
+  diagnostic is what it was."""
+  from kf_benchmarks_tpu import tracing
+
+  def stall_once():
+    logs, t = [], [0.0]
+    wd = telemetry.StallWatchdog(factor=3.0, min_stall_s=0.0,
+                                 log_fn=logs.append, time_fn=lambda: t[0])
+    wd.beat(0.1)
+    t[0] = 12.3
+    wd._check(t[0])
+    assert wd.stalls == 1
+    return logs
+
+  before = stall_once()
+  assert len(before) == 2 and not any("stalled inside" in l for l in before)
+  tr = tracing.RunTrace()
+  tracing.activate(tr)
+  try:
+    with tr.step("train", 31):
+      with tr.span("dispatch", "train_step"):
+        logs = stall_once()
+    idle = stall_once()        # a session, and the loop inside no span
+  finally:
+    tracing.deactivate()
+  assert ("stall watchdog: stalled inside run/train > dispatch/train_step "
+          "for 12.3 s") in logs
+  assert [l for l in logs if "stalled inside" not in l] == before
+  assert idle == before
+
+
 def test_watchdog_thread_survives_failing_check():
   """One raising check evaluation (e.g. the log sink erroring inside a
   diagnostic) logs and keeps the poll loop alive -- it must not retire

@@ -734,13 +734,15 @@ def test_span_totals_count_n_total_and_max_per_phase(tmp_path, with_path):
   tr.instant("faults", "kill")                # instants do not
   totals = tr.span_totals()
   assert totals[tracing.PHASE_SETUP]["spans"] == {
-      "setup/init_state": {"n": 2, "total_s": 1.25, "max_s": 1.0}}
+      "setup/init_state": {"n": 2, "total_s": 1.25, "max_s": 1.0,
+                           "self_s": 1.25}}
   timed = totals[tracing.PHASE_TIMED]["spans"]
   assert set(timed) == {"fetch/metrics", "feed/wait"}
   assert timed["fetch/metrics"]["n"] == 3
   assert timed["fetch/metrics"]["total_s"] == pytest.approx(0.009)
   assert timed["fetch/metrics"]["max_s"] == pytest.approx(0.004)
-  assert timed["feed/wait"] == {"n": 1, "total_s": 0.5, "max_s": 0.5}
+  assert timed["feed/wait"] == {"n": 1, "total_s": 0.5, "max_s": 0.5,
+                                "self_s": 0.5}
   # The span list is kept only with a path; the totals either way.
   assert len(tr.chrome_events()) > 1 if with_path else \
       len(tr.chrome_events()) == 1
@@ -779,7 +781,7 @@ def test_monitoring_time_span_becomes_a_compile_lane_span(tmp_path):
   # totals count outermost intervals, so they add up to the wall spent.
   setup = tr.span_totals()[tracing.PHASE_SETUP]
   assert setup["spans"]["compile/jaxpr_trace"] == {
-      "n": 2, "total_s": 2.5, "max_s": 2.5}
+      "n": 2, "total_s": 2.5, "max_s": 2.5, "self_s": 2.5}
   assert setup["spans"]["compile/jaxpr_to_mlir"]["total_s"] == 1.0
   assert setup["spans"]["compile/backend_compile"]["total_s"] == 5.0
   assert setup["counters"]["backend_compiles"] == 1
@@ -824,10 +826,10 @@ def test_cache_events_move_the_counters_and_the_ledgers_cache_hit():
   totals = tr.span_totals()
   assert totals[tracing.PHASE_SETUP]["counters"] == {
       "cache_hits": 0, "cache_misses": 1, "cache_requests": 1,
-      "backend_compiles": 1}
+      "backend_compiles": 1, "gc_collections": 0}
   assert totals[tracing.PHASE_TIMED]["counters"] == {
       "cache_hits": 3, "cache_misses": 0, "cache_requests": 4,
-      "backend_compiles": 0}
+      "backend_compiles": 0, "gc_collections": 0}
 
 
 def test_run_stats_carry_span_totals_for_every_phase():
@@ -868,6 +870,295 @@ def test_run_stats_carry_span_totals_for_every_phase():
                  for k in metrics_lib.flatten_stats(stats))
 
 
+# -- parents, self times, the step account -------------------------------------
+
+MS = 2.0 ** -10   # a binary "millisecond": sums of these are exact
+
+
+def _spans_by_name(tr):
+  return {e["name"]: e for e in tr.chrome_events() if e["ph"] == "X"}
+
+
+def test_live_spans_record_parent_and_self_time(tmp_path):
+  tr, clock = _trace(tmp_path)
+  with tr.span("handle", "step"):
+    clock.tick(2 * MS)
+    with tr.span("handle", "log_line"):
+      clock.tick(8 * MS)
+      with tr.span("checkpoint", "save"):
+        clock.tick(16 * MS)
+    with tr.span("fetch", "metrics"):     # a sibling of log_line
+      clock.tick(4 * MS)
+    # Retrospective records take no part: nobody's child, and the time
+    # they cover stays their enclosing span's own.
+    tr.add_span("device", "chunk", clock(), 32 * MS)
+    clock.tick(MS)
+  spans = _spans_by_name(tr)
+  ids = {name: e["args"]["span_id"] for name, e in spans.items()}
+  assert "parent_id" not in spans["step"]["args"]
+  assert spans["log_line"]["args"]["parent_id"] == ids["step"]
+  assert spans["save"]["args"]["parent_id"] == ids["log_line"]
+  assert spans["metrics"]["args"]["parent_id"] == ids["step"]
+  assert "parent_id" not in spans["chunk"]["args"]
+  rows = tr.span_totals()[tracing.PHASE_SETUP]["spans"]
+  assert rows["handle/step"]["total_s"] == 31 * MS
+  assert rows["handle/step"]["self_s"] == 3 * MS      # less 24 and 4
+  assert rows["handle/log_line"]["total_s"] == 24 * MS
+  assert rows["handle/log_line"]["self_s"] == 8 * MS
+  assert rows["checkpoint/save"]["self_s"] == 16 * MS
+  assert rows["fetch/metrics"]["self_s"] == 4 * MS
+  assert rows["device/chunk"]["self_s"] == 32 * MS
+  assert tracing.validate_chrome_trace(
+      {"traceEvents": tr.chrome_events()}) == []
+
+
+def test_spans_nest_per_thread(tmp_path):
+  """The feeder's worker thread has a stack of its own: what it opens
+  while the main thread is inside a span is nobody's child, and takes
+  nothing off that span's self time."""
+  import threading
+  tr, clock = _trace(tmp_path)
+  inside, done = threading.Event(), threading.Event()
+
+  def worker():
+    with tr.span("feed", "fetch"):
+      with tr.span("feed", "h2d"):
+        inside.set()
+        done.wait(5.0)
+
+  t = threading.Thread(target=worker)
+  with tr.step("train", 1):
+    with tr.span("dispatch", "train_step"):
+      t.start()
+      assert inside.wait(5.0)
+      assert tr.open_spans() == ["run/train", "dispatch/train_step"]
+      clock.tick(4 * MS)
+      done.set()
+      t.join()
+  spans = _spans_by_name(tr)
+  assert "parent_id" not in spans["fetch"]["args"]
+  assert spans["h2d"]["args"]["parent_id"] == \
+      spans["fetch"]["args"]["span_id"]
+  assert spans["train_step"]["args"]["parent_id"] == \
+      spans["train"]["args"]["span_id"]
+  rows = tr.span_totals()[tracing.PHASE_SETUP]["spans"]
+  assert rows["dispatch/train_step"]["self_s"] == \
+      rows["dispatch/train_step"]["total_s"]
+  assert tr.open_spans() == []
+
+
+def _iteration(tr, clock, step, plant=None, seconds=0.0):
+  """One made-up iteration of the timed loop, a binary millisecond in
+  every place; ``seconds`` more in the place ``plant`` names."""
+  def spend(place):
+    clock.tick(MS + (seconds if plant == place else 0.0))
+  with tr.step("train", step):
+    with tr.span("dispatch", "train_step", step=step):
+      spend("dispatch/train_step")
+    spend("self")                               # the bare loop body
+    with tr.span("fetch", "metrics"):
+      spend("fetch/metrics")
+    with tr.span("handle", "step"):
+      spend("handle/step")
+      with tr.span("handle", "log_line"):
+        spend("handle/log_line")
+
+
+@pytest.mark.parametrize("place", [
+    "dispatch/train_step", "fetch/metrics", "handle/step",
+    "handle/log_line", "self"])
+def test_step_account_adds_up_and_names_the_planted_stall(place):
+  tr, clock = _trace()
+  # Iterations outside the timed loop (warm-up has none today) leave no
+  # row.
+  _iteration(tr, clock, 99)
+  assert tr.step_account()["iterations"] == 0
+  tr.begin_phase(tracing.PHASE_TIMED)
+  for step in range(5, 17):                     # a resumed run: step 5 first
+    _iteration(tr, clock, step, place if step == 11 else None, 51 * MS)
+  account = tr.step_account()
+  assert account["iterations"] == 12 and len(account["rows"]) == 12
+  assert account["median_s"] == 5 * MS
+  for row in account["rows"]:
+    assert set(row) == {"step", "t0", "dur_s", "by_span"}
+    assert set(row["by_span"]) == {
+        "dispatch/train_step", "fetch/metrics", "handle/step",
+        "handle/log_line", "self"}
+    assert sum(row["by_span"].values()) == row["dur_s"]   # exactly
+  assert [r["step"] for r in account["rows"]] == list(range(5, 17))
+  assert account["rows"][1]["t0"] == account["rows"][0]["t0"] + 5 * MS
+  (stall,) = account["stalls"]
+  assert stall["step"] == 11 and stall["timed_step"] == 7
+  assert stall["dur_s"] == 56 * MS and stall["excess_s"] == 51 * MS
+  assert stall["under"] == place
+  assert stall["by_span"][place] == 52 * MS
+  (line,) = tr.stall_lines()
+  assert line.startswith(
+      "host stall: timed step 7 took %.1f ms (median %.1f): %s %.1f, " % (
+          56e3 * MS, 5e3 * MS, place, 52e3 * MS)), line
+  assert line.endswith("; then %.1f, %.1f" % (5e3 * MS, 5e3 * MS)), line
+  assert stall["then_s"] == [5 * MS, 5 * MS]
+  assert "images/sec" not in line and "\n" not in line
+
+
+def test_no_stall_no_line_and_at_most_eight_lines_longest_first():
+  tr, clock = _trace()
+  tr.begin_phase(tracing.PHASE_TIMED)
+  for step in range(40):
+    _iteration(tr, clock, step)
+  assert tr.step_account()["stalls"] == [] and tr.stall_lines() == []
+  for step in range(40, 50):
+    _iteration(tr, clock, step, "self", (step - 30) * MS)
+  account = tr.step_account()
+  assert [s["step"] for s in account["stalls"]] == list(range(49, 39, -1))
+  lines = tr.stall_lines()
+  assert len(lines) == tracing.MAX_STALL_LINES == 8
+  assert lines[0].startswith("host stall: timed step 50 took ")
+  assert lines[-1].startswith("host stall: timed step 43 took ")
+  # The run's last iteration has none after it.
+  assert account["stalls"][0]["then_s"] == [] and lines[0].endswith("; then -")
+
+
+def test_step_account_ring_keeps_the_newest_rows():
+  tr, clock = _trace()
+  tr.begin_phase(tracing.PHASE_TIMED)
+  extra = 10
+  for step in range(tracing.ACCOUNT_ROWS + extra):
+    with tr.step("train", step):
+      clock.tick(MS)
+  account = tr.step_account()
+  assert tracing.ACCOUNT_ROWS == 4096
+  assert account["iterations"] == 4096 + extra
+  assert len(account["rows"]) == 4096
+  assert account["rows"][0]["step"] == extra
+  assert account["rows"][-1]["by_span"] == {"self": MS}
+
+
+def test_host_gc_is_a_span_of_the_thread_the_collector_ran_on(tmp_path):
+  """The real collector under the real clock: ``activate`` installs the
+  one ``gc.callbacks`` hook, a full collection inside an iteration is a
+  ``host/gc`` span with its generation, a child of the iteration, named
+  by the account; ``deactivate`` takes the hook out."""
+  import gc
+
+  class Node:
+    def __init__(self):
+      self.me = self
+
+  hooks = list(gc.callbacks)
+  tr = tracing.RunTrace(path=str(tmp_path / "t.json"))
+  tracing.activate(tr)
+  try:
+    assert tracing._on_gc in gc.callbacks
+    tracing.activate(tr)                        # installed once
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    tr.begin_phase(tracing.PHASE_TIMED)
+    for step in range(1, 8):
+      garbage = [Node() for _ in range(200_000 if step == 4 else 0)]
+      with tr.step("train", step):
+        del garbage
+        if step == 4:
+          gc.collect()
+  finally:
+    tracing.deactivate()
+  assert gc.callbacks == hooks
+  trains = {e["args"]["step_num"]: e["args"]["span_id"]
+            for e in tr.chrome_events() if e["name"] == "train"}
+  # (building the garbage drew passes of its own, outside any iteration)
+  (full,) = [e for e in tr.chrome_events()
+             if e["name"] == "gc" and e["args"]["generation"] == 2
+             and e["args"].get("parent_id") == trains[4]]
+  assert full["cat"] == "host" and full["args"]["collected"] >= 200_000
+  timed = tr.span_totals()[tracing.PHASE_TIMED]
+  assert timed["counters"]["gc_collections"] == timed["spans"]["host/gc"]["n"]
+  account = tr.step_account()
+  stall = account["stalls"][0]
+  assert stall["timed_step"] == 4 and stall["under"] == "host/gc"
+  assert tr.stall_lines()[0].startswith(
+      "host stall: timed step 4 took ")
+  assert ": host/gc " in tr.stall_lines()[0]
+  # A pass with no session active goes nowhere, and raises nothing.
+  tracing._on_gc("start", {"generation": 0})
+  # A pass that began before the hook went in has no span to close.
+  tr.on_gc("stop", {"generation": 0, "collected": 0})
+
+
+def test_null_sink_answers_the_account_calls():
+  null = tracing.NULL_TRACE
+  assert null.step_account() == {"iterations": 0, "median_s": None,
+                                 "rows": [], "stalls": []}
+  assert null.stall_lines() == [] and null.open_spans() == []
+  tr, _ = _trace()
+  assert tr.step_account() == null.step_account()
+  assert tr.stall_lines() == [] and tr.open_spans() == []
+
+
+def test_e2e_step_account_names_a_slow_listener():
+  """Through ``BenchmarkCNN.run()``: one row per timed dispatch, every
+  row adding up, and a listener of the step line that sleeps is reported
+  under ``handle/log_line`` -- by the iteration that PRINTS the line,
+  which is the one that dispatches two steps later (the lag-2 metric
+  pipeline). The sleeper changes no loss: the account is the host's."""
+  import time as time_lib
+  slow_line, lines = 6, {}
+  orig = log_util.log_fn
+
+  def run(sleep_s):
+    logs = []
+
+    def listener(msg):
+      m = STEP_RE.match(str(msg))
+      if m and int(m.group(1)) == slow_line:
+        time_lib.sleep(sleep_s)
+      logs.append(str(msg))
+
+    log_util.log_fn = listener
+    try:
+      p = params_lib.make_params(
+          model="trivial", num_batches=12, num_warmup_batches=1,
+          device="cpu", display_every=1, batch_size=4)
+      stats = benchmark.BenchmarkCNN(p).run()
+    finally:
+      log_util.log_fn = orig
+    return logs, stats
+
+  logs, stats = run(0.25)
+  account = stats["step_account"]
+  assert account["iterations"] == 12 == len(account["rows"])
+  assert account["iterations"] == \
+      stats["span_totals"][tracing.PHASE_TIMED]["spans"][
+          "dispatch/train_step"]["n"]
+  for row in account["rows"]:
+    assert abs(sum(row["by_span"].values()) - row["dur_s"]) < 1e-6
+  stall = account["stalls"][0]
+  assert stall["timed_step"] == slow_line + 2
+  assert stall["under"] == "handle/log_line"
+  assert stall["by_span"]["handle/log_line"] >= 0.25
+  stall_lines = [l for l in logs if l.startswith("host stall: ")]
+  assert stall_lines and stall_lines[0].startswith(
+      "host stall: timed step %d took " % (slow_line + 2))
+  assert ": handle/log_line " in stall_lines[0]
+  # Whole lines, after the banner, never inside a step line.
+  marker_lines = [l for l in logs if "images/sec:" in l]
+  assert all(STEP_RE.match(l) or TOTAL_RE.match(l) for l in marker_lines)
+  assert logs.index(stall_lines[0]) > max(
+      i for i, l in enumerate(logs) if TOTAL_RE.match(l))
+  # handle/step's own time no longer holds the listener's.
+  timed = stats["span_totals"][tracing.PHASE_TIMED]["spans"]
+  assert timed["handle/log_line"]["n"] == 12
+  assert timed["handle/step"]["self_s"] < 0.25 <= \
+      timed["handle/step"]["total_s"]
+  # The two retrospective spans nothing read are gone.
+  for phase in stats["span_totals"].values():
+    assert not {"run/warmup", "run/timed_loop"} & set(phase["spans"])
+  quiet_logs, quiet = run(0.0)
+  losses = lambda ls: [m.group(5) for l in ls if (m := STEP_RE.match(l))]
+  assert len(losses(logs)) == 12 and losses(logs) == losses(quiet_logs)
+  from kf_benchmarks_tpu import metrics as metrics_lib
+  assert not any("step_account" in k
+                 for k in metrics_lib.flatten_stats(stats))
+
+
 def test_program_spans_land_in_the_profilers_host_plane(tmp_path):
   """ONE clock: with a jax.profiler capture open around a tiny run, the
   program's live spans are events of plane /host:CPU, in order within
@@ -897,10 +1188,12 @@ def test_program_spans_land_in_the_profilers_host_plane(tmp_path):
   steps = [e for e in events if e[2] == "train"]
   assert [e[3]["step_num"] for e in steps] == [1, 2, 3, 4, 5, 6]
   for start, end, _, _ in steps[2:]:   # the lag-2 ring is full from here
+    # (a pass of the garbage collector may fall anywhere)
     inside = [e[2] for e in events
-              if e[2] != "train" and start <= e[0] and e[1] <= end]
+              if e[2] not in ("train", "kf/host/gc")
+              and start <= e[0] and e[1] <= end]
     assert inside == ["kf/dispatch/train_step", "kf/fetch/metrics",
-                      "kf/handle/step"], inside
+                      "kf/handle/step", "kf/handle/log_line"], inside
   dispatches = [e for e in events if e[2] == "kf/dispatch/train_step"]
   assert [e[3]["step"] for e in dispatches[-6:]] == [0, 1, 2, 3, 4, 5]
   assert dispatches[0][3]["first_call"] == 1
