@@ -2019,6 +2019,11 @@ class BenchmarkCNN:
         # rows of one weight-gradient product, passes over the kernel's
         # f32 gradient a step. Static. None for a model without one.
         "lm_head": self._trace.static("lm_head"),
+        # The rotary stage by call site as the model stated it at the
+        # build (ops/rotary.stage_stats): layers, heads, dimensions
+        # rotated, the implementation and its block of rows, bytes a call
+        # and kept a layer. Static. None for a model without one.
+        "rotary": self._trace.static("rotary"),
         # The allocator's own account of the fullest device of the
         # mesh, read as the timed loop ends: live buffers at their peak,
         # what the runtime reserved for loaded programs at its peak, and

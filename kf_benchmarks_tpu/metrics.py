@@ -462,6 +462,13 @@ NON_METRIC_KEYS = frozenset({
     # ops/fused_loss.weight_grad_stats): a description of the program
     # that ran, None for every other model.
     "lm_head",
+    # PR 38: the rotary stage by call site as models/mla_moe_lm.py states
+    # it at the build ({calls_per_layer, layers, rot_dims, heads,
+    # head_dim, normed, implementation, block_rows, block_heads,
+    # bytes_read_and_written_per_call, residual_bytes_per_layer} a site;
+    # ops/rotary.stage_stats): a description of the program that ran,
+    # None for every other model.
+    "rotary",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

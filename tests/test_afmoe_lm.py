@@ -438,6 +438,67 @@ def test_counters_and_cores_reach_the_stats(two_step_stats):
   assert "tiles_visited" not in att["window"]
 
 
+def test_rotary_stage_reaches_the_stats(two_step_stats):
+  # Layers 1-4 are [window, full, window, window], 4 query heads over 2
+  # key heads of 8: a window layer rotates all 8 dimensions of q and of
+  # k, the full layer none, every site under its head norm; off the TPU
+  # the plain form (no kernel, no block of rows); 2 x 32 positions of
+  # float32 in and out a call, the input kept for the backward pass.
+  rotary = two_step_stats["rotary"]
+  assert sorted(rotary) == ["full_k", "full_q", "window_k", "window_q"]
+  assert rotary["window_q"] == {
+      "calls_per_layer": 1, "layers": 3, "rot_dims": 8, "heads": 4,
+      "head_dim": 8, "normed": True, "implementation": "xla",
+      "block_rows": 0, "block_heads": 0,
+      "bytes_read_and_written_per_call": 2 * 2 * SEQ * 4 * 8 * 4,
+      "residual_bytes_per_layer": 2 * SEQ * 4 * 8 * 4}
+  assert [(k, v["layers"], v["rot_dims"], v["heads"])
+          for k, v in sorted(rotary.items())] == [
+              ("full_k", 1, 0, 2), ("full_q", 1, 0, 4),
+              ("window_k", 3, 8, 2), ("window_q", 3, 8, 4)]
+
+
+def test_rotary_stage_of_the_trinity_cell_on_a_tpu(monkeypatch):
+  # What the benchmark's cell states (1 x 8192 tokens in bfloat16, 32
+  # query heads over 4 key heads of 128, 4 window layers and 1 full): the
+  # kernel at every site, over blocks of 1,024 positions of 8 of q's
+  # heads (4 MB in and out together) or of k's 4; and ONE log line,
+  # whatever the build's second module asks.
+  from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu import tracing
+  from kf_benchmarks_tpu.utils import log as log_util
+  model = lm.MLAMoELMModel(params_lib.make_params(
+      model="mla_moe_lm", lm_config="trinity-mini", seq_len=8192,
+      batch_size=1, lm_layers_held=5, lm_first_layer_held=1,
+      lm_layer_shards=8, device="cpu"))
+  model.set_batch_size(1)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  stats = model.rotary_stats(jnp.bfloat16)
+  assert [(k, v["layers"], v["rot_dims"], v["heads"], v["implementation"],
+           v["block_rows"], v["block_heads"])
+          for k, v in sorted(stats.items())] == [
+              ("full_k", 1, 0, 4, "pallas", 1024, 4),
+              ("full_q", 1, 0, 32, "pallas", 1024, 8),
+              ("window_k", 4, 128, 4, "pallas", 1024, 4),
+              ("window_q", 4, 128, 32, "pallas", 1024, 8)]
+  assert stats["window_q"]["bytes_read_and_written_per_call"] == 2 * 2 ** 26
+  assert stats["window_q"]["residual_bytes_per_layer"] == 2 ** 26
+  lines = []
+  monkeypatch.setattr(log_util, "log_fn", lines.append)
+  with tracing.session() as trace:
+    model._state_rotary(jnp.bfloat16)
+    model._state_rotary(jnp.bfloat16)     # the evaluation module's build
+    assert trace.static("rotary") == stats
+  said = [ln for ln in lines if ln.startswith("attention rotary: ")]
+  assert len(said) == 1
+  assert said[0].startswith(
+      "attention rotary: window_q in 4 layer(s): 32 head(s) of 128, 128 "
+      "rotated, normed, pallas over 1024 positions of 8 heads a block, "
+      "134217728 bytes read and written a call, 67108864 kept a layer; "
+      "window_k in 4 ")
+  assert "full_q in 1 layer(s): 32 head(s) of 128, 0 rotated" in said[0]
+
+
 def test_lm_head_reaches_the_stats(two_step_stats):
   # 2 x 32 positions in chunks of 4, one loss (no MTP module): groups of
   # a quarter of the sequence, 2 chunks of 8 float32 rows over the 512

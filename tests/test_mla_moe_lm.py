@@ -650,6 +650,50 @@ def test_attention_core_states_itself_in_the_stats(two_step_stats):
       "block_q": 0, "block_kv": 0, "block_kv_dkv": 0, "dq_partials": 0}
 
 
+def test_rotary_stage_states_itself_in_the_stats(two_step_stats):
+  # Latent attention's two sites, in the 3 layers held and the MTP block:
+  # q's whole head with its trailing rope dimensions rotated, and the one
+  # rotary key the heads share; no head norm; off the TPU the plain form.
+  rotary = two_step_stats["rotary"]
+  cfg = lm.load_lm_config("tiny")
+  assert sorted(rotary) == ["k_rot", "q"]
+  assert rotary["q"]["layers"] == rotary["k_rot"]["layers"] == 4
+  assert (rotary["q"]["heads"], rotary["q"]["head_dim"],
+          rotary["q"]["rot_dims"]) == (
+              cfg.num_attention_heads, cfg.qk_head_dim, cfg.qk_rope_head_dim)
+  assert (rotary["k_rot"]["heads"], rotary["k_rot"]["head_dim"],
+          rotary["k_rot"]["rot_dims"]) == (
+              1, cfg.qk_rope_head_dim, cfg.qk_rope_head_dim)
+  assert {v["implementation"] for v in rotary.values()} == {"xla"}
+  assert not any(v["normed"] or v["block_rows"] or v["block_heads"]
+                 for v in rotary.values())
+
+
+def test_rotary_stage_of_the_glm_cell_on_a_tpu(monkeypatch):
+  # What the benchmark's cell states (2 x 4096 tokens in bfloat16, 20
+  # heads of 192 + 64, 5 layers and the MTP block): q goes through the
+  # kernel whole, 512 positions of 5 heads a block (1,280 lanes a
+  # position), its trailing 64 dimensions rotated; the shared rotary key, one head of 64,
+  # is narrower than the lanes and stays plain ``jnp``.
+  from kf_benchmarks_tpu import params as params_lib
+  model = lm.MLAMoELMModel(params_lib.make_params(
+      model="mla_moe_lm", seq_len=4096, batch_size=2, lm_layers_held=5,
+      lm_layer_shards=8, device="cpu"))
+  model.set_batch_size(2)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  assert model.rotary_stats(jnp.bfloat16) == {
+      "q": {"calls_per_layer": 1, "layers": 6, "rot_dims": 64, "heads": 20,
+            "head_dim": 256, "normed": False, "implementation": "pallas",
+            "block_rows": 512, "block_heads": 5,
+            "bytes_read_and_written_per_call": 2 * 2 * 4096 * 20 * 256 * 2,
+            "residual_bytes_per_layer": 0},
+      "k_rot": {"calls_per_layer": 1, "layers": 6, "rot_dims": 64,
+                "heads": 1, "head_dim": 64, "normed": False,
+                "implementation": "xla", "block_rows": 0, "block_heads": 0,
+                "bytes_read_and_written_per_call": 2 * 2 * 4096 * 64 * 2,
+                "residual_bytes_per_layer": 0}}
+
+
 def test_attention_core_of_the_glm_cell_on_a_tpu(monkeypatch):
   # What the benchmark's cell states (2 x 4096 tokens, head size 256, 5
   # layers and the MTP block): ONE backward kernel pass a layer on scores

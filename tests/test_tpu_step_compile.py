@@ -258,3 +258,60 @@ def test_trinity_mini_kernels_on_the_tpu_compiler(topo):
   # The combine gathers the round as the products stored it (PR 33): no
   # copy of it with a zero row appended, of either type.
   assert f"[{rows + 1},{d}]" not in text
+
+
+@pytest.mark.parametrize("site,shape,rot_dims,normed,factor", [
+    ("trinity-mini window q", (1, 8192, 32, 128), 128, True, 128 ** -0.5),
+    ("trinity-mini window k", (1, 8192, 4, 128), 128, True, 1.0),
+    ("trinity-mini full q", (1, 8192, 32, 128), 0, True, 128 ** -0.5),
+    ("glm-4.7-flash q", (2, 4096, 20, 256), 64, False, 1.0),
+])
+def test_rotary_stage_on_the_tpu_compiler(topo, monkeypatch, site, shape,
+                                          rot_dims, normed, factor):
+  """The rotary stage at the two language-model cells' call shapes,
+  forward and backward, on the chip's own compiler (PR 38): ONE kernel
+  each way (``rotary_fwd``, ``rotary_bwd``), reading the projection's
+  output as the product leaves it, (T, H x D), and writing q head by
+  head as the core reads it, with no copy between; nothing float32 of
+  q's size and no array of half the rotated width exists outside the
+  kernels (autodiff of the plain composition made
+  ``slice_negate_fusion f32[1,8192,32,64]`` and kept float32 copies of
+  q); the backward's only other output is the scale's gradient by
+  block."""
+  import jax
+  from jax.sharding import SingleDeviceSharding
+  from kf_benchmarks_tpu.ops import rotary
+  one = SingleDeviceSharding(topo.devices[0])
+  sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  b, t, heads, head_dim = shape
+  plan = rotary.rotary_plan(t, heads, head_dim, rot_dims, normed,
+                            jnp.bfloat16)
+  assert plan.implementation == "pallas", site
+
+  def both(x, scale, dy):
+    # As the modules meet it: x a product's output, (B, T, H x D); the
+    # core takes q, and hands dq back, head by head.
+    tabs = rotary.stage_tables(shape, rot_dims, 10000.0, normed, x.dtype)
+    y, pull = jax.vjp(lambda x, scale: rotary.rotary_stage(
+        x.reshape(shape), tabs, scale if normed else None, rot_dims=rot_dims,
+        eps=1e-5, factor=factor), x, scale)
+    return y.swapaxes(1, 2), pull(dy.swapaxes(1, 2))
+  compiled = jax.jit(both).lower(
+      sds((b, t, heads * head_dim), jnp.bfloat16),
+      sds((head_dim,), jnp.float32),
+      sds((b, heads, t, head_dim), jnp.bfloat16)).compile()
+  text = compiled.as_text()
+  assert text.count('custom_call_target="tpu_custom_call"') == 2, site
+  assert "rotary_fwd" in text and "rotary_bwd" in text
+  assert "splash_mha" not in text
+  entry = text[text.index("ENTRY"):]
+  assert not re.search(r" copy\(| transpose\(", entry), site
+  size = rf"(?:{t},{heads},{head_dim}|{heads},{t},{head_dim}|"\
+         rf"{t},{heads * head_dim})\]"
+  assert not re.search(rf"f32\[{b},{size}", text), site
+  if rot_dims:
+    assert not re.search(rf"\[[\d,]*,{rot_dims // 2}\]", entry), site
+  # Beyond arguments and outputs: the tables and the scale's gradient by
+  # block, a few megabytes.
+  assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24, site
