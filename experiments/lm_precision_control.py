@@ -3,12 +3,12 @@
 on a program with ONE fault planted from outside. Each has to come out
 NOT ``correct``, by the number of the cell's reference check
 (``benchmarks/named_checks/<config>_reference_agrees.py``) named beside
-it; PERF.md section 6 (PR 27, PR 32) has the chip's readings.
+it; PERF.md section 6 (PR 27, PR 32, PR 39) has the chip's readings.
 
     python3 experiments/lm_precision_control.py [--fault <name>] \
         --workload <cell> --seed <n> --seconds 10 --trace 0
 
-Both configurations:
+Every configuration:
 
 * ``router_bf16`` (the default; the lower-precision control): the ROUTER
   of ``mla_moe_lm`` computed in bfloat16 (product, sigmoid, top-k and
@@ -39,9 +39,25 @@ trinity-mini alone (each ONE departure from the published layer):
 * ``no_post_norms``: the norms AFTER the two sublayers pass their input
   through. Fails ``layer_output_err``.
 
+nemotron-3-nano-30b-a3b alone (each ONE departure from the published
+layer, but the first, which is the lower-precision control of its
+state-space layers):
+
+* ``scan_bf16``: the scan's running sums, exponentials and carried state
+  in bfloat16, the nearest precision below the float32 the configuration
+  states. Fails ``scan_carry_err`` and ``layer_output_err``.
+* ``no_chunk_carry``: no state passes from a chunk to the next (every
+  chunk starts from zero). Fails ``scan_carry_err``, which reads 0.5 or
+  more.
+* ``gate_after_norm``: the Mamba mixer's gate applied AFTER its grouped
+  norm (Mamba-2's other published order). Fails ``layer_output_err``.
+* ``experts_gated``: the expert's form taken for the other families':
+  ``silu(u) * u`` in place of ``relu(u)^2`` between its two matrices,
+  routed and shared alike. Fails ``layer_output_err``.
+
 The program has no option for any of this: ``plant`` steers it from
 outside, here and in ``tests/benchmarks/test_bench_lm.py`` /
-``test_bench_afmoe.py``.
+``test_bench_afmoe.py`` / ``test_bench_nemotron_h.py``.
 """
 
 import dataclasses
@@ -53,7 +69,8 @@ sys.path.insert(0, ROOT)
 
 FAULTS = ("router_bf16", "state_unchanged", "half_batch", "no_mtp",
           "no_scaling", "window_2047", "window_2049", "rope_in_full",
-          "no_gate", "no_post_norms")
+          "no_gate", "no_post_norms", "scan_bf16", "no_chunk_carry",
+          "gate_after_norm", "experts_gated")
 
 
 def plant(fault, setattr_=setattr):
@@ -117,6 +134,33 @@ def plant(fault, setattr_=setattr):
         PassThrough(self.cfg.rms_norm_eps, self.param_dtype, name=name)
         if name.startswith("post_") and self.cfg.post_norms
         else norm(self, name)))
+  elif fault == "scan_bf16":
+    make = model.make_module
+    setattr_(model, "make_module", lambda self, *a, **k: make(
+        self, *a, **k).clone(scan_dtype=jnp.bfloat16))
+  elif fault == "no_chunk_carry":
+    from kf_benchmarks_tpu.ops import ssd
+    carried = ssd.carried_states
+    setattr_(ssd, "carried_states", lambda own, total: jnp.zeros_like(
+        carried(own, total)))
+  elif fault == "gate_after_norm":
+    from kf_benchmarks_tpu.ops import ssd
+
+    def norm_then_gate(y, z, scale, groups, eps):
+      return ssd.group_norm(y.astype(jnp.float32), scale, groups,
+                            eps) * jax.nn.silu(z.astype(jnp.float32))
+    setattr_(ssd, "gated_norm", norm_then_gate)
+  elif fault == "experts_gated":
+    from kf_benchmarks_tpu.parallel import expert
+    # Under a name of its own: the routed path's rounds are jitted with
+    # the activation's NAME as a static argument, so that no trace of the
+    # right form is served to the faulty one (or the other way round).
+    setattr_(expert, "ACTIVATIONS", dict(
+        expert.ACTIVATIONS, self_gated=lambda u: jax.nn.silu(u) * u))
+    load = mla_moe_lm.load_lm_config
+    setattr_(mla_moe_lm, "load_lm_config", lambda *a, **k:
+             dataclasses.replace(load(*a, **k),
+                                 expert_activation="self_gated"))
   elif fault == "state_unchanged":
     def get(self):
       step = self.__dict__.get("timed_step")

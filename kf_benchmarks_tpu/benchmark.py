@@ -2024,6 +2024,11 @@ class BenchmarkCNN:
         # rotated, the implementation and its block of rows, bytes a call
         # and kept a layer. Static. None for a model without one.
         "rotary": self._trace.static("rotary"),
+        # The state-space scan as the model stated it at the build
+        # (ops/ssd.scan_stats): layers, heads, groups, state, the chunk
+        # and chunks a sequence, the implementation, bytes of carried
+        # state and kept a layer. Static. None for a model without one.
+        "mamba": self._trace.static("mamba"),
         # The allocator's own account of the fullest device of the
         # mesh, read as the timed loop ends: live buffers at their peak,
         # what the runtime reserved for loaded programs at its peak, and

@@ -469,6 +469,12 @@ NON_METRIC_KEYS = frozenset({
     # ops/rotary.stage_stats): a description of the program that ran,
     # None for every other model.
     "rotary",
+    # PR 39: the state-space scan as models/mla_moe_lm.py states it at
+    # the build ({layers, heads, head_dim, groups, state, chunk,
+    # chunks_per_sequence, implementation, carried_state_bytes_per_layer,
+    # residual_bytes_per_layer}; ops/ssd.scan_stats): a description of
+    # the program that ran, None for every other model.
+    "mamba",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

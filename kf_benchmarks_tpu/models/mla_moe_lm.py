@@ -6,13 +6,19 @@ configuration's family (``model_type``, ``_FAMILIES``):
   one multi-token-prediction module;
 * ``afmoe`` (Arcee's Trinity family): grouped-query gated attention over
   WINDOW and FULL layers in one stack, norms on both sides of each
-  sublayer, muP's embedding scale, no MTP.
+  sublayer, muP's embedding scale, no MTP;
+* ``nemotron_h`` (NVIDIA's Nemotron-H family): ONE mixer a layer,
+  ``x + mixer(norm(x))``, its kind by the layer's own character of
+  ``hybrid_override_pattern``: a Mamba-2 state-space mixer
+  (``Mamba2Mixer`` over ``ops/ssd.py``), a mixture of TWO-matrix
+  ``relu(.)^2`` experts, or grouped-query attention with no gate, no
+  head norms and no positional rotation.
 
 ``MoE``, ``SwiGLU``, ``RMSNorm``, the rotary stage (``ops/rotary.py``:
 head norm, RoPE and the cast between a projection and the core, one
-pass each way), the losses and the share are the same code for both (the
-zoo name stays ``mla_moe_lm``: the module grew a second attention, not a
-second decoder).
+pass each way), the losses and the share are the same code for all (the
+zoo name stays ``mla_moe_lm``: the module grew a second attention and a
+third kind of mixer, not a second decoder).
 
 BEYOND-REFERENCE: the reference zoo has no language model. Every size
 comes from ONE data file, the model's published ``config.json`` held
@@ -30,8 +36,13 @@ set in ``_FAMILIES``.
         --lm_config=trinity-mini --seq_len=8192 --batch_size=1 \
         --lm_first_layer_held=1 --lm_layers_held=5 --lm_layer_shards=8 \
         --optimizer=adam --use_fp16=true
+    python -m kf_benchmarks_tpu.cli --model=mla_moe_lm \
+        --lm_config=nemotron-3-nano-30b-a3b --seq_len=8192 --batch_size=1 \
+        --lm_first_layer_held=0 --lm_layers_held=9 --lm_layer_shards=16 \
+        --lm_vocab_shards=8 --lm_layer_shard_index=0 --optimizer=adam \
+        --use_fp16=true
 
-The layer (RMSNorm, no biases):
+The layer (RMSNorm, no biases but the state-space convolution's):
 
 * latent attention (MLA, ``MLAttention``): queries through
   ``q_lora_rank`` (down, RMSNorm, up to heads x (nope + rope)); keys and
@@ -55,6 +66,23 @@ The layer (RMSNorm, no biases):
   ONE pass over q and one over k (the rotary stage; ``q_norm`` and
   ``k_norm`` hold the learned scales alone); the core's output times
   sigmoid(gate) goes through ``o_proj``;
+* the Mamba-2 mixer (``Mamba2Mixer``; H heads of P, G groups, state N):
+  ``[z | xBC | dt] = in_proj(u)``; ``xBC <- silu(conv(xBC))``, a causal
+  depthwise convolution over each channel's own last K positions;
+  ``[x | B | C] = xBC``; ``dt <- softplus(dt + dt_bias)``, ``A =
+  -exp(A_log)``; per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
+  ``y_t = S_t C_t + D x_t``, computed in CHUNKS with the state carried
+  between them (``ops/ssd.py``; float32 running sums, exponentials and
+  carried state); ``y <- RMSNorm over each group of (y * silu(z))``, the
+  gate BEFORE the norm; ``out_proj``. The mixer's inside is
+  rematerialised from ``in_proj``'s output; a sequence is a whole number
+  of chunks (``validation.py`` refuses any other with the reason);
+* a block of ONE mixer (``MixerBlock``, ``nemotron_h``): ``norm`` then
+  the mixer of the layer's kind, added to the residual; the final norm is
+  ``norm_f``; a mixture layer's experts are ``down(relu(up(x))^2)``, two
+  matrices at a width that no tile of the grouped product divides
+  (``expert.gmm_tiling`` masks the last), the shared expert the same at
+  its own width; the layers held are unrolled, each traced once;
 * norms: pre-norm (``input_layernorm``, ``post_attention_layernorm``
   before the feed-forward), or, ``post_norms``, one on each side of each
   sublayer (``input_layernorm`` / ``post_attention_layernorm`` around
@@ -84,11 +112,16 @@ The share of a deployment (model-configs guide, section 4) is said by
 flags: ``--lm_layers_held`` layers of the stack live here, from
 published layer ``--lm_first_layer_held`` on (the others on further
 chips, as pipeline stages; each held layer is dense or a mixture, window
-or full, by its OWN published index, and the embedding feeds the first
+or full, a state-space mixer or attention, by its OWN published index,
+and the embedding feeds the first
 of them), and each layer is divided over ``--lm_layer_shards`` chips of
 which this is ``--lm_layer_shard_index``: it holds a contiguous block of
 the routed experts and of the vocabulary's rows (token ids, labels,
-logits and the losses are over the slice). Attention, the shared expert,
+logits and the losses are over the slice); ``--lm_vocab_shards`` gives
+the vocabulary a number of chips of its own where a deployment divides
+the experts further than the rows (16 and 8: a sixteenth of the
+vocabulary would be under the guide's floor). Attention, the state-space
+mixers, the shared expert,
 the router and the norms are held whole. What the absent experts would
 add is left out, and no code stands in for the absent chips.
 
@@ -120,8 +153,9 @@ OUTSIDE the cores' scopes; its tables are built once a call of the
 model, under no attention scope); ``moe_route`` (router, top-k, sort,
 gather and scatter of rows), ``moe_experts`` inside it (the grouped
 products), ``shared_expert``, ``mtp``, ``lm_head`` (the model's
-``loss_function``). They are components OTHER than the four
-``train_step.STEP_SCOPES``.
+``loss_function``); ``mamba_mixer`` (everything of a state-space mixer)
+with ``mamba_conv`` and ``ssd_scan`` inside it. They are components
+OTHER than the four ``train_step.STEP_SCOPES``.
 """
 
 from __future__ import annotations
@@ -140,6 +174,7 @@ import flax.linen as nn
 from kf_benchmarks_tpu.models import model as model_lib
 from kf_benchmarks_tpu.ops import fused_loss as fused_loss_lib
 from kf_benchmarks_tpu.ops import rotary
+from kf_benchmarks_tpu.ops import ssd
 from kf_benchmarks_tpu.parallel import expert as expert_lib
 from kf_benchmarks_tpu.parallel import sequence as sequence_lib
 
@@ -168,6 +203,8 @@ ROUTER_PROBE_TOKENS = 1024
 # follows lies beyond any run of this program.
 PEAK_LEARNING_RATE = 2.2e-4
 WARMUP_STEPS = 2000
+# ``hybrid_override_pattern``'s characters: the kind of a layer's mixer.
+MAMBA, MIXTURE, ATTENTION = "M", "E", "*"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,13 +248,43 @@ class LMConfig:
   post_norms: bool = False
   embed_scale: float = 1.0
   bias_update_speed: float = BIAS_UPDATE_SPEED
+  # Grouped-query attention's extras (``afmoe`` has both; ``nemotron_h``
+  # neither, and no RoPE: its layers have no window).
+  attention_gate: bool = True
+  head_norms: bool = True
+  # A block of ONE mixer (``nemotron_h``): ``layer_pattern`` gives the
+  # KIND of each published layer's mixer, one character a layer
+  # (``MAMBA``, ``MIXTURE``, ``ATTENTION``); empty for the families whose
+  # every layer is attention THEN a feed-forward.
+  layer_pattern: str = ""
+  # The Mamba-2 mixer (``ops/ssd.py``): heads x head size channels,
+  # ``n_groups`` groups of B and C of ``ssm_state_size``, a depthwise
+  # convolution over ``conv_kernel`` positions, the scan's chunk, and the
+  # range the step's initial value is drawn from.
+  mamba_num_heads: int = 0
+  mamba_head_dim: int = 0
+  n_groups: int = 0
+  ssm_state_size: int = 0
+  conv_kernel: int = 0
+  chunk_size: int = 0
+  time_step_min: float = 0.001
+  time_step_max: float = 0.1
+  time_step_floor: float = 1e-4
+  # The expert's form: its activation (``parallel/expert.ACTIVATIONS``),
+  # gated by a third matrix or not, and the shared expert's width where
+  # the configuration states one of its own.
+  expert_activation: str = "silu"
+  expert_gated: bool = True
+  moe_shared_expert_intermediate_size: int = 0
   # The share: ``layers_held`` layers of num_hidden_layers from published
   # layer ``first_layer`` on, and which of how many chips that divide
-  # each layer this is.
+  # each layer this is; ``vocab_shards`` chips divide the vocabulary
+  # (0: as many as divide a layer).
   layers_held: int = 0
   first_layer: int = 0
   shards: int = 1
   shard_index: int = 0
+  vocab_shards: int = 0
 
   @property
   def experts_held(self) -> int:
@@ -229,7 +296,14 @@ class LMConfig:
 
   @property
   def vocab_rows(self) -> int:
-    return self.vocab_size // self.shards
+    return self.vocab_size // (self.vocab_shards or self.shards)
+
+  @property
+  def kinds(self) -> str:
+    """The mixer of each layer held, one character a layer (empty where
+    every layer is attention then a feed-forward)."""
+    return self.layer_pattern[self.first_layer:
+                              self.first_layer + self.layers_held]
 
   @property
   def dense_layers(self) -> int:
@@ -239,7 +313,22 @@ class LMConfig:
 
   @property
   def moe_layers(self) -> int:
+    if self.layer_pattern:
+      return self.kinds.count(MIXTURE)
     return self.layers_held - self.dense_layers
+
+  @property
+  def mamba_layers(self) -> int:
+    return self.kinds.count(MAMBA)
+
+  @property
+  def expert_matrices(self) -> int:
+    return 3 if self.expert_gated else 2
+
+  @property
+  def shared_width(self) -> int:
+    return (self.moe_shared_expert_intermediate_size or
+            self.moe_intermediate_size * self.n_shared_experts)
 
   @property
   def qk_head_dim(self) -> int:
@@ -256,6 +345,13 @@ class LMConfig:
   @property
   def windows(self) -> tuple:
     return tuple(self.window(i) for i in range(self.layers_held))
+
+  @property
+  def attention_windows(self) -> tuple:
+    """``windows`` of the layers held that HAVE attention (all of them,
+    but in a one-mixer block)."""
+    return tuple(w for i, w in enumerate(self.windows)
+                 if not self.layer_pattern or self.kinds[i] == ATTENTION)
 
 
 # A family (``model_type``) is a layer recipe, the names its config.json
@@ -284,20 +380,44 @@ _FAMILIES = {
                   "n_group": 1, "topk_group": 1, "num_expert_groups": 1,
                   "num_limited_groups": 1, "rope_scaling": None,
                   "tie_word_embeddings": False, "mup_enabled": True}),
+    # NVIDIA's Nemotron-H family: ONE mixer a layer (a Mamba-2 state-space
+    # mixer, a mixture of two-matrix relu^2 experts, or grouped-query
+    # attention with no gate, no head norms and no positional rotation),
+    # ``x + mixer(norm(x))``; sigmoid routing with a selection bias.
+    "nemotron_h": dict(
+        recipe=dict(attention="gqa", attention_gate=False, head_norms=False,
+                    expert_activation="relu2", expert_gated=False,
+                    first_k_dense_replace=0),
+        names={"norm_eps": "rms_norm_eps",
+               "hybrid_override_pattern": "layer_pattern"},
+        required={"mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+                  "n_group": 1, "topk_group": 1, "tie_word_embeddings": False,
+                  "attention_bias": False, "mamba_proj_bias": False,
+                  "mlp_bias": False, "use_bias": False,
+                  "use_conv_bias": True, "norm_topk_prob": True,
+                  "n_shared_experts": 1, "sliding_window": None,
+                  "residual_in_fp32": False}),
 }
 
 
-def load_lm_config(name: str, layers_held: Optional[int] = None,
-                   shards: int = 1, shard_index: int = 0,
-                   first_layer: int = 0) -> LMConfig:
-  """``lm_configs/<name>.json`` as an LMConfig with the share applied."""
+def read_config_file(name: str):
+  """(path, ``lm_configs/<name>.json`` as a dict)."""
   path = os.path.join(CONFIG_DIR, name + ".json")
   if not os.path.isfile(path):
     have = sorted(f[:-5] for f in os.listdir(CONFIG_DIR)
                   if f.endswith(".json"))
     raise ValueError(f"--lm_config={name}: no file {path}; have {have}")
   with open(path, encoding="utf-8") as f:
-    raw = json.load(f)
+    return path, json.load(f)
+
+
+def load_lm_config(name: str, layers_held: Optional[int] = None,
+                   shards: int = 1, shard_index: int = 0,
+                   first_layer: int = 0,
+                   vocab_shards: Optional[int] = None) -> LMConfig:
+  """``lm_configs/<name>.json`` as an LMConfig with the share applied
+  (``vocab_shards`` None: the vocabulary is divided like a layer)."""
+  path, raw = read_config_file(name)
   family = _FAMILIES.get(raw.get("model_type"))
   if family is None:
     raise ValueError(f"{path}: model_type={raw.get('model_type')!r} is not "
@@ -314,10 +434,20 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
       raise ValueError(f"{path}: latent attention has one key per head")
     if raw["num_nextn_predict_layers"] not in (0, 1):
       raise ValueError(f"{path}: at most one MTP module is implemented")
-  else:
-    if raw["num_attention_heads"] % raw["num_key_value_heads"]:
-      raise ValueError(f"{path}: {raw['num_key_value_heads']} key heads do "
-                       f"not divide {raw['num_attention_heads']} query heads")
+  elif raw["num_attention_heads"] % raw["num_key_value_heads"]:
+    raise ValueError(f"{path}: {raw['num_key_value_heads']} key heads do "
+                     f"not divide {raw['num_attention_heads']} query heads")
+  if "layer_pattern" in raw:
+    pattern = raw["layer_pattern"]
+    unknown = set(pattern) - {MAMBA, MIXTURE, ATTENTION}
+    if unknown or len(pattern) != raw["num_hidden_layers"]:
+      raise ValueError(
+          f"{path}: hybrid_override_pattern names {sorted(unknown)} or is "
+          f"not one character a layer ({len(pattern)})")
+    if raw["mamba_num_heads"] % raw["n_groups"]:
+      raise ValueError(f"{path}: {raw['n_groups']} groups do not divide "
+                       f"{raw['mamba_num_heads']} Mamba heads")
+  elif recipe["attention"] == "gqa":
     kinds = raw["layer_types"] = tuple(raw["layer_types"])
     unknown = set(kinds) - {"sliding_attention", "full_attention"}
     if unknown or len(kinds) != raw["num_hidden_layers"]:
@@ -327,7 +457,8 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
     # all it changes in the forward pass).
     recipe["embed_scale"] = math.sqrt(raw["hidden_size"])
   fields = {f.name for f in dataclasses.fields(LMConfig)}
-  cfg = LMConfig(**{k: v for k, v in raw.items() if k in fields}, **recipe)
+  cfg = LMConfig(**{k: v for k, v in raw.items()
+                    if k in fields and v is not None}, **recipe)
   held = (cfg.num_hidden_layers - first_layer if layers_held is None
           else layers_held)
   if not (0 <= first_layer and 1 <= held and
@@ -338,13 +469,22 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
   if not 0 <= shard_index < shards:
     raise ValueError(f"--lm_layer_shard_index={shard_index} of "
                      f"--lm_layer_shards={shards}")
-  for what, count in (("n_routed_experts", cfg.n_routed_experts),
-                      ("vocab_size", cfg.vocab_size)):
-    if count % shards:
-      raise ValueError(f"--lm_layer_shards={shards} does not divide "
-                       f"{what}={count}")
+  over = shards if vocab_shards is None else vocab_shards
+  for flag, n, what, count in (
+      ("lm_layer_shards", shards, "n_routed_experts", cfg.n_routed_experts),
+      ("lm_layer_shards" if vocab_shards is None else "lm_vocab_shards",
+       over, "vocab_size", cfg.vocab_size)):
+    if n < 1 or count % n:
+      raise ValueError(f"--{flag}={n} does not divide {what}={count}")
+  if not shard_index < over:
+    # The embedding and the head are built here: a chip past the last
+    # that holds rows of the vocabulary has neither.
+    raise ValueError(f"--lm_layer_shard_index={shard_index} holds no rows "
+                     f"of a vocabulary divided over --lm_vocab_shards="
+                     f"{over} chips")
   return dataclasses.replace(cfg, layers_held=held, first_layer=first_layer,
-                             shards=shards, shard_index=shard_index)
+                             shards=shards, shard_index=shard_index,
+                             vocab_shards=over)
 
 
 def _normal():
@@ -390,13 +530,14 @@ def rotary_sites(c: "LMConfig") -> dict:
                       layers=layers)}
   sites = {}
   for kind, window in (("window", c.sliding_window), ("full", None)):
-    layers = sum(w == window for w in c.windows)
+    layers = sum(w == window for w in c.attention_windows)
     for name, heads in (("q", c.num_attention_heads),
                         ("k", c.num_key_value_heads)):
-      if layers:
+      # A layer with neither head norms nor a window has no stage at all.
+      if layers and (c.head_norms or window is not None):
         sites[f"{kind}_{name}"] = dict(
-            heads=heads, head_dim=c.head_dim, normed=True, layers=layers,
-            rot_dims=c.head_dim if window is not None else 0)
+            heads=heads, head_dim=c.head_dim, normed=c.head_norms,
+            layers=layers, rot_dims=c.head_dim if window is not None else 0)
   return sites
 
 
@@ -421,10 +562,14 @@ class _Options(nn.Module):
   # The router's dtype: float32 is the configuration's; bfloat16 is the
   # lower-precision control of the tests and the benchmark's check.
   router_dtype: Any = jnp.float32
+  # The dtype of the state-space scan's running sums, exponentials and
+  # carried state (``ops/ssd.py``); bfloat16 is the control again.
+  scan_dtype: Any = jnp.float32
 
-  def dense(self, features, name):
+  def dense(self, features, name, init=None):
     return nn.Dense(features, use_bias=False, name=name, dtype=self.dtype,
-                    param_dtype=self.param_dtype, kernel_init=_normal())
+                    param_dtype=self.param_dtype,
+                    kernel_init=init or _normal())
 
   def norm(self, name):
     return RMSNorm(self.cfg.rms_norm_eps, self.param_dtype, name=name)
@@ -435,7 +580,7 @@ class _Options(nn.Module):
   def options(self):
     return dict(cfg=self.cfg, dtype=self.dtype,
                 param_dtype=self.param_dtype, moe_impl=self.moe_impl,
-                router_dtype=self.router_dtype)
+                router_dtype=self.router_dtype, scan_dtype=self.scan_dtype)
 
 
 class MLAttention(_Options):
@@ -483,11 +628,13 @@ def gated(core, gate):
 
 
 class GQAttention(_Options):
-  """Grouped-query attention with a gate on its output (``afmoe``):
-  RMSNorm over each head of q and of k, RoPE in the WINDOW layers only,
-  a full layer rotating nothing; query head n reads key head
-  n // (heads / key heads); the core's output times sigmoid(gate_proj(x))
-  before ``o_proj``."""
+  """Grouped-query attention: query head n reads key head
+  n // (heads / key heads). ``afmoe``: RMSNorm over each head of q and
+  of k (``head_norms``), RoPE in the WINDOW layers only, a full layer
+  rotating nothing, and the core's output times sigmoid(gate_proj(x))
+  before ``o_proj`` (``attention_gate``). ``nemotron_h``: none of the
+  three (Nemotron-H, arXiv:2504.03624 section 2.1: no position
+  embeddings): projections, the causal core, ``o_proj``."""
   window: Optional[int] = None
 
   @nn.compact
@@ -502,26 +649,34 @@ class GQAttention(_Options):
       q = self.dense(h * hd, "q_proj")(x).reshape(b, t, h, hd)
       k = self.dense(g * hd, "k_proj")(x).reshape(b, t, g, hd)
       v = self.dense(g * hd, "v_proj")(x).reshape(b, t, g, hd)
-      gate = self.dense(h * hd, "gate_proj")(x)
+      gate = (self.dense(h * hd, "gate_proj")(x) if c.attention_gate
+              else None)
       # The scores' scale 1/sqrt(head size) is no power of two at 128:
       # the kernel would scale q in q's dtype, a second bfloat16
       # rounding. It is folded into the head norm's float32 output (RoPE
       # is linear), one rounding at the cast, and the kernel scales
       # nothing. Norm, scale, RoPE and the cast are the rotary stage's
       # one pass (a full layer's rotates nothing).
-      stage = functools.partial(rotary.rotary_stage, rot_dims=rot,
-                                eps=c.rms_norm_eps)
-      q = stage(q, tables["window_q"] if rot else None,
-                self.head_scale("q_norm", hd), factor=1.0 / math.sqrt(hd))
-      k = stage(k, tables["window_k"] if rot else None,
-                self.head_scale("k_norm", hd))
+      # Without head norms and a window there is no stage to fold the
+      # scale into: the kernel's caller scales q, in q's dtype.
+      scale = 1.0 / math.sqrt(hd)
+      if c.head_norms or rot:
+        stage = functools.partial(rotary.rotary_stage, rot_dims=rot,
+                                  eps=c.rms_norm_eps)
+        norm = lambda name: (self.head_scale(name, hd) if c.head_norms
+                             else None)
+        q = stage(q, tables["window_q"] if rot else None, norm("q_norm"),
+                  factor=scale)
+        k = stage(k, tables["window_k"] if rot else None, norm("k_norm"))
+        scale = 1.0
       with jax.named_scope("attention_core_window" if self.window is not None
                            else "attention_core_full"):
         att = sequence_lib.pallas_flash_attention(
-            q, k, v, causal=True, scale=1.0, block=min(ATTN_BLOCK, t),
+            q, k, v, causal=True, scale=scale, block=min(ATTN_BLOCK, t),
             window=self.window)
+      att = att.reshape(b, t, h * hd)
       return self.dense(c.hidden_size, "o_proj")(
-          gated(att.reshape(b, t, h * hd), gate))
+          att if gate is None else gated(att, gate))
 
 
 class SwiGLU(_Options):
@@ -535,8 +690,22 @@ class SwiGLU(_Options):
         nn.silu(gate) * up)
 
 
+class PlainMLP(_Options):
+  """A two-matrix feed-forward, ``down(act(up(x)))`` (``nemotron_h``'s
+  shared expert: ``relu(.)^2``)."""
+  width: int = 0
+
+  @nn.compact
+  def __call__(self, x):
+    act = expert_lib.ACTIVATIONS[self.cfg.expert_activation]
+    return self.dense(self.cfg.hidden_size, "down_proj")(
+        act(self.dense(self.width, "up_proj")(x)))
+
+
 class MoE(_Options):
-  """The routed experts held here, beside the shared expert."""
+  """The routed experts held here, beside the shared expert. The
+  experts' FORM is the configuration's: gated by a third matrix
+  (``experts_gate``, SiLU) or plain (two matrices, ``relu(.)^2``)."""
 
   @nn.compact
   def __call__(self, x):
@@ -546,9 +715,9 @@ class MoE(_Options):
     router = self.param("router", _normal(), (d, e), self.param_dtype)
     expert = lambda name, shape: self.param(name, _normal(), (g,) + shape,
                                             self.param_dtype)
-    w_gate, w_up = expert("experts_gate", (d, f)), expert("experts_up",
-                                                          (d, f))
-    w_down = expert("experts_down", (f, d))
+    w_gate = expert("experts_gate", (d, f)) if c.expert_gated else None
+    w_up, w_down = expert("experts_up", (d, f)), expert("experts_down",
+                                                        (f, d))
     bias = self.variable("batch_stats", "select_bias", jnp.zeros, (e,),
                          jnp.float32)
     load = self.variable("batch_stats", "load", jnp.zeros, (e,),
@@ -578,7 +747,8 @@ class MoE(_Options):
       routed, counts = expert_lib.held_experts_ffn(
           flat, weights, idx, w_gate, w_up, w_down, c.first_expert,
           impl=self.moe_impl, rows=expert_lib.compact_rows(
-              b * t * c.num_experts_per_tok, g, e))
+              b * t * c.num_experts_per_tok, g, e),
+          activation=c.expert_activation)
       if self.is_mutable_collection("batch_stats") and \
           not self.is_initializing():
         # The step's loads over ALL the experts from this chip's tokens,
@@ -594,9 +764,94 @@ class MoE(_Options):
         bias.value = bias.value + c.bias_update_speed * jnp.sign(
             jnp.mean(all_load) - all_load)
     with jax.named_scope("shared_expert"):
-      shared = SwiGLU(width=f * c.n_shared_experts, name="shared_experts",
-                      **self.options())(x)
+      shared = (SwiGLU if c.expert_gated else PlainMLP)(
+          width=c.shared_width, name="shared_experts", **self.options())(x)
     return routed.reshape(b, t, d) + shared
+
+
+class ConvParams(nn.Module):
+  """A depthwise convolution's kernel (K, channels) and bias, under one
+  name (``conv1d/kernel``, ``conv1d/bias``); the arithmetic is
+  ``ssd.causal_conv``'s. Initialised as the family's public code leaves a
+  depthwise Conv1d: uniform within 1/sqrt(K)."""
+  param_dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, taps, channels):
+    bound = 1.0 / math.sqrt(taps)
+    uniform = lambda key, shape, dtype: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+    return (self.param("kernel", uniform, (taps, channels), self.param_dtype),
+            self.param("bias", uniform, (channels,), self.param_dtype))
+
+
+def initial_dt_bias(c: "LMConfig"):
+  """The Mamba-2 convention: a step drawn log-uniform in
+  [``time_step_min``, ``time_step_max``], floored at ``time_step_floor``,
+  through the inverse of softplus."""
+  def init(key, shape, dtype):
+    lo, hi = math.log(c.time_step_min), math.log(c.time_step_max)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                                lo, hi)), c.time_step_floor)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+  return init
+
+
+class Mamba2Mixer(_Options):
+  """The Mamba-2 state-space mixer: ``in_proj`` to [z | xBC | dt], the
+  causal depthwise convolution and SiLU over xBC, the chunked scan, the
+  gated norm and ``out_proj`` (``ops/ssd.mamba_core`` has everything
+  between the projections). Its inside is REMATERIALISED from
+  ``in_proj``'s output: a chunk's decay tables and the float32 copies of
+  x, B and C are formed again in the backward pass and never kept (a
+  layer keeps 169 MB at 8,192 tokens where the tables alone are 400 MB).
+  ``jax.checkpoint`` with its barriers, not ``nn.remat(prevent_cse=
+  False)``: outside a scan XLA would merge the repeat with its first
+  copy and keep everything."""
+
+  @nn.compact
+  def __call__(self, u):
+    c = self.cfg
+    heads, p, g, n = (c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
+                      c.ssm_state_size)
+    inner = heads * p
+    with jax.named_scope("mamba_mixer"):
+      zxbcdt = self.dense(2 * inner + 2 * g * n + heads, "in_proj")(u)
+      kernel, bias = ConvParams(self.param_dtype, name="conv1d")(
+          c.conv_kernel, inner + 2 * g * n)
+      a_log = self.param("A_log", lambda key, shape, dtype: jnp.log(
+          jnp.arange(1, shape[0] + 1, dtype=dtype)), (heads,),
+                         self.param_dtype)
+      d = self.param("D", nn.initializers.ones, (heads,), self.param_dtype)
+      dt_bias = self.param("dt_bias", initial_dt_bias(c), (heads,),
+                           self.param_dtype)
+      core = functools.partial(
+          ssd.mamba_core, heads=heads, head_dim=p, groups=g, state=n,
+          chunk=c.chunk_size, eps=c.rms_norm_eps, scan_dtype=self.scan_dtype)
+      y = jax.checkpoint(core)(zxbcdt, kernel, bias, a_log, d, dt_bias,
+                               self.head_scale("norm", inner))
+      # ``rescale_prenorm_residual``: the mixer's output projection starts
+      # smaller by the square root of the PUBLISHED depth.
+      return self.dense(c.hidden_size, "out_proj", nn.initializers.normal(
+          INIT_STD / math.sqrt(c.num_hidden_layers)))(y)
+
+
+class MixerBlock(_Options):
+  """One layer of a one-mixer stack (``nemotron_h``): ``x + mixer(norm(
+  x))``, the mixer of the layer's own ``kind``."""
+  kind: str = ATTENTION
+
+  @nn.compact
+  def __call__(self, x, tables=None):
+    self.sow("intermediates", "hidden_in", x)
+    mixer = {MAMBA: Mamba2Mixer, MIXTURE: MoE, ATTENTION: GQAttention}[
+        self.kind](name="mixer", **self.options())
+    h = self.norm("norm")(x).astype(self.dtype)
+    if self.kind == MIXTURE:
+      # ONE array for the router, the probe and the experts (``Block``
+      # has the reason).
+      h = jax.lax.optimization_barrier(h)
+    return x + (mixer(h, tables) if self.kind == ATTENTION else mixer(h)), None
 
 
 class Block(_Options):
@@ -658,42 +913,51 @@ class MLAMoELM(_Options):
     x = emb
     # RoPE's tables, once a call of the model: every layer reads them.
     tables = rope_tables(c, tokens.shape[1], self.dtype)
-    windows = c.windows
-    for i in range(c.dense_layers):
-      x, _ = block_cls(mixture=False, window=windows[i], name=f"dense_{i}",
-                       **self.options())(x, tables)
-    mixture_windows = windows[c.dense_layers:]
-    if c.moe_layers and self.scan_layers and len(set(mixture_windows)) == 1:
-      # One block body whatever the depth; per-layer parameters, router
-      # state and sown values stack on a leading layer axis.
-      x, _ = nn.scan(
-          block_cls,
-          variable_axes={"params": 0, "batch_stats": 0, "intermediates": 0},
-          split_rngs={"params": True}, in_axes=nn.broadcast,
-          length=c.moe_layers)(
-              name="layers", window=mixture_windows[0],
-              **self.options())(x, tables)
+    if c.layer_pattern:
+      # One mixer a layer, of the layer's own kind: unrolled like any
+      # stack of several kinds, nothing rematerialised but the inside of
+      # a Mamba mixer, which does that itself.
+      for i, kind in enumerate(c.kinds):
+        x, _ = MixerBlock(kind=kind, name=f"layer_{i}",
+                          **self.options())(x, tables)
     else:
-      # Layers of two kinds are two bodies: the held layers unrolled,
-      # each traced once (a deeper stage would scan one PERIOD of the
-      # pattern; the stage a chip holds is shorter than two periods).
-      # They are NOT rematerialised, whatever ``remat`` says of the
-      # scanned stack. Outside a scan XLA merges every operation that
-      # nn.remat(prevent_cse=False) repeats with its first copy, so the
-      # activations are kept under it as well (five layers at 8,192
-      # tokens fit the chip); what XLA does not merge would run twice:
-      # the routed path's rounds loop wherever a block's backward pass
-      # reads the feed-forward's output (a post-norm block's
-      # ``post_mlp_layernorm`` does), and the gather of the chosen
-      # experts' scores: 16 ms of the trinity-mini cell's 272 ms step,
-      # for 0.08 GiB MORE memory (PERF.md section 6, PR 35). The stack's
-      # form decides, and that follows from the configuration's
-      # ``layer_types``: no flag.
-      for i, window in enumerate(mixture_windows):
-        x, _ = Block(window=window, name=f"layer_{i}",
-                     **self.options())(x, tables)
+      windows = c.windows
+      for i in range(c.dense_layers):
+        x, _ = block_cls(mixture=False, window=windows[i], name=f"dense_{i}",
+                         **self.options())(x, tables)
+      mixture_windows = windows[c.dense_layers:]
+      if c.moe_layers and self.scan_layers and len(set(mixture_windows)) == 1:
+        # One block body whatever the depth; per-layer parameters, router
+        # state and sown values stack on a leading layer axis.
+        x, _ = nn.scan(
+            block_cls,
+            variable_axes={"params": 0, "batch_stats": 0, "intermediates": 0},
+            split_rngs={"params": True}, in_axes=nn.broadcast,
+            length=c.moe_layers)(
+                name="layers", window=mixture_windows[0],
+                **self.options())(x, tables)
+      else:
+        # Layers of two kinds are two bodies: the held layers unrolled,
+        # each traced once (a deeper stage would scan one PERIOD of the
+        # pattern; the stage a chip holds is shorter than two periods).
+        # They are NOT rematerialised, whatever ``remat`` says of the
+        # scanned stack. Outside a scan XLA merges every operation that
+        # nn.remat(prevent_cse=False) repeats with its first copy, so the
+        # activations are kept under it as well (five layers at 8,192
+        # tokens fit the chip); what XLA does not merge would run twice:
+        # the routed path's rounds loop wherever a block's backward pass
+        # reads the feed-forward's output (a post-norm block's
+        # ``post_mlp_layernorm`` does), and the gather of the chosen
+        # experts' scores: 16 ms of the trinity-mini cell's 272 ms step,
+        # for 0.08 GiB MORE memory (PERF.md section 6, PR 35). The stack's
+        # form decides, and that follows from the configuration's
+        # ``layer_types``: no flag.
+        for i, window in enumerate(mixture_windows):
+          x, _ = Block(window=window, name=f"layer_{i}",
+                       **self.options())(x, tables)
     self.sow("intermediates", "hidden_last", x)
-    h_main = self.norm("norm")(x).astype(self.dtype)
+    h_main = self.norm("norm_f" if c.layer_pattern else "norm")(x).astype(
+        self.dtype)
     h_mtp = None
     if c.num_nextn_predict_layers:
       with jax.named_scope("mtp"):
@@ -752,7 +1016,8 @@ class MLAMoELMModel(model_lib.Model):
     self._share = (get("lm_config", DEFAULT_CONFIG),
                    get("lm_layers_held", None), get("lm_layer_shards", 1),
                    get("lm_layer_shard_index", 0),
-                   get("lm_first_layer_held", 0))
+                   get("lm_first_layer_held", 0),
+                   get("lm_vocab_shards", None))
     self._cfg = None
     self.scanned_param_prefixes = ("layers",)
 
@@ -780,7 +1045,8 @@ class MLAMoELMModel(model_lib.Model):
         self._cfg = c = load_lm_config(*self._share)
       trace.set_static("moe", {
           "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
-          "buffer_rows": self._round_rows(c)})
+          "buffer_rows": self._round_rows(c),
+          "expert_matrices": c.expert_matrices})
       core = self.attention_core_stats()
       trace.set_static("attention", core)
       # One kind of core: its fields; two: a table by kind.
@@ -801,15 +1067,20 @@ class MLAMoELMModel(model_lib.Model):
             + ("; {tiles_visited} of {tiles_causal} causal score tiles "
                "visited".format(**one) if "tiles_causal" in one else ""))
       last = c.first_layer + c.layers_held - 1
+      held = (f"{c.mamba_layers} Mamba-2, {c.moe_layers} mixture, "
+              f"{len(c.attention_windows)} attention, one mixer a layer"
+              if c.layer_pattern else
+              f"{c.dense_layers} dense, {c.moe_layers} mixture, "
+              f"{c.num_nextn_predict_layers} MTP module(s)")
       log_util.log_fn(
           f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
-          f"layers ({c.first_layer}-{last}: {c.dense_layers} dense, "
-          f"{c.moe_layers} mixture, "
-          f"{c.num_nextn_predict_layers} MTP module(s)); chip "
+          f"layers ({c.first_layer}-{last}: {held}); chip "
           f"{c.shard_index} of {c.shards} per layer: experts "
           f"{c.first_expert}-{c.first_expert + c.experts_held - 1} of "
-          f"{c.n_routed_experts}, vocabulary rows 0-{c.vocab_rows - 1} of "
-          f"{c.vocab_size}; sequence length {self.seq_len}; no exchange")
+          f"{c.n_routed_experts} ({c.expert_matrices} matrices an expert); "
+          f"of {c.vocab_shards} over the vocabulary: rows "
+          f"0-{c.vocab_rows - 1} of {c.vocab_size}; sequence length "
+          f"{self.seq_len}; no exchange")
     return self._cfg
 
   @cfg.setter
@@ -840,7 +1111,7 @@ class MLAMoELMModel(model_lib.Model):
                   c.layers_held + c.num_nextn_predict_layers)[1]
     out = {}
     for kind, window in (("window", c.sliding_window), ("full", None)):
-      layers = sum(w == window for w in c.windows)
+      layers = sum(w == window for w in c.attention_windows)
       if not layers:
         continue
       plan, out[kind] = core(c.head_dim, window, layers)
@@ -874,6 +1145,7 @@ class MLAMoELMModel(model_lib.Model):
     self._state_combine(dtype)
     self._state_lm_head(dtype)
     self._state_rotary(dtype)
+    self._state_mamba(dtype)
     return MLAMoELM(cfg=self.cfg, dtype=dtype, param_dtype=param_dtype,
                     moe_impl="gmm" if on_tpu else "ragged_dot")
 
@@ -945,7 +1217,7 @@ class MLAMoELMModel(model_lib.Model):
     from kf_benchmarks_tpu import tracing
     from kf_benchmarks_tpu.utils import log as log_util
     sites, trace = self.rotary_stats(dtype), tracing.active()
-    if trace.static("rotary") == sites:
+    if not sites or trace.static("rotary") == sites:
       return
     trace.set_static("rotary", sites)
     log_util.log_fn("attention rotary: " + "; ".join(
@@ -958,6 +1230,35 @@ class MLAMoELMModel(model_lib.Model):
             "block".format(**one)
             if one["block_rows"] else "", **one)
         for name, one in sites.items()))
+
+  def mamba_stats(self, dtype):
+    """The run's ``stats["mamba"]``: the state-space scan at this job's
+    shapes (``ssd.scan_stats``: the plan, from the shapes and the
+    backend); None where no layer held has one."""
+    c = self.cfg
+    if not c.mamba_layers:
+      return None
+    return ssd.scan_stats(
+        self.get_batch_size(), self.seq_len, c.mamba_num_heads,
+        c.mamba_head_dim, c.n_groups, c.ssm_state_size, c.chunk_size,
+        c.mamba_layers, dtype)
+
+  def _state_mamba(self, dtype):
+    """``stats["mamba"]`` and its log line, stated like the combine:
+    where the module's type is known, and once."""
+    from kf_benchmarks_tpu import tracing
+    from kf_benchmarks_tpu.utils import log as log_util
+    scan, trace = self.mamba_stats(dtype), tracing.active()
+    if scan is None or trace.static("mamba") == scan:
+      return
+    trace.set_static("mamba", scan)
+    log_util.log_fn(
+        "mamba scan: {layers} layer(s), {heads} heads of {head_dim} over "
+        "{groups} groups, state {state}; {implementation}, "
+        "{chunks_per_sequence} chunks of {chunk} positions a sequence, "
+        "{carried_state_bytes_per_layer} bytes of carried state and "
+        "{residual_bytes_per_layer} kept for the backward pass a "
+        "layer".format(**scan))
 
   def get_input_shapes(self, subset):
     n = self.get_batch_size()

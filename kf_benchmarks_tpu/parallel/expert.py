@@ -166,7 +166,8 @@ def reference_switch_moe(x_grouped, gate_w, w1, b1, w2, b2,
 # scalar: no host sync, no recompilation, no pair dropped). No array of
 # N x k rows times a model width exists, forward or backward: a round
 # gathers ``rows`` rows of the tokens (``_rows_of_tokens``), runs the
-# three grouped products on them, and combines on the TOKEN side
+# expert's grouped products on them (three for a gated expert, two for a
+# plain one: ``expert_hidden``), and combines on the TOKEN side
 # (``_rows_to_tokens``): each token gathers its k pairs' rows from the
 # products' own output, in the type the products stored, and sums them
 # in float32 under the pairs' weights, which are in token order already.
@@ -212,13 +213,22 @@ def gmm_tiling(rows: int, contraction: int, columns: int):
   does not is masked work), 512 rows, and at most 1024 x 768 of the
   weight so that a tile and its double buffer fit the v5e's 16 MiB of
   scoped VMEM (1024 x 1536 was refused by the chip's compiler, PR 27).
-  A dimension none divides (the CPU tests' tiny sizes) is one tile."""
-  def tile(dim, sizes):
-    return next((s for s in sizes if dim % s == 0), dim)
-  tk = tile(contraction, (1024, 768, 512, 256, 128))
-  tn = tile(columns, (1024, 768, 512, 256, 128) if tk <= 768 else
-            (768, 512, 256, 128))
-  return tile(rows, (512, 256, 128, 64, 32, 16, 8)), tk, tn
+  A width NO listed size divides (1,856 = 29 x 64, a two-matrix expert's)
+  takes the size whose last tile is masked least, the larger of equals
+  (384: five tiles, 64 columns masked; the kernels mask a short last
+  tile of the contraction and clip one of the columns); a dimension
+  under the smallest size (the CPU tests' tiny sizes), and rows that no
+  size divides, are one tile."""
+  def tile(dim, sizes, masked=True):
+    divides = next((s for s in sizes if dim % s == 0), None)
+    fits = [s for s in sizes if s <= dim]
+    if divides or not (masked and fits):
+      return divides or dim
+    return min(fits, key=lambda s: (-dim % s, -s))
+  tk = tile(contraction, (1024, 896, 768, 512, 384, 256, 128))
+  tn = tile(columns, (1024, 896, 768, 512, 384, 256, 128) if tk <= 768 else
+            (768, 512, 384, 256, 128))
+  return tile(rows, (512, 256, 128, 64, 32, 16, 8), masked=False), tk, tn
 
 
 def route_topk(x, router_w, select_bias, k: int, scale: float,
@@ -410,12 +420,34 @@ def _grouped_matmul_bwd(impl, res, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "impl"))
+def relu2(x):
+  """``relu(x)^2``, the activation of a two-matrix expert."""
+  return jnp.square(jax.nn.relu(x))
+
+
+# What an expert applies between its products, by the name a
+# configuration gives it (``hidden_act`` / ``mlp_hidden_act``).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu2": relu2}
+
+
+def expert_hidden(xs, w_gate, w_up, product, activation: str):
+  """An expert's hidden rows, in the FORM its weights state: gated
+  (``w_gate`` given, three matrices: ``act(xs w_gate) * (xs w_up)``) or
+  plain (``w_gate`` None, two: ``act(xs w_up)``). ``product(rows, w)`` is
+  the grouped product."""
+  act = ACTIVATIONS[activation]
+  if w_gate is None:
+    return act(product(xs, w_up))
+  return act(product(xs, w_gate)) * product(xs, w_up)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "impl", "activation"))
 def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
-                  rows: int, impl: str):
+                  rows: int, impl: str, activation: str = "silu"):
   """Round ``i`` of the routed path: sorted rows ``[i x rows, (i + 1) x
   rows)``. Returns ``(y, computed)``: y (N, D) float32, the weighted
-  SiLU-gated outputs of the held experts for the pairs in these rows,
+  outputs of the held experts (``expert_hidden``'s form, then ``w_down``)
+  for the pairs in these rows,
   summed into their tokens; ``computed`` the pairs the products computed
   for their expert (``pairs_inside_groups``). A held pair outside the
   round is another round's: left out here, and not counted. (Jitted, as
@@ -432,60 +464,66 @@ def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
   slot = jnp.where((slot >= 0) & (slot < rows), slot, rows).reshape(-1, k)
   xs = jnp.where(live[:, None], _rows_of_tokens(x, tok, slot), 0)
   with jax.named_scope("moe_experts"):
-    h = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, live, impl))
-    h = h * grouped_matmul(xs, w_up, sizes, live, impl)
-    ys = grouped_matmul(h, w_down, sizes, live, impl)
+    product = lambda rows_, w: grouped_matmul(rows_, w, sizes, live, impl)
+    ys = product(expert_hidden(xs, w_gate, w_up, product, activation),
+                 w_down)
   y = _rows_to_tokens(ys, pair_w, tok, slot, start, plan)
   return y, pairs_inside_groups(cut(plan.key, fill=sizes.shape[0]), sizes,
                                 live)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl,
+                activation):
   """``experts_round`` summed over the rounds that hold a pair of a held
   expert. Differentiated as a whole: the backward runs each round's
   forward again inside its own loop, so nothing of a round outlives it
   and the backward needs nothing of this forward but its inputs. (A
   caller under ``nn.remat`` pays for this forward twice only where the
   backward pass reads y itself: the comment above ``compact_rows``.)"""
-  args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl)
+  args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl, activation)
   return _while_pairs_left(lambda i: experts_round(i, *args), plan, rows)
 
 
 def _while_pairs_left(one_round, plan, rows):
-  """``one_round(0) + one_round(1) + ...`` (a tuple of arrays) over the
+  """``one_round(0) + one_round(1) + ...`` (a tuple of arrays, or of
+  None where a two-matrix expert has no gate) over the
   rounds that start before the held pairs end. The loop starts from
   zeros, so that the executable holds a round ONCE (a first round
   outside the loop saved the zero-fill and the sums, 1.1 ms a layer, and
   cost 2 s of every process's set-up: PERF.md section 6, PR 28)."""
   if plan.key.shape[0] <= rows:
     return one_round(jnp.int32(0))
-  zeros = tuple(jnp.zeros(o.shape, o.dtype)
-                for o in jax.eval_shape(one_round, jnp.int32(0)))
+  zeros = jax.tree.map(lambda o: jnp.zeros(o.shape, o.dtype),
+                       jax.eval_shape(one_round, jnp.int32(0)))
   more = lambda carry: carry[0] * rows < plan.ends[-1]
-  add = lambda carry: (carry[0] + 1, tuple(
-      a + b for a, b in zip(carry[1], one_round(carry[0]))))
+  add = lambda carry: (carry[0] + 1, jax.tree.map(
+      jnp.add, carry[1], one_round(carry[0])))
   return lax.while_loop(more, add, (jnp.int32(0), zeros))[1]
 
 
-def _all_rounds_fwd(x, pair_w, w_gate, w_up, w_down, plan, rows, impl):
-  return (_all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl),
+def _all_rounds_fwd(x, pair_w, w_gate, w_up, w_down, plan, rows, impl,
+                    activation):
+  return (_all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl,
+                      activation),
           (x, pair_w, w_gate, w_up, w_down, plan))
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "impl"))
-def _round_pullback(i, g, inputs, plan, rows, impl):
+@functools.partial(jax.jit, static_argnames=("rows", "impl", "activation"))
+def _round_pullback(i, g, inputs, plan, rows, impl, activation="silu"):
   """The gradients of round ``i``'s y with respect to ``inputs`` (x,
-  pair_w and the three weights) at the cotangent ``g``."""
-  _, vjp = jax.vjp(lambda *a: experts_round(i, *a, plan, rows, impl)[0],
-                   *inputs)
+  pair_w and the weights; None for the gate a two-matrix expert lacks)
+  at the cotangent ``g``."""
+  _, vjp = jax.vjp(lambda *a: experts_round(i, *a, plan, rows, impl,
+                                            activation)[0], *inputs)
   return vjp(g)
 
 
-def _all_rounds_bwd(rows, impl, res, g):
+def _all_rounds_bwd(rows, impl, activation, res, g):
   *inputs, plan = res
-  pull = lambda i: _round_pullback(i, g[0], tuple(inputs), plan, rows, impl)
-  return _while_pairs_left(pull, plan, rows) + (None,)
+  pull = lambda i: _round_pullback(i, g[0], tuple(inputs), plan, rows, impl,
+                                   activation)
+  return tuple(_while_pairs_left(pull, plan, rows)) + (None,)
 
 
 _all_rounds.defvjp(_all_rounds_fwd, _all_rounds_bwd)
@@ -493,15 +531,19 @@ _all_rounds.defvjp(_all_rounds_fwd, _all_rounds_bwd)
 
 def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
                      first_expert: int, impl: str = "ragged_dot",
-                     rows: Optional[int] = None):
+                     rows: Optional[int] = None, activation: str = "silu"):
   """The held experts' part of a dropless top-k layer.
 
   x (N, D) tokens; weights, idx (N, k) from ``route_topk`` over ALL the
   experts; w_gate, w_up (G, D, F) and w_down (G, F, D) the G experts
   held, which are experts ``first_expert .. first_expert + G - 1``;
+  the expert's FORM is what the caller hands over: ``w_gate`` None is a
+  two-matrix expert, ``w_down(act(w_up x))``, else the gated three-matrix
+  one, ``w_down(act(w_gate x) * w_up x)``, ``activation`` naming ``act``
+  (``ACTIVATIONS``);
   ``rows`` the sorted rows of one round (``compact_rows``; static), all
   N x k by default. Returns ``(y, counts)``: y (N, D) the weighted sum
-  of the held experts' SiLU-gated outputs over each token's pairs that
+  of the held experts' outputs over each token's pairs that
   chose one (zero for a token that chose none), and ``counts`` a dict of
   int32 scalars: ``pairs_here``, the pairs whose expert is held (counted
   from the choices), ``pairs_computed``, counted from the other side:
@@ -512,16 +554,18 @@ def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
   while the rounds reach every held pair and the sort agrees with the
   group sizes.
 
-  Scopes: the caller wraps this in ``moe_route``; the three grouped
-  products sit under ``moe_experts`` inside it, in every round.
+  Scopes: the caller wraps this in ``moe_route``; the grouped products
+  (three a gated expert, two a plain one) sit under ``moe_experts``
+  inside it, in every round.
   """
   n, k = idx.shape
   rows = n * k if rows is None else rows
-  plan, held = sort_pairs(idx, first_expert, w_gate.shape[0])
+  plan, held = sort_pairs(idx, first_expert, w_up.shape[0])
   pair_w = jnp.where(held, weights, 0).astype(jnp.float32)
   y, computed = _all_rounds(
-      x, pair_w, w_gate.astype(x.dtype), w_up.astype(x.dtype),
-      w_down.astype(x.dtype), plan, rows, impl)
+      x, pair_w, None if w_gate is None else w_gate.astype(x.dtype),
+      w_up.astype(x.dtype), w_down.astype(x.dtype), plan, rows, impl,
+      activation)
   pairs_here = plan.ends[-1]
   counts = {"pairs_here": pairs_here, "pairs_computed": computed,
             "compact": (pairs_here <= rows).astype(jnp.int32)}

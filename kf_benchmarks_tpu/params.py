@@ -143,7 +143,9 @@ flags.DEFINE_integer("lm_layer_shards", None,
                      "divided (expert parallelism with a vocabulary-"
                      "parallel embedding and head). This job holds one "
                      "share: n_routed_experts / n contiguous experts and "
-                     "vocab_size / n rows; attention, shared expert, "
+                     "vocab_size / n rows (--lm_vocab_shards gives the "
+                     "vocabulary a number of its own); attention, the "
+                     "state-space mixers, shared expert, "
                      "router and norms whole. It runs WITHOUT the "
                      "exchange: the absent experts' part is left out. "
                      "None = 1 (like every mla_moe_lm flag, None by "
@@ -153,6 +155,14 @@ flags.DEFINE_integer("lm_layer_shards", None,
 flags.DEFINE_integer("lm_layer_shard_index", None,
                      "mla_moe_lm: which of the --lm_layer_shards shares "
                      "of a layer this job holds. None = 0.", lower_bound=0)
+flags.DEFINE_integer("lm_vocab_shards", None,
+                     "mla_moe_lm: over how many chips the VOCABULARY is "
+                     "divided (embedding, head, token ids, labels and the "
+                     "loss are over vocab_size / n rows), where that is "
+                     "not the number that divides a layer: a deployment "
+                     "whose experts lie over 16 chips may keep the "
+                     "vocabulary over 8 of them. None = "
+                     "--lm_layer_shards.", lower_bound=1)
 flags.DEFINE_integer("num_batches", None,
                      "Number of timed batches to run (ref :137-139).")
 flags.DEFINE_float("num_epochs", None,

@@ -308,7 +308,7 @@ def validate_cross_flags(params) -> None:
           "the K/V axis in whole blocks)")
   lm_flags = [f for f in ("seq_len", "lm_config", "lm_layers_held",
                           "lm_first_layer_held", "lm_layer_shards",
-                          "lm_layer_shard_index")
+                          "lm_layer_shard_index", "lm_vocab_shards")
               if getattr(p, f, None) is not None]
   if p.model != "mla_moe_lm":
     if lm_flags:
@@ -356,6 +356,22 @@ def validate_cross_flags(params) -> None:
           "--model=mla_moe_lm cannot be combined with "
           "--steps_per_dispatch > 1: its per-step counters are not "
           "stacked through the chunked program yet")
+    # A sequence the state-space scan cannot take is refused here, with
+    # the scan's own reason, not at the first trace of the step (lazy
+    # import, as above; a configuration that cannot be read is refused
+    # where it is loaded).
+    from kf_benchmarks_tpu.models import mla_moe_lm as _moe_lm
+    from kf_benchmarks_tpu.ops import ssd as _ssd
+    try:
+      _, raw = _moe_lm.read_config_file(
+          getattr(p, "lm_config", None) or _moe_lm.DEFAULT_CONFIG)
+    except ValueError:
+      raw = {}
+    if _moe_lm.MAMBA in raw.get("hybrid_override_pattern", ""):
+      why = _ssd.refusal(getattr(p, "seq_len", None) or
+                         _moe_lm.DEFAULT_SEQ_LEN, raw["chunk_size"])
+      if why:
+        raise ParamError(f"--seq_len with --lm_config={p.lm_config}: {why}")
   mesh_shape = getattr(p, "mesh_shape", None)
   sharded = bool(getattr(p, "shard_optimizer_state", False))
   if mesh_shape:

@@ -315,3 +315,55 @@ def test_rotary_stage_on_the_tpu_compiler(topo, monkeypatch, site, shape,
   # Beyond arguments and outputs: the tables and the scale's gradient by
   # block, a few megabytes.
   assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24, site
+
+
+def test_nemotron_h_kernels_on_the_tpu_compiler(topo):
+  """What the nemotron-3-nano-30b-a3b cell adds at its real widths,
+  forward and backward, on the chip's own compiler (PR 39): the routed
+  path over TWO-matrix experts at a width that no tile of the grouped
+  product divides (8 of 128 experts of 2688 x 1856 = 29 x 64: tiles of
+  384 with a masked last one, which the kernel's interpreted form passes
+  at small sizes in tests/test_nemotron_h_lm.py and only this compile
+  holds to the v5e's VMEM and tiling rules), and one Mamba-2 mixer's
+  inside (``ssd.mamba_core`` at 64 heads of 64 over 8 groups, state 128,
+  64 chunks of 128 positions): a program the compiler takes, in under
+  2 GB of temporaries a layer (1,697,026,048 bytes as written)."""
+  import jax
+  from jax.sharding import SingleDeviceSharding
+  from kf_benchmarks_tpu.ops import ssd
+  from kf_benchmarks_tpu.parallel import expert as expert_lib
+  one = SingleDeviceSharding(topo.devices[0])
+  sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+  tokens, k, d, f, g = 8192, 6, 2688, 1856, 8
+  rows = expert_lib.compact_rows(tokens * k, g, 128)
+  assert rows == 6144
+  assert expert_lib.gmm_tiling(rows, d, f) == (512, 896, 384)
+
+  def routed(x, weights, idx, w_up, w_down):
+    return jnp.sum(expert_lib.held_experts_ffn(
+        x, weights, idx, None, w_up, w_down, 0, impl="gmm", rows=rows,
+        activation="relu2")[0].astype(jnp.float32))
+  weight = lambda shape: sds(shape, jnp.float32)
+  text = jax.jit(jax.grad(routed, argnums=(0, 1, 3, 4))).lower(
+      sds((tokens, d), jnp.bfloat16), weight((tokens, k)),
+      sds((tokens, k), jnp.int32), weight((g, d, f)),
+      weight((g, f, d))).compile().as_text()
+  # The backward's loop holds a round once: its two products forward
+  # again and four backward.
+  assert text.count('custom_call_target="tpu_custom_call"') == 6
+  assert " while(" in text
+  assert not re.search(rf"\[{tokens * k},({d}|{f})\]", text)
+
+  heads, p, groups, state, chunk = 64, 64, 8, 128, 128
+  inner, conv = heads * p, heads * p + 2 * groups * state
+
+  def mixer(zxbcdt, kernel, bias, a_log, skip, dt_bias, scale):
+    return jnp.sum(jax.checkpoint(lambda *a: ssd.mamba_core(
+        *a, heads=heads, head_dim=p, groups=groups, state=state,
+        chunk=chunk, eps=1e-5))(zxbcdt, kernel, bias, a_log, skip, dt_bias,
+                                scale).astype(jnp.float32))
+  compiled = jax.jit(jax.grad(mixer, argnums=tuple(range(7)))).lower(
+      sds((1, tokens, 2 * inner + 2 * groups * state + heads), jnp.bfloat16),
+      weight((4, conv)), weight((conv,)), weight((heads,)), weight((heads,)),
+      weight((heads,)), weight((inner,))).compile()
+  assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
