@@ -45,10 +45,15 @@ state-space layers):
 
 * ``scan_bf16``: the scan's running sums, exponentials and carried state
   in bfloat16, the nearest precision below the float32 the configuration
-  states. Fails ``scan_carry_err`` and ``layer_output_err``.
+  states (``ssd.scan_plan`` hands bfloat16 steps to the einsums on every
+  backend: the kernels of PR 40 take float32 steps alone). Fails
+  ``scan_carry_err`` and ``layer_output_err``.
 * ``no_chunk_carry``: no state passes from a chunk to the next (every
   chunk starts from zero). Fails ``scan_carry_err``, which reads 0.5 or
-  more.
+  more. Planted in the form that RUNS: the kernels carry the state in a
+  VMEM scratch and never call ``ssd.carried_states``, so the control
+  also hands ``ssd_scan`` the einsums' plan (``scan_plan`` wrapped to
+  say ``"xla"`` whatever the backend) and loses the carry there.
 * ``gate_after_norm``: the Mamba mixer's gate applied AFTER its grouped
   norm (Mamba-2's other published order). Fails ``layer_output_err``.
 * ``experts_gated``: the expert's form taken for the other families':
@@ -140,9 +145,11 @@ def plant(fault, setattr_=setattr):
         self, *a, **k).clone(scan_dtype=jnp.bfloat16))
   elif fault == "no_chunk_carry":
     from kf_benchmarks_tpu.ops import ssd
-    carried = ssd.carried_states
+    carried, plan = ssd.carried_states, ssd.scan_plan
     setattr_(ssd, "carried_states", lambda own, total: jnp.zeros_like(
         carried(own, total)))
+    setattr_(ssd, "scan_plan", lambda *a, **k: dataclasses.replace(
+        plan(*a, **k), implementation="xla"))
   elif fault == "gate_after_norm":
     from kf_benchmarks_tpu.ops import ssd
 

@@ -2026,8 +2026,9 @@ class BenchmarkCNN:
         "rotary": self._trace.static("rotary"),
         # The state-space scan as the model stated it at the build
         # (ops/ssd.scan_stats): layers, heads, groups, state, the chunk
-        # and chunks a sequence, the implementation, bytes of carried
-        # state and kept a layer. Static. None for a model without one.
+        # and chunks a sequence, the implementation and the share of the
+        # scans that took the kernels, bytes of carried state and kept a
+        # layer. Static. None for a model without one.
         "mamba": self._trace.static("mamba"),
         # The allocator's own account of the fullest device of the
         # mesh, read as the timed loop ends: live buffers at their peak,

@@ -471,8 +471,9 @@ NON_METRIC_KEYS = frozenset({
     "rotary",
     # PR 39: the state-space scan as models/mla_moe_lm.py states it at
     # the build ({layers, heads, head_dim, groups, state, chunk,
-    # chunks_per_sequence, implementation, carried_state_bytes_per_layer,
-    # residual_bytes_per_layer}; ops/ssd.scan_stats): a description of
+    # chunks_per_sequence, implementation, kernel_share (PR 40),
+    # carried_state_bytes_per_layer, residual_bytes_per_layer};
+    # ops/ssd.scan_stats): a description of
     # the program that ran, None for every other model.
     "mamba",
 })

@@ -801,10 +801,13 @@ class Mamba2Mixer(_Options):
   """The Mamba-2 state-space mixer: ``in_proj`` to [z | xBC | dt], the
   causal depthwise convolution and SiLU over xBC, the chunked scan, the
   gated norm and ``out_proj`` (``ops/ssd.mamba_core`` has everything
-  between the projections). Its inside is REMATERIALISED from
-  ``in_proj``'s output: a chunk's decay tables and the float32 copies of
-  x, B and C are formed again in the backward pass and never kept (a
-  layer keeps 169 MB at 8,192 tokens where the tables alone are 400 MB).
+  between the projections; the scan is ``ssd.scan_plan``'s choice: two
+  Pallas kernels on a TPU at shapes that tile, einsums elsewhere). Its
+  inside is REMATERIALISED from ``in_proj``'s output: the float32 copies
+  of x, B and C, the scan's output and, in the einsums, a chunk's decay
+  tables are formed again in the backward pass and never kept (a layer
+  keeps 169 MB at 8,192 tokens where the tables alone are 400 MB), so
+  the scan's forward runs twice a step and its backward once.
   ``jax.checkpoint`` with its barriers, not ``nn.remat(prevent_cse=
   False)``: outside a scan XLA would merge the repeat with its first
   copy and keep everything."""
@@ -1254,8 +1257,8 @@ class MLAMoELMModel(model_lib.Model):
     trace.set_static("mamba", scan)
     log_util.log_fn(
         "mamba scan: {layers} layer(s), {heads} heads of {head_dim} over "
-        "{groups} groups, state {state}; {implementation}, "
-        "{chunks_per_sequence} chunks of {chunk} positions a sequence, "
+        "{groups} groups, state {state}; {implementation} (kernel share "
+        "{kernel_share}), {chunks_per_sequence} chunks of {chunk} positions a sequence, "
         "{carried_state_bytes_per_layer} bytes of carried state and "
         "{residual_bytes_per_layer} kept for the backward pass a "
         "layer".format(**scan))

@@ -571,7 +571,7 @@ def test_scan_and_share_reach_the_stats(two_step_stats):
   assert stats["mamba"] == {
       "layers": 4, "heads": 8, "head_dim": 4, "groups": 2, "state": 16,
       "chunk": 8, "chunks_per_sequence": 4, "implementation": "xla",
-      "carried_state_bytes_per_layer": 2 * 4 * 8 * 4 * 16 * 4,
+      "kernel_share": 0.0, "carried_state_bytes_per_layer": 2 * 4 * 8 * 4 * 16 * 4,
       "residual_bytes_per_layer": 2 * SEQ * 136 * 4}
   moe = stats["moe"]
   assert moe["expert_matrices"] == 2 and moe["experts_held"] == 4
@@ -586,6 +586,7 @@ def test_scan_and_share_reach_the_stats(two_step_stats):
   assert not stats.get("rotary")
   scan = [l for l in lines if l.startswith("mamba scan: ")]
   assert len(scan) == 1 and "4 chunks of 8 positions" in scan[0]
+  assert "; xla (kernel share 0.0), " in scan[0]
   share = [l for l in lines if l.startswith("mla_moe_lm share: ")]
   assert len(share) == 1
   assert ("4 Mamba-2, 4 mixture, 1 attention, one mixer a layer" in share[0]

@@ -367,3 +367,47 @@ def test_nemotron_h_kernels_on_the_tpu_compiler(topo):
       weight((4, conv)), weight((conv,)), weight((heads,)), weight((heads,)),
       weight((heads,)), weight((inner,))).compile()
   assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+def test_ssd_scan_kernels_on_the_tpu_compiler(topo, monkeypatch):
+  """One Mamba-2 mixer's inside at the nemotron cell's shapes with the
+  scan as ``ssd.scan_plan`` plans it on a TPU (PR 40): ONE kernel forward
+  (``ssd_scan_fwd``; the rematerialised inside runs it in the backward
+  pass, where it also writes the entering states) and ONE backward
+  (``ssd_scan_bwd``), which the chip's compiler takes inside its VMEM; no
+  array of a chunk's decay tables
+  ((64, 8, 8, 128, 128): 268 MB a layer in float32) and no relayout of
+  the scan's float32 output in front of the grouped norm (``copy
+  f32[1024,8,8,512]``: the norm's statistics are products with the
+  groups' membership) exist around them, in little over half of the einsums'
+  temporaries (875,506,176 bytes as written, 1,697,026,048 before)."""
+  import jax
+  from jax.sharding import SingleDeviceSharding
+  from kf_benchmarks_tpu.ops import ssd
+  one = SingleDeviceSharding(topo.devices[0])
+  sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+  weight = lambda shape: sds(shape, jnp.float32)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  tokens, heads, p, groups, state, chunk = 8192, 64, 64, 8, 128, 128
+  assert ssd.scan_plan(tokens, heads, groups, chunk, p, state) == (
+      ssd.ScanPlan("pallas", 128, 64))
+  inner, conv = heads * p, heads * p + 2 * groups * state
+
+  def mixer(zxbcdt, kernel, bias, a_log, skip, dt_bias, scale):
+    return jnp.sum(jax.checkpoint(lambda *a: ssd.mamba_core(
+        *a, heads=heads, head_dim=p, groups=groups, state=state,
+        chunk=chunk, eps=1e-5))(zxbcdt, kernel, bias, a_log, skip, dt_bias,
+                                scale).astype(jnp.float32))
+  compiled = jax.jit(jax.grad(mixer, argnums=tuple(range(7)))).lower(
+      sds((1, tokens, 2 * inner + 2 * groups * state + heads), jnp.bfloat16),
+      weight((4, conv)), weight((conv,)), weight((heads,)), weight((heads,)),
+      weight((heads,)), weight((inner,))).compile()
+  text = compiled.as_text()
+  assert text.count('custom_call_target="tpu_custom_call"') == 2
+  assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+  # Both carry the scope the benchmark reads them by.
+  calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+  assert all("ssd_scan/" in ln for ln in calls), calls
+  assert not re.search(r"\[1,64,8,8,128,128\]|\[64,8,8,128,128\]", text)
+  assert "f32[1024,8,8,512]" not in text
+  assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
