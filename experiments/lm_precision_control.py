@@ -60,6 +60,22 @@ state-space layers):
   ``silu(u) * u`` in place of ``relu(u)^2`` between its two matrices,
   routed and shared alike. Fails ``layer_output_err``.
 
+xing4.0-29b-a4b alone (the first is the lower-precision control of its
+hyper-connections; each other ONE departure from the published layer):
+
+* ``mhc_bf16``: the hyper-connections' coefficient path (norm statistic,
+  the product with phi, sigmoids, Sinkhorn-Knopp) in bfloat16, the
+  nearest precision below the float32 the configuration states. Fails
+  ``hc_pre_err``, ``hc_post_err`` and ``hc_res_err``.
+* ``sinkhorn_1``: ONE Sinkhorn-Knopp iteration in place of 20. Fails
+  ``hc_res_err`` and ``sinkhorn_residual``.
+* ``h_res_identity``: the residual mix H_res replaced by the identity
+  (Sinkhorn's output ignored). Fails ``hc_res_err`` and
+  ``layer_output_err``.
+* ``h_post_no_2``: h_post without its factor 2. Fails ``hc_post_err``.
+* ``no_yarn``: plain RoPE and the plain scale 1/sqrt(192) in place of
+  YaRN's frequencies and temperature. Fails ``attention_output_err``.
+
 The program has no option for any of this: ``plant`` steers it from
 outside, here and in ``tests/benchmarks/test_bench_lm.py`` /
 ``test_bench_afmoe.py`` / ``test_bench_nemotron_h.py``.
@@ -75,7 +91,8 @@ sys.path.insert(0, ROOT)
 FAULTS = ("router_bf16", "state_unchanged", "half_batch", "no_mtp",
           "no_scaling", "window_2047", "window_2049", "rope_in_full",
           "no_gate", "no_post_norms", "scan_bf16", "no_chunk_carry",
-          "gate_after_norm", "experts_gated")
+          "gate_after_norm", "experts_gated", "mhc_bf16", "sinkhorn_1",
+          "h_res_identity", "h_post_no_2", "no_yarn")
 
 
 def plant(fault, setattr_=setattr):
@@ -168,6 +185,22 @@ def plant(fault, setattr_=setattr):
     setattr_(mla_moe_lm, "load_lm_config", lambda *a, **k:
              dataclasses.replace(load(*a, **k),
                                  expert_activation="self_gated"))
+  elif fault == "mhc_bf16":
+    make = model.make_module
+    setattr_(model, "make_module", lambda self, *a, **k: make(
+        self, *a, **k).clone(mhc_dtype=jnp.bfloat16))
+  elif fault in ("sinkhorn_1", "no_yarn"):
+    load = mla_moe_lm.load_lm_config
+    change = (dict(hc_sinkhorn_iters=1) if fault == "sinkhorn_1"
+              else dict(yarn_factor=0.0))
+    setattr_(mla_moe_lm, "load_lm_config", lambda *a, **k:
+             dataclasses.replace(load(*a, **k), **change))
+  elif fault == "h_res_identity":
+    setattr_(mla_moe_lm, "sinkhorn", lambda logits, iters, eps:
+             jnp.broadcast_to(jnp.eye(logits.shape[-1], dtype=logits.dtype),
+                              logits.shape))
+  elif fault == "h_post_no_2":
+    setattr_(mla_moe_lm, "H_POST_SCALE", 1.0)
   elif fault == "state_unchanged":
     def get(self):
       step = self.__dict__.get("timed_step")

@@ -2009,6 +2009,14 @@ class BenchmarkCNN:
         "moe": (dict(self._trace.static("moe") or {},
                      **self.model.counter_stats(np.stack(counter_rows)))
                 if counter_rows else None),
+        # The residual streams' hyper-connections (models/mla_moe_lm.py,
+        # ``xing4_0``): streams, sublayers and Sinkhorn's iterations as
+        # stated at the build and, over the timed steps, the largest
+        # |row or column sum - 1| of any residual mix. None for a model
+        # without them.
+        "mhc": (dict(self._trace.static("mhc"),
+                     **self.model.mhc_counter_stats(np.stack(counter_rows)))
+                if counter_rows and self._trace.static("mhc") else None),
         # The attention core as the model stated it at the build
         # (models/mla_moe_lm.py, parallel/sequence.flash_plan): layers,
         # backward kernel passes a layer, the kernel's tiles. Static.

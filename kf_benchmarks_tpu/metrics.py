@@ -476,6 +476,11 @@ NON_METRIC_KEYS = frozenset({
     # ops/ssd.scan_stats): a description of
     # the program that ran, None for every other model.
     "mamba",
+    # PR 42: the residual streams' hyper-connections as models/
+    # mla_moe_lm.py states them ({streams, hidden_size, sublayers,
+    # sinkhorn_iters, eps, stream_bytes} at the build, sinkhorn_residual
+    # over the timed steps): None for every other model.
+    "mhc",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

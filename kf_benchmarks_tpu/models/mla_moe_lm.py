@@ -12,7 +12,10 @@ configuration's family (``model_type``, ``_FAMILIES``):
   ``hybrid_override_pattern``: a Mamba-2 state-space mixer
   (``Mamba2Mixer`` over ``ops/ssd.py``), a mixture of TWO-matrix
   ``relu(.)^2`` experts, or grouped-query attention with no gate, no
-  head norms and no positional rotation.
+  head norms and no positional rotation;
+* ``xing4_0`` (XingChen's Xing4.0): the DeepSeek-V3 block with YaRN and
+  ``hc_mult`` RESIDUAL STREAMS mixed around each sublayer by
+  manifold-constrained hyper-connections (``HyperConnection``).
 
 ``MoE``, ``SwiGLU``, ``RMSNorm``, the rotary stage (``ops/rotary.py``:
 head norm, RoPE and the cast between a projection and the core, one
@@ -40,6 +43,11 @@ set in ``_FAMILIES``.
         --lm_config=nemotron-3-nano-30b-a3b --seq_len=8192 --batch_size=1 \
         --lm_first_layer_held=0 --lm_layers_held=9 --lm_layer_shards=16 \
         --lm_vocab_shards=8 --lm_layer_shard_index=0 --optimizer=adam \
+        --use_fp16=true
+    python -m kf_benchmarks_tpu.cli --model=mla_moe_lm \
+        --lm_config=xing4.0-29b-a4b --seq_len=4096 --batch_size=1 \
+        --lm_first_layer_held=1 --lm_layers_held=5 --lm_layer_shards=8 \
+        --lm_head_shards=8 --lm_layer_shard_index=0 --optimizer=adam \
         --use_fp16=true
 
 The layer (RMSNorm, no biases but the state-space convolution's):
@@ -83,6 +91,22 @@ The layer (RMSNorm, no biases but the state-space convolution's):
   matrices at a width that no tile of the grouped product divides
   (``expert.gmm_tiling`` masks the last), the shared expert the same at
   its own width; the layers held are unrolled, each traced once;
+* manifold-constrained hyper-connections (mHC, DeepSeek-AI 2025, on
+  Hyper-Connections, Zhu et al. ICLR 2025; ``xing4_0``): each token
+  carries ``hc_mult`` = n streams X (n, C), in the compute dtype; around
+  each sublayer F (attention with ``input_layernorm``, the feed-forward
+  with ``post_attention_layernorm``) float32 coefficients from the
+  streams' RMS statistic and ONE product with phi (nC, 2n + n^2):
+  ``h_pre = sigmoid(.)``, ``h_post = 2 sigmoid(.)``, ``H_res`` =
+  ``hc_sinkhorn_iters`` Sinkhorn-Knopp iterations of ``exp(clamp(.))``,
+  doubly stochastic; ``y = F(sum_i h_pre[i] X[i])``, ``X'[i] = sum_j
+  H_res[i, j] X[j] + h_post[i] y``. The embedding is copied into the
+  streams, which are summed before the final norm and before the MTP
+  module's ``mtp_hnorm``; the MTP block expands and sums its own. The
+  scanned stack's carry is (B, T, n, C);
+* YaRN (``rope_scaling`` of type ``yarn``, ``xing4_0``): the rotary
+  stage's tables at YaRN's frequencies, and the scores' scale times its
+  temperature squared, folded into q's float32 pass;
 * norms: pre-norm (``input_layernorm``, ``post_attention_layernorm``
   before the feed-forward), or, ``post_norms``, one on each side of each
   sublayer (``input_layernorm`` / ``post_attention_layernorm`` around
@@ -120,10 +144,13 @@ the routed experts and of the vocabulary's rows (token ids, labels,
 logits and the losses are over the slice); ``--lm_vocab_shards`` gives
 the vocabulary a number of chips of its own where a deployment divides
 the experts further than the rows (16 and 8: a sixteenth of the
-vocabulary would be under the guide's floor). Attention, the state-space
-mixers, the shared expert,
-the router and the norms are held whole. What the absent experts would
-add is left out, and no code stands in for the absent chips.
+vocabulary would be under the guide's floor); ``--lm_head_shards`` divides
+latent attention's heads (tensor parallelism: this chip's block of heads
+in ``q_b_proj``, ``kv_b_proj`` and ``o_proj``, the down-projections
+whole). Otherwise attention, the state-space mixers, the shared expert,
+the router, the norms and the hyper-connections are held whole. What
+the absent experts and heads would add is left out, and no code stands
+in for the absent chips.
 
 The router's selection bias is STATE THE OPTIMIZER DOES NOT OWN: it
 lives in the ``batch_stats`` collection (the path batch-norm statistics
@@ -154,7 +181,9 @@ model, under no attention scope); ``moe_route`` (router, top-k, sort,
 gather and scatter of rows), ``moe_experts`` inside it (the grouped
 products), ``shared_expert``, ``mtp``, ``lm_head`` (the model's
 ``loss_function``); ``mamba_mixer`` (everything of a state-space mixer)
-with ``mamba_conv`` and ``ssd_scan`` inside it. They are components
+with ``mamba_conv`` and ``ssd_scan`` inside it; ``mhc`` (a
+hyper-connection's coefficients, Sinkhorn-Knopp, the pre-mix and the
+residual mix, around every sublayer). They are components
 OTHER than the four ``train_step.STEP_SCOPES``.
 """
 
@@ -276,15 +305,34 @@ class LMConfig:
   expert_activation: str = "silu"
   expert_gated: bool = True
   moe_shared_expert_intermediate_size: int = 0
+  # Manifold-constrained hyper-connections (``xing4_0``): ``hc_mult``
+  # residual streams (0: ONE stream, ``x + f(norm(x))``), the Sinkhorn
+  # iterations that make each sublayer's residual mix doubly stochastic,
+  # the epsilon of their denominators and the clamp of the mix's logits.
+  hc_mult: int = 0
+  hc_sinkhorn_iters: int = 0
+  hc_eps: float = 0.0
+  mhc_h_res_clamp_min: float = 0.0
+  mhc_h_res_clamp_max: float = 0.0
+  # YaRN (``rope_scaling`` of type ``yarn``; ``yarn_factor`` 0: plain
+  # RoPE and the plain softmax scale).
+  yarn_factor: float = 0.0
+  yarn_beta_fast: float = 0.0
+  yarn_beta_slow: float = 0.0
+  yarn_original_max_position: int = 0
+  yarn_mscale: float = 0.0
+  yarn_mscale_all_dim: float = 0.0
   # The share: ``layers_held`` layers of num_hidden_layers from published
   # layer ``first_layer`` on, and which of how many chips that divide
   # each layer this is; ``vocab_shards`` chips divide the vocabulary
-  # (0: as many as divide a layer).
+  # (0: as many as divide a layer); ``head_shards`` divide latent
+  # attention's heads (tensor parallelism over heads; 1: held whole).
   layers_held: int = 0
   first_layer: int = 0
   shards: int = 1
   shard_index: int = 0
   vocab_shards: int = 0
+  head_shards: int = 1
 
   @property
   def experts_held(self) -> int:
@@ -297,6 +345,41 @@ class LMConfig:
   @property
   def vocab_rows(self) -> int:
     return self.vocab_size // (self.vocab_shards or self.shards)
+
+  @property
+  def heads_held(self) -> int:
+    return self.num_attention_heads // self.head_shards
+
+  @property
+  def first_head(self) -> int:
+    return self.shard_index * self.heads_held if self.head_shards > 1 else 0
+
+  @property
+  def yarn(self) -> Optional[rotary.Yarn]:
+    if not self.yarn_factor:
+      return None
+    return rotary.Yarn(self.yarn_factor, self.yarn_beta_fast,
+                       self.yarn_beta_slow, self.yarn_original_max_position,
+                       self.yarn_mscale, self.yarn_mscale_all_dim)
+
+  @property
+  def softmax_scale(self) -> float:
+    """Latent attention's scores' scale: 1/sqrt(nope + rope), times
+    YaRN's temperature squared where the configuration states YaRN with
+    ``mscale_all_dim`` (DeepSeek-V3's release)."""
+    scale = 1.0 / math.sqrt(self.qk_head_dim)
+    if self.yarn_factor and self.yarn_mscale_all_dim:
+      scale *= rotary.yarn_mscale(self.yarn_factor,
+                                  self.yarn_mscale_all_dim) ** 2
+    return scale
+
+  @property
+  def sublayers(self) -> int:
+    """Sublayers behind a hyper-connection: two a layer held and two in
+    the MTP block (0 on one stream)."""
+    if not self.hc_mult:
+      return 0
+    return 2 * (self.layers_held + self.num_nextn_predict_layers)
 
   @property
   def kinds(self) -> str:
@@ -397,7 +480,35 @@ _FAMILIES = {
                   "use_conv_bias": True, "norm_topk_prob": True,
                   "n_shared_experts": 1, "sliding_window": None,
                   "residual_in_fp32": False}),
+    # XingChen's Xing4.0 family: the DeepSeek-V3 block with YaRN and
+    # ``hc_mult`` residual streams mixed around each sublayer by
+    # manifold-constrained hyper-connections (mHC, DeepSeek-AI 2025, on
+    # Hyper-Connections, Zhu et al., ICLR 2025).
+    "xing4_0": dict(
+        recipe=dict(attention="mla"), names={}, rope="yarn", streams=True,
+        required={"hidden_act": "silu", "attention_bias": False,
+                  "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+                  "scoring_func": "sigmoid", "moe_layer_freq": 1,
+                  "ep_size": 1, "tie_word_embeddings": False}),
 }
+
+
+def head_share_refusal(raw: dict, head_shards: int) -> Optional[str]:
+  """Why ``--lm_head_shards=head_shards`` cannot divide the configuration
+  ``raw`` (a config.json as a dict), or None: heads are divided in latent
+  attention alone (its q, kv and o projections hold a block of heads;
+  the grouped-query module has no such share), and into whole heads."""
+  if head_shards == 1:
+    return None
+  family = _FAMILIES.get(raw.get("model_type"), {})
+  if family.get("recipe", {}).get("attention") != "mla":
+    return (f"--lm_head_shards={head_shards}: heads are divided in latent "
+            f"attention alone, and model_type={raw.get('model_type')!r} "
+            "has none")
+  if raw["num_attention_heads"] % head_shards:
+    return (f"--lm_head_shards={head_shards} does not divide "
+            f"num_attention_heads={raw['num_attention_heads']}")
+  return None
 
 
 def read_config_file(name: str):
@@ -414,9 +525,11 @@ def read_config_file(name: str):
 def load_lm_config(name: str, layers_held: Optional[int] = None,
                    shards: int = 1, shard_index: int = 0,
                    first_layer: int = 0,
-                   vocab_shards: Optional[int] = None) -> LMConfig:
+                   vocab_shards: Optional[int] = None,
+                   head_shards: int = 1) -> LMConfig:
   """``lm_configs/<name>.json`` as an LMConfig with the share applied
-  (``vocab_shards`` None: the vocabulary is divided like a layer)."""
+  (``vocab_shards`` None: the vocabulary is divided like a layer;
+  ``head_shards``: chips that divide latent attention's heads)."""
   path, raw = read_config_file(name)
   family = _FAMILIES.get(raw.get("model_type"))
   if family is None:
@@ -426,6 +539,29 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
     if raw.get(key, want) != want:
       raise ValueError(f"{path}: {key}={raw[key]!r} is not implemented "
                        f"({raw['model_type']} here implements {want!r})")
+  scaling = raw.get("rope_scaling")
+  if family.get("rope") == "yarn":
+    if not isinstance(scaling, dict) or scaling.get(
+        "type", scaling.get("rope_type")) != "yarn":
+      raise ValueError(f"{path}: rope_scaling={scaling!r} is not implemented "
+                       f"({raw['model_type']} here implements YaRN alone)")
+    raw.update(yarn_factor=scaling["factor"],
+               yarn_beta_fast=scaling["beta_fast"],
+               yarn_beta_slow=scaling["beta_slow"],
+               yarn_original_max_position=scaling[
+                   "original_max_position_embeddings"],
+               yarn_mscale=scaling.get("mscale", 1),
+               yarn_mscale_all_dim=scaling.get("mscale_all_dim", 0))
+  streams = raw.get("hc_mult", 1)
+  if family.get("streams") and streams < 2:
+    raise ValueError(f"{path}: hc_mult={streams!r}: {raw['model_type']} here "
+                     "implements hyper-connections over 2 or more streams")
+  if not family.get("streams") and streams != 1:
+    raise ValueError(f"{path}: hc_mult={streams!r} is not implemented "
+                     f"({raw['model_type']} here has one residual stream)")
+  why = head_share_refusal(raw, head_shards)
+  if why:
+    raise ValueError(f"{path}: {why}")
   raw = {family["names"].get(k, k): v for k, v in raw.items()}
   recipe = dict(family["recipe"])
   if recipe["attention"] == "mla":
@@ -482,9 +618,13 @@ def load_lm_config(name: str, layers_held: Optional[int] = None,
     raise ValueError(f"--lm_layer_shard_index={shard_index} holds no rows "
                      f"of a vocabulary divided over --lm_vocab_shards="
                      f"{over} chips")
+  if head_shards > 1 and not shard_index < head_shards:
+    raise ValueError(f"--lm_layer_shard_index={shard_index} holds no heads "
+                     f"of attention divided over --lm_head_shards="
+                     f"{head_shards} chips")
   return dataclasses.replace(cfg, layers_held=held, first_layer=first_layer,
                              shards=shards, shard_index=shard_index,
-                             vocab_shards=over)
+                             vocab_shards=over, head_shards=head_shards)
 
 
 def _normal():
@@ -524,7 +664,7 @@ def rotary_sites(c: "LMConfig") -> dict:
     layers = c.layers_held + c.num_nextn_predict_layers
     rot = c.qk_rope_head_dim
     return {
-        "q": dict(heads=c.num_attention_heads, head_dim=c.qk_head_dim,
+        "q": dict(heads=c.heads_held, head_dim=c.qk_head_dim,
                   rot_dims=rot, normed=False, layers=layers),
         "k_rot": dict(heads=1, head_dim=rot, rot_dims=rot, normed=False,
                       layers=layers)}
@@ -548,7 +688,7 @@ def rope_tables(c: "LMConfig", seq_len: int, dtype) -> dict:
   which XLA forms once)."""
   return {name: rotary.stage_tables(
       (1, seq_len, site["heads"], site["head_dim"]), site["rot_dims"],
-      c.rope_theta, site["normed"], dtype)
+      c.rope_theta, site["normed"], dtype, c.yarn)
           for name, site in rotary_sites(c).items() if site["rot_dims"]}
 
 
@@ -565,6 +705,9 @@ class _Options(nn.Module):
   # The dtype of the state-space scan's running sums, exponentials and
   # carried state (``ops/ssd.py``); bfloat16 is the control again.
   scan_dtype: Any = jnp.float32
+  # The dtype of the hyper-connections' coefficient path (norm, product,
+  # sigmoids, Sinkhorn); bfloat16 is the control again.
+  mhc_dtype: Any = jnp.float32
 
   def dense(self, features, name, init=None):
     return nn.Dense(features, use_bias=False, name=name, dtype=self.dtype,
@@ -580,16 +723,28 @@ class _Options(nn.Module):
   def options(self):
     return dict(cfg=self.cfg, dtype=self.dtype,
                 param_dtype=self.param_dtype, moe_impl=self.moe_impl,
-                router_dtype=self.router_dtype, scan_dtype=self.scan_dtype)
+                router_dtype=self.router_dtype, scan_dtype=self.scan_dtype,
+                mhc_dtype=self.mhc_dtype)
+
+
+def exact_in_any_dtype(scale: float) -> bool:
+  """Is multiplying by ``scale`` exact in every float type (a power of
+  two)?"""
+  return math.frexp(scale)[0] == 0.5
 
 
 class MLAttention(_Options):
+  """Latent attention over the heads held here (``heads_held``: all of
+  them, or a chip's block of ``--lm_head_shards``: ``q_b_proj``,
+  ``kv_b_proj`` and ``o_proj`` hold those heads' columns and rows alone,
+  the down-projections and their norms whole). v enters the core at its
+  own head size."""
 
   @nn.compact
   def __call__(self, x, tables=None):
     c = self.cfg
     b, t, _ = x.shape
-    h, nope, rot, vd = (c.num_attention_heads, c.qk_nope_head_dim,
+    h, nope, rot, vd = (c.heads_held, c.qk_nope_head_dim,
                         c.qk_rope_head_dim, c.v_head_dim)
     tables = tables or rope_tables(c, t, self.dtype)
     with jax.named_scope("mla_attention"):
@@ -603,8 +758,15 @@ class MLAttention(_Options):
       kv = self.dense(h * (nope + vd), "kv_b_proj")(latent)
       kv = kv.reshape(b, t, h, nope + vd)
       # The rotary stage over q's whole head: the trailing rope dimensions
-      # rotated, the others passing through the same pass.
-      q = rotary.rotary_stage(q, tables["q"], rot_dims=rot)
+      # rotated, the others passing through the same pass. A scale that
+      # is no power of two (YaRN's) is folded into that pass in float32,
+      # as the grouped-query module folds its own; a power of two the
+      # kernel applies exactly in q's dtype.
+      scale = c.softmax_scale
+      factor = 1.0
+      if not exact_in_any_dtype(scale):
+        factor, scale = scale, 1.0
+      q = rotary.rotary_stage(q, tables["q"], rot_dims=rot, factor=factor)
       k_rot = rotary.rotary_stage(k_rot[:, :, None, :], tables["k_rot"],
                                   rot_dims=rot)
       k = jnp.concatenate(
@@ -612,12 +774,11 @@ class MLAttention(_Options):
       v = kv[..., nope:]
       with jax.named_scope("attention_core"):
         # The Pallas kernel on a TPU (one forward and ONE backward
-        # kernel; one head size serves queries, keys and values: nope +
-        # rope = v_head_dim here), materialised scores on the CPU
-        # (parallel/sequence.py).
+        # kernel; v at its own head size, which the kernel takes beside
+        # the queries' and keys': v_head_dim may differ from nope +
+        # rope), materialised scores on the CPU (parallel/sequence.py).
         att = sequence_lib.pallas_flash_attention(
-            q, k, v, causal=True, scale=1.0 / math.sqrt(nope + rot),
-            block=min(ATTN_BLOCK, t))
+            q, k, v, causal=True, scale=scale, block=min(ATTN_BLOCK, t))
       return self.dense(c.hidden_size, "o_proj")(
           att.reshape(b, t, h * vd))
 
@@ -839,6 +1000,138 @@ class Mamba2Mixer(_Options):
           INIT_STD / math.sqrt(c.num_hidden_layers)))(y)
 
 
+def sinkhorn(logits, iters: int, eps: float):
+  """Sinkhorn-Knopp over the last two axes of ``exp(logits)``: each
+  iteration divides by the row sums, then by the column sums, ``eps`` in
+  each denominator (mHC's projection onto the doubly stochastic
+  matrices)."""
+  m = jnp.exp(logits)
+  for _ in range(iters):
+    m = m / (jnp.sum(m, -1, keepdims=True) + eps)
+    m = m / (jnp.sum(m, -2, keepdims=True) + eps)
+  return m
+
+
+def sinkhorn_residual(h_res):
+  """The largest |row or column sum - 1| of any matrix in ``h_res``."""
+  return jnp.maximum(jnp.max(jnp.abs(jnp.sum(h_res, -1) - 1)),
+                     jnp.max(jnp.abs(jnp.sum(h_res, -2) - 1)))
+
+
+# What ``h_post``'s sigmoid is multiplied by (mHC: 2 sigmoid, so that a
+# stream's share of a sublayer's output lies in (0, 2)).
+H_POST_SCALE = 2.0
+# ``HyperConnection``'s initial values (not in config.json: the family's
+# assumed ones, benchmarks/configs/xing4.0-29b-a4b.json ``assumed``): the
+# scales of the token-dependent part (Hyper-Connections' small dynamic
+# start), and the spread of the static biases of all three coefficients.
+# A zero start would keep the streams equal copies of the embedding under
+# random weights (every stream the same update), and a mix of equal
+# streams is no mix: drawn biases part them from the first sublayer, as
+# training has in a model that is measured.
+HC_ALPHA_INIT = 0.01
+HC_BIAS_STD = 1.0
+
+
+class HyperConnection(_Options):
+  """The coefficients of ONE sublayer's manifold-constrained
+  hyper-connection, from the ``hc_mult`` streams X (B, T, n, C) in, in
+  float32 throughout (mHC's kernels):
+
+      x^    = RMSNorm(vec(X))                     (no learned scale)
+      z     = x^ phi                              phi (nC, 2n + n^2)
+      h_pre = sigmoid(alpha_pre  z_pre  + b_pre)              (n,)
+      h_post = 2 sigmoid(alpha_post z_post + b_post)          (n,)
+      H_res = Sinkhorn(exp(clamp(alpha_res mat(z_res) + b_res)))  (n, n)
+
+  ``phi`` is one matrix, its columns [pre | post | res] (the row-major
+  n x n of H_res), ``bias`` one vector in the same order, ``alpha`` the
+  three scales (pre, post, res). The norm's statistic multiplies the
+  PRODUCT (mHC's own reordering: x^ phi = r (x phi) for the row's
+  1/rms r); the product runs at HIGHEST precision (on a TPU a float32
+  product otherwise takes bfloat16 passes).
+
+  Returns (h_pre, h_post, H_res), float32. Leaves in ``batch_stats``
+  the largest |row or column sum - 1| of H_res over the step's tokens
+  (``sinkhorn_residual``) and a probe of the first
+  ``ROUTER_PROBE_TOKENS`` tokens: their streams and their three
+  coefficients (a check's way to hold the TIMED program's coefficients
+  to a reference)."""
+
+  @nn.compact
+  def __call__(self, streams):
+    c = self.cfg
+    b, t, n, d = streams.shape
+    cols = 2 * n + n * n
+    phi = self.param("phi", _normal(), (n * d, cols), self.param_dtype)
+
+    bias = self.param("bias", nn.initializers.normal(HC_BIAS_STD), (cols,),
+                      self.param_dtype)
+    alpha = self.param("alpha", nn.initializers.constant(HC_ALPHA_INIT),
+                       (3,), self.param_dtype)
+    probe = min(ROUTER_PROBE_TOKENS, b * t)
+    residual = self.variable("batch_stats", "sinkhorn_residual", jnp.zeros,
+                             (), jnp.float32)
+    probe_in = self.variable("batch_stats", "probe_streams", jnp.zeros,
+                             (probe, n, d), self.dtype)
+    probe_pre = self.variable("batch_stats", "probe_pre", jnp.zeros,
+                              (probe, n), jnp.float32)
+    probe_post = self.variable("batch_stats", "probe_post", jnp.zeros,
+                               (probe, n), jnp.float32)
+    probe_res = self.variable("batch_stats", "probe_res", jnp.zeros,
+                              (probe, n, n), jnp.float32)
+    dt = self.mhc_dtype
+    x = streams.reshape(b, t, n * d).astype(dt)
+    inv_rms = jax.lax.rsqrt(jnp.mean(jnp.square(x.astype(jnp.float32)), -1,
+                                     keepdims=True) + c.rms_norm_eps)
+    z = jnp.dot(x, phi.astype(dt), precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=dt) * inv_rms.astype(dt)
+    alpha, bias = alpha.astype(dt), bias.astype(dt)
+    h_pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
+    h_post = H_POST_SCALE * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] +
+                                           bias[n:2 * n])
+    logits = (alpha[2] * z[..., 2 * n:] + bias[2 * n:]).reshape(b, t, n, n)
+    h_res = sinkhorn(jnp.clip(logits, c.mhc_h_res_clamp_min,
+                              c.mhc_h_res_clamp_max),
+                     c.hc_sinkhorn_iters, c.hc_eps)
+    h_pre, h_post, h_res = (a.astype(jnp.float32)
+                            for a in (h_pre, h_post, h_res))
+    if self.is_mutable_collection("batch_stats") and \
+        not self.is_initializing():
+      residual.value = sinkhorn_residual(h_res)
+      probe_in.value = streams.reshape(b * t, n, d)[:probe].astype(self.dtype)
+      probe_pre.value = h_pre.reshape(b * t, n)[:probe]
+      probe_post.value = h_post.reshape(b * t, n)[:probe]
+      probe_res.value = h_res.reshape(b * t, n, n)[:probe]
+    return h_pre, h_post, h_res
+
+
+def premix(streams, h_pre, dtype):
+  """A sublayer's input: sum_i h_pre[i] X[i], in float32, cast once."""
+  return jnp.sum(h_pre[..., None] * streams.astype(jnp.float32),
+                 -2).astype(dtype)
+
+
+def residual_mix(streams, h_res, h_post, y):
+  """X'[i] = sum_j H_res[i, j] X[j] + h_post[i] y, in float32 as
+  elementwise products (a 4 x 4 mix is no work for the MXU), cast once
+  to the streams' dtype."""
+  x = streams.astype(jnp.float32)
+  mixed = jnp.sum(h_res[..., None] * x[..., None, :, :], -2)
+  return (mixed + h_post[..., None] *
+          y.astype(jnp.float32)[..., None, :]).astype(streams.dtype)
+
+
+def expand_streams(x, n: int):
+  """The embedding copied into ``n`` streams: (B, T, C) -> (B, T, n, C)."""
+  return jnp.broadcast_to(x[..., None, :], x.shape[:-1] + (n, x.shape[-1]))
+
+
+def merge_streams(streams):
+  """The streams summed (float32), before a final norm."""
+  return jnp.sum(streams.astype(jnp.float32), -2)
+
+
 class MixerBlock(_Options):
   """One layer of a one-mixer stack (``nemotron_h``): ``x + mixer(norm(
   x))``, the mixer of the layer's own ``kind``."""
@@ -880,6 +1173,25 @@ class Block(_Options):
     else:
       feed = SwiGLU(width=c.intermediate_size, name="mlp", **self.options())
     normed = lambda name, y: self.norm(name)(y).astype(self.dtype)
+    if c.hc_mult:
+      # ``hc_mult`` streams: each sublayer reads a mix of them and its
+      # output, with the streams themselves, is mixed back (mHC).
+      for hc, sublayer in (
+          ("attn_hc", lambda h: attend(normed("input_layernorm", h),
+                                       tables)),
+          ("mlp_hc", lambda h: feed(normed("post_attention_layernorm", h)))):
+        with jax.named_scope("mhc"):
+          h_pre, h_post, h_res = HyperConnection(
+              name=hc, **self.options())(x)
+          h = premix(x, h_pre, self.dtype)
+        y = sublayer(h)
+        # What a check holds a sublayer to on its own (sown values are
+        # kept only where a caller asks for ``intermediates``).
+        self.sow("intermediates", f"{hc}_in", h)
+        self.sow("intermediates", f"{hc}_out", y)
+        with jax.named_scope("mhc"):
+          x = residual_mix(x, h_res, h_post, y)
+      return x, None
     if c.post_norms:
       # A norm on both sides of each sublayer.
       x = x + normed("post_attention_layernorm",
@@ -913,7 +1225,9 @@ class MLAMoELM(_Options):
     emb = embed(tokens)
     if c.embed_scale != 1.0:
       emb = (emb.astype(jnp.float32) * c.embed_scale).astype(self.dtype)
-    x = emb
+    x = expand_streams(emb, c.hc_mult) if c.hc_mult else emb
+    # The streams are summed before a final norm (Hyper-Connections).
+    merged = merge_streams if c.hc_mult else (lambda streams: streams)
     # RoPE's tables, once a call of the model: every layer reads them.
     tables = rope_tables(c, tokens.shape[1], self.dtype)
     if c.layer_pattern:
@@ -959,8 +1273,8 @@ class MLAMoELM(_Options):
           x, _ = Block(window=window, name=f"layer_{i}",
                        **self.options())(x, tables)
     self.sow("intermediates", "hidden_last", x)
-    h_main = self.norm("norm_f" if c.layer_pattern else "norm")(x).astype(
-        self.dtype)
+    h_main = self.norm("norm_f" if c.layer_pattern else "norm")(
+        merged(x)).astype(self.dtype)
     h_mtp = None
     if c.num_nextn_predict_layers:
       with jax.named_scope("mtp"):
@@ -970,12 +1284,15 @@ class MLAMoELM(_Options):
         # it takes a rolled one and the loss leaves it out.
         nxt = jnp.roll(emb, -1, axis=1)
         joined = jnp.concatenate(
-            [self.norm("mtp_enorm")(nxt), self.norm("mtp_hnorm")(x)],
-            -1).astype(self.dtype)
+            [self.norm("mtp_enorm")(nxt), self.norm("mtp_hnorm")(
+                merged(x))], -1).astype(self.dtype)
         m = self.dense(c.hidden_size, "mtp_eh_proj")(joined)
+        if c.hc_mult:
+          # The MTP block expands its own input and sums its own streams.
+          m = expand_streams(m, c.hc_mult)
         m, _ = block_cls(name="mtp_block", **self.options())(m, tables)
         self.sow("intermediates", "hidden_mtp", m)
-        h_mtp = self.norm("mtp_norm")(m).astype(self.dtype)
+        h_mtp = self.norm("mtp_norm")(merged(m)).astype(self.dtype)
     head = self.param("lm_head", _normal(), (c.hidden_size, c.vocab_rows),
                       self.param_dtype)
     # No logits here: ``hidden`` is the pair (main, MTP or None), and both
@@ -1005,6 +1322,18 @@ MOE_COUNTERS = ("pairs_routed_here", "pairs_dropped", "load_max_over_mean",
                 "compact_layers")
 
 
+def mhc_counters(batch_stats):
+  """The step's hyper-connection counter from ``batch_stats``: the
+  largest |row or column sum - 1| of any sublayer's H_res, as a (1,)
+  float32 vector (``MHC_COUNTERS``)."""
+  leaves = jax.tree_util.tree_flatten_with_path(batch_stats)[0]
+  return jnp.max(jnp.stack([jnp.max(v) for path, v in leaves
+                            if path[-1].key == "sinkhorn_residual"]))[None]
+
+
+MHC_COUNTERS = ("sinkhorn_residual",)
+
+
 class MLAMoELMModel(model_lib.Model):
   """Decoder-only mixture-of-experts LM over synthetic token streams."""
 
@@ -1020,7 +1349,8 @@ class MLAMoELMModel(model_lib.Model):
                    get("lm_layers_held", None), get("lm_layer_shards", 1),
                    get("lm_layer_shard_index", 0),
                    get("lm_first_layer_held", 0),
-                   get("lm_vocab_shards", None))
+                   get("lm_vocab_shards", None),
+                   get("lm_head_shards", 1))
     self._cfg = None
     self.scanned_param_prefixes = ("layers",)
 
@@ -1032,8 +1362,12 @@ class MLAMoELMModel(model_lib.Model):
     return self.learning_rate * jnp.minimum(1.0, (step + 1) / WARMUP_STEPS)
 
   def step_counters(self, batch_stats):
-    """train_step.make_step_fns: counters that ride the step's metrics."""
-    return moe_counters(batch_stats, self.cfg)
+    """train_step.make_step_fns: counters that ride the step's metrics
+    (``MOE_COUNTERS``, then ``MHC_COUNTERS`` where there are streams)."""
+    counters = moe_counters(batch_stats, self.cfg)
+    if self.cfg.hc_mult:
+      counters = jnp.concatenate([counters, mhc_counters(batch_stats)])
+    return counters
 
   @property
   def cfg(self) -> LMConfig:
@@ -1050,6 +1384,14 @@ class MLAMoELMModel(model_lib.Model):
           "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
           "buffer_rows": self._round_rows(c),
           "expert_matrices": c.expert_matrices})
+      if c.hc_mult:
+        mhc = self.mhc_stats()
+        trace.set_static("mhc", mhc)
+        log_util.log_fn(
+            "mhc: {streams} residual streams of {hidden_size}, "
+            "{sublayers} sublayers behind a hyper-connection, Sinkhorn "
+            "{sinkhorn_iters} iterations (eps {eps}); {stream_bytes} bytes "
+            "of streams a sublayer".format(**mhc))
       core = self.attention_core_stats()
       trace.set_static("attention", core)
       # One kind of core: its fields; two: a table by kind.
@@ -1075,10 +1417,13 @@ class MLAMoELMModel(model_lib.Model):
               if c.layer_pattern else
               f"{c.dense_layers} dense, {c.moe_layers} mixture, "
               f"{c.num_nextn_predict_layers} MTP module(s)")
+      heads = (f"heads {c.first_head}-{c.first_head + c.heads_held - 1} of "
+               f"{c.num_attention_heads} (of {c.head_shards} over the "
+               "heads); " if c.head_shards > 1 else "")
       log_util.log_fn(
           f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
           f"layers ({c.first_layer}-{last}: {held}); chip "
-          f"{c.shard_index} of {c.shards} per layer: experts "
+          f"{c.shard_index} of {c.shards} per layer: {heads}experts "
           f"{c.first_expert}-{c.first_expert + c.experts_held - 1} of "
           f"{c.n_routed_experts} ({c.expert_matrices} matrices an expert); "
           f"of {c.vocab_shards} over the vocabulary: rows "
@@ -1125,6 +1470,25 @@ class MLAMoELMModel(model_lib.Model):
             tiles_visited=sequence_lib.plan_tiles(t, t, plan, window),
             tiles_causal=sequence_lib.plan_tiles(t, t, plan))
     return out
+
+  def mhc_stats(self):
+    """The run's static ``stats["mhc"]``: the streams, the sublayers
+    behind a hyper-connection and the bytes of the streams one sublayer
+    reads (what ``mhc_counter_stats`` adds to over the timed steps)."""
+    c = self.cfg
+    tokens = self.get_batch_size() * self.seq_len
+    return {"streams": c.hc_mult, "hidden_size": c.hidden_size,
+            "sublayers": c.sublayers, "sinkhorn_iters": c.hc_sinkhorn_iters,
+            "eps": c.hc_eps, "stream_bytes": tokens * c.hc_mult *
+            c.hidden_size * 2}
+
+  def mhc_counter_stats(self, rows):
+    """``rows`` (steps, counters) of ``step_counters`` as what the timed
+    steps add to ``stats["mhc"]``: the largest Sinkhorn residual of any
+    step (None where the configuration has one stream)."""
+    if not self.cfg.hc_mult:
+      return None
+    return {"sinkhorn_residual": float(rows[:, len(MOE_COUNTERS)].max())}
 
   def counter_stats(self, rows):
     """``rows`` (steps, len(MOE_COUNTERS)) of ``moe_counters`` as the

@@ -13,7 +13,10 @@ input's dtype (``x`` is a projection's output, (B, T, H, D)):
 * optionally a factor (the scores' scale where it is no power of two),
 * RoPE over the trailing ``rot_dims`` dimensions of each head, pairing
   dimension i with i + rot_dims/2 (``rotate_half``), the leading ones
-  passing through: ``n * cos + rotate_half(n) * sin``.
+  passing through: ``n * cos + rotate_half(n) * sin``. The angles are
+  plain RoPE's or, where a configuration states YaRN (``Yarn``), YaRN's
+  blend of RoPE's frequencies with those divided by its factor: the
+  stage's arithmetic does not change, its tables do.
 
 ``rotate_half`` never slices the lane axis into halves: it is a lane
 rotation of the whole row, and its sign lives in the sine table (the
@@ -44,10 +47,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _LANES = 128
 # What one grid step of the kernel moves: its input and output blocks
@@ -62,6 +67,47 @@ _MAX_BLOCK_ROWS = 1024
 # trinity-mini cell's set-up: PERF.md section 6, PR 38).
 _MAX_BLOCK_HEADS = 8
 _VMEM_LIMIT_BYTES = 48 * 2 ** 20
+
+
+class Yarn(NamedTuple):
+  """YaRN's frequency scaling as a configuration's ``rope_scaling``
+  states it (DeepSeek-V3's form: arXiv:2309.00071, and the release's
+  ``DeepseekV3YarnRotaryEmbedding``)."""
+  factor: float
+  beta_fast: float
+  beta_slow: float
+  original_max_position: int
+  mscale: float
+  mscale_all_dim: float
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+  """YaRN's attention temperature: 0.1 x mscale x ln(factor) + 1 (1 at a
+  factor of 1 or less)."""
+  return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(rot_dims: int, theta: float, yarn: Yarn) -> np.ndarray:
+  """The inverse frequency of each of the rot_dims/2 pairs under YaRN:
+  ``(1 - m_i) theta^(-2i/R) / factor + m_i theta^(-2i/R)``, m_i = 1 -
+  clip((i - low) / (high - low), 0, 1); low and high the pairs that turn
+  ``beta_fast`` and ``beta_slow`` times over ``original_max_position``
+  positions (floor and ceiling, clipped to the pairs). float32, as the
+  release computes it."""
+  half = rot_dims // 2
+  def pair_of(turns):
+    return (rot_dims * math.log(yarn.original_max_position /
+                                (turns * 2 * math.pi)) /
+            (2 * math.log(theta)))
+  low = max(math.floor(pair_of(yarn.beta_fast)), 0)
+  high = min(math.ceil(pair_of(yarn.beta_slow)), rot_dims - 1)
+  if low == high:
+    high += 0.001
+  i = np.arange(half, dtype=np.float32)
+  extra = (1.0 / theta ** (2 * i / rot_dims)).astype(np.float32)
+  m = 1 - np.clip((i - low) / (high - low), 0, 1).astype(np.float32)
+  return ((1 - m) * (extra / np.float32(yarn.factor)) + m * extra).astype(
+      np.float32)
 
 
 class Tables(NamedTuple):
@@ -112,26 +158,38 @@ def rotary_plan(seq_len: int, heads: int, head_dim: int, rot_dims: int,
 
 
 def tables(seq_len: int, rot_dims: int, theta: float,
-           width: int) -> Optional[Tables]:
+           width: int, yarn: Optional[Yarn] = None) -> Optional[Tables]:
   """cos and SIGNED sin of RoPE's angles for positions 0..seq_len-1 over
   the trailing ``width`` dimensions of a head, of which the last
   ``rot_dims`` are rotated (angles in float32, position x
-  theta^(-2i / rot_dims) for the pair (i, i + rot_dims/2)). The leading
+  theta^(-2i / rot_dims) for the pair (i, i + rot_dims/2), or YaRN's
+  frequency of the pair, ``yarn_inv_freq``). The leading
   ``width - rot_dims`` pass through: cos 1, sin 0. The sine's sign is
   ``rotate_half``'s: minus on the first half of the rotated dimensions.
   Every entry is computed in place from its own index: no half is built
-  and joined. None where nothing is rotated."""
+  and joined. None where nothing is rotated. Under YaRN cos and sin are
+  also scaled by mscale / mscale_all_dim where that is not 1."""
   if not rot_dims:
     return None
   lane = jnp.arange(width, dtype=jnp.int32) - (width - rot_dims)
   half = rot_dims // 2
-  pair = (2 * (lane % half)).astype(jnp.float32)
-  inv_freq = 1.0 / (theta ** (pair / rot_dims))
+  if yarn is None:
+    pair = (2 * (lane % half)).astype(jnp.float32)
+    inv_freq = 1.0 / (theta ** (pair / rot_dims))
+  else:
+    inv_freq = jnp.asarray(yarn_inv_freq(rot_dims, theta, yarn))[lane % half]
   ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq[None]
   rotated = (lane >= 0)[None]
-  cos = jnp.where(rotated, jnp.cos(ang), 1.0)
-  sin = jnp.where(rotated, jnp.where((lane < half)[None], -jnp.sin(ang),
-                                     jnp.sin(ang)), 0.0)
+  cos_of, sin_of = jnp.cos, jnp.sin
+  ratio = 1.0 if yarn is None else (
+      yarn_mscale(yarn.factor, yarn.mscale) /
+      yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+  if ratio != 1.0:
+    cos_of = lambda a: jnp.cos(a) * ratio
+    sin_of = lambda a: jnp.sin(a) * ratio
+  cos = jnp.where(rotated, cos_of(ang), 1.0)
+  sin = jnp.where(rotated, jnp.where((lane < half)[None], -sin_of(ang),
+                                     sin_of(ang)), 0.0)
   return Tables(cos, sin)
 
 
@@ -458,12 +516,12 @@ def rotary_stage(x, tabs: Optional[Tables] = None, scale=None, *,
 
 
 def stage_tables(shape, rot_dims: int, theta: float, normed: bool,
-                 dtype) -> Optional[Tables]:
+                 dtype, yarn: Optional[Yarn] = None) -> Optional[Tables]:
   """The tables ``rotary_stage`` wants for an x of ``shape`` (B, T, H, D)
   and ``dtype``: ``tables`` at its plan's width."""
   _, t, heads, head_dim = shape
   return tables(t, rot_dims, theta, rotary_plan(
-      t, heads, head_dim, rot_dims, normed, dtype).table_width)
+      t, heads, head_dim, rot_dims, normed, dtype).table_width, yarn)
 
 
 def stage_stats(batch: int, seq_len: int, heads: int, head_dim: int,

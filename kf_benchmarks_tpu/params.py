@@ -163,6 +163,17 @@ flags.DEFINE_integer("lm_vocab_shards", None,
                      "whose experts lie over 16 chips may keep the "
                      "vocabulary over 8 of them. None = "
                      "--lm_layer_shards.", lower_bound=1)
+flags.DEFINE_integer("lm_head_shards", None,
+                     "mla_moe_lm: over how many chips latent attention's "
+                     "HEADS are divided (tensor parallelism): this job "
+                     "holds heads num_attention_heads / n x "
+                     "--lm_layer_shard_index on, and q_b_proj, kv_b_proj "
+                     "and o_proj hold those heads alone; the down-"
+                     "projections and their norms whole. It runs WITHOUT "
+                     "the reduction of o_proj's partial sums: the absent "
+                     "heads' part is left out. Latent attention alone "
+                     "(validation.py). None = 1, the heads whole.",
+                     lower_bound=1)
 flags.DEFINE_integer("num_batches", None,
                      "Number of timed batches to run (ref :137-139).")
 flags.DEFINE_float("num_epochs", None,

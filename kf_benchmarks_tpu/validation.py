@@ -308,7 +308,8 @@ def validate_cross_flags(params) -> None:
           "the K/V axis in whole blocks)")
   lm_flags = [f for f in ("seq_len", "lm_config", "lm_layers_held",
                           "lm_first_layer_held", "lm_layer_shards",
-                          "lm_layer_shard_index", "lm_vocab_shards")
+                          "lm_layer_shard_index", "lm_vocab_shards",
+                          "lm_head_shards")
               if getattr(p, f, None) is not None]
   if p.model != "mla_moe_lm":
     if lm_flags:
@@ -372,6 +373,11 @@ def validate_cross_flags(params) -> None:
                          _moe_lm.DEFAULT_SEQ_LEN, raw["chunk_size"])
       if why:
         raise ParamError(f"--seq_len with --lm_config={p.lm_config}: {why}")
+    # Heads divide in latent attention alone, and into whole heads.
+    why = raw and _moe_lm.head_share_refusal(
+        raw, getattr(p, "lm_head_shards", None) or 1)
+    if why:
+      raise ParamError(f"{why} (--lm_config={p.lm_config})")
   mesh_shape = getattr(p, "mesh_shape", None)
   sharded = bool(getattr(p, "shard_optimizer_state", False))
   if mesh_shape:

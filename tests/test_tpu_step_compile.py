@@ -411,3 +411,59 @@ def test_ssd_scan_kernels_on_the_tpu_compiler(topo, monkeypatch):
   assert not re.search(r"\[1,64,8,8,128,128\]|\[64,8,8,128,128\]", text)
   assert "f32[1024,8,8,512]" not in text
   assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_xing_core_and_hyper_connection_on_the_tpu_compiler(topo):
+  """What the xing4.0-29b-a4b cell adds at its real widths, forward and
+  backward, on the chip's own compiler (PR 42): the attention core with
+  v at its OWN head size beside q's and k's (4 heads of 192 and 128,
+  4,096 positions: ONE forward and ONE backward kernel, nothing padded
+  to one size), and one sublayer's hyper-connection around a stand-in
+  sublayer (4 streams of 3,584: the float32 coefficients with their
+  product at HIGHEST precision, 20 Sinkhorn-Knopp iterations, the
+  pre-mix and the residual mix), in under 2 GB of temporaries (the
+  streams alone are 117 MB in bfloat16)."""
+  import jax
+  from jax.sharding import SingleDeviceSharding
+  from kf_benchmarks_tpu.models import mla_moe_lm
+  from kf_benchmarks_tpu.parallel import sequence as sequence_lib
+  one = SingleDeviceSharding(topo.devices[0])
+  sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+  tokens = 4096
+
+  def core(q, k, v):
+    return jnp.sum(sequence_lib.pallas_flash_attention(
+        q, k, v, causal=True, scale=1.0, block=mla_moe_lm.ATTN_BLOCK,
+        cpu_fallback=False).astype(jnp.float32))
+  qk = sds((1, tokens, 4, 192), jnp.bfloat16)
+  text = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
+      qk, qk, sds((1, tokens, 4, 128), jnp.bfloat16)).compile().as_text()
+  assert text.count('custom_call_target="tpu_custom_call"') == 2
+  assert "splash_mha_fwd_residuals" in text
+  assert "splash_mha_dkv_no_residuals" in text
+  # v and its gradient stay at 128: no copy of v padded to q's 192.
+  assert f"bf16[1,{tokens},4,128]" in text
+  assert not re.search(rf"bf16\[1,{tokens},4,(256|320|384)\]", text)
+
+  cfg = mla_moe_lm.load_lm_config("xing4.0-29b-a4b", 5, 8, 0, 1,
+                                  head_shards=8)
+  module = mla_moe_lm.HyperConnection(cfg=cfg, dtype=jnp.bfloat16)
+  streams = sds((1, tokens, 4, 3584), jnp.bfloat16)
+  variables = jax.eval_shape(lambda s: module.init(
+      {"params": jax.random.PRNGKey(0)}, s), jax.ShapeDtypeStruct(
+          streams.shape, streams.dtype))
+  params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                        variables["params"])
+  stats = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                       variables["batch_stats"])
+
+  def sublayer(p, s, x, w):
+    (pre, post, res), _ = module.apply({"params": p, "batch_stats": s}, x,
+                                       mutable=["batch_stats"])
+    h = mla_moe_lm.premix(x, pre, jnp.bfloat16)
+    y = jnp.dot(h, w)
+    return jnp.sum(mla_moe_lm.residual_mix(x, res, post, y).astype(
+        jnp.float32))
+  compiled = jax.jit(jax.grad(sublayer, argnums=(0, 2, 3))).lower(
+      params, stats, streams, sds((3584, 3584), jnp.bfloat16)).compile()
+  assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
