@@ -65,12 +65,14 @@ hyper-connections; each other ONE departure from the published layer):
 
 * ``mhc_bf16``: the hyper-connections' coefficient path (norm statistic,
   the product with phi, sigmoids, Sinkhorn-Knopp) in bfloat16, the
-  nearest precision below the float32 the configuration states. Fails
-  ``hc_pre_err``, ``hc_post_err`` and ``hc_res_err``.
+  nearest precision below the float32 the configuration states (which
+  ``mhc.mhc_plan`` sends to ``jax.numpy``). Fails ``hc_pre_err``,
+  ``hc_post_err`` and ``hc_res_err``.
 * ``sinkhorn_1``: ONE Sinkhorn-Knopp iteration in place of 20. Fails
   ``hc_res_err`` and ``sinkhorn_residual``.
 * ``h_res_identity``: the residual mix H_res replaced by the identity
-  (Sinkhorn's output ignored). Fails ``hc_res_err`` and
+  (Sinkhorn's output ignored; the plan sent to ``jax.numpy``, whose
+  ``sinkhorn`` is the one replaced). Fails ``hc_res_err`` and
   ``layer_output_err``.
 * ``h_post_no_2``: h_post without its factor 2. Fails ``hc_post_err``.
 * ``no_yarn``: plain RoPE and the plain scale 1/sqrt(192) in place of
@@ -186,9 +188,7 @@ def plant(fault, setattr_=setattr):
              dataclasses.replace(load(*a, **k),
                                  expert_activation="self_gated"))
   elif fault == "mhc_bf16":
-    make = model.make_module
-    setattr_(model, "make_module", lambda self, *a, **k: make(
-        self, *a, **k).clone(mhc_dtype=jnp.bfloat16))
+    setattr_(model, "mhc_dtype", jnp.bfloat16)
   elif fault in ("sinkhorn_1", "no_yarn"):
     load = mla_moe_lm.load_lm_config
     change = (dict(hc_sinkhorn_iters=1) if fault == "sinkhorn_1"
@@ -196,11 +196,16 @@ def plant(fault, setattr_=setattr):
     setattr_(mla_moe_lm, "load_lm_config", lambda *a, **k:
              dataclasses.replace(load(*a, **k), **change))
   elif fault == "h_res_identity":
-    setattr_(mla_moe_lm, "sinkhorn", lambda logits, iters, eps:
+    from kf_benchmarks_tpu.ops import mhc
+    plan = mhc.mhc_plan
+    setattr_(mhc, "sinkhorn", lambda logits, iters, eps:
              jnp.broadcast_to(jnp.eye(logits.shape[-1], dtype=logits.dtype),
                               logits.shape))
+    setattr_(mhc, "mhc_plan", lambda *a, **k: dataclasses.replace(
+        plan(*a, **k), implementation="xla"))
   elif fault == "h_post_no_2":
-    setattr_(mla_moe_lm, "H_POST_SCALE", 1.0)
+    from kf_benchmarks_tpu.ops import mhc
+    setattr_(mhc, "H_POST_SCALE", 1.0)
   elif fault == "state_unchanged":
     def get(self):
       step = self.__dict__.get("timed_step")

@@ -478,8 +478,9 @@ NON_METRIC_KEYS = frozenset({
     "mamba",
     # PR 42: the residual streams' hyper-connections as models/
     # mla_moe_lm.py states them ({streams, hidden_size, sublayers,
-    # sinkhorn_iters, eps, stream_bytes} at the build, sinkhorn_residual
-    # over the timed steps): None for every other model.
+    # sinkhorn_iters, eps, stream_bytes, implementation, token_block,
+    # kernel_share; ops/mhc.mhc_stats)} at the build,
+    # sinkhorn_residual over the timed steps): None for every other model.
     "mhc",
 })
 

@@ -103,7 +103,9 @@ The layer (RMSNorm, no biases but the state-space convolution's):
   H_res[i, j] X[j] + h_post[i] y``. The embedding is copied into the
   streams, which are summed before the final norm and before the MTP
   module's ``mtp_hnorm``; the MTP block expands and sums its own. The
-  scanned stack's carry is (B, T, n, C);
+  scanned stack's carry is (B, T, n, C). The arithmetic is
+  ``ops/mhc.py``'s (``mhc_pre``, ``mhc_post``: two Pallas kernels each
+  way on a TPU, ``mhc.mhc_plan``'s choice);
 * YaRN (``rope_scaling`` of type ``yarn``, ``xing4_0``): the rotary
   stage's tables at YaRN's frequencies, and the scores' scale times its
   temperature squared, folded into q's float32 pass;
@@ -202,6 +204,7 @@ import flax.linen as nn
 
 from kf_benchmarks_tpu.models import model as model_lib
 from kf_benchmarks_tpu.ops import fused_loss as fused_loss_lib
+from kf_benchmarks_tpu.ops import mhc
 from kf_benchmarks_tpu.ops import rotary
 from kf_benchmarks_tpu.ops import ssd
 from kf_benchmarks_tpu.parallel import expert as expert_lib
@@ -1000,27 +1003,6 @@ class Mamba2Mixer(_Options):
           INIT_STD / math.sqrt(c.num_hidden_layers)))(y)
 
 
-def sinkhorn(logits, iters: int, eps: float):
-  """Sinkhorn-Knopp over the last two axes of ``exp(logits)``: each
-  iteration divides by the row sums, then by the column sums, ``eps`` in
-  each denominator (mHC's projection onto the doubly stochastic
-  matrices)."""
-  m = jnp.exp(logits)
-  for _ in range(iters):
-    m = m / (jnp.sum(m, -1, keepdims=True) + eps)
-    m = m / (jnp.sum(m, -2, keepdims=True) + eps)
-  return m
-
-
-def sinkhorn_residual(h_res):
-  """The largest |row or column sum - 1| of any matrix in ``h_res``."""
-  return jnp.maximum(jnp.max(jnp.abs(jnp.sum(h_res, -1) - 1)),
-                     jnp.max(jnp.abs(jnp.sum(h_res, -2) - 1)))
-
-
-# What ``h_post``'s sigmoid is multiplied by (mHC: 2 sigmoid, so that a
-# stream's share of a sublayer's output lies in (0, 2)).
-H_POST_SCALE = 2.0
 # ``HyperConnection``'s initial values (not in config.json: the family's
 # assumed ones, benchmarks/configs/xing4.0-29b-a4b.json ``assumed``): the
 # scales of the token-dependent part (Hyper-Connections' small dynamic
@@ -1034,25 +1016,18 @@ HC_BIAS_STD = 1.0
 
 
 class HyperConnection(_Options):
-  """The coefficients of ONE sublayer's manifold-constrained
-  hyper-connection, from the ``hc_mult`` streams X (B, T, n, C) in, in
-  float32 throughout (mHC's kernels):
+  """The front half of ONE sublayer's manifold-constrained
+  hyper-connection, from the ``hc_mult`` streams X (B, T, n, C): the
+  sublayer's input h and the coefficients, float32 (``ops/mhc.py`` has
+  the arithmetic, ``mhc.mhc_pre``: two Pallas kernels on a TPU).
 
-      x^    = RMSNorm(vec(X))                     (no learned scale)
-      z     = x^ phi                              phi (nC, 2n + n^2)
-      h_pre = sigmoid(alpha_pre  z_pre  + b_pre)              (n,)
-      h_post = 2 sigmoid(alpha_post z_post + b_post)          (n,)
-      H_res = Sinkhorn(exp(clamp(alpha_res mat(z_res) + b_res)))  (n, n)
+  ``phi`` (nC, 2n + n^2) is one matrix, its columns [pre | post | res]
+  (the row-major n x n of H_res), ``bias`` one vector in the same order,
+  ``alpha`` the three scales (pre, post, res).
 
-  ``phi`` is one matrix, its columns [pre | post | res] (the row-major
-  n x n of H_res), ``bias`` one vector in the same order, ``alpha`` the
-  three scales (pre, post, res). The norm's statistic multiplies the
-  PRODUCT (mHC's own reordering: x^ phi = r (x phi) for the row's
-  1/rms r); the product runs at HIGHEST precision (on a TPU a float32
-  product otherwise takes bfloat16 passes).
-
-  Returns (h_pre, h_post, H_res), float32. Leaves in ``batch_stats``
-  the largest |row or column sum - 1| of H_res over the step's tokens
+  Returns ``mhc.Premixed``: h, h_pre, h_post, H_res and the streams as
+  ``mhc.mhc_post`` takes them. Leaves in ``batch_stats`` the
+  largest |row or column sum - 1| of H_res over the step's tokens
   (``sinkhorn_residual``) and a probe of the first
   ``ROUTER_PROBE_TOKENS`` tokens: their streams and their three
   coefficients (a check's way to hold the TIMED program's coefficients
@@ -1080,46 +1055,18 @@ class HyperConnection(_Options):
                                (probe, n), jnp.float32)
     probe_res = self.variable("batch_stats", "probe_res", jnp.zeros,
                               (probe, n, n), jnp.float32)
-    dt = self.mhc_dtype
-    x = streams.reshape(b, t, n * d).astype(dt)
-    inv_rms = jax.lax.rsqrt(jnp.mean(jnp.square(x.astype(jnp.float32)), -1,
-                                     keepdims=True) + c.rms_norm_eps)
-    z = jnp.dot(x, phi.astype(dt), precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=dt) * inv_rms.astype(dt)
-    alpha, bias = alpha.astype(dt), bias.astype(dt)
-    h_pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
-    h_post = H_POST_SCALE * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] +
-                                           bias[n:2 * n])
-    logits = (alpha[2] * z[..., 2 * n:] + bias[2 * n:]).reshape(b, t, n, n)
-    h_res = sinkhorn(jnp.clip(logits, c.mhc_h_res_clamp_min,
-                              c.mhc_h_res_clamp_max),
-                     c.hc_sinkhorn_iters, c.hc_eps)
-    h_pre, h_post, h_res = (a.astype(jnp.float32)
-                            for a in (h_pre, h_post, h_res))
+    out = mhc.mhc_pre(streams, phi, bias, alpha, mhc.Mixing(
+        c.hc_sinkhorn_iters, c.hc_eps,
+        (c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max), c.rms_norm_eps,
+        self.mhc_dtype), self.dtype)
     if self.is_mutable_collection("batch_stats") and \
         not self.is_initializing():
-      residual.value = sinkhorn_residual(h_res)
+      residual.value = mhc.sinkhorn_residual(out.h_res)
       probe_in.value = streams.reshape(b * t, n, d)[:probe].astype(self.dtype)
-      probe_pre.value = h_pre.reshape(b * t, n)[:probe]
-      probe_post.value = h_post.reshape(b * t, n)[:probe]
-      probe_res.value = h_res.reshape(b * t, n, n)[:probe]
-    return h_pre, h_post, h_res
-
-
-def premix(streams, h_pre, dtype):
-  """A sublayer's input: sum_i h_pre[i] X[i], in float32, cast once."""
-  return jnp.sum(h_pre[..., None] * streams.astype(jnp.float32),
-                 -2).astype(dtype)
-
-
-def residual_mix(streams, h_res, h_post, y):
-  """X'[i] = sum_j H_res[i, j] X[j] + h_post[i] y, in float32 as
-  elementwise products (a 4 x 4 mix is no work for the MXU), cast once
-  to the streams' dtype."""
-  x = streams.astype(jnp.float32)
-  mixed = jnp.sum(h_res[..., None] * x[..., None, :, :], -2)
-  return (mixed + h_post[..., None] *
-          y.astype(jnp.float32)[..., None, :]).astype(streams.dtype)
+      probe_pre.value = out.h_pre.reshape(b * t, n)[:probe]
+      probe_post.value = out.h_post.reshape(b * t, n)[:probe]
+      probe_res.value = out.h_res.reshape(b * t, n, n)[:probe]
+    return out
 
 
 def expand_streams(x, n: int):
@@ -1181,16 +1128,14 @@ class Block(_Options):
                                        tables)),
           ("mlp_hc", lambda h: feed(normed("post_attention_layernorm", h)))):
         with jax.named_scope("mhc"):
-          h_pre, h_post, h_res = HyperConnection(
-              name=hc, **self.options())(x)
-          h = premix(x, h_pre, self.dtype)
-        y = sublayer(h)
+          pre = HyperConnection(name=hc, **self.options())(x)
+        y = sublayer(pre.h)
         # What a check holds a sublayer to on its own (sown values are
         # kept only where a caller asks for ``intermediates``).
-        self.sow("intermediates", f"{hc}_in", h)
+        self.sow("intermediates", f"{hc}_in", pre.h)
         self.sow("intermediates", f"{hc}_out", y)
         with jax.named_scope("mhc"):
-          x = residual_mix(x, h_res, h_post, y)
+          x = mhc.mhc_post(pre, y)
       return x, None
     if c.post_norms:
       # A norm on both sides of each sublayer.
@@ -1337,6 +1282,11 @@ MHC_COUNTERS = ("sinkhorn_residual",)
 class MLAMoELMModel(model_lib.Model):
   """Decoder-only mixture-of-experts LM over synthetic token streams."""
 
+  # The hyper-connections' coefficient dtype (``_Options.mhc_dtype``), read
+  # by the module and by ``stats["mhc"]`` alike; bfloat16 is the check's
+  # lower-precision control.
+  mhc_dtype = jnp.float32
+
   def __init__(self, params=None):
     # bfloat16 needs no loss scale.
     super().__init__("mla_moe_lm", batch_size=2,
@@ -1384,14 +1334,6 @@ class MLAMoELMModel(model_lib.Model):
           "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
           "buffer_rows": self._round_rows(c),
           "expert_matrices": c.expert_matrices})
-      if c.hc_mult:
-        mhc = self.mhc_stats()
-        trace.set_static("mhc", mhc)
-        log_util.log_fn(
-            "mhc: {streams} residual streams of {hidden_size}, "
-            "{sublayers} sublayers behind a hyper-connection, Sinkhorn "
-            "{sinkhorn_iters} iterations (eps {eps}); {stream_bytes} bytes "
-            "of streams a sublayer".format(**mhc))
       core = self.attention_core_stats()
       trace.set_static("attention", core)
       # One kind of core: its fields; two: a table by kind.
@@ -1471,16 +1413,41 @@ class MLAMoELMModel(model_lib.Model):
             tiles_causal=sequence_lib.plan_tiles(t, t, plan))
     return out
 
-  def mhc_stats(self):
+  def mhc_stats(self, dtype):
     """The run's static ``stats["mhc"]``: the streams, the sublayers
-    behind a hyper-connection and the bytes of the streams one sublayer
-    reads (what ``mhc_counter_stats`` adds to over the timed steps)."""
+    behind a hyper-connection, the bytes of the streams one sublayer
+    reads and what the mixing runs at this job's shapes
+    (``mhc.mhc_stats``: the plan, from the shapes, the streams' and the
+    coefficients' dtypes and the backend); what ``mhc_counter_stats``
+    adds to over the timed steps. None where the configuration has one
+    stream."""
     c = self.cfg
+    if not c.hc_mult:
+      return None
     tokens = self.get_batch_size() * self.seq_len
-    return {"streams": c.hc_mult, "hidden_size": c.hidden_size,
-            "sublayers": c.sublayers, "sinkhorn_iters": c.hc_sinkhorn_iters,
-            "eps": c.hc_eps, "stream_bytes": tokens * c.hc_mult *
-            c.hidden_size * 2}
+    return dict({"streams": c.hc_mult, "hidden_size": c.hidden_size,
+                 "sublayers": c.sublayers,
+                 "sinkhorn_iters": c.hc_sinkhorn_iters, "eps": c.hc_eps,
+                 "stream_bytes": tokens * c.hc_mult * c.hidden_size *
+                 jnp.dtype(dtype).itemsize},
+                **mhc.mhc_stats(tokens, c.hc_mult, c.hidden_size, dtype,
+                                self.mhc_dtype))
+
+  def _state_mhc(self, dtype):
+    """``stats["mhc"]`` and its log line, stated like the combine: where
+    the module's type is known, and once."""
+    from kf_benchmarks_tpu import tracing
+    from kf_benchmarks_tpu.utils import log as log_util
+    streams, trace = self.mhc_stats(dtype), tracing.active()
+    if streams is None or trace.static("mhc") == streams:
+      return
+    trace.set_static("mhc", streams)
+    log_util.log_fn(
+        "mhc: {streams} residual streams of {hidden_size}, "
+        "{sublayers} sublayers behind a hyper-connection, Sinkhorn "
+        "{sinkhorn_iters} iterations (eps {eps}); {stream_bytes} bytes "
+        "of streams a sublayer; {implementation} (kernel share "
+        "{kernel_share})".format(**streams))
 
   def mhc_counter_stats(self, rows):
     """``rows`` (steps, counters) of ``step_counters`` as what the timed
@@ -1513,8 +1480,10 @@ class MLAMoELMModel(model_lib.Model):
     self._state_lm_head(dtype)
     self._state_rotary(dtype)
     self._state_mamba(dtype)
+    self._state_mhc(dtype)
     return MLAMoELM(cfg=self.cfg, dtype=dtype, param_dtype=param_dtype,
-                    moe_impl="gmm" if on_tpu else "ragged_dot")
+                    moe_impl="gmm" if on_tpu else "ragged_dot",
+                    mhc_dtype=self.mhc_dtype)
 
   def _round_rows(self, c: LMConfig) -> int:
     """The sorted rows of one round of the routed path at this job's

@@ -31,6 +31,7 @@ import pytest
 
 from kf_benchmarks_tpu.models import mla_moe_lm as lm
 from kf_benchmarks_tpu.models import model as model_lib
+from kf_benchmarks_tpu.ops import mhc
 from kf_benchmarks_tpu.ops import rotary
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -225,14 +226,14 @@ def test_sinkhorn_rows_and_columns_sum_to_one():
   # Logits of the spread the residual mix starts from (its static bias,
   # ``HC_RES_BIAS_STD``).
   logits = jax.random.normal(jax.random.PRNGKey(0), (64, 4, 4))
-  m = lm.sinkhorn(logits, 20, 1e-6)
+  m = mhc.sinkhorn(logits, 20, 1e-6)
   assert float(jnp.min(m)) > 0
   # The last step divides by the column sums: they are 1 within eps; the
   # rows within what 20 iterations leave.
   assert float(jnp.max(jnp.abs(jnp.sum(m, -2) - 1))) <= 1e-6 + 1e-6
-  assert float(lm.sinkhorn_residual(m)) < 1e-4
+  assert float(mhc.sinkhorn_residual(m)) < 1e-4
   # One iteration leaves rows far from 1: the check's fault reads there.
-  assert float(lm.sinkhorn_residual(lm.sinkhorn(logits, 1, 1e-6))) > 1e-2
+  assert float(mhc.sinkhorn_residual(mhc.sinkhorn(logits, 1, 1e-6))) > 1e-2
 
 
 def test_sinkhorn_gradient_against_finite_differences():
@@ -241,7 +242,7 @@ def test_sinkhorn_gradient_against_finite_differences():
   with jax.enable_x64(True):
     logits = jax.random.normal(jax.random.PRNGKey(1), (4, 4), jnp.float64)
     w = jax.random.normal(jax.random.PRNGKey(2), (4, 4), jnp.float64)
-    f = jax.jit(lambda z: jnp.sum(w * lm.sinkhorn(z, 20, 1e-6)))
+    f = jax.jit(lambda z: jnp.sum(w * mhc.sinkhorn(z, 20, 1e-6)))
     grad = np.asarray(jax.grad(f)(logits))
     h = 1e-6
     numeric = np.zeros((4, 4))
@@ -314,8 +315,7 @@ def test_head_and_expert_shares_add_up_to_the_uncut_layer():
       lambda a: a[None], params)), x, stats["mlp"]["select_bias"])[0]
   hc = lambda name, x: lm.HyperConnection(cfg=whole).apply(
       {"params": params[name], "batch_stats": stats[name]}, x)
-  h_pre, h_post, h_res = hc("attn_hc", x)
-  h = lm.premix(x, h_pre, jnp.float32)
+  h, h_pre, h_post, h_res, *_ = hc("attn_hc", x)
   normed = lm.RMSNorm(whole.rms_norm_eps).apply(
       {"params": params["input_layernorm"]}, h)
   attn = params["self_attn"]
@@ -332,11 +332,10 @@ def test_head_and_expert_shares_add_up_to_the_uncut_layer():
                 o_proj={"kernel": attn["o_proj"]["kernel"].reshape(
                     heads, cfg.v_head_dim, -1)[index]})
     y = y + lm.MLAttention(cfg=cfg).apply({"params": part}, normed)
-  x = lm.residual_mix(x, h_res, h_post, y)
-  h_pre, h_post, h_res = hc("mlp_hc", x)
+  x = mhc.residual_mix(x, h_res, h_post, y)
+  h, h_pre, h_post, h_res, *_ = hc("mlp_hc", x)
   normed = lm.RMSNorm(whole.rms_norm_eps).apply(
-      {"params": params["post_attention_layernorm"]},
-      lm.premix(x, h_pre, jnp.float32))
+      {"params": params["post_attention_layernorm"]}, h)
   mlp, mstats = params["mlp"], {"select_bias": stats["mlp"]["select_bias"]}
   layer = ref_layer(whole, "layers", jax.tree.map(lambda a: a[None], params))
   shared = ref.swiglu(layer["mlp"]["shared"], normed.reshape(
@@ -351,7 +350,7 @@ def test_head_and_expert_shares_add_up_to_the_uncut_layer():
         {"params": part, "batch_stats": dict(
             mstats, **{k: v for k, v in stats["mlp"].items()
                        if k != "select_bias"})}, normed) - shared
-  close(lm.residual_mix(x, h_res, h_post, routed + shared), uncut,
+  close(mhc.residual_mix(x, h_res, h_post, routed + shared), uncut,
         "the layer from the sum of the shares")
 
 
@@ -456,14 +455,14 @@ def test_control_fails():
   one Sinkhorn iteration fail the limit; float32 passes it."""
   cfg = tiny()
   d, _ = ref_cfg(cfg)
-  (pre, post, res), x, params = _coefficients(cfg)
+  (_, pre, post, res, *_), x, params = _coefficients(cfg)
   want = ref.hc_coefficients(d, params, x)
   for got, exp, what in zip((pre, post, res), want, ("pre", "post", "res")):
     close(got, exp, what)
-  (pre, post, res), _, _ = _coefficients(cfg, mhc_dtype=jnp.bfloat16)
+  (_, pre, post, res, *_), _, _ = _coefficients(cfg, mhc_dtype=jnp.bfloat16)
   with pytest.raises(AssertionError):
     close(res, want[2], "res in bfloat16")
-  (_, _, res), _, _ = _coefficients(dataclasses.replace(
+  (_, _, _, res, *_), _, _ = _coefficients(dataclasses.replace(
       cfg, hc_sinkhorn_iters=1))
   with pytest.raises(AssertionError):
     close(res, want[2], "res after one iteration")
