@@ -11,7 +11,9 @@ XLA fuses the path's neighbours otherwise.
         [--shape tokens,k,width,expert_width,held,experts]
 
 The default shape is the trinity-mini cell's (8192,8,2048,1024,16,128);
-the glm cell's is 8192,4,2048,1536,8,64. One process a tree (a chip
+the glm cell's is 8192,4,2048,1536,8,64. The path runs as the tree's
+model runs it: at ``expert.row_tile``'s tile where the tree has one (the
+tile-aligned layout), packed before. One process a tree (a chip
 belongs to the first process that touches JAX); ~80 s each.
 """
 
@@ -35,6 +37,8 @@ def main(argv=None) -> int:
   from kf_benchmarks_tpu.parallel import expert
   tokens, k, d, f, g, e = (int(a) for a in args.shape.split(","))
   rows = expert.compact_rows(tokens * k, g, e)
+  layout = ({"tile": expert.row_tile(tokens * k, g, e)}
+            if hasattr(expert, "row_tile") else {})
   keys = jax.random.split(jax.random.PRNGKey(7), 6)
   x = jax.random.normal(keys[0], (tokens, d), jnp.bfloat16)
   router = 0.02 * jax.random.normal(keys[1], (d, e), jnp.float32)
@@ -46,12 +50,13 @@ def main(argv=None) -> int:
   @jax.jit
   def value_and_gradients(x, weights, *w):
     y, pull = jax.vjp(lambda x, weights, *w: expert.held_experts_ffn(
-        x, weights, idx, *w, 0, impl="gmm", rows=rows)[0], x, weights, *w)
+        x, weights, idx, *w, 0, impl="gmm", rows=rows, **layout)[0], x,
+        weights, *w)
     return (y,) + pull(cotangent)
   outs = jax.block_until_ready(value_and_gradients(x, weights, *w))
   names = ("y", "d_x", "d_pair_w", "d_w_gate", "d_w_up", "d_w_down")
   print(json.dumps({
-      "root": args.root, "shape": args.shape, "rows": rows,
+      "root": args.root, "shape": args.shape, "rows": rows, **layout,
       "device": jax.devices()[0].device_kind,
       **{name: hashlib.sha256(np.asarray(
           out.astype(jnp.float32)).tobytes()).hexdigest()[:16]

@@ -890,6 +890,8 @@ class MoE(_Options):
                              jnp.float32)
     compact = self.variable("batch_stats", "compact", jnp.zeros, (),
                             jnp.float32)
+    tiled = self.variable("batch_stats", "rows_in_tiles", jnp.zeros, (),
+                          jnp.float32)
     # For a check of the timed program: every token's choice, and the
     # router's input and scores of the first few tokens.
     probe = min(ROUTER_PROBE_TOKENS, b * t)
@@ -908,11 +910,12 @@ class MoE(_Options):
           c.routed_scaling_factor, c.norm_topk_prob, self.router_dtype)
       self.sow("intermediates", "router_scores", scores)
       self.sow("intermediates", "topk_idx", idx)
+      pairs = b * t * c.num_experts_per_tok
       routed, counts = expert_lib.held_experts_ffn(
           flat, weights, idx, w_gate, w_up, w_down, c.first_expert,
-          impl=self.moe_impl, rows=expert_lib.compact_rows(
-              b * t * c.num_experts_per_tok, g, e),
-          activation=c.expert_activation)
+          impl=self.moe_impl, rows=expert_lib.compact_rows(pairs, g, e),
+          activation=c.expert_activation,
+          tile=expert_lib.row_tile(pairs, g, e))
       if self.is_mutable_collection("batch_stats") and \
           not self.is_initializing():
         # The step's loads over ALL the experts from this chip's tokens,
@@ -922,6 +925,7 @@ class MoE(_Options):
         load.value = all_load
         computed.value = counts["pairs_computed"].astype(jnp.float32)
         compact.value = counts["compact"].astype(jnp.float32)
+        tiled.value = counts["rows_in_tiles"].astype(jnp.float32)
         chosen.value = idx.astype(jnp.float32)
         probe_in.value = flat[:probe].astype(self.dtype)
         probe_scores.value = scores[:probe].astype(jnp.float32)
@@ -1260,11 +1264,11 @@ def moe_counters(batch_stats, cfg: LMConfig):
   return jnp.stack([
       pairs_here, pairs_here - total("pairs_computed"),
       jnp.max(jnp.max(held, -1) / jnp.maximum(jnp.mean(held, -1), 1e-9)),
-      total("compact")])
+      total("compact"), total("rows_in_tiles")])
 
 
 MOE_COUNTERS = ("pairs_routed_here", "pairs_dropped", "load_max_over_mean",
-                "compact_layers")
+                "compact_layers", "rows_in_tiles")
 
 
 def mhc_counters(batch_stats):
@@ -1332,7 +1336,7 @@ class MLAMoELMModel(model_lib.Model):
         self._cfg = c = load_lm_config(*self._share)
       trace.set_static("moe", {
           "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
-          "buffer_rows": self._round_rows(c),
+          "buffer_rows": self._round_rows(c), "row_tile": self._row_tile(c),
           "expert_matrices": c.expert_matrices})
       core = self.attention_core_stats()
       trace.set_static("attention", core)
@@ -1462,11 +1466,22 @@ class MLAMoELMModel(model_lib.Model):
     run's ``stats["moe"]``."""
     c = self.cfg
     layer_steps = rows.shape[0] * (c.moe_layers + c.num_nextn_predict_layers)
-    return {"pairs_routed_here": float(rows[:, 0].mean()),
-            "pairs_dropped": float(rows[:, 1].sum()),
-            "load_max_over_mean": float(rows[:, 2].max()),
-            "compact_share": float(rows[:, 3].sum()) / layer_steps,
-            "steps": int(rows.shape[0])}
+    moe = {"pairs_routed_here": float(rows[:, 0].mean()),
+           "pairs_dropped": float(rows[:, 1].sum()),
+           "load_max_over_mean": float(rows[:, 2].max()),
+           "compact_share": float(rows[:, 3].sum()) / layer_steps,
+           "steps": int(rows.shape[0])}
+    if rows.shape[1] > 4:      # rows of the four counters before the layout's
+      # The rows the grouped products' row tiles covered for a pair routed
+      # here, every mixture layer of every step, and its log line.
+      from kf_benchmarks_tpu.utils import log as log_util
+      moe["rows_per_pair"] = float(rows[:, 4].sum() /
+                                   max(rows[:, 0].sum(), 1))
+      log_util.log_fn(
+          f"moe layout: row tile {self._row_tile(c)}, rows per pair "
+          f"{moe['rows_per_pair']:.4g} (every held expert's rows from a "
+          "tile boundary where the tile is shorter than the round)")
+    return moe
 
   def make_module(self, nclass, phase_train, data_format="NHWC",
                   dtype=jnp.float32, param_dtype=jnp.float32):
@@ -1489,6 +1504,13 @@ class MLAMoELMModel(model_lib.Model):
     """The sorted rows of one round of the routed path at this job's
     batch (what ``MoE`` asks ``held_experts_ffn`` for)."""
     return expert_lib.compact_rows(
+        self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
+        c.experts_held, c.n_routed_experts)
+
+  def _row_tile(self, c: LMConfig) -> int:
+    """The grouped products' row tile at this job's batch, from which
+    every held expert's rows start (``expert.row_tile``)."""
+    return expert_lib.row_tile(
         self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
         c.experts_held, c.n_routed_experts)
 

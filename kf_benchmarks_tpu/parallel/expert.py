@@ -158,7 +158,9 @@ def reference_switch_moe(x_grouped, gate_w, w1, b1, w2, b2,
 #
 # The work follows the rows in use. All N x k pairs are sorted by held
 # expert (int32 keys; absent experts' pairs last), so the pairs held here
-# are a prefix of the sorted order, and the path works on that prefix in
+# are a prefix of the sorted order, each held expert's rows starting on a
+# boundary of the grouped products' row tile (``row_tile``; padding rows
+# between the groups hold no pair), and the path works on that prefix in
 # ROUNDS of ``rows`` sorted rows: ``compact_rows`` sizes a round at twice
 # the share of the pairs these experts get under perfect balance, so one
 # round is the common step, and a step whose pairs do not fit runs
@@ -195,6 +197,29 @@ def compact_rows(pairs: int, held: int, experts: int, tile: int = 512) -> int:
   return min(pairs, -(-share // tile) * tile)
 
 
+# Row tiles the layout chooses from, and the v5e's ridge in rows: a visit
+# of a row tile fetches its expert's weight tile, ``tm`` flop a byte of it
+# in bfloat16, against the chip's 197 TFLOP/s over 819 GB/s = 240.
+ROW_TILES = (128, 256, 512)
+RIDGE_ROWS = 240
+
+
+def row_tile(pairs: int, held: int, experts: int) -> int:
+  """Row tile of the routed path's grouped products, from the shapes: the
+  smallest of ``ROW_TILES`` at or above the ridge and at most the rows a
+  held expert expects under balance (``pairs / experts``), or the largest
+  such tile that those rows fill four times over (its padding, half a
+  tile an expert, an eighth of the rows). Every held expert's rows start
+  on a boundary of it (``sort_pairs``), so that megablox visits each row
+  tile for one expert. Where no tile fits (the CPU tests' tiny sizes) the
+  round's rows: one tile, and the groups packed end to end."""
+  expected = pairs // experts
+  fits = [s for s in ROW_TILES if RIDGE_ROWS <= s <= expected]
+  if not fits:
+    return compact_rows(pairs, held, experts)
+  return max([fits[0]] + [s for s in fits if 4 * s <= expected])
+
+
 def combine_stats(tokens: int, k: int, rows: int, width: int, dtype):
   """What the combine of ONE round reads (``_rows_to_tokens``), from the
   shapes alone: ``gathers`` row gathers of ``tokens`` rows each
@@ -208,9 +233,11 @@ def combine_stats(tokens: int, k: int, rows: int, width: int, dtype):
 
 
 def gmm_tiling(rows: int, contraction: int, columns: int):
-  """(rows, contraction, columns) of one tile of the grouped product:
-  the largest listed size that divides each dimension (a tile edge that
-  does not is masked work), 512 rows, and at most 1024 x 768 of the
+  """(rows, contraction, columns) of one tile of the grouped product, for
+  ``rows`` the layout's row tile (``row_tile``; the round's rows where the
+  groups are packed): the largest listed size that divides each dimension
+  (a tile edge that does not is masked work), 512 rows, and at most
+  1024 x 768 of the
   weight so that a tile and its double buffer fit the v5e's 16 MiB of
   scoped VMEM (1024 x 1536 was refused by the chip's compiler, PR 27).
   A width NO listed size divides (1,856 = 29 x 64, a two-matrix expert's)
@@ -254,11 +281,16 @@ def route_topk(x, router_w, select_bias, k: int, scale: float,
 
 class SortedPairs(NamedTuple):
   """A step's N x k (token, expert) pairs sorted by held expert (stable,
-  so by token within an expert; absent experts' pairs last). ``order[r]``
-  is the pair (token x k + choice) in sorted row r and ``key[r]`` the
-  held expert it chose (the number of held experts for an absent one's);
-  ``inv`` is the sorted row of each pair; ``ends`` (G,) the row where
-  each held expert's pairs end, so ``ends[-1]`` pairs are held here."""
+  so by token within an expert; absent experts' pairs last), each held
+  expert's rows starting on a multiple of the layout's tile: the rows
+  between one expert's last pair and the next tile boundary are PADDING,
+  which holds no pair. ``order[r]`` is the pair (token x k + choice) in
+  sorted row r, N x k or more in a padding row, and ``key[r]`` the held
+  expert its pair chose (the number of held experts G for an absent
+  one's and for padding); ``inv`` the sorted row of each of
+  ``order``'s entries, the pairs' first; ``ends`` (G,) the row where each
+  held expert's extent ends, its padding included, so the held experts'
+  groups take rows ``[0, ends[-1])``."""
   key: jnp.ndarray
   order: jnp.ndarray
   inv: jnp.ndarray
@@ -315,7 +347,7 @@ _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 @jax.custom_vjp
 def _sorted_values(v, order, inv):
-  """``v[order]`` for a permutation ``order`` of the N x k pairs and its
+  """``v[order]`` for a permutation ``order`` of the sorted rows and its
   inverse, as a sort of ``v`` by ``inv``, and backward a sort by
   ``order``: on the TPU a gather of 32,768 scalars takes 0.23 ms and a
   sort of them 0.025 ms (PERF.md section 6, PR 28)."""
@@ -355,8 +387,12 @@ def _rows_to_tokens_fwd(ys, pair_w, tok, slot, start, plan):
 
 def _rows_to_tokens_bwd(res, g):
   ys, pair_w, tok, start, plan = res
+  # Padding rows take weight 0: the pairs' weights are padded to the
+  # sorted rows before the sort.
+  pad = plan.order.shape[0] - pair_w.size
   w, unsort = jax.vjp(lambda p: _round_slice(_sorted_values(
-      p.reshape(-1), plan.order, plan.inv), start, ys.shape[0]), pair_w)
+      jnp.pad(p.reshape(-1), (0, pad)), plan.order, plan.inv), start,
+      ys.shape[0]), pair_w)
   g_rows = jnp.take(g, tok, axis=0, mode="clip")
   d_w = jnp.sum(g_rows * ys.astype(jnp.float32), axis=1)
   return ((g_rows * w[:, None]).astype(ys.dtype), *unsort(d_w),
@@ -366,11 +402,13 @@ def _rows_to_tokens_bwd(res, g):
 _rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def grouped_matmul(lhs, rhs, group_sizes, live, impl="gmm"):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_matmul(lhs, rhs, group_sizes, live, impl="gmm", tile=None):
   """``out[r] = lhs[r] @ rhs[group of r]`` for the rows of a round in
   use (``live``), zero elsewhere; ``group_sizes`` (G,) int32 rows per
-  held expert, in order. ``impl``: ``gmm`` is the TPU kernel (Pallas
+  held expert, in order; ``tile`` the layout's row tile (``row_tile``),
+  all the rows by default, from which ``gmm_tiling`` takes the kernel's.
+  ``impl``: ``gmm`` is the TPU kernel (Pallas
   megablox: its grid covers the tiles in use alone, and the rows it does
   not visit are left unwritten, hence the masks, which like everything
   XLA runs around the kernel pass over the whole round: the reason a
@@ -386,17 +424,17 @@ def grouped_matmul(lhs, rhs, group_sizes, live, impl="gmm"):
     # value a float32 output cast afterwards has, without that pass.
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     out = gmm(lhs, rhs, group_sizes, lhs.dtype,
-              gmm_tiling(lhs.shape[0], rhs.shape[1], rhs.shape[2]),
+              gmm_tiling(tile or lhs.shape[0], rhs.shape[1], rhs.shape[2]),
               interpret=impl == "gmm_interpret")
   return jnp.where(live[:, None], out, 0).astype(lhs.dtype)
 
 
-def _grouped_matmul_fwd(lhs, rhs, group_sizes, live, impl):
-  return (grouped_matmul(lhs, rhs, group_sizes, live, impl),
+def _grouped_matmul_fwd(lhs, rhs, group_sizes, live, impl, tile):
+  return (grouped_matmul(lhs, rhs, group_sizes, live, impl, tile),
           (lhs, rhs, group_sizes, live))
 
 
-def _grouped_matmul_bwd(impl, res, g):
+def _grouped_matmul_bwd(impl, tile, res, g):
   lhs, rhs, group_sizes, live = res
   g = jnp.where(live[:, None], g, 0)
   if impl == "ragged_dot":
@@ -406,7 +444,7 @@ def _grouped_matmul_bwd(impl, res, g):
   else:
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
     interpret = impl == "gmm_interpret"
-    rows, (_, d_in, d_out) = lhs.shape[0], rhs.shape
+    rows, (_, d_in, d_out) = tile or lhs.shape[0], rhs.shape
     dlhs = gmm(g, rhs, group_sizes, lhs.dtype,
                gmm_tiling(rows, d_out, d_in), transpose_rhs=True,
                interpret=interpret)
@@ -441,47 +479,67 @@ def expert_hidden(xs, w_gate, w_up, product, activation: str):
   return act(product(xs, w_gate)) * product(xs, w_up)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "impl", "activation"))
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "impl", "activation", "tile"))
 def experts_round(i, x, pair_w, w_gate, w_up, w_down, plan: SortedPairs,
-                  rows: int, impl: str, activation: str = "silu"):
+                  rows: int, impl: str, activation: str = "silu",
+                  tile: Optional[int] = None):
   """Round ``i`` of the routed path: sorted rows ``[i x rows, (i + 1) x
-  rows)``. Returns ``(y, computed)``: y (N, D) float32, the weighted
-  outputs of the held experts (``expert_hidden``'s form, then ``w_down``)
-  for the pairs in these rows,
+  rows)``, handed to the products in row tiles of ``tile`` (all the rows
+  by default). Returns ``(y, computed, tiled)``: y (N, D) float32, the
+  weighted outputs of the held experts (``expert_hidden``'s form, then
+  ``w_down``) for the pairs in these rows,
   summed into their tokens; ``computed`` the pairs the products computed
-  for their expert (``pairs_inside_groups``). A held pair outside the
+  for their expert (``pairs_inside_groups``); ``tiled`` the rows the
+  kernel's row tiles cover (``rows_in_tiles``). A held pair outside the
   round is another round's: left out here, and not counted. (Jitted, as
   ``_round_pullback`` is, so that every mixture block of a model shares
   ONE trace and one lowering of it; XLA inlines the calls.)"""
   k = pair_w.shape[1]
   start = i * rows
+  g = plan.ends.shape[0]
   cut = functools.partial(_round_slice, start=start, rows=rows)
   tok = cut(plan.order) // k
   ends = jnp.clip(plan.ends - start, 0, rows)
   sizes = jnp.diff(ends, prepend=0)
-  live = jnp.arange(rows, dtype=jnp.int32) < ends[-1]
-  slot = plan.inv - start
+  key = cut(plan.key, fill=g)
+  live = key < g                       # the rows that hold a held pair
+  slot = plan.inv[:pair_w.size] - start
   slot = jnp.where((slot >= 0) & (slot < rows), slot, rows).reshape(-1, k)
   xs = jnp.where(live[:, None], _rows_of_tokens(x, tok, slot), 0)
   with jax.named_scope("moe_experts"):
-    product = lambda rows_, w: grouped_matmul(rows_, w, sizes, live, impl)
+    product = lambda rows_, w: grouped_matmul(rows_, w, sizes, live, impl,
+                                              tile)
     ys = product(expert_hidden(xs, w_gate, w_up, product, activation),
                  w_down)
   y = _rows_to_tokens(ys, pair_w, tok, slot, start, plan)
-  return y, pairs_inside_groups(cut(plan.key, fill=sizes.shape[0]), sizes,
-                                live)
+  tm = gmm_tiling(tile or rows, *w_up.shape[1:])[0]
+  return (y, pairs_inside_groups(key, sizes, live),
+          rows_in_tiles(ends, tm))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def rows_in_tiles(ends, tm: int):
+  """Rows the grouped product's kernel computes for groups ending at
+  ``ends`` (G,) within a round, in row tiles of ``tm``: megablox visits
+  every tile a group touches once for that group (a tile two groups share
+  twice), so a group of ``[s, e)`` costs ``(e - 1) // tm - s // tm + 1``
+  tiles, ``ceil((e - s) / tm)`` where it starts on a tile boundary."""
+  starts = jnp.concatenate([jnp.zeros(1, ends.dtype), ends[:-1]])
+  tiles = jnp.where(ends > starts, (ends - 1) // tm - starts // tm + 1, 0)
+  return jnp.sum(tiles) * tm
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def _all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl,
-                activation):
+                activation, tile):
   """``experts_round`` summed over the rounds that hold a pair of a held
   expert. Differentiated as a whole: the backward runs each round's
   forward again inside its own loop, so nothing of a round outlives it
   and the backward needs nothing of this forward but its inputs. (A
   caller under ``nn.remat`` pays for this forward twice only where the
   backward pass reads y itself: the comment above ``compact_rows``.)"""
-  args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl, activation)
+  args = (x, pair_w, w_gate, w_up, w_down, plan, rows, impl, activation,
+          tile)
   return _while_pairs_left(lambda i: experts_round(i, *args), plan, rows)
 
 
@@ -503,26 +561,28 @@ def _while_pairs_left(one_round, plan, rows):
 
 
 def _all_rounds_fwd(x, pair_w, w_gate, w_up, w_down, plan, rows, impl,
-                    activation):
+                    activation, tile):
   return (_all_rounds(x, pair_w, w_gate, w_up, w_down, plan, rows, impl,
-                      activation),
+                      activation, tile),
           (x, pair_w, w_gate, w_up, w_down, plan))
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "impl", "activation"))
-def _round_pullback(i, g, inputs, plan, rows, impl, activation="silu"):
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "impl", "activation", "tile"))
+def _round_pullback(i, g, inputs, plan, rows, impl, activation="silu",
+                    tile=None):
   """The gradients of round ``i``'s y with respect to ``inputs`` (x,
   pair_w and the weights; None for the gate a two-matrix expert lacks)
   at the cotangent ``g``."""
   _, vjp = jax.vjp(lambda *a: experts_round(i, *a, plan, rows, impl,
-                                            activation)[0], *inputs)
+                                            activation, tile)[0], *inputs)
   return vjp(g)
 
 
-def _all_rounds_bwd(rows, impl, activation, res, g):
+def _all_rounds_bwd(rows, impl, activation, tile, res, g):
   *inputs, plan = res
   pull = lambda i: _round_pullback(i, g[0], tuple(inputs), plan, rows, impl,
-                                   activation)
+                                   activation, tile)
   return tuple(_while_pairs_left(pull, plan, rows)) + (None,)
 
 
@@ -531,7 +591,8 @@ _all_rounds.defvjp(_all_rounds_fwd, _all_rounds_bwd)
 
 def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
                      first_expert: int, impl: str = "ragged_dot",
-                     rows: Optional[int] = None, activation: str = "silu"):
+                     rows: Optional[int] = None, activation: str = "silu",
+                     tile: Optional[int] = None):
   """The held experts' part of a dropless top-k layer.
 
   x (N, D) tokens; weights, idx (N, k) from ``route_topk`` over ALL the
@@ -542,15 +603,22 @@ def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
   one, ``w_down(act(w_gate x) * w_up x)``, ``activation`` naming ``act``
   (``ACTIVATIONS``);
   ``rows`` the sorted rows of one round (``compact_rows``; static), all
-  N x k by default. Returns ``(y, counts)``: y (N, D) the weighted sum
-  of the held experts' outputs over each token's pairs that
+  N x k by default; ``tile`` the grouped products' row tile
+  (``row_tile``; static), the round's rows by default. Where the tile is
+  shorter than the round, every held expert's rows start on a multiple of
+  it (``sort_pairs``), and a round is whole tiles; where it is the
+  round, the groups are packed end to end. Returns ``(y, counts)``: y
+  (N, D) the weighted sum of the held experts' outputs over each token's
+  pairs that
   chose one (zero for a token that chose none), and ``counts`` a dict of
   int32 scalars: ``pairs_here``, the pairs whose expert is held (counted
   from the choices), ``pairs_computed``, counted from the other side:
   the rows of the rounds that ran that the grouped products were told
   are an expert's and that hold a pair of that expert
-  (``pairs_inside_groups``), and ``compact``, 1 where the step's pairs
-  fit one round. ``pairs_here - pairs_computed`` is the drop count: 0
+  (``pairs_inside_groups``), ``rows_in_tiles``, the rows the products'
+  row tiles covered over the rounds that ran (``rows_in_tiles``), and
+  ``compact``, 1 where the step's held experts' extents fit one round.
+  ``pairs_here - pairs_computed`` is the drop count: 0
   while the rounds reach every held pair and the sort agrees with the
   group sizes.
 
@@ -560,35 +628,53 @@ def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
   """
   n, k = idx.shape
   rows = n * k if rows is None else rows
-  plan, held = sort_pairs(idx, first_expert, w_up.shape[0])
+  tile = rows if tile is None else tile
+  align = tile if tile < rows else 1
+  if rows % align:
+    raise ValueError(f"a round of {rows} rows is no whole number of row "
+                     f"tiles of {tile}")
+  plan, held = sort_pairs(idx, first_expert, w_up.shape[0], align)
+  if rows >= n * k:     # one round of all the pairs holds their padding too
+    rows = max(rows, -(-plan.key.shape[0] // align) * align)
   pair_w = jnp.where(held, weights, 0).astype(jnp.float32)
-  y, computed = _all_rounds(
+  y, computed, tiled = _all_rounds(
       x, pair_w, None if w_gate is None else w_gate.astype(x.dtype),
       w_up.astype(x.dtype), w_down.astype(x.dtype), plan, rows, impl,
-      activation)
-  pairs_here = plan.ends[-1]
-  counts = {"pairs_here": pairs_here, "pairs_computed": computed,
-            "compact": (pairs_here <= rows).astype(jnp.int32)}
+      activation, tile)
+  counts = {"pairs_here": jnp.sum(held.astype(jnp.int32)),
+            "pairs_computed": computed, "rows_in_tiles": tiled,
+            "compact": (plan.ends[-1] <= rows).astype(jnp.int32)}
   return y.astype(x.dtype), counts
 
 
-def sort_pairs(idx, first_expert: int, g: int):
+def sort_pairs(idx, first_expert: int, g: int, tile: int = 1):
   """``idx`` (N, k), every token's chosen experts, as the ``SortedPairs``
-  of the ``g`` experts held from ``first_expert`` on; and ``held``
-  (N, k), whether a pair's expert is held. The loads are counted from
-  the choices, not from the sort."""
+  of the ``g`` experts held from ``first_expert`` on, each held expert's
+  rows starting on a multiple of ``tile``; and ``held`` (N, k), whether a
+  pair's expert is held. The loads are counted from the choices, not
+  from the sort. The padding is ONE sort with the pairs: ``tile - 1``
+  padding entries an expert join them, the first ``-load % tile`` under
+  that expert's key, so that they sort right after its pairs (the sort
+  is stable, and they come after every pair), the rest under a key past
+  every other. ``tile`` 1 packs the groups end to end."""
   n, k = idx.shape
   local = idx - first_expert
   held = (local >= 0) & (local < g)
   # Absent experts' pairs sort to the end, under a key of their own.
   key = jnp.where(held, local, g).reshape(-1)
+  experts = jnp.arange(g, dtype=key.dtype)
+  held_load = jnp.sum((key[:, None] == experts[None, :]).astype(jnp.int32),
+                      axis=0)
+  pad = -held_load % tile
+  spare = jnp.arange(tile - 1, dtype=key.dtype)[None, :]
+  key = jnp.concatenate([key, jnp.where(
+      spare < pad[:, None], experts[:, None], g + 1).reshape(-1)])
   sorted_key, order = lax.sort(
-      (key, jnp.arange(n * k, dtype=jnp.int32)), num_keys=1, is_stable=True)
-  held_load = jnp.sum(
-      (key[:, None] == jnp.arange(g, dtype=key.dtype)[None, :]).astype(
-          jnp.int32), axis=0)
-  return SortedPairs(sorted_key, order, jnp.argsort(order).astype(jnp.int32),
-                     jnp.cumsum(held_load)), held
+      (key, jnp.arange(key.shape[0], dtype=jnp.int32)), num_keys=1,
+      is_stable=True)
+  return SortedPairs(jnp.where(order < n * k, sorted_key, g), order,
+                     jnp.argsort(order).astype(jnp.int32),
+                     jnp.cumsum(held_load + pad)), held
 
 
 def pairs_inside_groups(sorted_key, group_sizes, live):

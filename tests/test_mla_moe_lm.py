@@ -497,7 +497,7 @@ def test_drop_count_of_one_round_forced_on_pairs_that_need_two(rows):
   pair_w = jnp.where(held, 0.5, 0.0)
   one = lambda i: expert_lib.experts_round(i, x, pair_w, *w, plan, rows,
                                            "ragged_dot")
-  (y0, computed0), (y1, computed1) = one(0), one(1)
+  (y0, computed0, _), (y1, computed1, _) = one(0), one(1)
   assert pairs_here - int(computed0) == pairs_here - rows
   assert int(computed0) + int(computed1) == pairs_here
   # ... and the two rounds together are the layer.
@@ -506,6 +506,117 @@ def test_drop_count_of_one_round_forced_on_pairs_that_need_two(rows):
   close(y, y0 + y1, "sum of the rounds")
   assert int(counts["pairs_computed"]) == pairs_here
   assert int(counts["compact"]) == 0
+
+
+# Choices of 48 tokens x 2 over 12 experts, 2..5 held, for the layout
+# whose every held expert's rows start on a row tile of 8: (a regime, the
+# round's rows). Rounds of 64 rows hold every held pair and its padding
+# but in ``second_round``, whose rounds of 16 rows are two tiles.
+LAYOUT_REGIMES = {
+    "uneven_loads": 64,
+    "one_empty_expert": 64,
+    "group_one_tile": 64,
+    "second_round": 16,
+}
+
+
+def _layout_choices(regime, n=48, k=2, e=12, first=2, tile=8, seed=0):
+  """(N, k) choices drawn with uneven popularity over the experts; held
+  expert ``first + 1`` chosen by nobody in ``one_empty_expert``, held
+  expert ``first`` by exactly one tile of tokens in ``group_one_tile``."""
+  rng = np.random.RandomState(seed)
+  p = rng.dirichlet(np.full(e, 0.7))
+  if regime == "one_empty_expert":
+    p[first + 1] = 0
+  if regime == "group_one_tile":
+    p[first] = 0
+  idx = np.stack([rng.choice(e, k, replace=False, p=p / p.sum())
+                  for _ in range(n)])
+  if regime == "group_one_tile":
+    idx[:tile, 0] = first
+  return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
+@pytest.mark.parametrize("regime", list(LAYOUT_REGIMES))
+def test_tile_aligned_layout_against_the_packed_one(regime, impl):
+  # The same layer with every held expert's rows from a multiple of the
+  # row tile and with the groups packed end to end: y bit for bit (each
+  # output row is the same products over the same contraction tiles), the
+  # five gradients to float32's last places (a weight gradient sums an
+  # expert's rows over other tile boundaries), no pair dropped, every
+  # group handed to the products from a tile boundary, and megablox's own
+  # visits what ``rows_in_tiles`` counts: one a tile for one expert.
+  from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+      make_group_metadata)
+  n, k, g, first, d, f, tile = 48, 2, 4, 2, 16, 8, 8
+  rows = LAYOUT_REGIMES[regime]
+  idx = _layout_choices(regime)
+  keys = jax.random.split(jax.random.PRNGKey(1), 6)
+  x = jax.random.normal(keys[0], (n, d))
+  weights = jax.nn.softmax(jax.random.normal(keys[1], (n, k)), -1)
+  w = [0.3 * jax.random.normal(key, shape) for key, shape in zip(
+      keys[2:], [(g, d, f), (g, d, f), (g, f, d)])]
+
+  def layer(tile_):
+    def fn(x, weights, *w):
+      y, counts = expert_lib.held_experts_ffn(
+          x, weights, idx, *w, first_expert=first, impl=impl, rows=rows,
+          tile=tile_)
+      return jnp.sum(jnp.sin(y)), (y, counts)
+    return jax.value_and_grad(fn, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+        x, weights, *w)
+  (_, (y, counts)), grads = layer(tile)
+  (_, (y_packed, packed)), want = layer(None)
+  assert np.array_equal(np.asarray(y), np.asarray(y_packed))
+  for name, got, ref_ in zip(("x", "weights", "gate", "up", "down"), grads,
+                             want):
+    close(got, ref_, f"{impl}: gradient of {name}", rtol=1e-5)
+  pairs_here = int(counts["pairs_here"])
+  assert pairs_here == int(packed["pairs_here"]) > 0
+  assert int(counts["pairs_computed"]) == pairs_here       # none dropped
+  plan, _ = expert_lib.sort_pairs(idx, first, g, tile)
+  ends = np.asarray(plan.ends)
+  loads = np.diff(ends, prepend=0)
+  assert not (ends % tile).any()
+  if regime == "one_empty_expert":
+    assert loads[1] == 0
+  if regime == "group_one_tile":
+    assert loads[0] == tile
+  # Padding rows hold no pair: the absent key, an index past the pairs.
+  key, order = np.asarray(plan.key), np.asarray(plan.order)
+  assert (key[order >= n * k] == g).all()
+  assert (key < g).sum() == pairs_here
+  rounds = -(-ends[-1] // rows)
+  assert (rounds > 1) == (regime == "second_round") == (
+      int(counts["compact"]) == 0)
+  visited = 0
+  for i in range(rounds):
+    inside = np.clip(ends - i * rows, 0, rows)
+    assert not (inside % tile).any()       # a group starts on a tile
+    sizes = jnp.asarray(np.diff(inside, prepend=0), jnp.int32)
+    _, tiles = make_group_metadata(
+        group_sizes=sizes, m=rows, tm=tile, start_group=jnp.int32(0),
+        num_nonzero_groups=g, visit_empty_groups=False)
+    assert int(tiles) == int(np.sum(-(-np.asarray(sizes) // tile)))
+    visited += int(tiles)
+  assert int(counts["rows_in_tiles"]) == visited * tile
+
+
+@pytest.mark.parametrize("pairs, held, experts, want", [
+    (4096 * 4, 8, 64, 256),      # xing: 256 rows an expert
+    (8192 * 8, 16, 128, 256),    # trinity-mini: 512
+    (8192 * 4, 8, 64, 256),      # glm-4.7-flash: 512
+    (8192 * 6, 8, 128, 256),     # nemotron: 384
+    (32768 * 4, 8, 64, 512),     # 2,048 rows an expert: four tiles of 512
+    (2048, 4, 16, 1024),         # the tiny preset at 1,024 tokens: the round
+    (64, 4, 16, 64),             # ... at 32 tokens: all the pairs
+])
+def test_row_tile(pairs, held, experts, want):
+  assert expert_lib.row_tile(pairs, held, experts) == want
+  rows = expert_lib.compact_rows(pairs, held, experts)
+  assert rows % want == 0
+  assert expert_lib.gmm_tiling(want, 2048, 1024)[0] == min(want, 512)
 
 
 @pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])

@@ -618,13 +618,15 @@ def _routed_inputs(n=64, d=256, f=192, e=8, g=4, k=2, seed=0):
 
 def test_gmm_tiling_of_a_width_no_size_divides():
   # 1,856 = 29 x 64: the size whose last tile is masked least; the widths
-  # of the cells before are tiled as they were.
-  assert expert_lib.gmm_tiling(6144, 2688, 1856) == (512, 896, 384)
-  assert expert_lib.gmm_tiling(6144, 1856, 2688) == (512, 384, 896)
-  assert expert_lib.gmm_tiling(8192, 2048, 1536) == (512, 1024, 768)
-  assert expert_lib.gmm_tiling(8192, 1536, 2048) == (512, 768, 1024)
-  assert expert_lib.gmm_tiling(8192, 2048, 1024) == (512, 1024, 512)
-  assert expert_lib.gmm_tiling(8192, 1024, 2048) == (512, 1024, 512)
+  # of the cells before are tiled as they were, the rows by the layout's
+  # row tile (256 in each cell: ``expert.row_tile``).
+  assert expert_lib.row_tile(8192 * 6, 8, 128) == 256
+  assert expert_lib.gmm_tiling(256, 2688, 1856) == (256, 896, 384)
+  assert expert_lib.gmm_tiling(256, 1856, 2688) == (256, 384, 896)
+  assert expert_lib.gmm_tiling(256, 2048, 1536) == (256, 1024, 768)
+  assert expert_lib.gmm_tiling(256, 1536, 2048) == (256, 768, 1024)
+  assert expert_lib.gmm_tiling(256, 2048, 1024) == (256, 1024, 512)
+  assert expert_lib.gmm_tiling(256, 1024, 2048) == (256, 1024, 512)
   assert expert_lib.gmm_tiling(64, 48, 20) == (64, 48, 20)
   assert expert_lib.gmm_tiling(128, 256, 192) == (128, 256, 128)
 

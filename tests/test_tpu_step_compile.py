@@ -111,12 +111,13 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
   tokens, k, d, f, g = 8192, 4, 2048, 1536, 8
   rows = expert_lib.compact_rows(tokens * k, g, 64)
-  assert rows == 8192
+  tile = expert_lib.row_tile(tokens * k, g, 64)
+  assert (rows, tile) == (8192, 256)
 
   def experts(xs, w_up, w_down, sizes, live):
-    h = expert_lib.grouped_matmul(xs, w_up, sizes, live, "gmm")
+    h = expert_lib.grouped_matmul(xs, w_up, sizes, live, "gmm", tile)
     return jnp.sum(expert_lib.grouped_matmul(
-        h, w_down, sizes, live, "gmm").astype(jnp.float32))
+        h, w_down, sizes, live, "gmm", tile).astype(jnp.float32))
   text = jax.jit(jax.grad(experts, argnums=(0, 1, 2))).lower(
       sds((rows, d), jnp.bfloat16), sds((g, d, f), jnp.bfloat16),
       sds((g, f, d), jnp.bfloat16), sds((g,), jnp.int32),
@@ -128,7 +129,7 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
   def routed(x, weights, idx, w_gate, w_up, w_down):
     return jnp.sum(expert_lib.held_experts_ffn(
         x, weights, idx, w_gate, w_up, w_down, 0, impl="gmm",
-        rows=rows)[0].astype(jnp.float32))
+        rows=rows, tile=tile)[0].astype(jnp.float32))
   weight = lambda shape: sds(shape, jnp.float32)
   text = jax.jit(jax.grad(routed, argnums=(0, 1, 3, 4, 5))).lower(
       sds((tokens, d), jnp.bfloat16), weight((tokens, k)),
@@ -136,6 +137,7 @@ def test_mla_moe_lm_kernels_on_the_tpu_compiler(topo):
       weight((g, f, d))).compile().as_text()
   # The backward's loop holds a round once: its three products forward
   # again and six backward (nothing reads the forward loop's own output).
+  # The layout's padding is rows of the sort, not of the round.
   assert text.count('custom_call_target="tpu_custom_call"') == 9
   assert " while(" in text
   assert not re.search(rf"\[{tokens * k},({d}|{f})\]", text)
@@ -244,13 +246,14 @@ def test_trinity_mini_kernels_on_the_tpu_compiler(topo):
 
   tokens, k, d, f, g, experts = 8192, 8, 2048, 1024, 16, 128
   rows = expert_lib.compact_rows(tokens * k, g, experts)
+  tile = expert_lib.row_tile(tokens * k, g, experts)
   assert rows == 16384
-  assert expert_lib.gmm_tiling(rows, d, f) == (512, 1024, 512)
+  assert expert_lib.gmm_tiling(tile, d, f) == (256, 1024, 512)
 
   def routed(x, weights, idx, w_gate, w_up, w_down):
     return jnp.sum(expert_lib.held_experts_ffn(
         x, weights, idx, w_gate, w_up, w_down, 0, impl="gmm",
-        rows=rows)[0].astype(jnp.float32))
+        rows=rows, tile=tile)[0].astype(jnp.float32))
   weight = lambda shape: sds(shape, jnp.float32)
   text = jax.jit(jax.grad(routed, argnums=(0, 1, 3, 4, 5))).lower(
       sds((tokens, d), jnp.bfloat16), weight((tokens, k)),
@@ -340,13 +343,14 @@ def test_nemotron_h_kernels_on_the_tpu_compiler(topo):
   sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
   tokens, k, d, f, g = 8192, 6, 2688, 1856, 8
   rows = expert_lib.compact_rows(tokens * k, g, 128)
+  tile = expert_lib.row_tile(tokens * k, g, 128)
   assert rows == 6144
-  assert expert_lib.gmm_tiling(rows, d, f) == (512, 896, 384)
+  assert expert_lib.gmm_tiling(tile, d, f) == (256, 896, 384)
 
   def routed(x, weights, idx, w_up, w_down):
     return jnp.sum(expert_lib.held_experts_ffn(
         x, weights, idx, None, w_up, w_down, 0, impl="gmm", rows=rows,
-        activation="relu2")[0].astype(jnp.float32))
+        activation="relu2", tile=tile)[0].astype(jnp.float32))
   weight = lambda shape: sds(shape, jnp.float32)
   text = jax.jit(jax.grad(routed, argnums=(0, 1, 3, 4))).lower(
       sds((tokens, d), jnp.bfloat16), weight((tokens, k)),
