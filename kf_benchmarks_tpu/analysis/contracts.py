@@ -419,16 +419,9 @@ def trace_contract(overrides: Dict[str, Any],
       state_sds.opt_state)
   # The (B, T, V) bound the fused-head LM contract is checked against:
   # the bytes of the logits tensor the program must NOT materialize.
-  if bench.model.get_name() == "transformer_lm":
-    from kf_benchmarks_tpu.models import transformer_lm as lm
-    itemsize = jnp.dtype(bench.compute_dtype).itemsize
-    aux["btv_bytes"] = int(in_shapes[0][0]) * lm.SEQ_LEN * lm.VOCAB * itemsize
-  elif bench.model.get_name() == "mla_moe_lm":
-    # Two losses through one head kernel (ops/fused_loss.py): neither
-    # may hold the logits of the whole batch over the rows held.
-    itemsize = jnp.dtype(bench.compute_dtype).itemsize
-    aux["btv_bytes"] = (int(in_shapes[0][0]) * int(in_shapes[0][1]) *
-                        bench.model.cfg.vocab_rows * itemsize)
+  btv_bytes = bench.model.logits_bytes(in_shapes[0], bench.compute_dtype)
+  if btv_bytes is not None:
+    aux["btv_bytes"] = btv_bytes
 
   # Static flop count (the cost-analysis surface the --tfprof_file dump
   # reads): the autotuner's cost model consumes it from the aux; absent

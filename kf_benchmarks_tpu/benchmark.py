@@ -1926,6 +1926,11 @@ class BenchmarkCNN:
       kungfu.run_barrier()
     # (ref stats dict: benchmark_cnn.py:2383-2391)
     stats = {
+        # What the program states of itself (RunTrace.state: the step's
+        # factor exchange, the model's blocks), each merged with what the
+        # model's counters add to it over the timed steps; the keys below
+        # are the harness's own.
+        **self._stated_blocks(counter_rows),
         "num_workers": max(self.num_workers, 1),
         "num_steps": num_steps,
         "average_wall_time": average_wall_time,
@@ -1995,49 +2000,6 @@ class BenchmarkCNN:
         # device operations' op_names to them (a warm compile cache can
         # hand over another version's metadata; benchmarks/spans.py).
         "step_scopes": list(train_step_lib.STEP_SCOPES),
-        # The factor data plane of the mean gradient (parallel/kungfu.py
-        # FactorExchange), counted from shapes when the step was traced:
-        # dense layers that took it, gradient bytes they keep off the
-        # all-reduce, bytes gathered instead. All 0 where nothing engages.
-        "factor_exchange": (self._trace.static("factor_exchange")
-                            or dict(kungfu.NO_FACTOR_EXCHANGE)),
-        # The expert layer's counters (models/mla_moe_lm.py): the static
-        # share (experts and vocabulary rows held) and, over the timed
-        # steps, pairs routed to held experts (mean), pairs dropped (sum;
-        # must be 0) and the largest load over the mean among the held
-        # experts. None for a model without them.
-        "moe": (dict(self._trace.static("moe") or {},
-                     **self.model.counter_stats(np.stack(counter_rows)))
-                if counter_rows else None),
-        # The residual streams' hyper-connections (models/mla_moe_lm.py,
-        # ``xing4_0``): streams, sublayers and Sinkhorn's iterations as
-        # stated at the build and, over the timed steps, the largest
-        # |row or column sum - 1| of any residual mix. None for a model
-        # without them.
-        "mhc": (dict(self._trace.static("mhc"),
-                     **self.model.mhc_counter_stats(np.stack(counter_rows)))
-                if counter_rows and self._trace.static("mhc") else None),
-        # The attention core as the model stated it at the build
-        # (models/mla_moe_lm.py, parallel/sequence.flash_plan): layers,
-        # backward kernel passes a layer, the kernel's tiles. Static.
-        # None for a model without one.
-        "attention": self._trace.static("attention"),
-        # The fused head's schedule as the model stated it at the build
-        # (ops/fused_loss.weight_grad_stats): positions a softmax chunk,
-        # rows of one weight-gradient product, passes over the kernel's
-        # f32 gradient a step. Static. None for a model without one.
-        "lm_head": self._trace.static("lm_head"),
-        # The rotary stage by call site as the model stated it at the
-        # build (ops/rotary.stage_stats): layers, heads, dimensions
-        # rotated, the implementation and its block of rows, bytes a call
-        # and kept a layer. Static. None for a model without one.
-        "rotary": self._trace.static("rotary"),
-        # The state-space scan as the model stated it at the build
-        # (ops/ssd.scan_stats): layers, heads, groups, state, the chunk
-        # and chunks a sequence, the implementation and the share of the
-        # scans that took the kernels, bytes of carried state and kept a
-        # layer. Static. None for a model without one.
-        "mamba": self._trace.static("mamba"),
         # The allocator's own account of the fullest device of the
         # mesh, read as the timed loop ends: live buffers at their peak,
         # what the runtime reserved for loaded programs at its peak, and
@@ -2133,6 +2095,14 @@ class BenchmarkCNN:
                         unit="examples/sec", global_step=gs)
     return {"top_1_accuracy": top1, "top_5_accuracy": top5,
             "eval_images_per_sec": eval_ips}
+
+  def _stated_blocks(self, counter_rows) -> Dict[str, Any]:
+    blocks = self._trace.statics()
+    counted = (self.model.counter_blocks(np.stack(counter_rows))
+               if counter_rows else {})
+    for key, counts in counted.items():
+      blocks[key] = dict(blocks.get(key) or {}, **counts)
+    return blocks
 
   def _run_eval(self) -> Dict[str, Any]:
     """Evaluation driver (ref: benchmark_cnn.py:1757-1794).

@@ -403,6 +403,9 @@ _info("tuned_config_entry", "Matched tuned-table entry fingerprint",
 
 # Run-stats / bench-JSON keys that are bookkeeping, not metrics: the
 # schema audit accepts them from the emitters without registration.
+# The blocks a program states of itself (tracing.RunTrace.state) reach
+# the stats under keys no emitter names, and flatten_stats skips them
+# as it skips every unregistered key.
 NON_METRIC_KEYS = frozenset({
     "state", "stopped_early", "restart_for_resize", "reshape_events",
     "aot_load_path", "value", "entries", "health",
@@ -437,51 +440,10 @@ NON_METRIC_KEYS = frozenset({
     # PR 23: the scopes the step program names (train_step.STEP_SCOPES),
     # config provenance for the benchmark's trace reader.
     "step_scopes",
-    # PR 25: the factor data plane's static counter ({layers,
-    # bytes_off_allreduce, bytes_gathered}; parallel/kungfu.py
-    # FactorExchange) -- computed from shapes when the step is traced:
-    # a description of the program that ran, not a measurement.
-    "factor_exchange",
-    # PR 27: the expert layer's counters of models/mla_moe_lm.py (the
-    # static share held, and pairs routed / dropped / imbalance over the
-    # timed steps): a nested table read by the benchmark's readers
-    # (benchmarks/layer_metrics/moe_*), None for every other model.
-    "moe",
     # PR 27: the allocator's peaks and limit on the fullest device, a
     # nested table (benchmarks/layer_metrics/hbm_peak_in_use_gib.py);
     # None on a backend without memory statistics.
     "device_memory",
-    # PR 30: the attention core as models/mla_moe_lm.py states it at the
-    # build ({core_layers, backward_kernel_passes, block, block_q,
-    # block_kv, block_kv_dkv, dq_partials}; parallel/sequence.flash_plan): a
-    # description of the program that ran, None for every other model.
-    "attention",
-    # PR 36: the fused head's schedule as models/mla_moe_lm.py states it
-    # at the build ({chunk, rows_per_weight_grad_product,
-    # weight_grad_passes, dlogits_bytes_held, losses};
-    # ops/fused_loss.weight_grad_stats): a description of the program
-    # that ran, None for every other model.
-    "lm_head",
-    # PR 38: the rotary stage by call site as models/mla_moe_lm.py states
-    # it at the build ({calls_per_layer, layers, rot_dims, heads,
-    # head_dim, normed, implementation, block_rows, block_heads,
-    # bytes_read_and_written_per_call, residual_bytes_per_layer} a site;
-    # ops/rotary.stage_stats): a description of the program that ran,
-    # None for every other model.
-    "rotary",
-    # PR 39: the state-space scan as models/mla_moe_lm.py states it at
-    # the build ({layers, heads, head_dim, groups, state, chunk,
-    # chunks_per_sequence, implementation, kernel_share (PR 40),
-    # carried_state_bytes_per_layer, residual_bytes_per_layer};
-    # ops/ssd.scan_stats): a description of
-    # the program that ran, None for every other model.
-    "mamba",
-    # PR 42: the residual streams' hyper-connections as models/
-    # mla_moe_lm.py states them ({streams, hidden_size, sublayers,
-    # sinkhorn_iters, eps, stream_bytes, implementation, token_block,
-    # kernel_share; ops/mhc.mhc_stats)} at the build,
-    # sinkhorn_residual over the timed steps): None for every other model.
-    "mhc",
 })
 
 _PROM_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")

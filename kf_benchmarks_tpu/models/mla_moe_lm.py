@@ -202,6 +202,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from kf_benchmarks_tpu import tracing
 from kf_benchmarks_tpu.models import model as model_lib
 from kf_benchmarks_tpu.ops import fused_loss as fused_loss_lib
 from kf_benchmarks_tpu.ops import mhc
@@ -1283,6 +1284,40 @@ def mhc_counters(batch_stats):
 MHC_COUNTERS = ("sinkhorn_residual",)
 
 
+def _attention_lines(core):
+  """``stats["attention"]``'s log lines: one kind of core, its fields;
+  two, a line a kind."""
+  return [
+      f"attention core{kind}: {one['core_layers']} layer(s), " + (
+          "backward in {backward_kernel_passes} kernel pass a layer; "
+          "scores in blocks of {block}, the forward fetching "
+          "{block_q} queries x {block_kv} keys a grid step, "
+          "{block_kv_dkv} keys held across a backward sweep, "
+          "{dq_partials} partial dq summed outside the kernel"
+          if one["backward_kernel_passes"] else
+          "materialised scores (no kernel off the TPU)").format(**one)
+      + ("; window {window}, {query_heads} query heads over "
+         "{key_heads} key heads".format(**one) if "key_heads" in one else "")
+      + ("; {tiles_visited} of {tiles_causal} causal score tiles "
+         "visited".format(**one) if "tiles_causal" in one else "")
+      for kind, one in ([("", core)] if "core_layers" in core else
+                        [(f" ({k})", v) for k, v in core.items()])]
+
+
+def _rotary_lines(sites):
+  """``stats["rotary"]``'s log line: every call site in one."""
+  return ["attention rotary: " + "; ".join(
+      "{0} in {layers} layer(s): {heads} head(s) of {head_dim}, "
+      "{rot_dims} rotated, {1}, {implementation}{2}, "
+      "{bytes_read_and_written_per_call} bytes read and written a call, "
+      "{residual_bytes_per_layer} kept a layer".format(
+          name, "normed" if one["normed"] else "no norm",
+          " over {block_rows} positions of {block_heads} heads a "
+          "block".format(**one)
+          if one["block_rows"] else "", **one)
+      for name, one in sites.items())]
+
+
 class MLAMoELMModel(model_lib.Model):
   """Decoder-only mixture-of-experts LM over synthetic token streams."""
 
@@ -1326,60 +1361,85 @@ class MLAMoELMModel(model_lib.Model):
   @property
   def cfg(self) -> LMConfig:
     """The configuration with the share applied, read at first use (the
-    build, inside the run's trace session): one live span, one log line
-    that states the share, and the share's static counters."""
+    build, inside the run's trace session) under one live span."""
     if self._cfg is None:
-      from kf_benchmarks_tpu import tracing
-      from kf_benchmarks_tpu.utils import log as log_util
-      trace = tracing.active()
-      with trace.span("setup", "load_lm_config", config=self._share[0]):
-        self._cfg = c = load_lm_config(*self._share)
-      trace.set_static("moe", {
-          "experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
-          "buffer_rows": self._round_rows(c), "row_tile": self._row_tile(c),
-          "expert_matrices": c.expert_matrices})
-      core = self.attention_core_stats()
-      trace.set_static("attention", core)
-      # One kind of core: its fields; two: a table by kind.
-      for kind, one in ([("", core)] if "core_layers" in core else
-                        [(f" ({k})", v) for k, v in core.items()]):
-        log_util.log_fn(
-            f"attention core{kind}: {one['core_layers']} layer(s), " + (
-                "backward in {backward_kernel_passes} kernel pass a layer; "
-                "scores in blocks of {block}, the forward fetching "
-                "{block_q} queries x {block_kv} keys a grid step, "
-                "{block_kv_dkv} keys held across a backward sweep, "
-                "{dq_partials} partial dq summed outside the kernel"
-                if one["backward_kernel_passes"] else
-                "materialised scores (no kernel off the TPU)").format(**one)
-            + ("; window {window}, {query_heads} query heads over "
-               "{key_heads} key heads".format(**one)
-               if "key_heads" in one else "")
-            + ("; {tiles_visited} of {tiles_causal} causal score tiles "
-               "visited".format(**one) if "tiles_causal" in one else ""))
-      last = c.first_layer + c.layers_held - 1
-      held = (f"{c.mamba_layers} Mamba-2, {c.moe_layers} mixture, "
-              f"{len(c.attention_windows)} attention, one mixer a layer"
-              if c.layer_pattern else
-              f"{c.dense_layers} dense, {c.moe_layers} mixture, "
-              f"{c.num_nextn_predict_layers} MTP module(s)")
-      heads = (f"heads {c.first_head}-{c.first_head + c.heads_held - 1} of "
-               f"{c.num_attention_heads} (of {c.head_shards} over the "
-               "heads); " if c.head_shards > 1 else "")
-      log_util.log_fn(
-          f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
-          f"layers ({c.first_layer}-{last}: {held}); chip "
-          f"{c.shard_index} of {c.shards} per layer: {heads}experts "
-          f"{c.first_expert}-{c.first_expert + c.experts_held - 1} of "
-          f"{c.n_routed_experts} ({c.expert_matrices} matrices an expert); "
-          f"of {c.vocab_shards} over the vocabulary: rows "
-          f"0-{c.vocab_rows - 1} of {c.vocab_size}; sequence length "
-          f"{self.seq_len}; no exchange")
+      with tracing.active().span("setup", "load_lm_config",
+                                 config=self._share[0]):
+        self._cfg = load_lm_config(*self._share)
     return self._cfg
 
   @cfg.setter
   def cfg(self, value: LMConfig) -> None:
     self._cfg = value
+
+  def state_blocks(self, dtype):
+    """The run's static blocks, each ``stats[key]`` with its log lines,
+    stated through the run's trace where the module's type is known
+    (``RunTrace.state``: once, though the build makes a training and an
+    evaluation module). ``moe`` is the share (experts and vocabulary
+    rows held, a round's rows and their row tile) with what a round's
+    combine gathers, and from a table of which type; the others are the
+    ``*_stats`` below, stated where the configuration has them."""
+    c = self.cfg
+    tokens = self.get_batch_size() * self.seq_len
+    rows = self._round_rows(c)
+    moe = {"experts_held": c.experts_held, "vocab_rows": c.vocab_rows,
+           "buffer_rows": rows, "row_tile": self._row_tile(c),
+           "expert_matrices": c.expert_matrices,
+           "combine": expert_lib.combine_stats(
+               tokens, c.num_experts_per_tok, rows, c.hidden_size, dtype)}
+    say = lambda line: lambda block: [line.format(**block)]
+    blocks = (
+        ("moe", moe, lambda moe: [self._share_line(c), (
+            "moe combine: {gathers} gathers of {0} rows a round "
+            "({rows_gathered} rows) from the products' own {table_dtype} "
+            "rows ({table_bytes} bytes), weighted on the token side"
+        ).format(tokens, **moe["combine"])]),
+        ("attention", self.attention_core_stats(), _attention_lines),
+        ("lm_head", self.lm_head_stats(dtype), say(
+            "lm head: {losses} loss(es), float32 softmax over {chunk} "
+            "positions at a time; the kernel's gradient from products over "
+            "{rows_per_weight_grad_product} rows a loss, "
+            "{weight_grad_passes} passes over its float32 accumulator a "
+            "step, {dlogits_bytes_held} bytes of dlogits held")),
+        ("rotary", self.rotary_stats(dtype), _rotary_lines),
+        ("mamba", self.mamba_stats(dtype), say(
+            "mamba scan: {layers} layer(s), {heads} heads of {head_dim} over "
+            "{groups} groups, state {state}; {implementation} (kernel share "
+            "{kernel_share}), {chunks_per_sequence} chunks of {chunk} "
+            "positions a sequence, {carried_state_bytes_per_layer} bytes of "
+            "carried state and {residual_bytes_per_layer} kept for the "
+            "backward pass a layer")),
+        ("mhc", self.mhc_stats(dtype), say(
+            "mhc: {streams} residual streams of {hidden_size}, "
+            "{sublayers} sublayers behind a hyper-connection, Sinkhorn "
+            "{sinkhorn_iters} iterations (eps {eps}); {stream_bytes} bytes "
+            "of streams a sublayer; {implementation} (kernel share "
+            "{kernel_share})")))
+    trace = tracing.active()
+    for key, block, lines in blocks:
+      if block:
+        trace.state(key, block, *lines(block))
+
+  def _share_line(self, c: LMConfig) -> str:
+    last = c.first_layer + c.layers_held - 1
+    held = (f"{c.mamba_layers} Mamba-2, {c.moe_layers} mixture, "
+            f"{len(c.attention_windows)} attention, one mixer a layer"
+            if c.layer_pattern else
+            f"{c.dense_layers} dense, {c.moe_layers} mixture, "
+            f"{c.num_nextn_predict_layers} MTP module(s)")
+    heads = (f"heads {c.first_head}-{c.first_head + c.heads_held - 1} of "
+             f"{c.num_attention_heads} (of {c.head_shards} over the "
+             "heads); " if c.head_shards > 1 else "")
+    return (
+        f"mla_moe_lm share: {c.layers_held} of {c.num_hidden_layers} "
+        f"layers ({c.first_layer}-{last}: {held}); chip "
+        f"{c.shard_index} of {c.shards} per layer: {heads}experts "
+        f"{c.first_expert}-{c.first_expert + c.experts_held - 1} of "
+        f"{c.n_routed_experts} ({c.expert_matrices} matrices an expert); "
+        f"of {c.vocab_shards} over the vocabulary: rows "
+        f"0-{c.vocab_rows - 1} of {c.vocab_size}; sequence length "
+        f"{self.seq_len}; no exchange")
 
   def attention_core_stats(self):
     """The run's ``stats["attention"]``: what the core of every attention
@@ -1422,7 +1482,7 @@ class MLAMoELMModel(model_lib.Model):
     behind a hyper-connection, the bytes of the streams one sublayer
     reads and what the mixing runs at this job's shapes
     (``mhc.mhc_stats``: the plan, from the shapes, the streams' and the
-    coefficients' dtypes and the backend); what ``mhc_counter_stats``
+    coefficients' dtypes and the backend); what ``counter_blocks``
     adds to over the timed steps. None where the configuration has one
     stream."""
     c = self.cfg
@@ -1436,30 +1496,6 @@ class MLAMoELMModel(model_lib.Model):
                  jnp.dtype(dtype).itemsize},
                 **mhc.mhc_stats(tokens, c.hc_mult, c.hidden_size, dtype,
                                 self.mhc_dtype))
-
-  def _state_mhc(self, dtype):
-    """``stats["mhc"]`` and its log line, stated like the combine: where
-    the module's type is known, and once."""
-    from kf_benchmarks_tpu import tracing
-    from kf_benchmarks_tpu.utils import log as log_util
-    streams, trace = self.mhc_stats(dtype), tracing.active()
-    if streams is None or trace.static("mhc") == streams:
-      return
-    trace.set_static("mhc", streams)
-    log_util.log_fn(
-        "mhc: {streams} residual streams of {hidden_size}, "
-        "{sublayers} sublayers behind a hyper-connection, Sinkhorn "
-        "{sinkhorn_iters} iterations (eps {eps}); {stream_bytes} bytes "
-        "of streams a sublayer; {implementation} (kernel share "
-        "{kernel_share})".format(**streams))
-
-  def mhc_counter_stats(self, rows):
-    """``rows`` (steps, counters) of ``step_counters`` as what the timed
-    steps add to ``stats["mhc"]``: the largest Sinkhorn residual of any
-    step (None where the configuration has one stream)."""
-    if not self.cfg.hc_mult:
-      return None
-    return {"sinkhorn_residual": float(rows[:, len(MOE_COUNTERS)].max())}
 
   def counter_stats(self, rows):
     """``rows`` (steps, len(MOE_COUNTERS)) of ``moe_counters`` as the
@@ -1483,6 +1519,16 @@ class MLAMoELMModel(model_lib.Model):
           "tile boundary where the tile is shorter than the round)")
     return moe
 
+  def counter_blocks(self, rows):
+    """What the timed steps add to the run's blocks: ``moe``
+    (``counter_stats``) and, where there are streams, ``mhc``'s largest
+    Sinkhorn residual of any step."""
+    blocks = {"moe": self.counter_stats(rows)}
+    if self.cfg.hc_mult:
+      blocks["mhc"] = {
+          "sinkhorn_residual": float(rows[:, len(MOE_COUNTERS)].max())}
+    return blocks
+
   def make_module(self, nclass, phase_train, data_format="NHWC",
                   dtype=jnp.float32, param_dtype=jnp.float32):
     del nclass, phase_train, data_format
@@ -1491,11 +1537,7 @@ class MLAMoELMModel(model_lib.Model):
     # TPU only, and their outputs carry no vma type, which the step's
     # shard_map checker refuses (transformer_lm.py has the whole story).
     self.relax_shard_map_vma = on_tpu
-    self._state_combine(dtype)
-    self._state_lm_head(dtype)
-    self._state_rotary(dtype)
-    self._state_mamba(dtype)
-    self._state_mhc(dtype)
+    self.state_blocks(dtype)
     return MLAMoELM(cfg=self.cfg, dtype=dtype, param_dtype=param_dtype,
                     moe_impl="gmm" if on_tpu else "ragged_dot",
                     mhc_dtype=self.mhc_dtype)
@@ -1514,28 +1556,6 @@ class MLAMoELMModel(model_lib.Model):
         self.get_batch_size() * self.seq_len * c.num_experts_per_tok,
         c.experts_held, c.n_routed_experts)
 
-  def _state_combine(self, dtype):
-    """``stats["moe"]["combine"]`` and its log line: what a round's
-    combine gathers, and from a table of which type. Stated where the
-    module's type is known, beside the share's own counters (``cfg``),
-    and once (the build makes a training and an evaluation module)."""
-    from kf_benchmarks_tpu import tracing
-    from kf_benchmarks_tpu.utils import log as log_util
-    c, trace = self.cfg, tracing.active()
-    tokens = self.get_batch_size() * self.seq_len
-    combine = expert_lib.combine_stats(
-        tokens, c.num_experts_per_tok, self._round_rows(c), c.hidden_size,
-        dtype)
-    moe = trace.static("moe") or {}
-    if moe.get("combine") == combine:
-      return
-    trace.set_static("moe", dict(moe, combine=combine))
-    log_util.log_fn(
-        "moe combine: {gathers} gathers of {0} rows a round "
-        "({rows_gathered} rows) from the products' own {table_dtype} rows "
-        "({table_bytes} bytes), weighted on the token side".format(
-            tokens, **combine))
-
   def lm_head_stats(self, dtype):
     """The run's ``stats["lm_head"]``: the fused head's schedule at this
     job's shapes (``fused_loss.weight_grad_stats``; two losses where
@@ -1545,22 +1565,6 @@ class MLAMoELMModel(model_lib.Model):
         self.get_batch_size(), self.seq_len, self.loss_chunk, c.vocab_rows,
         2 if c.num_nextn_predict_layers else 1, dtype)
 
-  def _state_lm_head(self, dtype):
-    """``stats["lm_head"]`` and its log line, stated like the combine:
-    where the module's type is known, and once."""
-    from kf_benchmarks_tpu import tracing
-    from kf_benchmarks_tpu.utils import log as log_util
-    head, trace = self.lm_head_stats(dtype), tracing.active()
-    if trace.static("lm_head") == head:
-      return
-    trace.set_static("lm_head", head)
-    log_util.log_fn(
-        "lm head: {losses} loss(es), float32 softmax over {chunk} "
-        "positions at a time; the kernel's gradient from products over "
-        "{rows_per_weight_grad_product} rows a loss, "
-        "{weight_grad_passes} passes over its float32 accumulator a "
-        "step, {dlogits_bytes_held} bytes of dlogits held".format(**head))
-
   def rotary_stats(self, dtype):
     """The run's ``stats["rotary"]``: the rotary stage's call sites
     (``rotary_sites``) with what each runs at this job's shapes
@@ -1568,26 +1572,6 @@ class MLAMoELMModel(model_lib.Model):
     return {name: rotary.stage_stats(self.get_batch_size(), self.seq_len,
                                      dtype=dtype, **site)
             for name, site in rotary_sites(self.cfg).items()}
-
-  def _state_rotary(self, dtype):
-    """``stats["rotary"]`` and its log line, stated like the combine:
-    where the module's type is known, and once."""
-    from kf_benchmarks_tpu import tracing
-    from kf_benchmarks_tpu.utils import log as log_util
-    sites, trace = self.rotary_stats(dtype), tracing.active()
-    if not sites or trace.static("rotary") == sites:
-      return
-    trace.set_static("rotary", sites)
-    log_util.log_fn("attention rotary: " + "; ".join(
-        "{0} in {layers} layer(s): {heads} head(s) of {head_dim}, "
-        "{rot_dims} rotated, {1}, {implementation}{2}, "
-        "{bytes_read_and_written_per_call} bytes read and written a call, "
-        "{residual_bytes_per_layer} kept a layer".format(
-            name, "normed" if one["normed"] else "no norm",
-            " over {block_rows} positions of {block_heads} heads a "
-            "block".format(**one)
-            if one["block_rows"] else "", **one)
-        for name, one in sites.items()))
 
   def mamba_stats(self, dtype):
     """The run's ``stats["mamba"]``: the state-space scan at this job's
@@ -1601,22 +1585,12 @@ class MLAMoELMModel(model_lib.Model):
         c.mamba_head_dim, c.n_groups, c.ssm_state_size, c.chunk_size,
         c.mamba_layers, dtype)
 
-  def _state_mamba(self, dtype):
-    """``stats["mamba"]`` and its log line, stated like the combine:
-    where the module's type is known, and once."""
-    from kf_benchmarks_tpu import tracing
-    from kf_benchmarks_tpu.utils import log as log_util
-    scan, trace = self.mamba_stats(dtype), tracing.active()
-    if scan is None or trace.static("mamba") == scan:
-      return
-    trace.set_static("mamba", scan)
-    log_util.log_fn(
-        "mamba scan: {layers} layer(s), {heads} heads of {head_dim} over "
-        "{groups} groups, state {state}; {implementation} (kernel share "
-        "{kernel_share}), {chunks_per_sequence} chunks of {chunk} positions a sequence, "
-        "{carried_state_bytes_per_layer} bytes of carried state and "
-        "{residual_bytes_per_layer} kept for the backward pass a "
-        "layer".format(**scan))
+  def logits_bytes(self, batch_shape, dtype) -> int:
+    """The logits of the whole batch over the vocabulary rows held: two
+    losses go through one head kernel (ops/fused_loss.py), and neither
+    may hold them."""
+    return (int(batch_shape[0]) * int(batch_shape[1]) * self.cfg.vocab_rows
+            * jnp.dtype(dtype).itemsize)
 
   def get_input_shapes(self, subset):
     n = self.get_batch_size()

@@ -71,10 +71,20 @@ class Model:
     del batch_stats
     return None
 
-  def counter_stats(self, rows):
-    """``rows`` (timed steps, counters) of ``step_counters`` as the
-    run's table of them (``stats["moe"]`` for mla_moe_lm)."""
+  def counter_blocks(self, rows):
+    """``rows`` (timed steps, counters) of ``step_counters`` as what the
+    timed steps add to the run's blocks, by key: each is merged into the
+    ``stats`` entry of the block the model stated under that key
+    (``RunTrace.state``)."""
     del rows
+    return {}
+
+  def logits_bytes(self, batch_shape, dtype) -> Optional[int]:
+    """The bytes of the logits of a batch of ``batch_shape`` (the first
+    input's) over the vocabulary, at ``dtype``: the tensor a fused-head
+    language model's program must not materialise (the contract
+    auditor's bound). None for a model without one."""
+    del batch_shape, dtype
     return None
 
   def get_input_shapes(self, subset: str) -> Sequence[Sequence[int]]:

@@ -528,6 +528,10 @@ class TransformerLMModel(model_lib.Model):
                                 fsdp_block_hook=fsdp_block_hook,
                                 **tiling)
 
+  def logits_bytes(self, batch_shape, dtype) -> int:
+    """The (B, T, V) logits the fused head never materialises."""
+    return int(batch_shape[0]) * SEQ_LEN * VOCAB * jnp.dtype(dtype).itemsize
+
   def get_input_shapes(self, subset):
     n = self.get_batch_size()
     if self.packed:

@@ -354,7 +354,7 @@ class RunTrace:
         PHASE_SETUP: dict.fromkeys(COUNTER_KEYS, 0)}
     # Outermost compile intervals still standing: (t0, t1, totals row).
     self._compile_cover: List[Any] = []
-    # What the program states of itself at trace time (set_static).
+    # What the program states of itself (state).
     self._statics: Dict[str, Any] = {}
 
   # -- clock ------------------------------------------------------------------
@@ -655,18 +655,27 @@ class RunTrace:
 
   # -- static counters --------------------------------------------------------
 
-  def set_static(self, key: str, value: Dict[str, Any]) -> None:
-    """A counter the program computes from shapes while it is traced
-    (``factor_exchange``: layers, bytes kept off the all-reduce, bytes
-    gathered). SET, not added to: a step traced twice states the same
-    thing twice."""
+  def state(self, key: str, value: Dict[str, Any], *lines: str) -> None:
+    """A block the program states of itself from shapes, at the build or
+    while it is traced, and the run's ``stats[key]``: SET, not added to,
+    and ``lines`` logged only where ``value`` differs from what the trace
+    holds, so what two builds or two traces state alike is said once."""
     with self._lock:
+      if self._statics.get(key) == value:
+        return
       self._statics[key] = dict(value)
+    for line in lines:
+      self._log(line)
 
   def static(self, key: str) -> Optional[Dict[str, Any]]:
     with self._lock:
       value = self._statics.get(key)
       return dict(value) if value is not None else None
+
+  def statics(self) -> Dict[str, Dict[str, Any]]:
+    """Every block stated (``state``), by key."""
+    with self._lock:
+      return {k: dict(v) for k, v in self._statics.items()}
 
   def compile_mark(self):
     """The counters now; hand it to ``note_compile(since=...)`` after a
@@ -1083,11 +1092,14 @@ class _NullTrace:
   def begin_phase(self, phase: str) -> None:
     pass
 
-  def set_static(self, key: str, value) -> None:
+  def state(self, key: str, value, *lines: str) -> None:
     pass
 
   def static(self, key: str):
     return None
+
+  def statics(self) -> Dict[str, Any]:
+    return {}
 
   def span_totals(self) -> Dict[str, Any]:
     return {}
@@ -1172,7 +1184,7 @@ def session(trace: Optional[RunTrace] = None):
   """``trace`` (a fresh RunTrace by default) is the active one inside the
   block; what was active before is put back after it. For code that
   traces a step outside a run and wants what the step states of itself
-  (``set_static``): the contract auditor, tests."""
+  (``state``): the contract auditor, tests."""
   global _active
   outer, _active = _active, trace if trace is not None else RunTrace()
   try:

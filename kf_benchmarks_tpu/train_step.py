@@ -683,9 +683,8 @@ def _exchange(plan, strategy, grads, factor_plan):
       return reduce(grads)
     grads = _reduce_unclaimed(grads, factor_plan.claimed, reduce)
     counters = factor_plan.counters()
-    tracing.active().set_static("factor_exchange", counters)
-    from kf_benchmarks_tpu.utils import log as log_util
-    log_util.log_fn(
+    tracing.active().state(
+        "factor_exchange", counters,
         "factor exchange: %d dense layer(s) form the mean gradient "
         "from all-gathered factors: %.1f MB kept off the "
         "all-reduce, %.1f MB gathered instead" % (
@@ -1016,8 +1015,7 @@ def make_step_fns(model, module, eval_module, strategy, tx, lr_fn, params,
         "total_loss is not bit-identical to the replicated-param L2 "
         "on this model family (pass --weight_decay=0 for bit-exact "
         "A/Bs)")
-  tracing.active().set_static("factor_exchange",
-                              kungfu.NO_FACTOR_EXCHANGE)
+  tracing.active().state("factor_exchange", kungfu.NO_FACTOR_EXCHANGE)
   state_specs = TrainState(
       step=P(), params=P(axis_all), opt_state=P(axis_all),
       batch_stats=P(axis_all), loss_scale=P(),

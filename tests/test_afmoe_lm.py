@@ -466,7 +466,6 @@ def test_rotary_stage_of_the_trinity_cell_on_a_tpu(monkeypatch):
   # whatever the build's second module asks.
   from kf_benchmarks_tpu import params as params_lib
   from kf_benchmarks_tpu import tracing
-  from kf_benchmarks_tpu.utils import log as log_util
   model = lm.MLAMoELMModel(params_lib.make_params(
       model="mla_moe_lm", lm_config="trinity-mini", seq_len=8192,
       batch_size=1, lm_layers_held=5, lm_first_layer_held=1,
@@ -484,10 +483,9 @@ def test_rotary_stage_of_the_trinity_cell_on_a_tpu(monkeypatch):
   assert stats["window_q"]["bytes_read_and_written_per_call"] == 2 * 2 ** 26
   assert stats["window_q"]["residual_bytes_per_layer"] == 2 ** 26
   lines = []
-  monkeypatch.setattr(log_util, "log_fn", lines.append)
-  with tracing.session() as trace:
-    model._state_rotary(jnp.bfloat16)
-    model._state_rotary(jnp.bfloat16)     # the evaluation module's build
+  with tracing.session(tracing.RunTrace(log_fn=lines.append)) as trace:
+    model.state_blocks(jnp.bfloat16)
+    model.state_blocks(jnp.bfloat16)      # the evaluation module's build
     assert trace.static("rotary") == stats
   said = [ln for ln in lines if ln.startswith("attention rotary: ")]
   assert len(said) == 1
@@ -515,7 +513,7 @@ def test_lm_head_of_the_trinity_cell(monkeypatch):
   # group, so the kernel's gradient is 4 products over 2,048 rows where
   # a chunk a product made 16; and the one log line says so.
   from kf_benchmarks_tpu import params as params_lib
-  from kf_benchmarks_tpu.utils import log as log_util
+  from kf_benchmarks_tpu import tracing
   model = lm.MLAMoELMModel(params_lib.make_params(
       model="mla_moe_lm", lm_config="trinity-mini", seq_len=8192,
       batch_size=1, lm_layers_held=5, lm_first_layer_held=1,
@@ -526,8 +524,8 @@ def test_lm_head_of_the_trinity_cell(monkeypatch):
       "weight_grad_passes": 4, "dlogits_bytes_held": 2048 * 25024 * 2,
       "losses": 1}
   lines = []
-  monkeypatch.setattr(log_util, "log_fn", lines.append)
-  model._state_lm_head(jnp.bfloat16)
+  with tracing.session(tracing.RunTrace(log_fn=lines.append)):
+    model.state_blocks(jnp.bfloat16)
   assert [ln for ln in lines if ln.startswith("lm head: ")] == [
       "lm head: 1 loss(es), float32 softmax over 512 positions at a time; "
       "the kernel's gradient from products over 2048 rows a loss, 4 passes "
@@ -541,15 +539,16 @@ def test_attention_cores_of_the_trinity_cell_on_a_tpu(monkeypatch):
   # backward kernel pass a layer, and the window layers' kernels visit
   # fewer score tiles than the causal half at their own tiling.
   from kf_benchmarks_tpu import params as params_lib
+  from kf_benchmarks_tpu import tracing
   from kf_benchmarks_tpu.parallel import sequence
-  from kf_benchmarks_tpu.utils import log as log_util
   model = lm.MLAMoELMModel(params_lib.make_params(
       model="mla_moe_lm", lm_config="trinity-mini", seq_len=8192,
       batch_size=1, lm_layers_held=5, lm_first_layer_held=1,
       lm_layer_shards=8, device="cpu"))
   lines = []
-  monkeypatch.setattr(log_util, "log_fn", lines.append)
   monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  with tracing.session(tracing.RunTrace(log_fn=lines.append)):
+    model.state_blocks(jnp.bfloat16)
   assert model.cfg.windows == (2048, 2048, None, 2048, 2048)
   att = model.attention_core_stats()
   assert att["window"]["core_layers"] == 4 and att["full"]["core_layers"] == 1

@@ -250,6 +250,43 @@ def test_telemetry_never_interleaves_inside_step_lines(tmp_path):
   assert stats["num_steps"] == 6
 
 
+def test_a_block_a_model_states_reaches_the_stats_with_its_counts():
+  """A block under a key neither the harness nor the metrics registry
+  names: stated at the build (said once over the two modules), merged
+  with what the model's counters add to it over the timed steps."""
+  import jax.numpy as jnp
+  from kf_benchmarks_tpu import tracing
+  from kf_benchmarks_tpu.models import trivial_model
+  from kf_benchmarks_tpu.parallel import kungfu
+
+  class Counting(trivial_model.TrivialModel):
+    def make_module(self, *args, **kwargs):
+      tracing.active().state("novel_block", {"stated": 3}, "novel block: 3")
+      return super().make_module(*args, **kwargs)
+
+    def step_counters(self, batch_stats):
+      return jnp.asarray([1.0, 2.0], jnp.float32)
+
+    def counter_blocks(self, rows):
+      return {"novel_block": {"steps": int(rows.shape[0]),
+                              "counted": float(rows[:, 1].sum())}}
+
+  logs = []
+  orig, log_util.log_fn = log_util.log_fn, logs.append
+  try:
+    p = params_lib.make_params(model="trivial", num_batches=3,
+                               num_warmup_batches=1, device="cpu",
+                               display_every=1, batch_size=4)
+    stats = benchmark.BenchmarkCNN(p, model=Counting(params=p)).run()
+  finally:
+    log_util.log_fn = orig
+  assert stats["num_steps"] == 3
+  assert stats["novel_block"] == {"stated": 3, "steps": 3, "counted": 6.0}
+  assert logs.count("novel block: 3") == 1
+  # The step program's own block, stated by every build.
+  assert stats["factor_exchange"] == kungfu.NO_FACTOR_EXCHANGE
+
+
 def test_stats_carry_compile_and_dispatch_overhead():
   """The BENCH-trajectory fields (round 8): compile_s is the first
   dispatch call's wall time (blocks on trace+compile), and

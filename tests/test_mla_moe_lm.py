@@ -811,14 +811,15 @@ def test_attention_core_of_the_glm_cell_on_a_tpu(monkeypatch):
   # of 512 x 512, the forward fetching 1,024 queries and keys a grid
   # step, 1,024 keys held across the backward's sweep, four partial dq.
   from kf_benchmarks_tpu import params as params_lib
-  from kf_benchmarks_tpu.utils import log as log_util
+  from kf_benchmarks_tpu import tracing
   model = lm.MLAMoELMModel(params_lib.make_params(
       model="mla_moe_lm", seq_len=4096, batch_size=2, lm_layers_held=5,
       lm_layer_shards=8, device="cpu"))
   lines = []
-  monkeypatch.setattr(log_util, "log_fn", lines.append)
   monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-  assert model.cfg.layers_held == 5       # the first read states the core
+  with tracing.session(tracing.RunTrace(log_fn=lines.append)):
+    model.state_blocks(jnp.bfloat16)      # the build states the core
+  assert model.cfg.layers_held == 5
   assert [ln for ln in lines if ln.startswith("attention core: ")] == [
       "attention core: 6 layer(s), backward in 1 kernel pass a layer; "
       "scores in blocks of 512, the forward fetching 1024 queries x 1024 "

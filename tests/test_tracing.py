@@ -348,6 +348,27 @@ def test_active_registry_and_null_sink():
   assert tracing.active() is tracing.NULL_TRACE
 
 
+def test_state_sets_a_block_and_logs_it_once_a_value(monkeypatch):
+  lines = []
+  tr = tracing.RunTrace(log_fn=lines.append)
+  tr.state("block", {"a": 1}, "block: a 1", "block: its second line")
+  tr.state("block", {"a": 1}, "block: a 1")     # the evaluation build
+  assert lines == ["block: a 1", "block: its second line"]
+  assert tr.static("block") == {"a": 1}
+  tr.state("block", {"a": 2}, "block: a 2")     # SET, and said again
+  assert lines[2:] == ["block: a 2"]
+  tr.state("other", {"b": 0})
+  assert tr.statics() == {"block": {"a": 2}, "other": {"b": 0}}
+  tr.statics()["block"]["a"] = 9                # a copy
+  assert tr.static("block") == {"a": 2} and len(lines) == 3
+  # With no session the sink holds nothing and says nothing.
+  said = []
+  monkeypatch.setattr(log_util, "log_fn", said.append)
+  tracing.active().state("block", {"a": 1}, "block: a 1")
+  assert tracing.active().static("block") is None
+  assert tracing.active().statics() == {} and said == []
+
+
 def test_resolve_run_id_prefers_env(monkeypatch):
   monkeypatch.setenv("KF_RUN_ID", "run-fixed")
   assert tracing.resolve_run_id() == "run-fixed"
